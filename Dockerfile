@@ -1,8 +1,9 @@
 # Pinned test/dev environment for tensor2robot_tpu.
 # Reference parity: the reference shipped a docker/ + CI setup pinning
 # its TF1 environment (SURVEY.md §3 last row); this is the jax-era
-# equivalent. TPU production images swap jax for jax[tpu] at the same
-# pinned version.
+# equivalent. requirements.txt is the one installation — libtpu
+# included — for the CPU suite (this image) and for TPU machines
+# (`python chip_smoke.py`); only the environment below differs.
 #
 # Build:  docker build -t tensor2robot-tpu .
 # Test:   docker run --rm tensor2robot-tpu
@@ -25,6 +26,6 @@ RUN pip install -r requirements.txt
 
 COPY tensor2robot_tpu/ tensor2robot_tpu/
 COPY tests/ tests/
-COPY bench.py __graft_entry__.py ./
+COPY bench.py chip_smoke.py __graft_entry__.py ./
 
 CMD ["python", "-m", "pytest", "tests/", "-q"]

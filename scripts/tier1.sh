@@ -1,10 +1,11 @@
 #!/usr/bin/env bash
 # Tier-1 verify: the ROADMAP.md command VERBATIM (same log path, same
-# DOTS_PASSED accounting the driver greps), then the serving-bench
-# smoke (one small bucket table on CPU, no BENCH_DETAIL.json write) so
-# the serving bench path itself is exercised by tier-1 tooling, then
-# the coldstart-bench smoke (tiny cold/warm trainer probes against a
-# throwaway persistent compile cache, no BENCH_DETAIL.json write).
+# DOTS_PASSED accounting the driver greps), then every bench axis's
+# --dry-run smoke. All of it runs on the CPU (JAX_PLATFORMS=cpu) and
+# checks correctness and counters only; no number from here is a
+# device metric, and the non---dry-run bench refuses to start without
+# a TPU. The chip is exercised by `python chip_smoke.py` (README
+# "Running"); `python chip_smoke.py --rehearse-cpu` rehearses it here.
 #
 # Usage: scripts/tier1.sh   (from the repo root)
 set -u

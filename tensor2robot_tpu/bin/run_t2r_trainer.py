@@ -161,14 +161,12 @@ def main(argv):
 
 
 def _import_configurable_families() -> None:
+  # Every in-tree family's dependencies are part of the pinned
+  # installation (requirements.txt): one that fails to import is a
+  # bug, and skipping it would only move the error to the first
+  # config that names one of its configurables.
   for module in list(_DEFAULT_MODULES) + list(FLAGS.import_modules):
-    try:
-      importlib.import_module(module)
-    except ImportError as e:
-      if module in FLAGS.import_modules:
-        raise
-      # In-tree families are best-effort (optional deps may be absent).
-      print(f"Note: skipping {module}: {e}")
+    importlib.import_module(module)
 
 
 if __name__ == "__main__":

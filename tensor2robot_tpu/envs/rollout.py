@@ -403,8 +403,8 @@ def train_anakin(
   # touching the process-global tracer identity.
   metric_logger = MetricLogger(model_dir, role="anakin")
   hook_list = HookList(list(hooks))
-  from tensor2robot_tpu.startup.compile_cache import CompileWatch
-  CompileWatch.install_tap()
+  from tensor2robot_tpu.startup import compile_cache
+  compile_cache.configure_compilation_cache()
   # The always-on perf plane (ISSUE 15): resource watermarks + alert
   # sentinel per process, live MFU gauges at log cadence below.
   from tensor2robot_tpu.telemetry import perf as perf_lib
@@ -766,7 +766,10 @@ def train_anakin(
     anakin_step = jax.pmap(iteration, axis_name=POD_AXIS,
                            devices=devices, in_axes=(0, None),
                            donate_argnums=(0,))
-    state = jax.device_put_replicated(state, devices)
+    # One replica per pod device along a new leading axis, like the
+    # ring and the env states above; pmap places slice i on device i.
+    state = jax.tree_util.tree_map(
+        lambda x: jnp.broadcast_to(x[None], (d,) + x.shape), state)
   else:
     anakin_step = jax.jit(iteration, donate_argnums=(0,))
 

@@ -312,6 +312,7 @@ def front_main(config, front_index: int, root_address,
   role = f"front-{front_index}"
   injector = faults_lib.install(config, role)
   try:
+    proc.claim_device(role)
     state = _FrontState(config, front_index, injector)
     server = rpc_lib.RpcServer(state.handle, **_server_kwargs(config))
   except BaseException as e:

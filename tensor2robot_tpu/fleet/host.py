@@ -588,6 +588,7 @@ def host_main(config, ready_conn, stop_event, heartbeat,
   # from the first RPC.
   faults_lib.install(config, role)
   try:
+    proc.claim_device(role)
     state = _HostState(config, host_index=host_index)
     server = rpc_lib.RpcServer(state.handle, **_server_kwargs(config))
   except BaseException as e:

@@ -318,6 +318,7 @@ def learner_main(config, model_dir: str, address, heartbeat,
         maybe_initialize_distributed,
     )
     maybe_initialize_distributed()
+    proc.claim_device(plan["role"])
     tmetrics.gauge("fleet.learner_group.size").set(world_size)
     tmetrics.gauge("fleet.learner_group.rank").set(rank)
 

@@ -148,6 +148,7 @@ def pod_main(config, pod_index: int, address, stop_event,
   perf_lib.start_resource_sampler()
   injector = faults_lib.install(config, pod_id,
                                 incarnation=incarnation)
+  proc.claim_device(pod_id)
   rpc_kwargs = dict(
       authkey=config.authkey,
       call_timeout_secs=config.rpc_call_timeout_secs,

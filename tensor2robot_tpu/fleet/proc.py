@@ -14,13 +14,16 @@ DO want a distributed runtime (the learner with
 `FleetConfig.distributed_learner=True`) get a fresh ephemeral
 coordinator address handed to them explicitly by the orchestrator.
 
-Kept jax-free so actor processes can import it without paying the XLA
-runtime (pinned by tests/test_fleet.py).
+Kept jax-free at import so actor processes can import it without
+paying the XLA runtime (pinned by tests/test_fleet.py); only
+`claim_device`, which the JAX-using children call, imports jax.
 """
 
 from __future__ import annotations
 
 import os
+import sys
+import threading
 import time
 
 # The launch-contract variables `maybe_initialize_distributed` reads.
@@ -69,6 +72,51 @@ def adopt_coordinator(address: str, num_processes: int = 1,
   os.environ["JAX_COORDINATOR_ADDRESS"] = address
   os.environ["JAX_NUM_PROCESSES"] = str(num_processes)
   os.environ["JAX_PROCESS_ID"] = str(process_id)
+
+
+def claim_device(role: str, timeout_secs: float = 60.0) -> None:
+  """Initialises this child's JAX backend, or ends the process saying
+  why it could not.
+
+  A chip belongs to one process at a time, and every fleet host,
+  learner, front and pod is a JAX process: on a one-chip machine only
+  the first of them gets the chip, and the rest either raise inside
+  backend initialisation or block there. Either way the child exits
+  at once with the reason, instead of sitting silent until the
+  orchestrator's heartbeat timer (300 s) calls it hung. The fleet is
+  not brought up on a chip machine yet; it runs with
+  `JAX_PLATFORMS=cpu`.
+  """
+  outcome = {}
+
+  def initialise():
+    try:
+      import jax
+      outcome["devices"] = jax.devices()
+    except Exception as e:  # noqa: BLE001 — reported below, then fatal
+      outcome["error"] = e
+
+  thread = threading.Thread(target=initialise, daemon=True,
+                            name="claim-device")
+  thread.start()
+  thread.join(timeout_secs)
+  if "devices" in outcome:
+    return
+  reason = (repr(outcome["error"]) if "error" in outcome else
+            f"backend initialisation still blocked after "
+            f"{timeout_secs:.0f} s")
+  message = (
+      f"fleet {role}: no JAX device for this process ({reason}). A chip "
+      "belongs to one process at a time and every fleet host, learner, "
+      "front and pod is a JAX process; the fleet is not brought up on "
+      "a chip machine yet — run it with JAX_PLATFORMS=cpu.")
+  if "error" in outcome:
+    raise SystemExit(message)
+  # Blocked inside the runtime: no exception will ever unwind this
+  # process, and interpreter shutdown would wait on the same lock.
+  sys.stderr.write(message + "\n")
+  sys.stderr.flush()
+  os._exit(1)
 
 
 def beat(heartbeat) -> None:

@@ -22,7 +22,8 @@ operands — the same contract as the XLA path, verified to bf16
 tolerance against it in tests (interpret mode on CPU, compiled on
 TPU).
 
-MEASURED OUTCOME (v5e, bench primary config): the fused kernel runs
+MEASURED OUTCOME (v5e, bench primary config, on an earlier
+installation; not measured on this one): the fused kernel runs
 the tail in 3.09 ms vs 1.12 ms for the tuned XLA P-major formulation
 in `GraspingQNetwork.score_population` (3.84 vs 1.29 ms at 128-wide
 channels — width doesn't flip it). The kernel's per-state loop,

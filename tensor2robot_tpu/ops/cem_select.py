@@ -9,7 +9,10 @@ q-head MLP applied to the pooled population features, a RUNNING top-k
 over sample blocks (flash-attention-style: merge each block's
 candidates into the kept elite set, so no full score tensor ever
 exists), and the elite mean/std/best reduction — one HBM read of the
-pooled features, four [B, ·] rows out.
+pooled features, four [B, ·] rows out. In the kernel the population
+index stays on the leading (untiled) axis with states on sublanes and
+features on lanes, so the per-state top-k is elementwise work across
+vregs and nothing is relaid out between sublanes and lanes.
 
 Selection semantics are EXACTLY `lax.top_k`'s: ties broken toward the
 lower sample index. The running merge preserves that globally because
@@ -20,9 +23,10 @@ Numerics: MLP GEMMs accumulate in f32 (`preferred_element_type`) from
 the caller's operand dtype; all selection/statistics math is f32. The
 `cem_select_lax` reference implements the identical contract in plain
 lax and is the parity oracle for the interpret-mode CPU tests; on
-hardware the compiled kernel is gated by `bench.py --mfu` / `--verify`
-(tolerances in the `ops/flash_attention.py` style — interpret exact,
-hardware at MXU-epsilon bars).
+hardware the compiled kernel is checked against it by `chip_smoke.py`
+and `bench.py --verify` at the flagship shape (first compiled on a
+v5e in PR 21: exact agreement). Its speed against the lax path is not
+measured; `cem_select="lax"` stays the default.
 """
 
 from __future__ import annotations
@@ -35,7 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 _NEG_INF = float("-inf")
-_LANES = 128
 
 
 def _mlp_f32(x, flat_dense):
@@ -56,70 +59,78 @@ def _mlp_f32(x, flat_dense):
 def _select_top(scores, actions, num_elites):
   """Iterative top-k with lax.top_k tie semantics (first index wins).
 
-  scores [N, 1] f32 (−inf = masked), actions [N, A] f32. Returns
-  (top_scores [E, 1], top_actions [E, A]) in descending score order.
+  scores [N, Bb, 1] f32, actions [N, Bb, A] f32: candidates on the
+  LEADING axis, states on sublanes. Returns (top_scores [E, Bb, 1],
+  top_actions [E, Bb, A]) in descending score order per state.
   """
   n = scores.shape[0]
-  idx = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+  idx = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
   top_s, top_a = [], []
   work = scores
   for _ in range(num_elites):
-    m = jnp.max(work)
-    first = jnp.min(jnp.where(work == m, idx, n))
-    onehot = (idx == first).astype(jnp.float32)  # [N, 1]
-    top_s.append(m.reshape(1, 1))
-    top_a.append(jnp.sum(onehot * actions, axis=0, keepdims=True))
-    work = jnp.where(onehot > 0, _NEG_INF, work)
+    m = jnp.max(work, axis=0, keepdims=True)               # [1, Bb, 1]
+    first = jnp.min(jnp.where(work == m, idx, n), axis=0,
+                    keepdims=True)
+    hit = idx == first                                     # [N, Bb, 1]
+    top_s.append(m)
+    top_a.append(jnp.sum(jnp.where(hit, actions, 0.0), axis=0,
+                         keepdims=True))
+    work = jnp.where(hit, _NEG_INF, work)
   return jnp.concatenate(top_s, axis=0), jnp.concatenate(top_a, axis=0)
 
 
-def _cem_select_kernel(pooled_ref, samples_ref, *rest, block_b: int,
-                       p: int, c: int, a_dim: int, num_elites: int,
-                       block_p: int, min_std: float, sigmoid: bool,
-                       compute_dtype):
-  """One grid cell: `block_b` states' full populations → elite stats."""
-  flat_dense = rest[:-1]
-  out_ref = rest[-1]
-  chunks = -(-p // block_p)  # ceil
-  p_pad = chunks * block_p
+def _cem_select_kernel(pooled_ref, samples_ref, *rest, p: int,
+                       num_elites: int, block_p: int, min_std: float,
+                       sigmoid: bool):
+  """One grid cell: `Bb` states' full populations → elite stats.
 
-  for b in range(block_b):
-    x = pooled_ref[:, b].astype(compute_dtype)        # [P, C]
-    acts = samples_ref[b].astype(jnp.float32)         # [P, A]
-    if p_pad != p:
-      x = jnp.concatenate(
-          [x, jnp.zeros((p_pad - p, c), x.dtype)], axis=0)
-      acts = jnp.concatenate(
-          [acts, jnp.zeros((p_pad - p, a_dim), acts.dtype)], axis=0)
+  Laid out for Mosaic: the population index stays on the LEADING
+  (untiled) axis from the HBM block to the last reduction, states sit
+  on sublanes and features on lanes — so the per-state top-k is
+  elementwise work across vregs, sample blocks are free leading-axis
+  slices, and no value is ever moved between sublanes and lanes.
+  """
+  hidden, (w_last, b_last) = rest[:-6], rest[-6:-4]
+  mean_ref, std_ref, best_a_ref, best_s_ref = rest[-4:]
+  block_b, c = pooled_ref.shape[1:]
+  a_dim = samples_ref.shape[-1]
 
-    top_s = jnp.full((num_elites, 1), _NEG_INF, jnp.float32)
-    top_a = jnp.zeros((num_elites, a_dim), jnp.float32)
-    for ci in range(chunks):
-      lo = ci * block_p
-      s = _mlp_f32(x[lo:lo + block_p], flat_dense)     # [bp, 1]
-      if sigmoid:
-        s = jax.nn.sigmoid(s)
-      row = lo + jax.lax.broadcasted_iota(jnp.int32, (block_p, 1), 0)
-      s = jnp.where(row < p, s, _NEG_INF)
-      # Merge kept elites with this block; kept entries come FIRST in
-      # combined order, so a tie between a kept elite (earlier global
-      # index by construction) and a new candidate resolves to the
-      # kept one — the global lax.top_k tie order.
-      comb_s = jnp.concatenate([top_s, s], axis=0)
-      comb_a = jnp.concatenate([top_a, acts[lo:lo + block_p]], axis=0)
-      top_s, top_a = _select_top(comb_s, comb_a, num_elites)
+  # MLP over all P·Bb rows at once; hidden layers on the MXU, the
+  # width-1 output layer as a lane reduction (an N=1 matmul would pad
+  # to a full MXU pass for one useful column).
+  h = pooled_ref[...].reshape(p * block_b, c)
+  for layer in range(len(hidden) // 2):
+    w, b = hidden[2 * layer], hidden[2 * layer + 1]
+    h = jax.lax.dot_general(
+        h, w[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) + b[...].astype(jnp.float32)
+    h = jnp.maximum(h, 0.0).astype(pooled_ref.dtype)
+  scores = jnp.sum(
+      h.astype(jnp.float32) * w_last[...].astype(jnp.float32),
+      axis=1, keepdims=True) + b_last[...].astype(jnp.float32)
+  if sigmoid:
+    scores = jax.nn.sigmoid(scores)
+  scores = scores.reshape(p, block_b, 1)
+  acts = samples_ref[...]                                  # [P, Bb, A]
 
-    mean = jnp.mean(top_a, axis=0, keepdims=True)       # [1, A]
-    var = jnp.mean((top_a - mean) ** 2, axis=0, keepdims=True)
-    std = jnp.maximum(jnp.sqrt(var), min_std)
-    pad = jnp.zeros((1, _LANES - a_dim), jnp.float32)
-    rows = jnp.concatenate([
-        jnp.concatenate([mean, pad], axis=1),
-        jnp.concatenate([std, pad], axis=1),
-        jnp.concatenate([top_a[0:1], pad], axis=1),
-        jnp.broadcast_to(top_s[0:1], (1, _LANES)),
-    ], axis=0)                                          # [4, 128]
-    out_ref[b] = rows
+  top_s = jnp.full((num_elites, block_b, 1), _NEG_INF, jnp.float32)
+  top_a = jnp.zeros((num_elites, block_b, a_dim), jnp.float32)
+  for lo in range(0, p, block_p):
+    hi = min(lo + block_p, p)
+    # Merge kept elites with this block; kept entries come FIRST in
+    # combined order, so a tie between a kept elite (earlier global
+    # index by construction) and a new candidate resolves to the
+    # kept one — the global lax.top_k tie order.
+    top_s, top_a = _select_top(
+        jnp.concatenate([top_s, scores[lo:hi]], axis=0),
+        jnp.concatenate([top_a, acts[lo:hi]], axis=0), num_elites)
+
+  mean = jnp.mean(top_a, axis=0)                           # [Bb, A]
+  var = jnp.mean((top_a - mean[None]) ** 2, axis=0)
+  mean_ref[...] = mean
+  std_ref[...] = jnp.maximum(jnp.sqrt(var), min_std)
+  best_a_ref[...] = top_a[0]
+  best_s_ref[...] = top_s[0]
 
 
 @functools.partial(
@@ -134,7 +145,7 @@ def fused_cem_select(
     sigmoid: bool = False,
     interpret: bool = False,
     block_p: int = 64,
-    block_b: int = 2,
+    block_b: int = 16,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
   """Fused CEM iteration tail. Returns (mean, std, best_action,
   best_score) — mean/std/best_action [B, A] f32, best_score [B] f32.
@@ -150,48 +161,56 @@ def fused_cem_select(
     sigmoid: apply sigmoid to scores before selection (the
       `sigmoid_q` grasp-success head semantics; monotone, so selection
       is unchanged but best_score is reported on the sigmoid scale).
-    interpret: pallas interpret mode (CPU tests).
+    interpret: pallas interpret mode (every backend but TPU).
     block_p: sample-block width of the running top-k; P need NOT be a
-      multiple (the tail block is index-masked to −inf).
-    block_b: states per grid cell; falls back to 1 when B % block_b.
+      multiple (the tail block is simply shorter).
+    block_b: states per grid cell; B is zero-padded up to a multiple.
+      Compiled, it must be a multiple of the sublane tile of
+      `pooled.dtype` (8 for f32, 16 for bf16) or cover all of B.
   """
   p, b, c = pooled.shape
   if samples.shape[:2] != (b, p):
     raise ValueError(f"samples {samples.shape} != [B={b}, P={p}, A]")
   a_dim = samples.shape[-1]
-  if a_dim > _LANES:
-    raise ValueError(f"action_dim {a_dim} > {_LANES} unsupported")
   if num_elites > p:
     raise ValueError(f"num_elites {num_elites} > population {p}")
   if dense_params[-1][0].shape[-1] != 1:
     raise ValueError("q-head MLP must end at width 1")
-  block_b = block_b if b % block_b == 0 else 1
   block_p = min(block_p, max(p, 1))
+  # P-major like `pooled` (a [B, P, A] f32 transpose — P·A floats per
+  # state, noise next to the pooled features).
+  samples = samples.astype(jnp.float32).transpose(1, 0, 2)
+  b_pad = -(-b // block_b) * block_b
+  if b_pad != b:
+    pooled = jnp.pad(pooled, ((0, 0), (0, b_pad - b), (0, 0)))
+    samples = jnp.pad(samples, ((0, 0), (0, b_pad - b), (0, 0)))
 
   flat_dense = []
-  for w, bias in dense_params:
+  for w, bias in dense_params[:-1]:
     flat_dense += [w, bias.reshape(1, -1)]
+  w_last, b_last = dense_params[-1]
+  flat_dense += [w_last.reshape(1, -1), b_last.reshape(1, 1)]
 
   kernel = functools.partial(
-      _cem_select_kernel, block_b=block_b, p=p, c=c, a_dim=a_dim,
-      num_elites=num_elites, block_p=block_p, min_std=min_std,
-      sigmoid=sigmoid, compute_dtype=pooled.dtype)
-  full = lambda *shape: pl.BlockSpec(  # noqa: E731
-      shape, lambda i: (0,) * len(shape))
-  out = pl.pallas_call(
+      _cem_select_kernel, p=p, num_elites=num_elites, block_p=block_p,
+      min_std=min_std, sigmoid=sigmoid)
+  full = lambda x: pl.BlockSpec(x.shape, lambda i: (0,) * x.ndim)  # noqa: E731
+  per_state = lambda width: pl.BlockSpec(  # noqa: E731
+      (block_b, width), lambda i: (i, 0))
+  rows = lambda width: jax.ShapeDtypeStruct(  # noqa: E731
+      (b_pad, width), jnp.float32)
+  mean, std, best_action, best_score = pl.pallas_call(
       kernel,
-      grid=(b // block_b,),
+      grid=(b_pad // block_b,),
       in_specs=[
           pl.BlockSpec((p, block_b, c), lambda i: (0, i, 0)),
-          pl.BlockSpec((block_b, p, a_dim), lambda i: (i, 0, 0)),
-      ] + [full(*x.shape) for x in flat_dense],
-      out_specs=pl.BlockSpec((block_b, 4, _LANES),
-                             lambda i: (i, 0, 0)),
-      out_shape=jax.ShapeDtypeStruct((b, 4, _LANES), jnp.float32),
+          pl.BlockSpec((p, block_b, a_dim), lambda i: (0, i, 0)),
+      ] + [full(x) for x in flat_dense],
+      out_specs=[per_state(a_dim)] * 3 + [per_state(1)],
+      out_shape=[rows(a_dim)] * 3 + [rows(1)],
       interpret=interpret,
-  )(pooled, samples.astype(jnp.float32), *flat_dense)
-  return (out[:, 0, :a_dim], out[:, 1, :a_dim], out[:, 2, :a_dim],
-          out[:, 3, 0])
+  )(pooled, samples, *flat_dense)
+  return mean[:b], std[:b], best_action[:b], best_score[:b, 0]
 
 
 def cem_select_lax(

@@ -118,8 +118,7 @@ def _maybe_enable_cpu_collectives() -> None:
   backend's cross-process collectives, so it is selected whenever the
   CPU backend could end up primary: platforms unset (auto-detect on a
   CPU-only host) or explicitly naming cpu. Only an explicit
-  accelerator-only selection (e.g. `JAX_PLATFORMS=tpu`) skips it; on
-  jax builds without the option this degrades to the old behavior.
+  accelerator-only selection (e.g. `JAX_PLATFORMS=tpu`) skips it.
   """
   import jax
 
@@ -127,8 +126,4 @@ def _maybe_enable_cpu_collectives() -> None:
                or str(getattr(jax.config, "jax_platforms", None) or ""))
   if platforms and "cpu" not in platforms.lower():
     return  # accelerator-only selection: CPU backend never primary
-  try:
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-  except Exception:  # older/newer jax: option renamed or absent
-    log.warning("could not select gloo CPU collectives; multi-process "
-                "CPU runs may fail", exc_info=True)
+  jax.config.update("jax_cpu_collectives_implementation", "gloo")

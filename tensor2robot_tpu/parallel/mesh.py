@@ -73,19 +73,11 @@ def create_mesh(
 
 
 def shard_map_compat(body, mesh: Mesh, *, in_specs, out_specs):
-  """`shard_map` across jax versions: the top-level `jax.shard_map`
-  binding (with `check_vma`) only exists in newer jaxes; older ones
-  ship it under `jax.experimental.shard_map` with the `check_rep`
-  spelling. The replication check is disabled either way (pmean'd
-  scalars the framework returns from per-device bodies are
-  legitimately replicated, but the checker can't always prove it)."""
-  if hasattr(jax, "shard_map"):
-    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-  from jax.experimental.shard_map import shard_map
-
-  return shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+  """`jax.shard_map` with the replication check off: pmean'd scalars
+  the framework returns from per-device bodies are legitimately
+  replicated, but the checker can't always prove it."""
+  return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

@@ -99,7 +99,8 @@ class QTOptLearner:
     cem_select: "lax" (top_k + gather, exact reference) or "fused" —
       scoring + running arg-top-k + elite stats run as one Pallas
       kernel (`ops.fused_cem_select`) through `cem_maximize`'s
-      select_fn seam; interpret-mode on CPU backends.
+      select_fn seam; compiled on TPU, interpreted on every other
+      backend (decided when the step is traced).
     """
     if cem_inference not in ("bf16", "int8"):
       raise ValueError(f"cem_inference={cem_inference!r} not in "
@@ -119,9 +120,6 @@ class QTOptLearner:
     self._cem_inference = cem_inference
     self._cem_select = cem_select
     self._act_scales: Optional[Dict[str, float]] = None
-    # Pallas compiles Mosaic on TPU only; every other backend runs the
-    # fused kernel through the interpreter (exact, just not fast).
-    self._fused_interpret = jax.default_backend() != "tpu"
 
   @property
   def model(self) -> GraspingQModel:
@@ -182,6 +180,11 @@ class QTOptLearner:
     self._act_scales = net_lib.scales_from_stats(
         jax.device_get(stats))
     return self._act_scales
+
+  def set_activation_scales(self, scales: Dict[str, float]) -> None:
+    """Adopts scales an earlier `calibrate()` produced (a resumed run
+    keeps the constants its first start traced with)."""
+    self._act_scales = {k: float(v) for k, v in scales.items()}
 
   def ensure_calibrated(self, state) -> None:
     """Calibrates from a spec-random batch when nothing better ran —
@@ -254,10 +257,13 @@ class QTOptLearner:
     sigmoid = self._model.sigmoid_q
 
     def select_fn(actions, min_std):
+      # Traced, so the backend is already up (a constructor gin calls
+      # must not claim the chip). Mosaic compiles on TPU only; every
+      # other backend runs the kernel through the interpreter.
       return fused_cem_select(
           pool_fn(actions), actions, dense,
           num_elites=self._cem_elites, min_std=min_std,
-          sigmoid=sigmoid, interpret=self._fused_interpret)
+          sigmoid=sigmoid, interpret=jax.default_backend() != "tpu")
 
     return None, select_fn
 

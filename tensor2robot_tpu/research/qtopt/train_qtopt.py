@@ -9,6 +9,7 @@ async SavedModel export the supervised trainer uses.
 
 from __future__ import annotations
 
+import json
 import logging
 import os
 import time
@@ -33,6 +34,30 @@ from tensor2robot_tpu.train_eval import MetricLogger
 from tensor2robot_tpu.utils import checkpoints as ckpt_lib
 
 log = logging.getLogger(__name__)
+
+ACT_SCALES_FILE = "int8_act_scales.json"
+
+
+def _calibrate_once_per_run(learner, state, batch, model_dir: str,
+                            write: bool) -> None:
+  """Calibrates the int8 activation scales on a run's FIRST start and
+  keeps them beside its checkpoints; every resume adopts them.
+
+  The scales are trace-time constants of the train step: a resume that
+  recalibrated on its restored params would train a different program
+  than the run it continues — and compile it again, where every other
+  program of a restart comes out of the persistent cache.
+  """
+  path = os.path.join(model_dir, ACT_SCALES_FILE)
+  if os.path.exists(path):
+    with open(path) as f:
+      learner.set_activation_scales(json.load(f))
+    return
+  scales = learner.calibrate(state, batch)
+  if write:
+    with open(path + ".tmp", "w") as f:
+      json.dump(scales, f)
+    os.replace(path + ".tmp", path)
 
 
 @gin.configurable
@@ -64,11 +89,9 @@ def train_qtopt(
   `iterations_per_loop` (SURVEY.md §4.1: "the hot loop"): K train
   steps run as ONE device program per host call — a `lax.scan` over K
   host-stacked replay batches — so host/dispatch latency is paid once
-  per K steps instead of every step (on a tunneled or remote-host
-  chip, per-step dispatch caps throughput an order of magnitude below
-  the chip's measured rate). The reference's quantization semantics
-  apply: every cadence (log, checkpoint, max steps) must be a
-  multiple of K, per-step hooks observe only each dispatch's LAST
+  per K steps instead of every step. The reference's quantization
+  semantics apply: every cadence (log, checkpoint, max steps) must be
+  a multiple of K, per-step hooks observe only each dispatch's LAST
   metrics, and the per-step PRNG stream is identical to K=1 (folded
   by absolute step inside the scan).
 
@@ -122,11 +145,11 @@ def train_qtopt(
   chief = jax.process_index() == 0
   metric_logger = MetricLogger(model_dir) if chief else None
   hook_list = HookList(list(hooks))
-  # Compile-cache traffic → telemetry registry (the CompileWatch tap):
-  # a warm-path recompile lands in this loop's log, not only under
-  # bench --coldstart.
-  from tensor2robot_tpu.startup.compile_cache import CompileWatch
-  CompileWatch.install_tap()
+  # Places the persistent compile cache and taps its traffic into the
+  # telemetry registry: a warm-path recompile lands in this loop's
+  # log, not only under bench --coldstart.
+  from tensor2robot_tpu.startup import compile_cache
+  compile_cache.configure_compilation_cache()
   # The always-on perf plane (ISSUE 15): resource watermarks sampled
   # per process, sentinel rules evaluated at log cadence, and the live
   # MFU gauges published below (the PerfMeter built once the state
@@ -197,7 +220,9 @@ def train_qtopt(
   # replay batch BEFORE the step is traced (the scales are trace-time
   # constants; see QTOptLearner.calibrate / docs/PERF.md).
   if getattr(learner, "needs_calibration", False):
-    learner.calibrate(state, replay_buffer.sample(batch_size))
+    _calibrate_once_per_run(learner, state,
+                            replay_buffer.sample(batch_size), model_dir,
+                            write=chief)
 
   writer = ckpt_lib.CheckpointWriter(
       model_dir, max_to_keep=max_checkpoints_to_keep)

@@ -109,19 +109,13 @@ class ModelArena:
         larger than the whole budget is a configuration error and
         raises at load.
       cache_dir: persistent XLA compilation-cache directory for warm
-        reloads (forwarded to `configure_compilation_cache`; None
-        keeps the process's current cache config — gin/env). Without
-        a cache configured, reloads RECOMPILE and the arena logs a
-        warning once: eviction is then a latency cliff, not a shuffle.
+        reloads, forwarded to `configure_compilation_cache` (whose
+        placement contract applies: ignored under
+        `JAX_COMPILATION_CACHE_DIR`; None keeps the process's cache).
     """
     from tensor2robot_tpu.startup import compile_cache
     self._compile_cache = compile_cache
     compile_cache.configure_compilation_cache(cache_dir=cache_dir)
-    if compile_cache.cache_dir() is None:
-      log.warning(
-          "ModelArena without a persistent compilation cache: evicted "
-          "tenants will RECOMPILE on reload (set ModelArena.cache_dir "
-          "or %s).", compile_cache.ENV_CACHE_DIR)
     self._budget = None if budget_bytes is None else int(budget_bytes)
     self._specs: Dict[str, _TenantSpec] = {}
     # Structural lock: guards the spec/resident tables and the LRU

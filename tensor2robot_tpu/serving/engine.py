@@ -121,10 +121,6 @@ class BucketedServingEngine:
     self._state_avals = jax.tree_util.tree_map(
         compile_cache.aval_of, placed)
     self._compiled: Dict[int, Any] = {}
-    # Donation is disabled when the persistent cache is live on CPU —
-    # see compile_cache.donation_unsafe_with_cache (jaxlib heap bug).
-    if compile_cache.donation_unsafe_with_cache():
-      donate_features = False
     donate = (1,) if donate_features else ()
     self._jitted = jax.jit(fn, donate_argnums=donate)
     self._swap_lock = threading.Lock()

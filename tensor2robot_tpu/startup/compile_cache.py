@@ -6,11 +6,20 @@ serialized executable under `jax_compilation_cache_dir`; a process that
 re-traces the same program skips XLA entirely and deserializes the
 cached binary (the pjit/TPUv4 scaling work, arXiv:2204.06514, is what
 makes frequent restarts affordable at pod scale). This module is the
-ONE place the cache is configured — trainer, predictors, serving
-engine, and bench all call `configure_compilation_cache()` so a fleet
-config is a single gin binding (or env var) away:
+ONE place the cache is placed — all three train loops, the predictor,
+the serving engine and bench call `configure_compilation_cache()`.
 
-    configure_compilation_cache.cache_dir = "/mnt/fleet/xla-cache"
+Placement contract (the directory is part of the cache key, so it must
+not move between the processes that are meant to share it):
+
+  * `JAX_COMPILATION_CACHE_DIR` set — jax reads it itself (it is the
+    default of the `jax_compilation_cache_dir` flag). This module
+    creates the directory and NEVER updates that flag, whatever gin or
+    a caller passes.
+  * unset — the cache lives at `DEFAULT_CACHE_DIR`, one fixed path
+    inside the checkout resolved from the package location. An
+    explicit `cache_dir=` may override only in this case (tests, the
+    cold/warm probes).
 
 `CompileWatch` taps `jax.monitoring` for the cache's hit/miss events —
 the proof obligation for every warm-start claim in this repo is
@@ -25,22 +34,25 @@ import threading
 from typing import Optional
 
 import jax
+from jax.experimental.compilation_cache import (
+    compilation_cache as jax_compilation_cache,
+)
 
 from tensor2robot_tpu import config as gin
 from tensor2robot_tpu.telemetry import metrics as tmetrics
 
 log = logging.getLogger(__name__)
 
-ENV_CACHE_DIR = "T2R_COMPILATION_CACHE_DIR"
+# jax's own variable: the default of its `jax_compilation_cache_dir` flag.
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-# jax.monitoring event names (stable across the jax versions we pin).
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 _CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 _BACKEND_COMPILE_DURATION = "/jax/core/compile/backend_compile_duration"
-
-_configured: Optional[tuple] = None  # (dir, min_entry_size, min_secs)
-_configured_dir: Optional[str] = None
 
 
 def aval_of(x):
@@ -57,68 +69,42 @@ def aval_of(x):
 
 
 @gin.configurable
-def configure_compilation_cache(
-    cache_dir: Optional[str] = None,
-    min_entry_size_bytes: int = -1,
-    min_compile_time_secs: float = 0.0,
-) -> Optional[str]:
-  """Points jax's persistent compilation cache at `cache_dir`.
+def configure_compilation_cache(cache_dir: Optional[str] = None) -> str:
+  """Places jax's persistent compilation cache; returns its directory.
 
-  Idempotent and safe to call from every entry point (trainer,
-  predictor, serving engine, bench): unconfigured (no gin binding, no
-  `T2R_COMPILATION_CACHE_DIR` env var, no explicit arg) it is a no-op
-  returning None; configured, it creates the directory and sets the
-  three jax knobs. Call order vs. jit does not matter — jax consults
-  the config at each compile.
+  Idempotent and safe to call from every entry point. Call order vs.
+  jit does not matter — a directory change resets jax's
+  once-per-process cache latch (`_reset_jax_cache_latch`). Every
+  executable is persisted, however small or quick to compile: restart
+  latency is the point, and a warm start is proven by ZERO misses.
 
   Args:
-    cache_dir: cache directory; falls back to the env var. None
-      disables (leaves jax's current setting untouched so an outer
-      harness's cache survives).
-    min_entry_size_bytes: smallest executable worth persisting
-      (-1: everything — restart latency is the point here, so even
-      tiny programs pay their way).
-    min_compile_time_secs: only persist compiles slower than this
-      (0.0: everything, same rationale).
-
-  Returns the resolved cache dir (None when disabled).
+    cache_dir: explicit directory. Honoured only when
+      `JAX_COMPILATION_CACHE_DIR` is unset (module docstring); it then
+      stays in force for later no-arg calls in the process, so a
+      library entry point (train loop, serving engine) never re-points
+      a probe's or a test's cache.
   """
-  global _configured, _configured_dir
-  # Every entry point that wires the cache also gets the registry tap
-  # (cache dir or not): compile traffic is telemetry either way.
+  # Every entry point that places the cache also gets the registry tap.
   CompileWatch.install_tap()
-  if not cache_dir:
-    # The env var is a DEFAULT, not an override: once any caller has
-    # configured a cache explicitly (a bench probe's throwaway dir, a
-    # test fixture), a later no-arg call from a library entry point
-    # (train_eval_model, the serving engine) must keep it — not
-    # silently re-point the process at the fleet cache.
-    if _configured is not None:
-      return _configured_dir
-    cache_dir = os.environ.get(ENV_CACHE_DIR)
-  if not cache_dir:
-    return _configured_dir
-  cache_dir = os.path.abspath(cache_dir)
-  os.makedirs(cache_dir, exist_ok=True)
-  # Idempotence keys on ALL the knobs, not just the dir: an entry
-  # point that configures with defaults first must not swallow a later
-  # explicit reconfiguration of the min-entry thresholds.
-  wanted = (cache_dir, int(min_entry_size_bytes),
-            float(min_compile_time_secs))
-  if _configured != wanted:
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                      int(min_entry_size_bytes))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_time_secs))
-    if _configured is None or _configured[0] != cache_dir:
+  env_dir = os.environ.get(ENV_CACHE_DIR)
+  if env_dir:
+    resolved = env_dir
+    if cache_dir and os.path.abspath(cache_dir) != os.path.abspath(env_dir):
+      log.info("%s=%s is set; ignoring cache_dir=%s", ENV_CACHE_DIR,
+               env_dir, cache_dir)
+  else:
+    resolved = os.path.abspath(
+        cache_dir or jax.config.jax_compilation_cache_dir
+        or DEFAULT_CACHE_DIR)
+    if jax.config.jax_compilation_cache_dir != resolved:
+      jax.config.update("jax_compilation_cache_dir", resolved)
       _reset_jax_cache_latch()
-    _configured = wanted
-    _configured_dir = cache_dir
-    log.info("Persistent XLA compilation cache at %s "
-             "(min_entry_size_bytes=%d, min_compile_time_secs=%g)",
-             cache_dir, min_entry_size_bytes, min_compile_time_secs)
-  return _configured_dir
+      log.info("Persistent XLA compilation cache at %s", resolved)
+  os.makedirs(resolved, exist_ok=True)
+  jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+  jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+  return resolved
 
 
 def _reset_jax_cache_latch() -> None:
@@ -127,58 +113,22 @@ def _reset_jax_cache_latch() -> None:
   jax initializes the persistent cache lazily at the FIRST compile and
   never re-reads `jax_compilation_cache_dir` afterwards — so a single
   compile anywhere in the import chain (flax init, orbax, a spec
-  helper) before this module runs would silently pin the process to
-  "no cache" and every warm-start claim would be wrong. The reset
-  makes configuration order-independent; already-compiled programs
-  simply stay in the in-process jit cache.
+  helper) before this module runs would pin the process to whatever
+  the flag said then. The reset makes configuration order-independent;
+  already-compiled programs simply stay in the in-process jit cache.
   """
-  try:
-    from jax._src import compilation_cache as _cc
-    _cc.reset_cache()
-  except Exception:  # private API; degrade to the lazy-init behavior
-    log.warning("Could not reset jax's compilation-cache latch; the "
-                "cache dir may be ignored if a compile already "
-                "happened in this process.", exc_info=True)
-
-
-def cache_dir() -> Optional[str]:
-  """The live persistent-cache directory (None = no cache configured).
-
-  The seam warm-load claims check BEFORE promising anything: the
-  serving arena's "evicted tenants reload without recompiling"
-  contract only holds with a cache configured, so it consults this at
-  construction and warns loudly when the answer is None.
-  """
-  return _configured_dir
-
-
-def donation_unsafe_with_cache() -> bool:
-  """True when buffer donation must be disabled for cache safety.
-
-  Empirically pinned on jaxlib 0.4.37's XLA:CPU: executing a
-  DESERIALIZED executable that donates input buffers, in a process
-  where tensorstore (an orbax restore) has been active, corrupts the
-  glibc heap — `malloc(): unsorted double linked list corrupted` at
-  the next unrelated allocation. The triple is exact: freshly-compiled
-  + donation + restore is fine, deserialized + no-donation + restore
-  is fine, deserialized + donation WITHOUT a restore is fine. A
-  restart is precisely restore + deserialized programs, so with the
-  persistent cache enabled on the CPU backend the trainer and the
-  serving engine trade donation (a buffer-reuse optimization that
-  matters on HBM-constrained accelerators, little on host CPU) for a
-  warm start that doesn't segfault. TPU/GPU backends keep donation —
-  the persistent cache is production-standard there.
-  """
-  return _configured_dir is not None and jax.default_backend() == "cpu"
+  jax_compilation_cache.reset_cache()
 
 
 def reset_compilation_cache_config() -> None:
-  """Detaches jax from the persistent cache (tests restore isolation)."""
-  global _configured, _configured_dir
+  """Forgets an explicit `cache_dir=` placement, so the next
+  `configure_compilation_cache()` goes back to `DEFAULT_CACHE_DIR`
+  (tests and probes restore isolation). A no-op under
+  `JAX_COMPILATION_CACHE_DIR`, where no placement was ever made."""
+  if os.environ.get(ENV_CACHE_DIR):
+    return
   jax.config.update("jax_compilation_cache_dir", None)
   _reset_jax_cache_latch()
-  _configured = None
-  _configured_dir = None
 
 
 def cache_entry_count(cache_dir: str) -> int:

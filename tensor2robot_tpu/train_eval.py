@@ -129,6 +129,20 @@ def _spec_batch_avals(spec, batch_size: int, sharding):
       spec)
 
 
+def _specs_predict_batches(generator) -> bool:
+  """Do this generator's wire specs say what its batches look like?
+
+  Not when a leaf is a sequence: episode generators add the time axis
+  (and a `sequence_length` feature) themselves, so the flat spec is
+  not the batch's aval and the step compiles at its first real batch.
+  """
+  return not any(
+      spec.is_sequence
+      for tree in (generator.feature_spec, generator.label_spec)
+      if tree is not None
+      for spec in jax.tree_util.tree_leaves(tree))
+
+
 def _batch_matches(avals, batch) -> bool:
   """Does a concrete batch pytree carry exactly the predicted avals?"""
   try:
@@ -288,11 +302,8 @@ def train_eval_model(
   repl = mesh_lib.replicated(mesh)
   batch_sh = mesh_lib.batch_sharding(mesh)
   feed_sharding = batch_sh
-  # Donation is disabled when the persistent cache is live on CPU —
-  # see compile_cache.donation_unsafe_with_cache (jaxlib heap bug).
-  donate = not compile_cache.donation_unsafe_with_cache()
   train_step, eval_step = _compile_steps(
-      model, mesh, donate=donate, state_shardings=state_shardings)
+      model, mesh, state_shardings=state_shardings)
 
   if k > 1:
     stacked_sh = prefetch_lib.stacked_sharding(batch_sh)
@@ -308,7 +319,7 @@ def train_eval_model(
         in_shardings=(state_shardings, stacked_sh, stacked_sh,
                       repl, repl),
         out_shardings=(state_shardings, repl),
-        donate_argnums=(0,) if donate else (),
+        donate_argnums=(0,),
     )
 
   # --- overlapped cold-start: AOT compile ∥ restore ∥ input spin-up ---
@@ -354,7 +365,7 @@ def train_eval_model(
     out: Dict[str, Any] = {}
     state_avals = jax.tree_util.tree_map(compile_cache.aval_of, state)
     rng_aval = jax.ShapeDtypeStruct((2,), np.uint32, sharding=repl)
-    if will_train:
+    if will_train and _specs_predict_batches(input_generator_train):
       bs = batch_size or input_generator_train.batch_size
       f_aval = _spec_batch_avals(
           input_generator_train.feature_spec, bs, batch_sh)
@@ -364,20 +375,16 @@ def train_eval_model(
         f_aval = _stack_avals(f_aval, stacked_sh)
         l_aval = _stack_avals(l_aval, stacked_sh)
       out["train_avals"] = (f_aval, l_aval)
-      try:
-        if k > 1:
-          step0_aval = jax.ShapeDtypeStruct((), np.int32, sharding=repl)
-          out["train"] = train_step.lower(
-              state_avals, f_aval, l_aval, rng_aval,
-              step0_aval).compile()
-        else:
-          out["train"] = train_step.lower(
-              state_avals, f_aval, l_aval, rng_aval).compile()
-      except Exception:
-        log.warning(
-            "AOT train-step compile failed; the first step will "
-            "compile on demand.", exc_info=True)
-    if input_generator_eval is not None:
+      if k > 1:
+        step0_aval = jax.ShapeDtypeStruct((), np.int32, sharding=repl)
+        out["train"] = train_step.lower(
+            state_avals, f_aval, l_aval, rng_aval,
+            step0_aval).compile()
+      else:
+        out["train"] = train_step.lower(
+            state_avals, f_aval, l_aval, rng_aval).compile()
+    if (input_generator_eval is not None
+        and _specs_predict_batches(input_generator_eval)):
       ebs = (eval_batch_size or batch_size
              or input_generator_eval.batch_size)
       ef_aval = _spec_batch_avals(
@@ -385,13 +392,8 @@ def train_eval_model(
       el_aval = _spec_batch_avals(
           input_generator_eval.label_spec, ebs, batch_sh)
       out["eval_avals"] = (ef_aval, el_aval)
-      try:
-        out["eval"] = eval_step.lower(
-            state_avals, ef_aval, el_aval).compile()
-      except Exception:
-        log.warning(
-            "AOT eval-step compile failed; the first eval will "
-            "compile on demand.", exc_info=True)
+      out["eval"] = eval_step.lower(
+          state_avals, ef_aval, el_aval).compile()
     return out
 
   aot: Optional[Dict[str, Any]] = None

@@ -1,17 +1,20 @@
 """ctypes loader for the native host-data-path kernels.
 
 Compiles `native/gather.cc` into a shared library on first use (g++,
-cached under `native/_build/`) and exposes typed wrappers. Everything
-degrades gracefully: no compiler, a failed build, or an exotic dtype
-all fall back to the numpy implementations, so the Python-only install
-keeps working — the native path is a throughput upgrade for many-core
-TPU hosts, not a hard dependency (the reference's data loaders were
-native for the same reason).
+cached under `native/_build/` in a file named after the source's
+content hash, so a copied or stale binary is never trusted by age)
+and exposes typed wrappers. Everything degrades gracefully: no
+compiler, a failed build, or an exotic dtype all fall back to the
+numpy implementations, so the Python-only install keeps working — the
+native path is a throughput upgrade for many-core TPU hosts, not a
+hard dependency (the reference's data loaders were native for the
+same reason).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,28 +29,36 @@ _LOAD_FAILED = False
 _SRC_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native")
 _BUILD_DIR = os.path.join(_SRC_DIR, "_build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libt2r_native.so")
 _SRC = os.path.join(_SRC_DIR, "gather.cc")
 
 
-def _build() -> Optional[str]:
+def _lib_path() -> str:
+  """The library path for the CURRENT source: keyed on gather.cc's
+  content, not its mtime (a copy of the tree keeps neither order nor
+  age of its files)."""
+  with open(_SRC, "rb") as f:
+    digest = hashlib.sha256(f.read()).hexdigest()[:16]
+  return os.path.join(_BUILD_DIR, f"libt2r_native.{digest}.so")
+
+
+def _build(lib_path: str) -> Optional[str]:
   os.makedirs(_BUILD_DIR, exist_ok=True)
   # Compile to a per-process temp name, then atomically rename: actor
   # and learner processes racing on a fresh checkout must never dlopen
   # a half-written library.
-  tmp_path = f"{_LIB_PATH}.{os.getpid()}.tmp"
+  tmp_path = f"{lib_path}.{os.getpid()}.tmp"
   cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
          _SRC, "-o", tmp_path]
   try:
     subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    os.replace(tmp_path, _LIB_PATH)
+    os.replace(tmp_path, lib_path)
   except (OSError, subprocess.SubprocessError):
     try:
       os.unlink(tmp_path)
     except OSError:
       pass
     return None
-  return _LIB_PATH
+  return lib_path
 
 
 def load_library() -> Optional[ctypes.CDLL]:
@@ -56,11 +67,13 @@ def load_library() -> Optional[ctypes.CDLL]:
   with _LOCK:
     if _LIB is not None or _LOAD_FAILED:
       return _LIB
-    path = _LIB_PATH
-    src_mtime = os.path.getmtime(_SRC) if os.path.exists(_SRC) else 0
-    if (not os.path.exists(path)
-        or os.path.getmtime(path) < src_mtime):
-      path = _build()
+    try:
+      path = _lib_path()
+    except OSError:  # no source shipped: the numpy paths serve
+      _LOAD_FAILED = True
+      return None
+    if not os.path.exists(path):
+      path = _build(path)
     if path is None:
       _LOAD_FAILED = True
       return None

@@ -49,25 +49,27 @@ PEAK_BF16_FLOPS = {
 
 def device_peak_flops(device: Optional[jax.Device] = None
                       ) -> Optional[float]:
-  """Best-effort bf16 peak FLOP/s for a device; None when unknown.
+  """bf16 peak FLOP/s of a device.
 
-  ``T2R_PEAK_FLOPS_OVERRIDE`` (env) overrides the table — how the
-  perf-plane tests pin live-MFU on a CPU host with no table entry, and
-  how an operator can compute pseudo-MFU against a custom roofline.
+  On platform ``tpu`` the `PEAK_BF16_FLOPS` table is the only source
+  and a `device_kind` it does not list raises: an MFU against a
+  guessed or substituted peak is worse than none. Off-TPU there is no
+  peak (None, MFU unpublished) unless ``T2R_PEAK_FLOPS_OVERRIDE``
+  (env) supplies one — how the perf-plane tests exercise the live-MFU
+  path on a CPU host.
   """
-  override = os.environ.get("T2R_PEAK_FLOPS_OVERRIDE")
-  if override:
-    try:
-      return float(override)
-    except ValueError:
-      log.warning("ignoring unparseable T2R_PEAK_FLOPS_OVERRIDE=%r",
-                  override)
   device = device or jax.devices()[0]
-  kind = getattr(device, "device_kind", "").lower()
-  for key, peak in PEAK_BF16_FLOPS.items():
-    if key in kind:
-      return peak
-  return None
+  if device.platform == "tpu":
+    kind = device.device_kind.lower()
+    for key, peak in PEAK_BF16_FLOPS.items():
+      if key in kind:
+        return peak
+    raise ValueError(
+        f"No peak FLOP/s entry for TPU device_kind "
+        f"{device.device_kind!r}; add it to "
+        "utils.profiling.PEAK_BF16_FLOPS with its source.")
+  override = os.environ.get("T2R_PEAK_FLOPS_OVERRIDE")
+  return float(override) if override else None
 
 
 def compiled_flops_per_call(compiled: Any) -> Optional[float]:

@@ -245,3 +245,18 @@ class TestReviewRegressions:
     )
     subprocess.run([sys.executable, "-c", code], check=True,
                    timeout=120)
+
+  def test_broken_in_tree_family_raises(self, monkeypatch):
+    """Every in-tree family's dependencies are installed: one that
+    fails to import is a bug to surface, not a module to skip."""
+    from absl import flags
+
+    from tensor2robot_tpu.bin import run_t2r_trainer
+
+    if not flags.FLAGS.is_parsed():
+      flags.FLAGS.mark_as_parsed()
+    monkeypatch.setattr(
+        run_t2r_trainer, "_DEFAULT_MODULES",
+        ("tensor2robot_tpu.models", "tensor2robot_tpu.no_such_family"))
+    with pytest.raises(ImportError, match="no_such_family"):
+      run_t2r_trainer._import_configurable_families()

@@ -448,9 +448,10 @@ class TestPodAnakin:
         lambda s, f, l, r: model.train_step(
             s, struct(f), struct(l), r, axis_name="pod"),
         axis_name="pod", devices=devices, in_axes=(0, 0, 0, None))
-    got, got_metrics = pod_step(
-        jax.device_put_replicated(state, devices), split(feats),
-        split(labels), rng)
+    replicated = jax.tree_util.tree_map(
+        lambda x: jnp.broadcast_to(x[None], (2,) + x.shape), state)
+    got, got_metrics = pod_step(replicated, split(feats),
+                                split(labels), rng)
 
     # Replication invariant: both replicas hold bitwise-equal params.
     for leaf in jax.tree_util.tree_leaves(

@@ -262,6 +262,48 @@ class TestActorImportClosure:
     assert "BACKEND_FREE" in result.stdout
 
 
+class TestClaimDevice:
+  """A fleet child that cannot get its device ends at once, saying
+  why — not silently at the orchestrator's heartbeat timeout."""
+
+  def test_returns_when_the_backend_comes_up(self):
+    from tensor2robot_tpu.fleet import proc
+
+    assert proc.claim_device("host") is None
+
+  def test_init_failure_exits_with_the_reason(self, monkeypatch):
+    import jax
+
+    from tensor2robot_tpu.fleet import proc
+
+    def no_chip():
+      raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_chip)
+    with pytest.raises(SystemExit) as failure:
+      proc.claim_device("front-1")
+    message = str(failure.value)
+    assert "fleet front-1" in message
+    assert "Unable to initialize backend 'tpu'" in message
+    assert "one process at a time" in message
+    assert "JAX_PLATFORMS=cpu" in message
+
+  def test_blocked_init_kills_the_process(self):
+    code = (
+        "import time, jax\n"
+        "from tensor2robot_tpu.fleet import proc\n"
+        "jax.devices = lambda: time.sleep(600)\n"
+        "proc.claim_device('learner', timeout_secs=0.2)\n"
+        "print('SURVIVED')\n")
+    result = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, cwd=REPO,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert result.returncode == 1
+    assert "SURVIVED" not in result.stdout
+    assert "still blocked after" in result.stderr
+
+
 class TestHostSessionAbort:
   """The mid-episode crash contract across the process boundary."""
 
@@ -592,10 +634,15 @@ class TestHybridPodracer:
     plan = faults.FaultPlan(seed=0, events=(faults.FaultEvent(
         fault=faults.ACTOR_CRASH, target="pod-0", at=2,
         mode="mid_episode"),))
+    # The run must outlast the respawn for the recovery to be observed.
+    # With the persistent compile cache warm the learner's 16 default
+    # steps take no time at all, so it gets enough steps to still be
+    # training when the new pod stamps its first heartbeat.
     config = _tiny_config(
         num_actors=0, pod_hosts=1, envs_per_pod=8,
         pod_rollout_length=2, env="mujoco_pose", fault_plan=plan,
-        max_actor_restarts=2, restart_window_secs=600.0)
+        max_actor_restarts=2, restart_window_secs=600.0,
+        max_train_steps=160)
     fleet = Fleet(config, str(tmp_path / "fleet"))
     result = fleet.run()
 

@@ -4,6 +4,7 @@ import os
 import struct
 
 import numpy as np
+import pytest
 
 from tensor2robot_tpu.utils import profiling, xplane
 
@@ -74,14 +75,26 @@ class TestXplaneReader:
 
 class TestMFU:
 
-  def test_known_device_peak(self):
+  def test_known_device_peak(self, monkeypatch):
     class FakeDevice:
+      platform = "tpu"
       device_kind = "TPU v5 lite"
+    # The override is a CPU-test device: it never replaces a TPU peak.
+    monkeypatch.setenv("T2R_PEAK_FLOPS_OVERRIDE", "1e12")
     assert profiling.device_peak_flops(FakeDevice()) == 197e12
     assert profiling.mfu(100.0, 197e8, FakeDevice()) == 0.01
 
-  def test_unknown_device_returns_none(self):
+  def test_unknown_tpu_kind_raises(self, monkeypatch):
     class FakeDevice:
-      device_kind = "QPU mystery"
-    assert profiling.device_peak_flops(FakeDevice()) is None
-    assert profiling.mfu(1.0, 1.0, FakeDevice()) is None
+      platform = "tpu"
+      device_kind = "TPU mystery"
+    monkeypatch.setenv("T2R_PEAK_FLOPS_OVERRIDE", "1e12")
+    with pytest.raises(ValueError, match="TPU mystery"):
+      profiling.device_peak_flops(FakeDevice())
+
+  def test_cpu_has_no_peak_unless_overridden(self, monkeypatch):
+    monkeypatch.delenv("T2R_PEAK_FLOPS_OVERRIDE", raising=False)
+    assert profiling.device_peak_flops() is None
+    assert profiling.mfu(1.0, 1.0) is None
+    monkeypatch.setenv("T2R_PEAK_FLOPS_OVERRIDE", "2e12")
+    assert profiling.device_peak_flops() == 2e12

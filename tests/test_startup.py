@@ -68,8 +68,58 @@ class TestCompileCache:
     finally:
       compile_cache.reset_compilation_cache_config()
 
-  def test_unconfigured_is_noop(self):
-    assert compile_cache.configure_compilation_cache() is None
+  def test_placement_without_the_env_var(self, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR unset (conftest): no argument places
+    the cache at the fixed default; an explicit dir overrides it and
+    stays in force for later no-arg callers; a reset goes back."""
+    default = compile_cache.DEFAULT_CACHE_DIR  # conftest's session dir
+    explicit = str(tmp_path / "explicit")
+    try:
+      assert compile_cache.configure_compilation_cache() == default
+      assert jax.config.jax_compilation_cache_dir == default
+      assert compile_cache.configure_compilation_cache(
+          cache_dir=explicit) == explicit
+      assert os.path.isdir(explicit)
+      # A library entry point's no-arg call keeps the explicit dir.
+      assert compile_cache.configure_compilation_cache() == explicit
+      assert jax.config.jax_compilation_cache_dir == explicit
+    finally:
+      compile_cache.reset_compilation_cache_config()
+    assert compile_cache.configure_compilation_cache() == default
+
+  def test_env_var_placement_never_touches_the_flag(self, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself; the program
+    creates the directory, ignores every explicit dir, never updates
+    `jax_compilation_cache_dir`, and the executables land there. The
+    same child pins the unset-case default to the in-checkout path."""
+    env_dir = str(tmp_path / "from_env")
+    other = str(tmp_path / "explicit")
+    code = (
+        "import os\n"
+        "import numpy as np\n"
+        "import jax\n"
+        "updates = []\n"
+        "real_update = jax.config.update\n"
+        "jax.config.update = lambda name, value: (\n"
+        "    updates.append(name), real_update(name, value))[1]\n"
+        "from tensor2robot_tpu.startup import compile_cache\n"
+        f"assert compile_cache.DEFAULT_CACHE_DIR == {os.path.join(REPO_ROOT, '.jax_cache')!r}\n"
+        f"env_dir, other = {env_dir!r}, {other!r}\n"
+        "assert not os.path.exists(env_dir)\n"
+        "for arg in (None, other):\n"
+        "  assert compile_cache.configure_compilation_cache(\n"
+        "      cache_dir=arg) == env_dir\n"
+        "assert os.path.isdir(env_dir) and not os.path.exists(other)\n"
+        "compile_cache.reset_compilation_cache_config()\n"
+        "assert 'jax_compilation_cache_dir' not in updates, updates\n"
+        "assert jax.config.jax_compilation_cache_dir == env_dir\n"
+        "jax.jit(lambda x: (x * 3.0).sum() + 1.0)(\n"
+        "    np.ones((33, 33), np.float32)).block_until_ready()\n"
+        "assert compile_cache.cache_entry_count(env_dir) >= 1\n")
+    env = _subprocess_env()
+    env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    subprocess.run([sys.executable, "-c", code], env=env, timeout=600,
+                   check=True)
 
   def test_persistent_cache_roundtrip_across_processes(self, tmp_path):
     """THE warm-restart contract: the second process with the same
