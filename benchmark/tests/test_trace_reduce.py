@@ -1,0 +1,87 @@
+"""The reduction from a profiler trace to busy time, per-operation time,
+collective time and idle gaps: on made-up events, and on a small trace
+recorded on a TPU v5e (tools/trace_probe.py, PR 23: three executions of
+a jitted 4-iteration scan with 20 ms of sleep between them)."""
+
+import os
+
+import pytest
+
+from benchmark.harness import trace_reduce as tr
+
+RECORDED = os.path.join(os.path.dirname(__file__), "data",
+                        "v5e_scan4.xplane.pb")
+
+
+def test_union_merges_overlaps():
+  assert tr.union_ns([(0, 10), (5, 12), (20, 30), (30, 31)]) == 23
+
+
+def test_umbrella_events_are_not_counted_twice():
+  ops = [("%while = ...", 0, 100),           # spans its body
+         ("%fusion.1 = ...", 0, 40),
+         ("%all-reduce.2 = ...", 40, 30),
+         ("%fusion.3 = ...", 80, 20),        # 10 ns of loop overhead
+         ("%copy.4 = ...", 200, 50)]
+  summary = tr.device_summary(ops, (0, 300))
+  assert summary["busy_ns"] == 40 + 30 + 20 + 50
+  assert summary["per_op_ns"]["while"] == 10
+  assert summary["per_op_ns"]["fusion.1"] == 40
+  assert summary["collective_ns"] == 30
+  assert sum(summary["per_op_ns"].values()) == 150
+
+
+def test_window_clips_operations():
+  summary = tr.device_summary([("%a = ...", 0, 100)], (50, 80))
+  assert summary["busy_ns"] == 30
+
+
+def test_idle_gaps_are_named_by_the_host_event_inside_them():
+  modules = [("jit_k_steps(1)", 0, 100), ("jit_k_steps(1)", 400, 100)]
+  host = [("train_qtopt", 0, 500),            # enclosing frame: skipped
+          ("$prefetch.py:1 __next__", 120, 250),
+          ("device_get", 380, 10)]
+  gaps = tr.idle_gaps(modules, host, (0, 500))
+  assert gaps == [["$prefetch.py:1 __next__", 300 / 1e9]]
+
+
+@pytest.fixture(scope="module")
+def recorded():
+  return tr.reduce_trace(RECORDED, 1, program="jit_prog")
+
+
+def test_recorded_trace_busy_and_idle(recorded):
+  planes = tr.load(RECORDED)
+  ops = planes["/device:TPU:0"][tr.OPS_LINE]
+  modules = planes["/device:TPU:0"][tr.MODULES_LINE]
+  summed = sum(d for _, _, d in ops) / 1e9
+  in_programs = sum(d for _, _, d in modules) / 1e9
+  assert recorded["program_runs"] == 3
+  # Summing event durations counts the scan's body twice (the %while
+  # umbrella and its operations); the union cannot exceed the time the
+  # programs were on the chip.
+  assert summed > 1.5 * recorded["busy_s"]
+  assert recorded["busy_s"] <= in_programs
+  assert recorded["busy_s"] > 0.9 * in_programs
+  assert recorded["program_busy_s"] == pytest.approx(recorded["busy_s"])
+  # 35 us of work in a 92 ms recording: the chip idles in the sleeps.
+  assert 1 - recorded["busy_s"] / recorded["window_s"] > 0.99
+  # The wait before the first program is the profiler starting up, the
+  # two between programs are the script's sleeps.
+  gaps = dict(recorded["idle_gaps"])
+  assert gaps["$time sleep"] == pytest.approx(0.043, abs=0.003)
+  assert "$profiler.py:101 start_trace" in gaps
+  assert recorded["collective_s"] == 0.0
+
+
+def test_recorded_trace_names_operations(recorded):
+  names = [name for name, _ in recorded["device_ops"]]
+  assert names[0].startswith("fusion")
+  assert "while" in names  # present, with its self time only
+  per_op = dict(recorded["device_ops"])
+  assert per_op["while"] < 0.01 * per_op[names[0]]
+
+
+def test_too_few_device_planes_is_an_error():
+  with pytest.raises(ValueError):
+    tr.reduce_trace(RECORDED, 4)
