@@ -22,6 +22,7 @@ import numpy as np
 
 from tensor2robot_tpu import config as gin
 from tensor2robot_tpu.specs import TensorSpecStruct
+from tensor2robot_tpu.telemetry import core as tracing
 from tensor2robot_tpu.telemetry import metrics as tmetrics
 
 
@@ -95,12 +96,19 @@ class StackedBatchStream:
   from the consumer thread while the prefetch thread is still blocked
   inside `__next__` (a generator would refuse with "generator already
   executing"; closing the plane instead UNBLOCKS that thread).
+
+  Spans (docs/OBSERVABILITY.md): `feed.sample` around each of the K
+  pulls, `feed.stack` around the stack. `seq` counts the stacks this
+  stream has yielded; the prefetcher that consumes it counts the same
+  pulls, and the loop's `TimedIterator` what comes out of the one
+  FIFO between them, so the three agree without being passed along.
   """
 
   def __init__(self, stream: Iterator[Any], k: int):
     self._it = iter(stream)
     self._k = int(k)
     self._exhausted = False
+    self._seq = 0
 
   def __iter__(self):
     return self
@@ -109,9 +117,10 @@ class StackedBatchStream:
     if self._exhausted:
       raise StopIteration
     batches = []
-    for _ in range(self._k):
+    for i in range(self._k):
       try:
-        batches.append(next(self._it))
+        with tracing.span("feed.sample", seq=self._seq, i=i):
+          batches.append(next(self._it))
       except StopIteration:
         self._exhausted = True
         if batches:
@@ -124,8 +133,12 @@ class StackedBatchStream:
               "than K=1 would.", self._k, len(batches), len(batches))
         self.close()  # the inner stream is done: release it now
         raise
-    return jax.tree_util.tree_map(
-        lambda *xs: np.stack(xs), *batches)
+    with tracing.span("feed.stack", seq=self._seq,
+                      bytes=tree_nbytes(batches)):
+      stacked = jax.tree_util.tree_map(
+          lambda *xs: np.stack(xs), *batches)
+    self._seq += 1
+    return stacked
 
   def close(self) -> None:
     closer = getattr(self._it, "close", None)
@@ -178,6 +191,12 @@ def stacked_sharding(sharding: jax.sharding.NamedSharding
       sharding.mesh, jax.sharding.PartitionSpec(None, *sharding.spec))
 
 
+def tree_nbytes(tree: Any) -> int:
+  """Bytes held by the array leaves of a pytree (a span's `bytes`)."""
+  return sum(getattr(x, "nbytes", 0)
+             for x in jax.tree_util.tree_leaves(tree))
+
+
 def device_put_batch(batch: Any, sharding: jax.sharding.Sharding) -> Any:
   """Places a pytree of host numpy arrays as global sharded jax.Arrays."""
 
@@ -199,6 +218,14 @@ class ShardedPrefetcher:
   image decode) host iterator and performs the H2D transfer, keeping up
   to `buffer_size` global batches resident ahead of compute. This is the
   framework's single host↔device seam; everything downstream is jitted.
+
+  The thread's time is named by spans that share the batch's `seq`
+  (docs/OBSERVABILITY.md): `feed.sample` around the pull (`feed.pull`
+  around a `StackedBatchStream`'s, which names its own K pulls and the
+  stack), `feed.device_put` around the placement as this thread lives it
+  (the call, plus the wait for the transfer only where the zero-copy
+  protocol makes one), `feed.queue_put` around the bounded put, which
+  is long only while the feed is ahead of the loop.
   """
 
   def __init__(self,
@@ -223,22 +250,40 @@ class ShardedPrefetcher:
     release = None
     if getattr(self._iterator, "release_after_transfer", False):
       release = getattr(self._iterator, "release_consumed", None)
+    # A `StackedBatchStream` names its own K pulls and the stack; the
+    # pull around them is `feed.pull`, whose self time is what the pull
+    # costs besides: the K batches and the previous dispatch's host
+    # copy (rebound here) go back to the allocator inside it.
+    pull, pull_args = (("feed.pull", {})
+                       if isinstance(self._iterator, StackedBatchStream)
+                       else ("feed.sample", {"i": 0}))
     try:
-      for batch in self._iterator:
-        placed = device_put_batch(batch, self._sharding)
-        if release is not None:
-          jax.block_until_ready(placed)
-          release()
+      source = iter(self._iterator)
+      seq = 0
+      while True:
+        try:
+          with tracing.span(pull, seq=seq, **pull_args):
+            batch = next(source)
+        except StopIteration:
+          break
+        with tracing.span("feed.device_put", seq=seq,
+                          bytes=tree_nbytes(batch)):
+          placed = device_put_batch(batch, self._sharding)
+          if release is not None:
+            jax.block_until_ready(placed)
+            release()
         # Bounded put that notices close(): don't block forever holding
         # device buffers once the consumer abandoned the stream.
-        while not self._stop.is_set():
-          try:
-            self._queue.put(placed, timeout=0.1)
-            break
-          except queue.Full:
-            continue
+        with tracing.span("feed.queue_put", seq=seq):
+          while not self._stop.is_set():
+            try:
+              self._queue.put(placed, timeout=0.1)
+              break
+            except queue.Full:
+              continue
         if self._stop.is_set():
           return
+        seq += 1
     except BaseException as e:  # surfaced on the consumer thread
       self._error = e
     finally:
@@ -349,16 +394,23 @@ class TimedIterator:
   def __init__(self, iterator: Iterator[Any]):
     self._it = iter(iterator)
     self.wait_secs = 0.0
+    self.seq = -1  # of the item returned last: the feed's `seq`
 
   def __iter__(self):
     return self
 
   def __next__(self):
-    t0 = time.perf_counter()
+    # One reading serves the fraction and the `loop.wait_feed` span.
+    t0 = time.monotonic()
     try:
-      return next(self._it)
+      item = next(self._it)
     finally:
-      self.wait_secs += time.perf_counter() - t0
+      waited = time.monotonic() - t0
+      self.wait_secs += waited
+    self.seq += 1
+    tracing.get_tracer().record("loop.wait_feed", t0, waited,
+                                seq=self.seq)
+    return item
 
   def wait_fraction(self, interval_secs: float) -> float:
     """Clamped share of `interval_secs` spent blocked; resets the

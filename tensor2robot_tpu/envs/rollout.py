@@ -832,10 +832,10 @@ def train_anakin(
         scalars.update(telemetry.registry().scalars("rsrc."))
         telemetry.registry().gauge("train.grad_steps_per_sec").set(
             scalars["grad_steps_per_sec"])
-        # Live utilization (perf.mfu / flops_per_sec /
-        # device_time_fraction) — bench's denominator, pod-aware.
+        # Live utilization (perf.mfu / flops_per_sec) — bench's
+        # denominator, pod-aware.
         scalars.update(perf_meter.publish(
-            scalars["grad_steps_per_sec"], dt))
+            scalars["grad_steps_per_sec"]))
         metric_logger.write("train", step, scalars)
         if watch_sentinel is not None:
           watch_sentinel.evaluate(

@@ -47,6 +47,7 @@ import numpy as np
 from tensor2robot_tpu import config as gin
 from tensor2robot_tpu import specs as specs_lib
 from tensor2robot_tpu.specs import TensorSpecStruct
+from tensor2robot_tpu.telemetry import core as tracing
 from tensor2robot_tpu.telemetry import metrics as tmetrics
 from tensor2robot_tpu.utils import native
 
@@ -135,6 +136,9 @@ class ReplayStore:
     self._spill_dir = spill_dir
     self._shards = [_Shard(self._flat_spec, self._shard_capacity)
                     for _ in range(self._num_shards)]
+    self._row_bytes = sum(
+        store.nbytes // self._shard_capacity
+        for store in self._shards[0].storage.values())
     self._rng = np.random.default_rng(seed)
     # One lock for the sampler state (rng + cross-shard bookkeeping);
     # it is never held while a shard gather runs, so adds into other
@@ -325,6 +329,25 @@ class ReplayStore:
     itself is already striped across cores inside `native.gather_rows`,
     which is why there is no per-shard thread fan-out here.
     """
+    with tracing.span("replay.draw", rows=batch_size):
+      shard_ids, local = self._draw(batch_size)
+    # `native`: the library is loaded; a store's arrays are contiguous,
+    # so it then serves every gather here (`native.gather_rows` counts
+    # rows by path for the cases where it cannot).
+    with tracing.span("replay.gather", rows=batch_size,
+                      bytes=batch_size * self._row_bytes,
+                      native=native.native_available()):
+      out, ages, row_ids = self._gather(batch_size, shard_ids, local)
+    with self._stats_lock:
+      self.samples_total += batch_size
+      self.sample_calls += 1
+    self._tm_samples.inc(batch_size)
+    np.maximum(ages, 0, out=ages)  # adds race the step tag by design
+    return TensorSpecStruct.from_flat_dict(out), ages, row_ids
+
+  def _draw(self, batch_size: int) -> Tuple[np.ndarray, np.ndarray]:
+    """(shard ids, slots in the shard) of one batch, under the
+    sampler's lock."""
     with self._sample_lock:
       sizes = [s.size for s in self._shards]
       total = sum(sizes)
@@ -349,6 +372,12 @@ class ReplayStore:
         finally:
           for sh in self._shards:
             sh.lock.release()
+    return shard_ids, local
+
+  def _gather(self, batch_size: int, shard_ids: np.ndarray,
+              local: np.ndarray):
+    """(rows by key, ages, global row ids) of the drawn slots, each
+    shard's slice under that shard's lock only."""
     now = self._learner_step
     if self._num_shards == 1:
       # The legacy-exact path: one gather, draw order preserved.
@@ -396,12 +425,7 @@ class ReplayStore:
         out = {key: arr[inverse] for key, arr in out.items()}
         ages = ages[inverse]
         row_ids = row_ids[inverse]
-    with self._stats_lock:
-      self.samples_total += batch_size
-      self.sample_calls += 1
-    self._tm_samples.inc(batch_size)
-    np.maximum(ages, 0, out=ages)  # adds race the step tag by design
-    return TensorSpecStruct.from_flat_dict(out), ages, row_ids
+    return out, ages, row_ids
 
   def _draw_uniform(self, batch: int, sizes: List[int], total: int):
     """One rng call over the live total (the legacy-exact draw)."""

@@ -84,13 +84,14 @@ def cem_maximize(
                                                             min_std)
     else:
       scores = score_fn(samples)  # [B, P]
-      elite_scores, elite_idx = jax.lax.top_k(scores, num_elites)
-      elites = jnp.take_along_axis(
-          samples, elite_idx[..., None], axis=1)  # [B, E, A]
-      new_mean = jnp.mean(elites, axis=1)
-      new_std = jnp.maximum(jnp.std(elites, axis=1), min_std)
-      it_best = elites[:, 0]              # top-1 this iteration
-      it_best_score = elite_scores[:, 0]
+      with jax.named_scope("cem_pool"):  # the selection, in a trace
+        elite_scores, elite_idx = jax.lax.top_k(scores, num_elites)
+        elites = jnp.take_along_axis(
+            samples, elite_idx[..., None], axis=1)  # [B, E, A]
+        new_mean = jnp.mean(elites, axis=1)
+        new_std = jnp.maximum(jnp.std(elites, axis=1), min_std)
+        it_best = elites[:, 0]              # top-1 this iteration
+        it_best_score = elite_scores[:, 0]
     improved = it_best_score > best_score
     best_action = jnp.where(improved[:, None], it_best, best_action)
     best_score = jnp.maximum(best_score, it_best_score)

@@ -19,6 +19,13 @@ The network is split at the action merge into two callable halves:
 exploits the split: the torso runs ONCE per state and only the (much
 cheaper) head runs per population candidate, instead of re-convolving
 the full image population × iterations times per Bellman target.
+
+Device-side names (`jax.named_scope`: operation metadata only, shown
+by a profiler trace on every operation): `torso` (encode, either
+tower), `q_head` (the online critic's action merge, head convs and
+MLP; in the population path the MLP alone), `cem_tower` (the
+population path's action embed, merge and head convs), `cem_pool`
+(its spatial pooling; `cem.py` names the selection the same).
 """
 
 from __future__ import annotations
@@ -97,6 +104,7 @@ class GraspingQNetwork(nn.Module):
     self._q_head = MLP(hidden_sizes=tuple(self.dense_sizes),
                        output_size=1, dtype=self.dtype, name="q_head")
 
+  @jax.named_scope("torso")
   def encode(self, image, train: bool = False, taps=None):
     """Action-independent half: image → torso feature map [B,h,w,C].
 
@@ -127,6 +135,7 @@ class GraspingQNetwork(nn.Module):
       x = nn.relu(x)
     return x
 
+  @jax.named_scope("q_head")
   def head(self, encoded, features, train: bool = False):
     """Action-dependent half: (torso features, action+extras) → Q."""
     a = _gather_action_extras(features, self.dtype)
@@ -170,7 +179,8 @@ class GraspingQNetwork(nn.Module):
     if self._head_convs:
       pooled = self._population_tail(
           self._population_merge(encoded, a))
-      logit = self._q_head(pooled, train=False)
+      with jax.named_scope("q_head"):
+        logit = self._q_head(pooled, train=False)
       return logit[..., 0].astype(jnp.float32).reshape(p, b).T
     x = encoded[:, None] + a[:, :, None, None, :]
     x = x.reshape((b * p,) + x.shape[2:])
@@ -178,6 +188,7 @@ class GraspingQNetwork(nn.Module):
     logit = self._q_head(x, train=False)
     return logit[..., 0].astype(jnp.float32).reshape(b, p)
 
+  @jax.named_scope("cem_tower")
   def _population_action_embed(self, extras, actions):
     """Action + extras → merge-channel embedding a [B, P, C]."""
     b, p, a_dim = actions.shape
@@ -193,6 +204,7 @@ class GraspingQNetwork(nn.Module):
     a = nn.relu(self._action_embed_0(a))
     return self._action_embed_1(a)  # [B, P, C]
 
+  @jax.named_scope("cem_tower")
   def _population_merge(self, encoded, a):
     """The linearity-split merge: [P·B, h', w', C'] relu'd tensor.
 
@@ -253,14 +265,16 @@ class GraspingQNetwork(nn.Module):
     """Remaining head convs + spatial pool: [P·B, h', w', C'] →
     pooled [P·B, C'']. `taps` records each conv's input under
     ``head_in_<i>`` (int8 calibration points)."""
-    for i, conv in enumerate(self._head_convs[1:], start=1):
-      if taps is not None:
-        taps[f"head_in_{i}"] = x
-      x = conv(x)
-      if self.use_batch_norm:
-        x = self._head_bns[i](x, use_running_average=True)
-      x = nn.relu(x)
-    return jnp.mean(x, axis=(1, 2))
+    with jax.named_scope("cem_tower"):
+      for i, conv in enumerate(self._head_convs[1:], start=1):
+        if taps is not None:
+          taps[f"head_in_{i}"] = x
+        x = conv(x)
+        if self.use_batch_norm:
+          x = self._head_bns[i](x, use_running_average=True)
+        x = nn.relu(x)
+    with jax.named_scope("cem_pool"):
+      return jnp.mean(x, axis=(1, 2))
 
   def pool_population(self, encoded, extras, actions):
     """`score_population` minus the q-head MLP: pooled population
@@ -408,6 +422,7 @@ def _int8_conv(x, layer, stride, dtype):
   return jnp.maximum(y, 0.0).astype(dtype)
 
 
+@jax.named_scope("torso")
 def quantized_encode(network: GraspingQNetwork, tower: dict, image):
   """int8 twin of `GraspingQNetwork.encode` (eval mode)."""
   dt = network.dtype
@@ -445,16 +460,17 @@ def _quantized_population_pooled(network: GraspingQNetwork,
   dt = network.dtype
   b, p, _ = actions.shape
 
-  parts = [actions.astype(dt)]
-  for key in sorted(extras):
-    value = extras[key]
-    if jnp.issubdtype(value.dtype, jnp.floating):
-      parts.append(jnp.broadcast_to(
-          value.reshape(b, 1, -1).astype(dt),
-          (b, p, int(np.prod(value.shape[1:])))))
-  a = _dense(params, "action_embed_0", jnp.concatenate(parts, -1),
-             dt, relu=True)
-  a = _dense(params, "action_embed_1", a, dt)  # [B, P, C]
+  with jax.named_scope("cem_tower"):
+    parts = [actions.astype(dt)]
+    for key in sorted(extras):
+      value = extras[key]
+      if jnp.issubdtype(value.dtype, jnp.floating):
+        parts.append(jnp.broadcast_to(
+            value.reshape(b, 1, -1).astype(dt),
+            (b, p, int(np.prod(value.shape[1:])))))
+    a = _dense(params, "action_embed_0", jnp.concatenate(parts, -1),
+               dt, relu=True)
+    a = _dense(params, "action_embed_1", a, dt)  # [B, P, C]
 
   if not network.head_filters:
     x = encoded[:, None] + a[:, :, None, None, :]
@@ -462,35 +478,38 @@ def _quantized_population_pooled(network: GraspingQNetwork,
     return jnp.mean(x, axis=(1, 2)).reshape(b, p, -1) \
         .transpose(1, 0, 2).reshape(p * b, -1)
 
-  # conv0 linearity split, on the raw kernel (bias only without BN).
-  k0 = params["head_conv_0"]["kernel"].astype(dt)
-  c = encoded.shape[-1]
-  enc0 = jax.lax.conv_general_dilated(
-      encoded.astype(dt), k0, (2, 2), "SAME",
-      dimension_numbers=_CONV_DIMS)
-  basis = jnp.broadcast_to(
-      jnp.eye(c, dtype=dt)[:, None, None, :], (c,) + encoded.shape[1:])
-  v = jax.lax.conv_general_dilated(
-      basis, k0, (2, 2), "SAME", dimension_numbers=_CONV_DIMS)
-  if network.use_batch_norm:
-    bn_scale, bn_shift = _eval_bn_affine(params["head_bn_0"],
-                                         variables["batch_stats"]
-                                         ["head_bn_0"])
-    enc0 = (enc0.astype(jnp.float32) * bn_scale
-            + bn_shift).astype(dt)
-    v = (v.astype(jnp.float32) * bn_scale).astype(dt)
-  else:
-    enc0 = enc0 + params["head_conv_0"]["bias"].astype(dt)
-  h2, w2, oc = v.shape[1:]
-  a_pm = a.transpose(1, 0, 2).reshape(p * b, c)
-  act = (a_pm @ v.reshape(c, -1)).reshape(p * b, h2, w2, oc)
-  enc_rep = jnp.concatenate([enc0] * p, axis=0)
-  x = nn.relu(act + enc_rep)  # the hot tensor; int8 from here on
-  for i, layer in enumerate(tower["head"]):
-    x = _int8_conv(x, layer, (2, 2), dt)
-  return jnp.mean(x, axis=(1, 2))
+  with jax.named_scope("cem_tower"):
+    # conv0 linearity split, on the raw kernel (bias only without BN).
+    k0 = params["head_conv_0"]["kernel"].astype(dt)
+    c = encoded.shape[-1]
+    enc0 = jax.lax.conv_general_dilated(
+        encoded.astype(dt), k0, (2, 2), "SAME",
+        dimension_numbers=_CONV_DIMS)
+    basis = jnp.broadcast_to(
+        jnp.eye(c, dtype=dt)[:, None, None, :], (c,) + encoded.shape[1:])
+    v = jax.lax.conv_general_dilated(
+        basis, k0, (2, 2), "SAME", dimension_numbers=_CONV_DIMS)
+    if network.use_batch_norm:
+      bn_scale, bn_shift = _eval_bn_affine(params["head_bn_0"],
+                                           variables["batch_stats"]
+                                           ["head_bn_0"])
+      enc0 = (enc0.astype(jnp.float32) * bn_scale
+              + bn_shift).astype(dt)
+      v = (v.astype(jnp.float32) * bn_scale).astype(dt)
+    else:
+      enc0 = enc0 + params["head_conv_0"]["bias"].astype(dt)
+    h2, w2, oc = v.shape[1:]
+    a_pm = a.transpose(1, 0, 2).reshape(p * b, c)
+    act = (a_pm @ v.reshape(c, -1)).reshape(p * b, h2, w2, oc)
+    enc_rep = jnp.concatenate([enc0] * p, axis=0)
+    x = nn.relu(act + enc_rep)  # the hot tensor; int8 from here on
+    for i, layer in enumerate(tower["head"]):
+      x = _int8_conv(x, layer, (2, 2), dt)
+  with jax.named_scope("cem_pool"):
+    return jnp.mean(x, axis=(1, 2))
 
 
+@jax.named_scope("q_head")
 def _q_head_mlp(params, pooled, dtype):
   """The q-head MLP from raw params (bf16 — tiny, not quantized)."""
   q_head = params["q_head"]

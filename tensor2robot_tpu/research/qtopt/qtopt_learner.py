@@ -260,10 +260,12 @@ class QTOptLearner:
       # Traced, so the backend is already up (a constructor gin calls
       # must not claim the chip). Mosaic compiles on TPU only; every
       # other backend runs the kernel through the interpreter.
-      return fused_cem_select(
-          pool_fn(actions), actions, dense,
-          num_elites=self._cem_elites, min_std=min_std,
-          sigmoid=sigmoid, interpret=jax.default_backend() != "tpu")
+      pooled = pool_fn(actions)
+      with jax.named_scope("cem_pool"):
+        return fused_cem_select(
+            pooled, actions, dense,
+            num_elites=self._cem_elites, min_std=min_std,
+            sigmoid=sigmoid, interpret=jax.default_backend() != "tpu")
 
     return None, select_fn
 
@@ -348,17 +350,23 @@ class QTOptLearner:
     ts = state.train_state
     q_next = self._target_q_values(
         state.target_params, ts.batch_stats, next_features, rng_cem)
-    reward = flat["reward"].reshape(-1).astype(jnp.float32)
-    done = flat["done"].reshape(-1).astype(jnp.float32)
-    target = reward + self._gamma * (1.0 - done) * q_next
-    if self._clip_targets is not None:
-      target = jnp.clip(target, *self._clip_targets)
-    target = jax.lax.stop_gradient(target)
+    # Device-side names (`jax.named_scope`, metadata only) for a
+    # profiler trace: the target arithmetic is `bellman_loss`, the
+    # critic's forward and backward pass `backward` (the loss sits in
+    # the model, inside it), then `optimizer` and `polyak` below.
+    with jax.named_scope("bellman_loss"):
+      reward = flat["reward"].reshape(-1).astype(jnp.float32)
+      done = flat["done"].reshape(-1).astype(jnp.float32)
+      target = reward + self._gamma * (1.0 - done) * q_next
+      if self._clip_targets is not None:
+        target = jnp.clip(target, *self._clip_targets)
+      target = jax.lax.stop_gradient(target)
 
     labels = TensorSpecStruct.from_flat_dict(
         {"target_q": target[:, None]})
-    grads, new_stats, metrics = self._model.train_grads(
-        ts, features, labels, rng_net, axis_name=axis_name)
+    with jax.named_scope("backward"):
+      grads, new_stats, metrics = self._model.train_grads(
+          ts, features, labels, rng_net, axis_name=axis_name)
     metrics["q_next_mean"] = jnp.mean(q_next)
     metrics["target_mean"] = jnp.mean(target)
     if axis_name is not None:
@@ -371,11 +379,13 @@ class QTOptLearner:
   def apply_gradients(self, state: QTOptState, grads: Any,
                       new_stats: Any) -> QTOptState:
     """The update half: critic optimizer step + Polyak target sync."""
-    new_ts = self._model.apply_gradients(state.train_state, grads,
-                                         new_stats)
-    new_target = jax.tree_util.tree_map(
-        functools.partial(_polyak, self._tau),
-        new_ts.params, state.target_params)
+    with jax.named_scope("optimizer"):
+      new_ts = self._model.apply_gradients(state.train_state, grads,
+                                           new_stats)
+    with jax.named_scope("polyak"):
+      new_target = jax.tree_util.tree_map(
+          functools.partial(_polyak, self._tau),
+          new_ts.params, state.target_params)
     return QTOptState(train_state=new_ts, target_params=new_target)
 
   # ---- on-robot / actor policy ----

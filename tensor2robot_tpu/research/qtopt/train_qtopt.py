@@ -119,9 +119,21 @@ def train_qtopt(
   updates 1/N of every weight instead of all replicas repeating the
   full update. On a 1-device mesh it is a bitwise no-op (pinned);
   checkpoints are unaffected (save gathers to host either way).
+
+  Every stage of the loop thread is a telemetry span
+  (docs/OBSERVABILITY.md, "Standard spans": `loop.wait_feed`,
+  `qtopt.dispatch`, `loop.after_step`, `loop.log` > `loop.log_sync`,
+  `loop.save` > `loop.save_d2h` / `loop.save_write` /
+  `loop.after_checkpoint`), as is every stage of the feed thread. A
+  process that has not configured the tracer gets the role `trainer`
+  in memory mode here: a bounded ring, nothing written, and a
+  sentinel page's flight record holds the loop's last spans. A
+  caller's configuration, `enabled=False` included, is left alone.
   """
   if mesh is None:
     mesh = mesh_lib.create_mesh()
+  if telemetry.get_tracer().role is None:
+    telemetry.configure("trainer")
   # Validate the dispatch quantization BEFORE any side effects
   # (hook begin() starts actor threads; a late ValueError would leak
   # them past their teardown owner, the loop's try/finally).
@@ -290,11 +302,31 @@ def train_qtopt(
   # prefetcher's __next__ per log interval), logged beside the
   # staleness metrics.
   prefetch_iter = prefetch_lib.TimedIterator(prefetcher)
+
+  def save(step: int) -> None:
+    # EVERY rank saves (orbax's save barrier is collective; a
+    # chief-only call would wedge the chief in
+    # `sync_global_processes` while the peers train on) — orbax's
+    # primary-host rule keeps process 0 the only data writer.
+    # `after_checkpoint` runs on every rank too (rank > 0 carries
+    # no publish hook, so it is a no-op there) to keep per-rank
+    # hook bookkeeping in step.
+    with telemetry.span("loop.save", step=step):
+      with telemetry.span("loop.save_d2h", step=step):
+        host_state = jax.device_get(state)
+      with telemetry.span("loop.save_write", step=step):
+        writer.save(step, host_state,
+                    params=host_state.train_state.params,
+                    batch_stats=host_state.train_state.batch_stats)
+      with telemetry.span("loop.after_checkpoint", step=step):
+        hook_list.after_checkpoint(step, state.train_state, model_dir)
+
   try:
-    for transitions in prefetch_iter:
+    for transitions in prefetch_iter:  # spans as `loop.wait_feed`
       if step >= max_train_steps:
         break
-      with perf_meter.dispatch("qtopt.dispatch", step=step, k=k):
+      with perf_meter.dispatch("qtopt.dispatch", step=step, k=k,
+                               seq=prefetch_iter.seq):
         if k == 1:
           state, metrics = train_step(
               state, transitions, jax.random.fold_in(step_rng, step))
@@ -307,58 +339,53 @@ def train_qtopt(
       steps_since_log += k
       if tag_step is not None:
         tag_step(step)  # one int store; actors tag adds with it
-      hook_list.after_step(step, metrics)
+      with telemetry.span("loop.after_step", step=step):
+        hook_list.after_step(step, metrics)
       if chief and (step % log_every_steps == 0
                     or step == max_train_steps):
-        scalars = jax.device_get(metrics)
-        dt = time.time() - t_last
-        scalars["grad_steps_per_sec"] = steps_since_log / max(dt, 1e-9)
-        scalars["input_wait_fraction"] = prefetch_iter.wait_fraction(dt)
-        # Data-plane instrumentation rides the train log: fill,
-        # add/sample rates, drops/evictions, staleness — next to the
-        # loop's own throughput, the way stall_fraction is.
-        replay_metrics = getattr(replay_buffer, "metrics_scalars", None)
-        if replay_metrics is not None:
-          scalars.update(replay_metrics())
-        # Compile-cache counters from the telemetry registry: a miss
-        # delta after the first interval is a warm-path recompile.
-        scalars.update(telemetry.registry().scalars("compile_cache."))
-        # Resource watermarks persist with the run (the report tool's
-        # watermark section; the registry alone dies with the process).
-        scalars.update(telemetry.registry().scalars("rsrc."))
-        telemetry.registry().gauge("train.grad_steps_per_sec").set(
-            scalars["grad_steps_per_sec"])
-        # Live utilization (perf.mfu / flops_per_sec /
-        # device_time_fraction) — same denominator as bench MFU.
-        scalars.update(perf_meter.publish(
-            scalars["grad_steps_per_sec"], dt))
-        metric_logger.write("train", step, scalars)
-        if watch_sentinel is not None:
-          watch_sentinel.evaluate(
-              {**telemetry.registry().scalars(), **scalars},
-              step=step)
-        t_last = time.time()
-        steps_since_log = 0
+        with telemetry.span("loop.log", step=step):
+          # The one place the loop waits for the device: the dispatch
+          # enqueued above has to finish before its metrics exist.
+          with telemetry.span("loop.log_sync", step=step):
+            scalars = jax.device_get(metrics)
+          dt = time.time() - t_last
+          scalars["grad_steps_per_sec"] = (
+              steps_since_log / max(dt, 1e-9))
+          scalars["input_wait_fraction"] = (
+              prefetch_iter.wait_fraction(dt))
+          # Data-plane instrumentation rides the train log: fill,
+          # add/sample rates, drops/evictions, staleness — next to the
+          # loop's own throughput, the way stall_fraction is.
+          replay_metrics = getattr(replay_buffer, "metrics_scalars",
+                                   None)
+          if replay_metrics is not None:
+            scalars.update(replay_metrics())
+          # Compile-cache counters from the telemetry registry: a miss
+          # delta after the first interval is a warm-path recompile.
+          scalars.update(
+              telemetry.registry().scalars("compile_cache."))
+          # Resource watermarks persist with the run (the report
+          # tool's watermark section; the registry alone dies with
+          # the process).
+          scalars.update(telemetry.registry().scalars("rsrc."))
+          telemetry.registry().gauge("train.grad_steps_per_sec").set(
+              scalars["grad_steps_per_sec"])
+          # Live utilization (perf.mfu / flops_per_sec) — same
+          # denominator as bench MFU.
+          scalars.update(perf_meter.publish(
+              scalars["grad_steps_per_sec"]))
+          metric_logger.write("train", step, scalars)
+          if watch_sentinel is not None:
+            watch_sentinel.evaluate(
+                {**telemetry.registry().scalars(), **scalars},
+                step=step)
+          t_last = time.time()
+          steps_since_log = 0
       if step % save_checkpoints_steps == 0 or step == max_train_steps:
-        # EVERY rank saves (orbax's save barrier is collective; a
-        # chief-only call would wedge the chief in
-        # `sync_global_processes` while the peers train on) — orbax's
-        # primary-host rule keeps process 0 the only data writer.
-        # `after_checkpoint` runs on every rank too (rank > 0 carries
-        # no publish hook, so it is a no-op there) to keep per-rank
-        # hook bookkeeping in step.
-        host_state = jax.device_get(state)
-        writer.save(step, host_state,
-                    params=host_state.train_state.params,
-                    batch_stats=host_state.train_state.batch_stats)
+        save(step)
         last_saved = step
-        hook_list.after_checkpoint(step, state.train_state, model_dir)
     if last_saved != step:
-      host_state = jax.device_get(state)
-      writer.save(step, host_state,
-                  params=host_state.train_state.params,
-                  batch_stats=host_state.train_state.batch_stats)
-      hook_list.after_checkpoint(step, state.train_state, model_dir)
+      save(step)
   finally:
     # end() in the FINALLY: hooks now own real teardown (actor
     # threads); a training-loop exception must not leak collectors.

@@ -212,6 +212,12 @@ class Tracer:
       return
     self._record(name, time.monotonic(), 0.0, args or None)
 
+  def record(self, name: str, t0: float, dur: float, **args) -> None:
+    """A span whose caller read the clock itself (`time.monotonic`
+    at its start, and the duration): a measurement the caller needs
+    anyway, tracer on or off, is taken once and lands here as well."""
+    self._record(name, t0, dur, args or None)
+
   def _record(self, name: str, t0: float, dur: float,
               args: Optional[Dict[str, Any]]) -> None:
     if not self.enabled:

@@ -9,12 +9,10 @@ healthy and how fast should it be", always on:
     `utils.profiling.analytic_flops` model — bench MFU and live
     ``perf.mfu`` agree by construction (the shared-code-path pin in
     tests/test_perf_plane.py).
-  * `PerfMeter` — per-process live attribution: wraps each train
-    dispatch in the standard telemetry span while accumulating its
-    wall time, and at log cadence publishes ``perf.mfu``,
-    ``perf.flops_per_sec`` and ``perf.device_time_fraction`` gauges
-    into the registry (so every ``metrics_<tag>.jsonl`` envelope and
-    the Prometheus endpoint carry utilization for free). Device-count
+  * `PerfMeter` — per-process live attribution: at log cadence
+    publishes the ``perf.mfu`` and ``perf.flops_per_sec`` gauges into
+    the registry (so every ``metrics_<tag>.jsonl`` envelope and the
+    Prometheus endpoint carry utilization for free). Device-count
     aware: the pod trainers pass their device count so MFU stays the
     per-chip fraction-of-peak at any scale.
   * `ResourceSampler` — a daemon sampler thread per process role:
@@ -42,8 +40,7 @@ import atexit
 import logging
 import os
 import threading
-import time
-from typing import Any, Callable, Dict, Iterable, Optional, Sequence
+from typing import Callable, Dict, Iterable, Optional, Sequence
 
 from tensor2robot_tpu.telemetry import core
 from tensor2robot_tpu.telemetry import metrics as tmetrics
@@ -106,18 +103,15 @@ class PerfMeter:
       meter = perf.PerfMeter(flops_per_step=..., peak_flops=...,
                              devices=D)
       ...
-      with meter.dispatch("qtopt.dispatch", step=step):  # = span + timer
+      with meter.dispatch("qtopt.dispatch", step=step):  # the span
         state, metrics = train_step(...)
       ...
-      scalars.update(meter.publish(grad_steps_per_sec, interval_secs))
+      scalars.update(meter.publish(grad_steps_per_sec))
 
   ``flops_per_step`` is the analytic MODEL flops of one GLOBAL train
   step (`utils.profiling.analytic_flops`; pod trainers multiply their
   per-device count by D); ``devices`` scales the peak so ``perf.mfu``
-  stays the per-chip fraction-of-peak. ``perf.device_time_fraction``
-  is the share of the log interval spent inside dispatch spans — the
-  dispatch-span-derived busy fraction (host-side wall including the
-  device program; the stall/input-wait gauges decompose the rest).
+  stays the per-chip fraction-of-peak.
   """
 
   def __init__(self,
@@ -131,65 +125,32 @@ class PerfMeter:
     self.devices = max(int(devices), 1)
     self._registry = registry or tmetrics.registry()
     self.enabled = plane_enabled() if enabled is None else bool(enabled)
-    self._busy_secs = 0.0
-    self._busy_lock = threading.Lock()
 
   def dispatch(self, name: str, **args):
-    """The standard dispatch span + busy-time accumulation in one
-    context manager (replaces the bare `telemetry.span` at the train
-    loops' dispatch sites)."""
-    return _DispatchSpan(self, core.span(name, **args))
+    """The train loops' standard dispatch span. It closes when the
+    ENQUEUE returns, not when the device is done (PERF.md): it times
+    the host's side of a dispatch and is no measure of device time."""
+    return core.span(name, **args)
 
-  def _add_busy(self, secs: float) -> None:
-    with self._busy_lock:
-      self._busy_secs += secs
-
-  def publish(self, steps_per_sec: float,
-              interval_secs: float) -> Dict[str, float]:
+  def publish(self, steps_per_sec: float) -> Dict[str, float]:
     """Publishes the interval's perf gauges; returns them as scalars
-    for the trainer's `metrics_<tag>.jsonl` record. Resets the busy
-    accumulator (one call per log interval)."""
-    with self._busy_lock:
-      busy, self._busy_secs = self._busy_secs, 0.0
+    for the trainer's `metrics_<tag>.jsonl` record (one call per log
+    interval)."""
     if not self.enabled:
       return {}
     out: Dict[str, float] = {}
-    out["perf.device_time_fraction"] = min(
-        max(busy / max(interval_secs, 1e-9), 0.0), 1.0)
     if self.flops_per_step:
       out["perf.flops_per_sec"] = steps_per_sec * self.flops_per_step
     util = mfu_value(steps_per_sec, self.flops_per_step,
                      self.peak_flops, devices=self.devices)
     if util is not None:
       out["perf.mfu"] = util
-    self._registry.gauge("perf.device_time_fraction").set(
-        out["perf.device_time_fraction"])
     if "perf.flops_per_sec" in out:
       self._registry.gauge("perf.flops_per_sec").set(
           out["perf.flops_per_sec"])
     if "perf.mfu" in out:
       self._registry.gauge("perf.mfu").set(out["perf.mfu"])
     return out
-
-
-class _DispatchSpan:
-  """Context manager pairing a telemetry span with busy accounting."""
-
-  __slots__ = ("_meter", "_span", "_t0")
-
-  def __init__(self, meter: PerfMeter, span: Any):
-    self._meter = meter
-    self._span = span
-
-  def __enter__(self) -> "_DispatchSpan":
-    self._t0 = time.monotonic()
-    self._span.__enter__()
-    return self
-
-  def __exit__(self, exc_type, exc, tb) -> bool:
-    self._span.__exit__(exc_type, exc, tb)
-    self._meter._add_busy(time.monotonic() - self._t0)
-    return False
 
 
 def host_rss_source() -> Callable[[], Dict[str, float]]:

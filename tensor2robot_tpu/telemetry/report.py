@@ -34,8 +34,7 @@ from tensor2robot_tpu.telemetry import sentinel as sentinel_lib
 # Throughput scalars worth a headline row, in display order.
 RATE_KEYS = ("steps_per_sec", "grad_steps_per_sec",
              "env_steps_per_sec", "bellman_batches_per_sec",
-             "perf.flops_per_sec", "perf.mfu",
-             "perf.device_time_fraction", "stall_fraction",
+             "perf.flops_per_sec", "perf.mfu", "stall_fraction",
              "input_wait_fraction")
 MERGED_TRACE_NAMES = ("merged_trace.json", "merged_trace.json.gz",
                       "fleet_trace.json.gz", "fleet_trace.json")

@@ -461,7 +461,7 @@ def train_eval_model(
   # the denominator is XLA's cost analysis of the AOT-compiled train
   # program (÷ K for the scanned dispatch) — approximate but stable
   # for the run; absent (lazy-jit fallback), perf.mfu is simply not
-  # published and device_time_fraction still is.
+  # published.
   from tensor2robot_tpu.telemetry import perf as perf_lib
   from tensor2robot_tpu.telemetry import sentinel as sentinel_lib
   from tensor2robot_tpu.utils import profiling
@@ -539,10 +539,9 @@ def train_eval_model(
               scalars["steps_per_sec"])
           telemetry.registry().gauge("train.stall_fraction").set(
               scalars["stall_fraction"])
-          # Live utilization (perf.mfu / flops_per_sec /
-          # device_time_fraction): the always-on perf plane.
-          scalars.update(perf_meter.publish(
-              scalars["steps_per_sec"], dt))
+          # Live utilization (perf.mfu / flops_per_sec): the
+          # always-on perf plane.
+          scalars.update(perf_meter.publish(scalars["steps_per_sec"]))
           final_metrics = scalars
           t_last = time.time()
           steps_since_log = 0

@@ -22,6 +22,8 @@ from typing import Optional
 
 import numpy as np
 
+from tensor2robot_tpu.telemetry import metrics as tmetrics
+
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _LOAD_FAILED = False
@@ -131,9 +133,14 @@ def gather_rows(src: np.ndarray, idx: np.ndarray,
         f"gather_rows: out shape/dtype {out.shape}/{out.dtype} does "
         f"not match {(idx.shape[0],) + src.shape[1:]}/{src.dtype}.")
   lib = load_library()
+  # Rows by path, so that a run can tell whether the library built and
+  # served it (docs/OBSERVABILITY.md).
   if lib is None or not _rows_ok(src) or not _rows_ok(out):
     np.take(src, idx, axis=0, out=out)
+    tmetrics.counter("native.gather_rows.fallback_rows").inc(
+        idx.shape[0])
     return out
+  tmetrics.counter("native.gather_rows.native_rows").inc(idx.shape[0])
   row_bytes = int(src.dtype.itemsize * np.prod(src.shape[1:], dtype=np.int64))
   lib.t2r_gather_rows(
       src.ctypes.data_as(ctypes.c_void_p),

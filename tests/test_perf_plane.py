@@ -85,34 +85,34 @@ class TestSharedDenominator:
 
 class TestPerfMeter:
 
-  def test_publish_sets_gauges_and_busy_fraction(self):
-    import time
+  def test_publish_sets_gauges_and_dispatch_is_the_plain_span(self):
+    tcore.configure("trainer")
     meter = perf_lib.PerfMeter(flops_per_step=100.0, peak_flops=1e3,
                                devices=2, enabled=True)
-    with meter.dispatch("x.dispatch"):
-      time.sleep(0.01)
-    out = meter.publish(steps_per_sec=5.0, interval_secs=0.1)
+    with meter.dispatch("x.dispatch", step=3):
+      pass
+    (span,) = tcore.get_tracer().snapshot_spans()
+    assert span["name"] == "x.dispatch" and span["args"] == {"step": 3}
+    out = meter.publish(steps_per_sec=5.0)
     assert out["perf.flops_per_sec"] == pytest.approx(500.0)
     assert out["perf.mfu"] == pytest.approx(5.0 * 100.0 / (1e3 * 2))
-    assert 0.0 < out["perf.device_time_fraction"] <= 1.0
     gauges = tmetrics.registry().snapshot()["gauges"]
     assert gauges["perf.mfu"] == pytest.approx(out["perf.mfu"])
-    # The accumulator resets per interval.
-    out2 = meter.publish(5.0, 0.1)
-    assert out2["perf.device_time_fraction"] == 0.0
+    # The dispatch span times the enqueue: nothing is published under
+    # a device's name from it (PERF.md, PR 24).
+    assert "perf.device_time_fraction" not in out
+    assert "perf.device_time_fraction" not in gauges
 
   def test_unknown_peak_publishes_no_mfu(self):
     meter = perf_lib.PerfMeter(flops_per_step=100.0, peak_flops=None,
                                enabled=True)
-    out = meter.publish(5.0, 0.1)
-    assert "perf.mfu" not in out
-    assert "perf.flops_per_sec" in out
-    assert "perf.device_time_fraction" in out
+    out = meter.publish(5.0)
+    assert set(out) == {"perf.flops_per_sec"}
 
   def test_disabled_plane_publishes_nothing(self):
     meter = perf_lib.PerfMeter(flops_per_step=100.0, peak_flops=1e3,
                                enabled=False)
-    assert meter.publish(5.0, 0.1) == {}
+    assert meter.publish(5.0) == {}
     assert tmetrics.registry().snapshot()["gauges"] == {}
 
 
@@ -236,8 +236,8 @@ def _read_perf_record(model_dir):
   records = read_records(os.path.join(model_dir, "metrics_train.jsonl"))
   assert records
   record = records[-1]
-  assert "perf.device_time_fraction" in record
-  assert 0.0 <= record["perf.device_time_fraction"] <= 1.0
+  assert "perf.device_time_fraction" not in record
+  assert "perf.flops_per_sec" in record
   return record
 
 
@@ -473,7 +473,6 @@ class TestReportCli:
         record = trecords.make_record(step, {
             "grad_steps_per_sec": 100.0 + step,
             "perf.mfu": 0.2 + step / 1000.0,
-            "perf.device_time_fraction": 0.8,
             "rsrc.host_rss_bytes_peak": 1.0e9,
         }, role="trainer", wall=1000.0 + step)
         f.write(json.dumps(record) + "\n")

@@ -1,0 +1,373 @@
+"""The stage spans of the QT-Opt loop thread and of the feed thread
+(ISSUE 24; docs/OBSERVABILITY.md "Standard spans"), the counters and
+named scopes beside them, and the benchmark readers' helper that cuts
+a window out of the ring."""
+
+import re
+import time
+
+import numpy as np
+import pytest
+
+from tensor2robot_tpu import config as gin
+from tensor2robot_tpu import telemetry
+from tensor2robot_tpu.telemetry import core as tcore
+from tensor2robot_tpu.telemetry import flightrec
+from tensor2robot_tpu.telemetry import metrics as tmetrics
+from tensor2robot_tpu.telemetry import perf as perf_lib
+
+K = 2
+FEED_SPANS = {"feed.pull", "feed.sample", "replay.draw",
+              "replay.gather", "feed.stack", "feed.device_put",
+              "feed.queue_put"}
+LOOP_SPANS = {"loop.wait_feed", "qtopt.dispatch", "loop.after_step",
+              "loop.log", "loop.log_sync", "loop.save",
+              "loop.save_d2h", "loop.save_write",
+              "loop.after_checkpoint"}
+CHILDREN = {"feed.sample": "feed.pull", "feed.stack": "feed.pull",
+            "replay.draw": "feed.sample", "replay.gather": "feed.sample",
+            "loop.log_sync": "loop.log", "loop.save_d2h": "loop.save",
+            "loop.save_write": "loop.save",
+            "loop.after_checkpoint": "loop.save"}
+SCOPES = ("torso", "cem_tower", "cem_pool", "q_head", "bellman_loss",
+          "backward", "optimizer", "polyak")
+
+
+def _reset():
+  tcore.reset_for_tests()
+  tmetrics.reset_for_tests()
+  perf_lib.stop_resource_sampler()
+  perf_lib.set_plane_enabled(None)
+  gin.clear_config()
+
+
+@pytest.fixture
+def clean_plane():
+  _reset()
+  yield
+  _reset()
+
+
+def _learner(**kwargs):
+  from tensor2robot_tpu.research.qtopt import (
+      GraspingQModel,
+      QTOptLearner,
+  )
+  return QTOptLearner(
+      GraspingQModel(image_size=16, torso_filters=(8,),
+                     head_filters=(8, 8), dense_sizes=(16,),
+                     action_dim=2),
+      cem_population=8, cem_iterations=1, cem_elites=2, **kwargs)
+
+
+def _train(model_dir, **kwargs):
+  from tensor2robot_tpu.research.qtopt.train_qtopt import train_qtopt
+  args = dict(learner=_learner(), model_dir=str(model_dir),
+              prefill_random=True, max_train_steps=16, batch_size=16,
+              log_every_steps=4, save_checkpoints_steps=8, seed=0,
+              steps_per_dispatch=K)
+  args.update(kwargs)
+  return train_qtopt(**args)
+
+
+@pytest.fixture(scope="module")
+def ring(tmp_path_factory):
+  """The ring a stand-alone K=2 `train_qtopt` leaves behind."""
+  _reset()
+  _train(tmp_path_factory.mktemp("spans"))
+  tracer = telemetry.get_tracer()
+  spans = tracer.snapshot_spans()
+  state = {"role": tracer.role, "enabled": tracer.enabled,
+           "path": tracer.trace_path, "dropped": tracer.spans_dropped}
+  _reset()
+  return spans, state
+
+
+def _by_name(spans, name):
+  return [s for s in spans if s["name"] == name]
+
+
+class TestSpansOfARun:
+
+  def test_the_trainer_configures_memory_mode(self, ring):
+    _, state = ring
+    assert state == {"role": "trainer", "enabled": True, "path": None,
+                     "dropped": 0}
+
+  @pytest.mark.parametrize("name", sorted(FEED_SPANS | LOOP_SPANS))
+  def test_every_stage_is_in_the_ring_on_its_thread(self, ring, name):
+    spans, _ = ring
+    loop_tid = _by_name(spans, "qtopt.dispatch")[0]["tid"]
+    feed_tid = _by_name(spans, "feed.device_put")[0]["tid"]
+    assert loop_tid != feed_tid
+    found = _by_name(spans, name)
+    assert found, name
+    want = feed_tid if name in FEED_SPANS else loop_tid
+    assert {s["tid"] for s in found} == {want}
+
+  @pytest.mark.parametrize("child,parent", sorted(CHILDREN.items()))
+  def test_children_lie_inside_their_parents(self, ring, child, parent):
+    spans, _ = ring
+    parents = _by_name(spans, parent)
+    for c in _by_name(spans, child):
+      assert any(p["tid"] == c["tid"] and p["ts"] <= c["ts"]
+                 and c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-9
+                 for p in parents), (child, c)
+
+  def test_seq_ties_feed_wait_and_dispatch(self, ring):
+    spans, _ = ring
+    dispatches = _by_name(spans, "qtopt.dispatch")
+    assert [d["args"]["step"] for d in dispatches] == list(
+        range(0, 16, K))
+    assert [d["args"]["seq"] for d in dispatches] == list(range(8))
+    assert all(d["args"]["k"] == K for d in dispatches)
+    for d in dispatches:
+      seq = d["args"]["seq"]
+      of = lambda name: [s for s in _by_name(spans, name)  # noqa: E731
+                         if s["args"]["seq"] == seq]
+      assert sorted(s["args"]["i"] for s in of("feed.sample")) == [0, 1]
+      for name in ("feed.pull", "feed.stack", "feed.device_put",
+                   "feed.queue_put", "loop.wait_feed"):
+        assert len(of(name)) == 1, (name, seq)
+      # The feed made the batch before the loop got it, and the loop
+      # got it before it dispatched it.
+      put, = of("feed.queue_put")
+      wait, = of("loop.wait_feed")
+      assert put["ts"] <= wait["ts"] + wait["dur"] <= d["ts"] + 1e-9
+    stack = _by_name(spans, "feed.stack")[0]
+    gather = _by_name(spans, "replay.gather")[0]
+    assert stack["args"]["bytes"] == K * gather["args"]["bytes"] > 0
+    assert gather["args"]["rows"] == 16
+    assert isinstance(gather["args"]["native"], bool)
+
+  def test_loop_thread_is_named_from_first_to_last_dispatch(self, ring):
+    from benchmark.harness import trace_reduce
+    spans, _ = ring
+    dispatches = _by_name(spans, "qtopt.dispatch")
+    tid = dispatches[0]["tid"]
+    t0 = dispatches[0]["ts"]
+    t1 = dispatches[-1]["ts"] + dispatches[-1]["dur"]
+    covered = trace_reduce.union_ns(
+        (max(s["ts"], t0), min(s["ts"] + s["dur"], t1))
+        for s in spans if s["tid"] == tid and s["name"] in LOOP_SPANS
+        and s["ts"] < t1 and s["ts"] + s["dur"] > t0)
+    assert covered / (t1 - t0) >= 0.95
+
+  def test_k1_names_the_prefetchers_own_pull(self, clean_plane,
+                                             tmp_path):
+    _train(tmp_path, steps_per_dispatch=1, max_train_steps=4)
+    spans = telemetry.get_tracer().snapshot_spans()
+    samples = _by_name(spans, "feed.sample")
+    assert samples and not _by_name(spans, "feed.stack")
+    assert not _by_name(spans, "feed.pull")
+    assert {s["args"]["i"] for s in samples} == {0}
+    seqs = [d["args"]["seq"] for d in _by_name(spans, "qtopt.dispatch")]
+    assert seqs == [0, 1, 2, 3]
+    assert set(seqs) <= {s["args"]["seq"] for s in samples}
+
+
+def test_callers_disabled_tracer_survives_train_qtopt(clean_plane,
+                                                      tmp_path):
+  telemetry.configure("bench_off_arm", enabled=False)
+  _train(tmp_path, max_train_steps=4)
+  tracer = telemetry.get_tracer()
+  assert (tracer.role, tracer.enabled) == ("bench_off_arm", False)
+  assert tracer.spans_recorded == 0 and not tracer.snapshot_spans()
+
+
+def test_sentinel_page_dumps_the_loops_spans(clean_plane, tmp_path):
+  """A stand-alone trainer's flight record used to hold an empty
+  `spans` list: nothing configured the tracer."""
+  gin.bind_parameter("default_watches.host_rss_budget_bytes", 1.0)
+  _train(tmp_path)
+  dumps = flightrec.read_dumps(flightrec.flightrec_dir(str(tmp_path)))
+  assert dumps and dumps[0]["reason"].startswith("sentinel page:")
+  names = {s["name"] for s in dumps[0]["spans"]}
+  assert {"loop.wait_feed", "loop.log_sync", "qtopt.dispatch",
+          "feed.sample"} <= names
+
+
+def test_timed_iterator_takes_one_measurement(clean_plane):
+  """`input_wait_fraction` and the `loop.wait_feed` span are the same
+  reading; the fraction works with the tracer off."""
+  from tensor2robot_tpu.data import prefetch
+
+  def slow():
+    for i in range(3):
+      time.sleep(0.01)
+      yield i
+
+  off = prefetch.TimedIterator(slow())
+  assert list(off) == [0, 1, 2] and off.seq == 2
+  assert off.wait_secs >= 0.03
+  assert not telemetry.get_tracer().snapshot_spans()
+  telemetry.configure("trainer")
+  on = prefetch.TimedIterator(slow())
+  assert list(on) == [0, 1, 2]
+  spans = telemetry.get_tracer().snapshot_spans()
+  assert [s["args"]["seq"] for s in spans] == [0, 1, 2]
+  # The pull that found the stream dry is waited for, not a span.
+  assert on.wait_secs == pytest.approx(sum(s["dur"] for s in spans),
+                                       abs=0.01)
+
+
+@pytest.mark.parametrize("path", ["native", "fallback"])
+def test_gather_rows_counts_rows_by_path(clean_plane, path):
+  from tensor2robot_tpu.utils import native
+  if path == "native" and not native.native_available():
+    pytest.skip("no native library on this machine")
+  src = np.arange(40, dtype=np.float32).reshape(10, 4)
+  if path == "fallback":
+    src = src[:, :2]  # not contiguous: numpy serves it
+  idx = np.array([7, 1, 3])
+  np.testing.assert_array_equal(native.gather_rows(src, idx), src[idx])
+  rows = telemetry.registry().scalars("native.gather_rows.")
+  assert rows == {f"native.gather_rows.{path}_rows": 3.0}
+
+
+def test_span_clock_is_the_harness_clock():
+  """The tracer stamps `time.monotonic`; the benchmark harness takes
+  the device trace's time zero with `time.perf_counter`. A span's `ts`
+  less that zero is a time on the trace only while the two are one
+  clock (CLOCK_MONOTONIC on Linux)."""
+  mono = time.get_clock_info("monotonic")
+  perf = time.get_clock_info("perf_counter")
+  assert mono.implementation == perf.implementation
+  assert mono.monotonic and perf.monotonic
+  assert abs(time.monotonic() - time.perf_counter()) < 1e-3
+
+
+@pytest.mark.parametrize("cem_inference", ["bf16", "int8"])
+def test_named_scopes_reach_the_lowered_step(cem_inference):
+  import jax
+
+  from tensor2robot_tpu.specs import make_random_tensors
+  learner = _learner(cem_inference=cem_inference)
+  state = learner.create_state(jax.random.PRNGKey(0), batch_size=2)
+  batch = make_random_tensors(learner.transition_specification(),
+                              batch_size=4, seed=0)
+  if learner.needs_calibration:
+    learner.calibrate(state, batch)
+  text = jax.jit(learner.train_step).lower(
+      state, batch, jax.random.PRNGKey(1)).as_text(debug_info=True)
+  names = re.findall(r'loc\("([^"]*/[^"]*)"', text)
+  for scope in SCOPES:
+    assert any(re.search(rf"(^|/){scope}/", name) for name in names), \
+        scope
+  # The online critic's pass is the backward scope's, torso included.
+  assert any("/backward/" in name and "/torso/" in name
+             for name in names)
+
+
+# ---- the benchmark readers' helper, on made-up spans ----
+
+
+def _span(name, ts, dur, tid, **args):
+  return {"name": name, "ts": ts, "dur": dur, "tid": tid, "args": args}
+
+
+def _made_up_run(dispatches=4, k=2, period=1.0):
+  """`dispatches` cycles of `period` seconds on a loop thread (1) and
+  a feed thread (2) that runs one cycle ahead; steps start at 100."""
+  spans = []
+  for seq in range(dispatches):
+    step = 100 + seq * k
+    f0 = seq * period  # feed cycle: 0.95 of 1.0 named
+    for i in range(k):
+      at = f0 + 0.2 * i
+      spans.append(_span("replay.gather", at + 0.05, 0.1, 2, rows=8))
+      spans.append(_span("feed.sample", at, 0.2, 2, seq=seq, i=i))
+    if k > 1:  # the pull frees for 0.05 after the stack
+      spans.append(_span("feed.stack", f0 + 0.4, 0.1, 2, seq=seq))
+      spans.append(_span("feed.pull", f0, 0.55, 2, seq=seq))
+    spans.append(_span("feed.device_put", f0 + 0.55, 0.3, 2, seq=seq))
+    spans.append(_span("feed.queue_put", f0 + 0.85, 0.1, 2, seq=seq))
+    l0 = (seq + 1) * period  # loop cycle: 0.9 of 1.0 named
+    spans.append(_span("loop.wait_feed", l0, 0.5, 1, seq=seq))
+    spans.append(_span("qtopt.dispatch", l0 + 0.5, 0.1, 1, step=step,
+                       k=k, seq=seq))
+    spans.append(_span("loop.log_sync", l0 + 0.65, 0.1 + 0.01 * seq, 1,
+                       step=step + k))
+    spans.append(_span("loop.log", l0 + 0.6, 0.3, 1, step=step + k))
+  return spans
+
+
+class TestSpanWindow:
+
+  def _select(self, spans, steps, k=2):
+    from benchmark.layer_metrics import span_window
+    return span_window.select(spans, steps, k, k)
+
+  def test_window_by_step_and_self_time_by_containment(self):
+    spans = _made_up_run()
+    # Records at steps 104 and 106 cover the dispatches from 102, 104.
+    window = self._select(spans, [104, 106])
+    assert window["steps"] == 4 and window["seqs"] == [1, 2]
+    loop, feed = window["loop"], window["feed"]
+    assert (loop["tid"], feed["tid"]) == (1, 2)
+    assert loop["seconds"] == pytest.approx(2.0)
+    assert loop["self_s"]["loop.log"] == pytest.approx(
+        2 * 0.3 - 0.11 - 0.12)
+    assert loop["self_s"]["loop.log_sync"] == pytest.approx(0.23)
+    assert loop["unnamed_s"] == pytest.approx(2 * 0.1)
+    assert feed["seconds"] == pytest.approx(2.0)
+    assert feed["self_s"]["feed.sample"] == pytest.approx(4 * 0.1)
+    assert feed["self_s"]["replay.gather"] == pytest.approx(4 * 0.1)
+    assert feed["self_s"]["feed.pull"] == pytest.approx(2 * 0.05)
+    assert feed["unnamed_s"] == pytest.approx(2 * 0.05)
+    assert len(window["spans"]["loop.log_sync"]) == 2
+
+  def test_readers_on_the_made_up_run(self, clean_plane):
+    from benchmark.layer_metrics import (
+        feed_device_put_ms_per_step,
+        feed_native_gather_share,
+        feed_queue_full_share,
+        feed_sample_ms_per_step,
+        feed_stack_ms_per_step,
+        host_unnamed_share,
+        loop_log_sync_ms,
+        loop_save_ms,
+        span_window,
+    )
+    run = {"span_window": self._select(_made_up_run(), [104, 106])}
+    assert feed_sample_ms_per_step.read(run) == pytest.approx(200.0)
+    assert feed_stack_ms_per_step.read(run) == pytest.approx(50.0)
+    assert feed_device_put_ms_per_step.read(run) == pytest.approx(150.0)
+    assert feed_queue_full_share.read(run) == pytest.approx(10.0)
+    assert loop_log_sync_ms.read(run) == pytest.approx(115.0)
+    assert loop_save_ms.read(run) is None  # no save in the window
+    assert host_unnamed_share.read(run) == pytest.approx(10.0)  # loop
+    assert feed_native_gather_share.read(run) is None  # nothing counted
+    tmetrics.counter("native.gather_rows.native_rows").inc(30)
+    tmetrics.counter("native.gather_rows.fallback_rows").inc(10)
+    assert feed_native_gather_share.read(run) == pytest.approx(75.0)
+    assert span_window.of_run(run) is run["span_window"]
+
+  def test_none_after_a_rolled_ring_never_a_partial_number(self):
+    spans = _made_up_run()
+    steps = [102, 104, 106]
+    assert self._select(spans, steps)["steps"] == 6
+    # The ring drops the oldest spans first: without the first
+    # dispatch's feed spans, or its dispatch span, there is no window.
+    assert self._select(spans[5:], steps) is None
+    rolled = [s for s in spans if not (
+        s["name"] == "qtopt.dispatch" and s["args"]["seq"] == 0)]
+    assert self._select(rolled, steps) is None
+    assert self._select([], steps) is None  # the parent has no spans
+    assert self._select(spans, []) is None
+
+  def test_unstacked_feed_has_neither_pull_nor_stack(self):
+    window = self._select(_made_up_run(k=1), [101, 102], k=1)
+    assert window["seqs"] == [0, 1] and window["steps"] == 2
+    assert "feed.stack" not in window["spans"]
+    assert window["feed"]["seconds"] == pytest.approx(2.0)
+
+  def test_of_run_reads_this_processes_ring(self, clean_plane,
+                                            monkeypatch):
+    from benchmark.layer_metrics import host_unnamed_share, span_window
+    monkeypatch.setattr(telemetry.get_tracer(), "snapshot_spans",
+                        _made_up_run)
+    run = {"records": [{"step": 104}, {"step": 106}], "k": 2,
+           "config": {"train": {"log_every_steps": 2}}}
+    assert span_window.of_run(run)["seqs"] == [1, 2]
+    assert host_unnamed_share.read(run) == pytest.approx(10.0)
