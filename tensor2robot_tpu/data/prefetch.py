@@ -80,6 +80,11 @@ def validate_steps_per_dispatch(k: int, **cadences: Optional[int]
   return k
 
 
+# Slots of a `StackedBatchStream`'s ring: one is filled while the
+# transfer reads the other.
+_RING_SLOTS = 2
+
+
 class StackedBatchStream:
   """Groups K consecutive batches into one [K, B, ...]-stacked pytree.
 
@@ -97,11 +102,28 @@ class StackedBatchStream:
   inside `__next__` (a generator would refuse with "generator already
   executing"; closing the plane instead UNBLOCKS that thread).
 
+  Each stack is a set of fresh arrays that belong to whoever iterates
+  the stream, unless that consumer calls `reuse_buffers`: a consumer
+  that copies every dispatch off the host (`ShardedPrefetcher`, onto
+  devices that do not alias host memory) and says through
+  `transfer_started` which arrays are being made from the dispatch it
+  was last given. The stream then stacks into a ring of
+  `_RING_SLOTS` host buffers of its own, allocated per leaf from the
+  shapes of the first dispatch, and writes a slot again only when the
+  arrays that were reading it are ready: memory that is written a
+  second time costs a copy, memory fresh from the allocator a page
+  fault for every 4 KB. A dispatch whose tree, shapes or dtypes are
+  not the ring's gets fresh arrays. The K batches are never touched:
+  they stay the inner stream's own.
+
   Spans (docs/OBSERVABILITY.md): `feed.sample` around each of the K
-  pulls, `feed.stack` around the stack. `seq` counts the stacks this
-  stream has yielded; the prefetcher that consumes it counts the same
-  pulls, and the loop's `TimedIterator` what comes out of the one
-  FIFO between them, so the three agree without being passed along.
+  pulls, `feed.buffer_wait` around the wait for a slot's readers,
+  `feed.stack` around the stack. `seq` counts the stacks this stream
+  has yielded; the prefetcher that consumes it counts the same pulls,
+  and the loop's `TimedIterator` what comes out of the one FIFO
+  between them, so the three agree without being passed along.
+  Counters: `feed.stack.reused_dispatches` (stacked into the ring) and
+  `feed.stack.fresh_dispatches`.
   """
 
   def __init__(self, stream: Iterator[Any], k: int):
@@ -109,9 +131,61 @@ class StackedBatchStream:
     self._k = int(k)
     self._exhausted = False
     self._seq = 0
+    # The ring, once a consumer asked for it: per slot None, or the
+    # slot's host leaves with the arrays that were last made from
+    # them. A slot yielded and not reported through
+    # `transfer_started` is in neither place: it is the consumer's.
+    self._ring: Optional[list] = None
+    self._signature = None  # of the dispatches the ring holds
+    self._slot = 0  # the slot the next dispatch is written to
+    self._lent = None  # (slot, its leaves) of the dispatch yielded last
 
   def __iter__(self):
     return self
+
+  def reuse_buffers(self) -> None:
+    """The consumer's promise, made before it iterates: the arrays of
+    a dispatch are read by nothing but the copy it reports through
+    `transfer_started`, so the stream may write them again once that
+    copy is ready."""
+    if self._ring is None:
+      self._ring = [None] * _RING_SLOTS
+
+  def transfer_started(self, placed: Any) -> None:
+    """`placed` (a pytree of arrays with `block_until_ready`) is being
+    made from the dispatch this stream yielded last."""
+    ring, lent = self._ring, self._lent
+    self._lent = None
+    if ring is not None and lent is not None:
+      slot, leaves = lent
+      ring[slot] = (leaves, placed)
+
+  def _claim_slot(self, batches) -> Any:
+    """A pytree of host arrays to stack `batches` into, or None for
+    fresh ones. Waits until nothing reads them any more."""
+    ring = self._ring  # `close` on another thread unbinds it
+    self._lent = None  # what was yielded and not reported stays away
+    if ring is None:
+      return None
+    signature = _dispatch_signature(batches)
+    if self._signature is None:
+      self._signature = signature
+    if signature is None or signature != self._signature:
+      return None
+    tree, avals = signature
+    slot, self._slot = self._slot, (self._slot + 1) % len(ring)
+    # Taken out of the ring: the device arrays a slot waits on live,
+    # for the ring's part, no longer than this call.
+    held, ring[slot] = ring[slot], None
+    if held is None:
+      leaves = [np.empty((self._k,) + shape, dtype)
+                for shape, dtype in avals]
+    else:
+      leaves, placed = held
+      with tracing.span("feed.buffer_wait", seq=self._seq):
+        jax.block_until_ready(placed)
+    self._lent = (slot, leaves)
+    return jax.tree_util.tree_unflatten(tree, leaves)
 
   def __next__(self):
     if self._exhausted:
@@ -133,17 +207,41 @@ class StackedBatchStream:
               "than K=1 would.", self._k, len(batches), len(batches))
         self.close()  # the inner stream is done: release it now
         raise
+    into = self._claim_slot(batches)
     with tracing.span("feed.stack", seq=self._seq,
                       bytes=tree_nbytes(batches)):
-      stacked = jax.tree_util.tree_map(
-          lambda *xs: np.stack(xs), *batches)
+      if into is None:
+        stacked = jax.tree_util.tree_map(
+            lambda *xs: np.stack(xs), *batches)
+      else:
+        stacked = jax.tree_util.tree_map(
+            lambda out, *xs: np.stack(xs, out=out), into, *batches)
+    tmetrics.counter("feed.stack.fresh_dispatches" if into is None
+                     else "feed.stack.reused_dispatches").inc()
     self._seq += 1
     return stacked
 
   def close(self) -> None:
+    # The ring goes too: its host buffers, and the device arrays its
+    # slots wait on.
+    self._ring = self._lent = None
     closer = getattr(self._it, "close", None)
     if callable(closer):
       closer()
+
+
+def _dispatch_signature(batches) -> Optional[tuple]:
+  """(tree, ((shape, dtype) per leaf)) where the K batches of a
+  dispatch are numpy arrays of one tree, shape and dtype leaf by leaf,
+  else None: only then is `np.stack(xs, out=...)` into a buffer of
+  that shape the same bytes as `np.stack(xs)`."""
+  signatures = set()
+  for batch in batches:
+    leaves, tree = jax.tree_util.tree_flatten(batch)
+    if not all(isinstance(x, np.ndarray) for x in leaves):
+      return None
+    signatures.add((tree, tuple((x.shape, x.dtype) for x in leaves)))
+  return signatures.pop() if len(signatures) == 1 else None
 
 
 def stack_batches(stream: Iterator[Any], k: int) -> StackedBatchStream:
@@ -211,6 +309,13 @@ def device_put_batch(batch: Any, sharding: jax.sharding.Sharding) -> Any:
   return jax.tree_util.tree_map(put, batch)
 
 
+def _copies_off_host(sharding: jax.sharding.Sharding) -> bool:
+  """Whether arrays placed with `sharding` own their bytes: the CPU
+  client may alias an aligned numpy array instead of copying it, and
+  writing that array again would change the `jax.Array`."""
+  return all(d.platform != "cpu" for d in sharding.device_set)
+
+
 class ShardedPrefetcher:
   """Iterator wrapper: host batches → mesh-sharded arrays, N steps ahead.
 
@@ -226,6 +331,13 @@ class ShardedPrefetcher:
   (the call, plus the wait for the transfer only where the zero-copy
   protocol makes one), `feed.queue_put` around the bounded put, which
   is long only while the feed is ahead of the loop.
+
+  The thread reads each host batch once, to place it, and hands on
+  only the placed arrays. So where placement copies the bytes off the
+  host (any platform but `cpu`, whose client may alias a numpy array)
+  it lets a `StackedBatchStream` stack into a ring of its own buffers
+  (`reuse_buffers`), and tells it after each placement which arrays
+  are reading the buffer it yielded last (`transfer_started`).
   """
 
   def __init__(self,
@@ -252,11 +364,18 @@ class ShardedPrefetcher:
       release = getattr(self._iterator, "release_consumed", None)
     # A `StackedBatchStream` names its own K pulls and the stack; the
     # pull around them is `feed.pull`, whose self time is what the pull
-    # costs besides: the K batches and the previous dispatch's host
-    # copy (rebound here) go back to the allocator inside it.
-    pull, pull_args = (("feed.pull", {})
-                       if isinstance(self._iterator, StackedBatchStream)
+    # costs besides: the K batches go back to the allocator inside it
+    # (and the previous dispatch's host copy, rebound here, where the
+    # stream made it afresh).
+    stacked = isinstance(self._iterator, StackedBatchStream)
+    pull, pull_args = (("feed.pull", {}) if stacked
                        else ("feed.sample", {"i": 0}))
+    # This thread reads a host batch once, to place it, and the loop
+    # never sees it: where placing copies the bytes off the host the
+    # stream may stack the next dispatches into the same memory.
+    reuse = stacked and _copies_off_host(self._sharding)
+    if reuse:
+      self._iterator.reuse_buffers()
     try:
       source = iter(self._iterator)
       seq = 0
@@ -269,6 +388,8 @@ class ShardedPrefetcher:
         with tracing.span("feed.device_put", seq=seq,
                           bytes=tree_nbytes(batch)):
           placed = device_put_batch(batch, self._sharding)
+          if reuse:
+            self._iterator.transfer_started(placed)
           if release is not None:
             jax.block_until_ready(placed)
             release()

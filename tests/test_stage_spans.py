@@ -25,6 +25,7 @@ LOOP_SPANS = {"loop.wait_feed", "qtopt.dispatch", "loop.after_step",
               "loop.save_d2h", "loop.save_write",
               "loop.after_checkpoint"}
 CHILDREN = {"feed.sample": "feed.pull", "feed.stack": "feed.pull",
+            "feed.buffer_wait": "feed.pull",
             "replay.draw": "feed.sample", "replay.gather": "feed.sample",
             "loop.log_sync": "loop.log", "loop.save_d2h": "loop.save",
             "loop.save_write": "loop.save",
@@ -78,9 +79,35 @@ def ring(tmp_path_factory):
   tracer = telemetry.get_tracer()
   spans = tracer.snapshot_spans()
   state = {"role": tracer.role, "enabled": tracer.enabled,
-           "path": tracer.trace_path, "dropped": tracer.spans_dropped}
+           "path": tracer.trace_path, "dropped": tracer.spans_dropped,
+           "stack_counts": telemetry.registry().scalars("feed.stack.")}
   _reset()
   return spans, state
+
+
+@pytest.fixture(scope="module")
+def lending_ring(tmp_path_factory):
+  """The ring, and the `feed.stack.` counters, of the same run on
+  devices whose placement copies the bytes off the host, as an
+  accelerator's does: the prefetcher then lends the stream's buffers
+  (ISSUE 25). The CPU client may alias a host array, so here the copy
+  is made for it."""
+  import jax
+
+  from tensor2robot_tpu.data import prefetch
+  real = prefetch.device_put_batch
+  _reset()
+  with pytest.MonkeyPatch.context() as patch:
+    patch.setattr(prefetch, "_copies_off_host", lambda sharding: True)
+    patch.setattr(
+        prefetch, "device_put_batch",
+        lambda batch, sharding: real(
+            jax.tree_util.tree_map(np.array, batch), sharding))
+    _train(tmp_path_factory.mktemp("lending"))
+  spans = telemetry.get_tracer().snapshot_spans()
+  counts = telemetry.registry().scalars("feed.stack.")
+  _reset()
+  return spans, counts
 
 
 def _by_name(spans, name):
@@ -91,8 +118,10 @@ class TestSpansOfARun:
 
   def test_the_trainer_configures_memory_mode(self, ring):
     _, state = ring
-    assert state == {"role": "trainer", "enabled": True, "path": None,
-                     "dropped": 0}
+    assert {key: state[key] for key in
+            ("role", "enabled", "path", "dropped")} == {
+                "role": "trainer", "enabled": True, "path": None,
+                "dropped": 0}
 
   @pytest.mark.parametrize("name", sorted(FEED_SPANS | LOOP_SPANS))
   def test_every_stage_is_in_the_ring_on_its_thread(self, ring, name):
@@ -106,13 +135,16 @@ class TestSpansOfARun:
     assert {s["tid"] for s in found} == {want}
 
   @pytest.mark.parametrize("child,parent", sorted(CHILDREN.items()))
-  def test_children_lie_inside_their_parents(self, ring, child, parent):
-    spans, _ = ring
-    parents = _by_name(spans, parent)
-    for c in _by_name(spans, child):
-      assert any(p["tid"] == c["tid"] and p["ts"] <= c["ts"]
-                 and c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-9
-                 for p in parents), (child, c)
+  def test_children_lie_inside_their_parents(self, ring, lending_ring,
+                                             child, parent):
+    # `feed.buffer_wait` exists only where the prefetcher lends.
+    assert _by_name(lending_ring[0], child)
+    for spans in (ring[0], lending_ring[0]):
+      parents = _by_name(spans, parent)
+      for c in _by_name(spans, child):
+        assert any(p["tid"] == c["tid"] and p["ts"] <= c["ts"]
+                   and c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-9
+                   for p in parents), (child, c)
 
   def test_seq_ties_feed_wait_and_dispatch(self, ring):
     spans, _ = ring
@@ -139,6 +171,41 @@ class TestSpansOfARun:
     assert stack["args"]["bytes"] == K * gather["args"]["bytes"] > 0
     assert gather["args"]["rows"] == 16
     assert isinstance(gather["args"]["native"], bool)
+
+  def test_lending_keeps_one_stack_span_a_dispatch(self, lending_ring):
+    spans, counts = lending_ring
+    stacks = _by_name(spans, "feed.stack")
+    seqs = [s["args"]["seq"] for s in stacks]
+    assert seqs == list(range(len(seqs))) and len(seqs) >= 8
+    gather = _by_name(spans, "replay.gather")[0]
+    for stack in stacks:
+      assert stack["args"]["bytes"] == K * gather["args"]["bytes"] > 0
+    for seq in range(8):  # the dispatches the loop consumed
+      of = lambda name: [s for s in _by_name(spans, name)  # noqa: E731
+                         if s["args"]["seq"] == seq]
+      assert sorted(s["args"]["i"] for s in of("feed.sample")) == [0, 1]
+      for name in ("feed.pull", "feed.stack", "feed.device_put",
+                   "feed.queue_put", "loop.wait_feed"):
+        assert len(of(name)) == 1, (name, seq)
+    # Every dispatch went into the ring; each but the two that found
+    # their slot new waited for the slot's readers, inside the pull
+    # and before the stack.
+    assert counts == {"feed.stack.reused_dispatches": float(len(seqs))}
+    waits = _by_name(spans, "feed.buffer_wait")
+    assert [w["args"]["seq"] for w in waits] == seqs[2:]
+    pulls = {p["args"]["seq"]: p for p in _by_name(spans, "feed.pull")}
+    by_seq = {s["args"]["seq"]: s for s in stacks}
+    for wait in waits:
+      pull, stack = pulls[wait["args"]["seq"]], by_seq[wait["args"]["seq"]]
+      assert wait["tid"] == pull["tid"]
+      assert pull["ts"] <= wait["ts"]
+      assert wait["ts"] + wait["dur"] <= stack["ts"] + 1e-9
+
+  def test_stack_counters_add_up_to_the_dispatches(self, ring):
+    spans, state = ring
+    assert state["stack_counts"] == {
+        "feed.stack.fresh_dispatches":
+            float(len(_by_name(spans, "feed.stack")))}
 
   def test_loop_thread_is_named_from_first_to_last_dispatch(self, ring):
     from benchmark.harness import trace_reduce
@@ -342,6 +409,14 @@ class TestSpanWindow:
     tmetrics.counter("native.gather_rows.fallback_rows").inc(10)
     assert feed_native_gather_share.read(run) == pytest.approx(75.0)
     assert span_window.of_run(run) is run["span_window"]
+
+  def test_stack_reuse_share_reads_the_two_counters(self, clean_plane):
+    from benchmark.layer_metrics import feed_stack_reuse_share
+    assert feed_stack_reuse_share.read({}) is None  # as on the parent
+    tmetrics.counter("feed.stack.reused_dispatches").inc(3)
+    assert feed_stack_reuse_share.read({}) == pytest.approx(100.0)
+    tmetrics.counter("feed.stack.fresh_dispatches").inc(1)
+    assert feed_stack_reuse_share.read({}) == pytest.approx(75.0)
 
   def test_none_after_a_rolled_ring_never_a_partial_number(self):
     spans = _made_up_run()
