@@ -7,20 +7,22 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from benchmark.harness import weights
-from benchmark.reference import qnet
-
-HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def load_limits(cell_name: str) -> Dict[str, float]:
-  with open(os.path.join(HERE, "limits", f"{cell_name}.json")) as f:
+VS_CONTROL = "_vs_control"
+
+
+def load_limits(data_dir: str, cell_name: str) -> Dict[str, float]:
+  """`<data_dir>/limits/<cell>.json` without its notes (keys that
+  start with `_`)."""
+  with open(os.path.join(data_dir, "limits",
+                         f"{cell_name}.json")) as f:
     return {k: v for k, v in json.load(f).items()
             if not k.startswith("_")}
 
@@ -54,35 +56,6 @@ def worst_leaf_gap(program: Dict[str, np.ndarray],
   return max(abs(p[k] - r[k]) / max(r[k], floor, 1e-30) for k in r)
 
 
-def follow_reference(config: dict, inputs: dict, seed32: int,
-                     quant: qnet.Quant = qnet.REFERENCE):
-  """The reference through the first dispatch: K Bellman steps on the
-  K batches the loop's stream yielded first, from the benchmark's
-  weights. Returns (state after K steps, last step's metrics)."""
-  cfg = qnet.NetConfig.from_config(config)
-  rows = config["reference"]["cem_rows_per_block"]
-  step_fn = jax.jit(
-      lambda state, batch, rng: qnet.bellman_step(
-          cfg, state, batch, rng, quant, rows))
-  with jax.default_matmul_precision("highest"):
-    k = len(inputs["batches"])
-    step0 = inputs["first_step"] - k
-    state = qnet.init_state(
-        {k: jnp.asarray(v) for k, v in inputs["params"].items()},
-        {k: jnp.asarray(v) for k, v in inputs["stats"].items()},
-        step0, weights.ADAM_NU0)
-    # The loop keys step s with fold_in(PRNGKey(seed + 1), s).
-    step_rng = jax.random.PRNGKey(seed32 + 1)
-    metrics = None
-    for i, batch in enumerate(inputs["batches"]):
-      state, metrics = step_fn(
-          state, {key: jnp.asarray(v) for key, v in batch.items()},
-          jax.random.fold_in(step_rng, step0 + i))
-    state = jax.device_get(state)
-    metrics = {key: float(v) for key, v in metrics.items()}
-  return state, metrics
-
-
 def _adam_mu(opt_state) -> Dict[str, np.ndarray]:
   for part in jax.tree_util.tree_leaves(
       opt_state, is_leaf=lambda x: hasattr(x, "mu")):
@@ -96,47 +69,92 @@ def numbers_between(got_state: dict, got_metrics: Dict[str, float],
                     ref_metrics: Dict[str, float]) -> Dict[str, float]:
   """The numbers compared for a train cell. `got_*` is what stands in
   the program's place (flat dicts: params, mu, stats), `ref_*` the
-  reference after the same steps, `start` the weights both began at."""
+  reference after the same steps, `start` the weights both began at.
+  `<name>_rel_gap` for every scalar of the reference's metrics
+  (`loss`, `grad_norm` and what a family's reference adds),
+  `q_next_mean_gap` only where the reference has a CEM target,
+  `bn_stats_worst_leaf_gap` only where it has running statistics."""
   delta = {k: np.asarray(got_state["params"][k], np.float64) - start[k]
            for k in start}
   ref_delta = {k: np.asarray(ref_state["params"][k], np.float64)
                - start[k] for k in start}
-  return {
-      "loss_rel_gap": abs(got_metrics["loss"] - ref_metrics["loss"])
-      / abs(ref_metrics["loss"]),
-      "grad_norm_rel_gap":
-          abs(got_metrics["grad_norm"] - ref_metrics["grad_norm"])
-          / ref_metrics["grad_norm"],
-      "q_next_mean_gap":
-          abs(got_metrics["q_next_mean"] - ref_metrics["q_next_mean"]),
-      "adam_mu_worst_leaf_gap": worst_leaf_gap(got_state["mu"],
-                                               ref_state["mu"]),
-      "param_change_worst_leaf_gap": worst_leaf_gap(delta, ref_delta),
-      "bn_stats_worst_leaf_gap": worst_leaf_gap(got_state["stats"],
-                                                ref_state["stats"]),
-  }
+  # Every scalar both sides report, loss and gradient norm first.
+  numbers = {
+      f"{name}_rel_gap":
+          abs(got_metrics[name] - ref) / max(abs(ref), 1e-30)
+      for name, ref in ref_metrics.items() if name in got_metrics}
+  if "q_next_mean" in ref_metrics:  # a mean of probabilities
+    numbers["q_next_mean_gap"] = abs(
+        got_metrics["q_next_mean"] - ref_metrics["q_next_mean"])
+  numbers["adam_mu_worst_leaf_gap"] = worst_leaf_gap(got_state["mu"],
+                                                     ref_state["mu"])
+  numbers["param_change_worst_leaf_gap"] = worst_leaf_gap(delta,
+                                                          ref_delta)
+  if ref_state["stats"]:
+    numbers["bn_stats_worst_leaf_gap"] = worst_leaf_gap(
+        got_state["stats"], ref_state["stats"])
+  return numbers
 
 
-def train_numbers(inputs: dict, ref_state: dict,
-                  ref_metrics: Dict[str, float]) -> Dict[str, float]:
-  """`numbers_between` for the loop's first dispatch: its last step's
-  metrics and the state it checkpointed."""
-  state = inputs["first_state"]
-  got = {"params": weights.flatten(state.params),
-         "mu": _adam_mu(state.opt_state),
-         "stats": weights.flatten(state.batch_stats)}
-  return numbers_between(got, inputs["first_metrics"],
-                         inputs["params"], ref_state, ref_metrics)
+def program_state(state) -> dict:
+  """The train state a loop checkpointed after its first dispatch, as
+  the flat dicts `numbers_between` takes."""
+  return {"params": weights.flatten(state.params),
+          "mu": _adam_mu(state.opt_state),
+          "stats": weights.flatten(state.batch_stats)}
 
 
-def check_train(cell_name: str, config: dict, run: dict,
-                limits: Optional[Dict[str, float]] = None,
-                out=print) -> bool:
+def numbers_of(follow, config: dict, run: dict,
+               control: bool = False) -> Dict[str, float]:
+  """`numbers_between` for the loop's first dispatch (its last step's
+  metrics and the state it checkpointed) against the reference after
+  the same K steps, or with `control` for the control in the program's
+  place against that same reference. `follow(config, inputs, seed32,
+  control)` is a kind's own: (state, metrics) of the reference or of
+  the control after the K steps."""
+  inputs = run["check_inputs"]
+  if "reference" not in run:  # the control is held against the same
+    run["reference"] = follow(config, inputs, run["seed32"], False)
+  if control:
+    got_state, got_metrics = follow(config, inputs, run["seed32"], True)
+  else:
+    got_state, got_metrics = (program_state(inputs["first_state"]),
+                              inputs["first_metrics"])
+  return numbers_between(got_state, got_metrics, inputs["params"],
+                         *run["reference"])
+
+
+def decide(numbers, config: dict, run: dict,
+           limits: Dict[str, float], out=print) -> bool:
+  """`correct` of a run of a train loop: `numbers(config, run)` (a
+  driver's) under `limits`, once the loop gave what they are read
+  from. Leaves each number beside its limit under `run["compared"]`,
+  for the result's line.
+
+  A limit named `<number>_vs_control` holds the program's `<number>`
+  over the control's on the same rows and weights: how far the program
+  stands from the reference in units of how far the next lower
+  precision stands from it. Seeds differ tenfold in how much a rounding
+  moves the numbers, the program's and the control's together, so the
+  quotient is steady where the number itself is not (PERF.md §2); the
+  control in the program's place reads 1. Only a cell whose limits name
+  such a number pays for the control's K steps."""
   inputs = run["check_inputs"]
   if inputs["first_state"] is None or len(inputs["batches"]) != run["k"]:
     out("check: the loop gave no first checkpoint or too few batches")
     return False
-  ref_state, ref_metrics = follow_reference(config, inputs,
-                                            run["seed32"])
-  numbers = train_numbers(inputs, ref_state, ref_metrics)
-  return verdict(numbers, limits or load_limits(cell_name), out)
+  read = numbers(config, run)
+  quotients = [name for name in limits if name.endswith(VS_CONTROL)]
+  if quotients:
+    control = numbers(config, run, control=True)
+    for name in quotients:
+      base = name[:-len(VS_CONTROL)]
+      read[name] = read[base] / control[base]
+  # A number that is not finite goes as text: the result's line has to
+  # stay JSON that any reader takes.
+  run["compared"] = {
+      name: {"value": value if value is None or np.isfinite(value)
+             else repr(value), "limit": limit}
+      for name, limit in limits.items()
+      for value in (read.get(name),)}
+  return verdict(read, limits, out)

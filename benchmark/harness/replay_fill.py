@@ -8,6 +8,7 @@ from typing import Dict, List
 
 import numpy as np
 
+from benchmark.harness import window
 from tensor2robot_tpu.research.qtopt.replay_buffer import ReplayBuffer
 
 
@@ -46,33 +47,10 @@ def fill(buffer: ReplayBuffer, rows: int, seed: int,
     buffer.add(chunk)
 
 
-class _KeepFirst:
-  """Iterator over a batch stream that keeps the first `keep` batches.
-  A class, not a generator, so that the prefetcher can close it from
-  another thread."""
-
-  def __init__(self, inner, kept: List[Dict[str, np.ndarray]],
-               keep: int):
-    self._inner, self._kept, self._keep = iter(inner), kept, keep
-
-  def __iter__(self):
-    return self
-
-  def __next__(self):
-    batch = next(self._inner)
-    if len(self._kept) < self._keep:
-      self._kept.append(dict(batch.to_flat_dict()))
-    return batch
-
-  def close(self) -> None:
-    closer = getattr(self._inner, "close", None)
-    if callable(closer):
-      closer()
-
-
 class RecordingReplay(ReplayBuffer):
   """The shipped buffer; its stream also keeps the first `keep` batches
-  it yields (host arrays the sampler just gathered, untouched)."""
+  it yields (the host arrays the sampler just gathered; a leaf that is
+  a view of memory the stream owns is copied: `window.KeepFirst`)."""
 
   def __init__(self, *args, keep: int = 0, **kwargs):
     super().__init__(*args, **kwargs)
@@ -80,5 +58,6 @@ class RecordingReplay(ReplayBuffer):
     self.kept: List[Dict[str, np.ndarray]] = []
 
   def as_stream(self, batch_size: int):
-    return _KeepFirst(super().as_stream(batch_size), self.kept,
-                      self._keep)
+    return window.KeepFirst(super().as_stream(batch_size), self.kept,
+                            self._keep,
+                            lambda batch: dict(batch.to_flat_dict()))

@@ -1,11 +1,15 @@
-"""Drives a train cell: the shipped `train_qtopt` loop, unedited, with
-the benchmark's replay rows, the benchmark's weights (as the checkpoint
-the loop resumes from) and one benchmark hook that opens and closes the
-measured window from inside the loop.
+"""The `train` kind: the shipped `train_qtopt` loop, unedited, with the
+benchmark's replay rows, the benchmark's weights (as the checkpoint the
+loop resumes from) and the benchmark's hook (`harness/window.py`), which
+opens and closes the measured window from inside the loop.
 
 What is timed is therefore everything the loop does between two device
 syncs: replay sampling, K-stacking, the prefetcher's H2D, the K-step
 program, logging (with its own syncs) and checkpoints.
+
+Here is what is QT-Opt's: the learner, the replay fill, the start
+checkpoint, the call, and the reference's K Bellman steps that the
+outputs check follows.
 """
 
 from __future__ import annotations
@@ -13,139 +17,18 @@ from __future__ import annotations
 import json
 import os
 import time
-from typing import Any, Dict, List, Optional
+from typing import Dict
 
 import jax
+import jax.numpy as jnp
 
-from benchmark.harness import program, replay_fill, weights
-from tensor2robot_tpu.hooks import Hook
+from benchmark.harness import program
 
-
-class WindowClosed(Exception):
-  """Raised by the hook to end `train_qtopt` once the window is shut."""
-
-
-class CompileCounter:
-  """Counts backend compile requests while `armed`: a compile inside
-  the window fails the run."""
-
-  EVENT = "/jax/compilation_cache/compile_requests_use_cache"
-
-  def __init__(self):
-    import jax.monitoring as monitoring
-    self.armed = False
-    self.count = 0
-    monitoring.register_event_listener(self._on_event)
-
-  def _on_event(self, event: str, **kwargs) -> None:
-    if self.armed and event == self.EVENT:
-      self.count += 1
-
-
-class WindowHook(Hook):
-  """Opens the window after `warm` dispatches and closes it `seconds`
-  later, each time behind a `block_until_ready` on the dispatch's
-  metrics; in a traced run records a few dispatches in between.
-
-  The window closes on the first dispatch after `seconds` that also
-  completes a whole save period (`period_steps`) since it opened, so
-  that every window holds as many saves per step as the loop makes:
-  closed on any dispatch, a window of eight dispatches held four
-  saves and one of nine five, and the rate swung with that count."""
-
-  def __init__(self, warm: int, seconds: float, period_steps: int,
-               compiles: CompileCounter, clock_start: float,
-               trace_dir: Optional[str] = None,
-               trace_dispatches: int = 0):
-    self._warm, self._seconds = warm, seconds
-    self._period = period_steps
-    self._compiles = compiles
-    self._clock_start = clock_start
-    self._trace_dir, self._trace_n = trace_dir, trace_dispatches
-    self._dispatches = 0
-    self._tracing_until: Optional[int] = None
-    self.t0 = self.t1 = None
-    self.step0 = self.step1 = None
-    self.setup_s: Optional[float] = None
-    self.first_metrics: Optional[Dict[str, float]] = None
-    self.first_state: Any = None
-    self.first_step: Optional[int] = None
-    self._last_step: Optional[int] = None
-    self.checkpoint_stalls_ms: List[float] = []
-    self.trace_span = None  # [t_start, t_stop] on the host clock
-    # (name, start, end) on the host clock while the trace records:
-    # what the loop was doing, for the trace's idle gaps.
-    self.host_spans: List[tuple] = []
-    self._last_after_step: Optional[float] = None
-    self.first_dispatch_done: Optional[float] = None
-
-  def after_step(self, step: int, metrics: dict) -> None:
-    self._dispatches += 1
-    now = time.perf_counter()
-    if self._tracing_until is not None and self._last_after_step:
-      self.host_spans.append(
-          ("train_qtopt: wait for the feed, dispatch, log",
-           self._last_after_step, now))
-    self._last_step, self._last_after_step = step, now
-    if self._dispatches == 1:
-      self.first_metrics = {k: float(v) for k, v in
-                            jax.device_get(metrics).items()}
-      self.first_step = step
-      self.first_dispatch_done = time.perf_counter()
-    if self.t0 is None:
-      if self._dispatches >= self._warm:
-        jax.block_until_ready(metrics)
-        self.t0, self.step0 = time.perf_counter(), step
-        self.setup_s = self.t0 - self._clock_start
-        self._compiles.armed = True
-        if self._trace_dir:
-          # Device tracing only. With the host tracer at level 1 or 2
-          # (or the Python tracer) this loop's host side grew by a
-          # quarter of a GB a second until the machine's 40 GiB were
-          # gone and no dispatch finished (my chip runs, PR 23).
-          options = jax.profiler.ProfileOptions()
-          options.python_tracer_level = 0
-          options.host_tracer_level = 0
-          t_trace = time.perf_counter()  # the recording's time zero
-          jax.profiler.start_trace(self._trace_dir,
-                                   profiler_options=options)
-          self._tracing_until = self._dispatches + self._trace_n
-          self.trace_span = [t_trace, None]
-      return
-    if self._tracing_until is not None \
-        and self._dispatches >= self._tracing_until:
-      jax.block_until_ready(metrics)
-      self.trace_span[1] = time.perf_counter()
-      jax.profiler.stop_trace()
-      self._tracing_until = None
-    if (now >= self.t0 + self._seconds and self._tracing_until is None
-        and (step - self.step0) % self._period == 0):
-      jax.block_until_ready(metrics)
-      self.t1, self.step1 = time.perf_counter(), step
-      self._compiles.armed = False
-      raise WindowClosed()
-
-  def after_checkpoint(self, step: int, state, model_dir: str) -> None:
-    now = time.perf_counter()
-    if self.first_state is None:
-      if step != self.first_step:
-        raise RuntimeError(
-            f"first checkpoint at step {step}, first dispatch ended at "
-            f"{self.first_step}: the resume step is not aligned")
-      self.first_state = jax.device_get(state)
-    elif self.t0 is not None and step == self._last_step:
-      # `_last_after_step` is still this step's `after_step`.
-      self.checkpoint_stalls_ms.append(
-          (now - self._last_after_step) * 1e3)
-      if self._tracing_until is not None:
-        self.host_spans.append(("train_qtopt: checkpoint",
-                                self._last_after_step, now))
-      self._last_after_step = now
-
-  def end(self, step: int, state, model_dir: str) -> None:
-    if self._tracing_until is not None:  # loop died inside the trace
-      jax.profiler.stop_trace()
-      self._tracing_until = None
+# The harness's other modules are imported inside the functions that
+# use them, after the trainer's own import: imported before it (at this
+# module's top), `setup_s` read 3 to 4 s more on the chip machine, most
+# of it inside the trainer's import of orbax (PERF.md §6, PR 26: why is
+# not known).
 
 
 def _write_start_checkpoint(learner, params, stats, step: int,
@@ -166,26 +49,15 @@ def _write_start_checkpoint(learner, params, stats, step: int,
       json.dump(scales, f)
 
 
-def _window_records(model_dir: str, step0: int, step1: int):
-  """The loop's own log records whose interval lies in the window."""
-  path = os.path.join(model_dir, "metrics_train.jsonl")
-  records = []
-  if os.path.exists(path):
-    with open(path) as f:
-      for line in f:
-        rec = json.loads(line)
-        if step0 < rec["step"] <= step1:
-          records.append({"step": rec["step"], **rec["payload"]})
-  return records
-
-
 def run(config: dict, traffic: dict, *, seed: int,
         seconds: float, trace: bool, devices, clock_start: float,
         work_dir: str) -> dict:
-  """One run of a train cell; returns the run's record (see run.py)."""
+  """One run of a train cell; returns the run's record
+  (benchmark/README.md, "The driver contract")."""
   from tensor2robot_tpu.parallel import mesh as mesh_lib
   from tensor2robot_tpu.research.qtopt.train_qtopt import train_qtopt
   from tensor2robot_tpu.startup import compile_cache
+  from benchmark.harness import replay_fill, weights, window
 
   marks = {"import_trainer_s": time.perf_counter() - clock_start}
   compile_cache.configure_compilation_cache()
@@ -194,9 +66,7 @@ def run(config: dict, traffic: dict, *, seed: int,
   k = train["steps_per_dispatch"]
   batch = train["batch_size_per_chip"] * chips
   save_every = train["save_checkpoints_steps"]
-  # The loop resumes a run some ten thousand steps old, one dispatch
-  # short of a save: its first dispatch ends on a checkpoint step.
-  resume_step = save_every * -(-10000 // save_every) - k
+  resume_step = window.resume_step(save_every, k)
   seed32 = seed % (2 ** 31 - 1)
 
   learner = program.build_learner(config)
@@ -220,15 +90,11 @@ def run(config: dict, traffic: dict, *, seed: int,
   del params, stats
   marks["weights_and_checkpoint_s"] = time.perf_counter() - t
 
-  compiles = CompileCounter()
-  trace_dir = os.path.join(work_dir, "trace") if trace else None
-  hook = WindowHook(traffic["warm_dispatches"], seconds, save_every,
-                    compiles, clock_start, trace_dir,
-                    traffic["trace_dispatches"])
-  from tensor2robot_tpu import telemetry
-  cache0 = telemetry.registry().scalars("compile_cache.")
-  t_loop = time.perf_counter()
-  try:
+  hook = window.hook_for(
+      loop_name="train_qtopt", traffic=traffic, seconds=seconds,
+      period_steps=save_every, clock_start=clock_start,
+      work_dir=work_dir, trace=trace)
+  with window.until_closed(hook, "train_qtopt", marks):
     train_qtopt(
         learner=learner, model_dir=model_dir, replay_buffer=buffer,
         # Far beyond any window; the hook ends the loop.
@@ -239,43 +105,68 @@ def run(config: dict, traffic: dict, *, seed: int,
         mesh=mesh_lib.create_mesh(devices=devices), hooks=[hook],
         seed=seed32, prefill_random=False, steps_per_dispatch=k,
         shard_weight_update=train["shard_weight_update"])
-  except WindowClosed:
-    pass
-  else:
-    raise RuntimeError("train_qtopt returned before the window closed")
-  cache1 = telemetry.registry().scalars("compile_cache.")
-  marks["loop_start_to_first_dispatch_s"] = (
-      hook.first_dispatch_done - t_loop)
-  marks["first_dispatch_to_window_s"] = (
-      hook.t0 - hook.first_dispatch_done)
-  marks["compile_cache"] = {
-      key: cache1.get(key, 0.0) - cache0.get(key, 0.0)
-      for key in cache1}
-  peak = max(d.memory_stats()["peak_bytes_in_use"] for d in devices) \
-      if devices[0].platform != "cpu" else 0
-  records = _window_records(model_dir, hook.step0, hook.step1)
-  window_s = hook.t1 - hook.t0
-  steps = hook.step1 - hook.step0
-  return {
-      "kind": "train",
-      "config": config, "chips": chips, "k": k, "batch": batch,
-      "seed32": seed32, "resume_step": resume_step,
-      "window_s": window_s, "steps": steps,
-      "attempted": steps // k, "failed": compiles.count,
-      "end_to_end": {"train_steps_per_s": steps / window_s,
-                     "setup_s": hook.setup_s},
-      "records": records,
-      "checkpoint_stalls_ms": hook.checkpoint_stalls_ms,
-      "trace_dir": trace_dir, "trace_span": hook.trace_span,
-      "host_spans": hook.host_spans,
-      "trace_program": "jit_k_steps",
-      "memory_peak_bytes": peak,
-      "setup_split": marks,
-      "check_inputs": {
-          "params": host_params, "stats": host_stats,
-          "batches": buffer.kept,
-          "first_metrics": hook.first_metrics,
-          "first_state": hook.first_state,
-          "first_step": hook.first_step,
-      },
-  }
+  return window.record(
+      hook, kind="train", config=config, devices=devices, k=k,
+      batch=batch, seed32=seed32, resume_step=resume_step,
+      model_dir=model_dir, trace_program="jit_k_steps", marks=marks,
+      check_inputs={"params": host_params, "stats": host_stats,
+                    "batches": buffer.kept})
+
+
+def control_quant():
+  """The control of the outputs check: the reference one precision
+  below the configuration's (int8 for the bf16 critic update, int4 for
+  the int8 CEM tower)."""
+  from benchmark.reference import qnet
+  return qnet.Quant(critic_bits=8, tower_bits=4)
+
+
+def follow_reference(config: dict, inputs: dict, seed32: int,
+                     quant=None):
+  """The reference through the first dispatch: K Bellman steps on the
+  K batches the loop's stream yielded first, from the benchmark's
+  weights. `quant` (a `qnet.Quant`) lowers the precision: None is the
+  reference itself. Returns (state after K steps, last step's
+  metrics)."""
+  from benchmark.harness import weights
+  from benchmark.reference import qnet
+  quant = quant or qnet.REFERENCE
+  cfg = qnet.NetConfig.from_config(config)
+  rows = config["reference"]["cem_rows_per_block"]
+  step_fn = jax.jit(
+      lambda state, batch, rng: qnet.bellman_step(
+          cfg, state, batch, rng, quant, rows))
+  with jax.default_matmul_precision("highest"):
+    k = len(inputs["batches"])
+    step0 = inputs["first_step"] - k
+    state = qnet.init_state(
+        {k: jnp.asarray(v) for k, v in inputs["params"].items()},
+        {k: jnp.asarray(v) for k, v in inputs["stats"].items()},
+        step0, weights.ADAM_NU0)
+    # The loop keys step s with fold_in(PRNGKey(seed + 1), s).
+    step_rng = jax.random.PRNGKey(seed32 + 1)
+    metrics = None
+    for i, batch in enumerate(inputs["batches"]):
+      state, metrics = step_fn(
+          state, {key: jnp.asarray(v) for key, v in batch.items()},
+          jax.random.fold_in(step_rng, step0 + i))
+    state = jax.device_get(state)
+    metrics = {key: float(v) for key, v in metrics.items()}
+  return state, metrics
+
+
+def numbers(config: dict, run: dict,
+            control: bool = False) -> Dict[str, float]:
+  """The numbers the check compares, for the program or for the
+  control in its place (`check.numbers_of`)."""
+  from benchmark.harness import check
+  return check.numbers_of(
+      lambda config, inputs, seed32, lowered: follow_reference(
+          config, inputs, seed32, control_quant() if lowered else None),
+      config, run, control)
+
+
+def check(cell_name: str, config: dict, run: dict,
+          limits: Dict[str, float], out=print) -> bool:
+  from benchmark.harness import check
+  return check.decide(numbers, config, run, limits, out)

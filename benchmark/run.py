@@ -3,11 +3,13 @@
   python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s>
                            --trace <0|1>
 
-Everything that belongs to one cell is data found by name: the cell in
-BENCHMARK.json, its configuration under configs/, its traffic mix under
-traffic/ (whose `kind` picks the driver), its limits under limits/, and
-one reader per per-layer metric under layer_metrics/. The last line of
-standard output is the result as one JSON object.
+Everything that belongs to one cell is a file found by name: the cell
+in BENCHMARK.json, its configuration under configs/, its traffic mix
+under traffic/, the driver of the mix's `kind`
+(`harness/<kind>_driver.py`), its limits under limits/, and one reader
+per per-layer metric under layer_metrics/. This file holds no table of
+kinds. The last line of standard output is the result as one JSON
+object.
 """
 
 import time
@@ -26,25 +28,56 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-DRIVERS = {"train": "benchmark.harness.train_driver"}
-CHECKS = {"train": "check_train"}
+BENCH_FILE = os.path.join(ROOT, "BENCHMARK.json")
 
 
-def load_cell(name: str):
-  with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def data_dir(bench: dict) -> str:
+  """Where a benchmark file's traffic/ and limits/ live: the first of
+  its `paths` (`benchmark/` for the root's file)."""
+  return os.path.join(ROOT, bench["paths"][0])
+
+
+def load_cell(name: str, bench_file: str = BENCH_FILE):
+  """(benchmark file, cell, configuration, traffic mix) of one cell of
+  `bench_file`: the root's BENCHMARK.json, or a file of the same keys
+  beside data directories of its own (a test's stand-in)."""
+  with open(bench_file) as f:
     bench = json.load(f)
   cells = {w["name"]: w for w in bench["workloads"]}
   if name not in cells:
-    raise SystemExit(f"no workload {name!r} in BENCHMARK.json; it has "
+    raise SystemExit(f"no workload {name!r} in {bench_file}; it has "
                      f"{sorted(cells)}")
   cell = cells[name]
   config_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
   with open(os.path.join(ROOT, config_entry["file"])) as f:
     config = json.load(f)
-  with open(os.path.join(HERE, "traffic",
+  with open(os.path.join(data_dir(bench), "traffic",
                          f"{cell['traffic']}.json")) as f:
     traffic = json.load(f)
   return bench, cell, config, traffic
+
+
+def kinds():
+  """The traffic kinds that exist: one `harness/<kind>_driver.py`
+  each."""
+  suffix = "_driver.py"
+  return sorted(name[:-len(suffix)]
+                for name in os.listdir(os.path.join(HERE, "harness"))
+                if name.endswith(suffix))
+
+
+def driver_of(kind: str):
+  """The module `benchmark.harness.<kind>_driver`: `run`, `check` and
+  `numbers` of one traffic kind (benchmark/README.md, "The driver
+  contract")."""
+  name = f"benchmark.harness.{kind}_driver"
+  try:
+    return importlib.import_module(name)
+  except ModuleNotFoundError as e:
+    if e.name != name:
+      raise  # the driver is there; something it imports is not
+    raise SystemExit(f"no traffic kind {kind!r} (no {name}); the kinds "
+                     f"are {kinds()}") from None
 
 
 def metrics_of(bench: dict, cell: dict, group: str):
@@ -70,8 +103,13 @@ def main() -> int:
       "--rehearse-cpu", action="store_true",
       help="sandbox only: tiny sizes on whatever devices JAX has; "
       "prints no device metric")
+  parser.add_argument(
+      "--bench-file", default=BENCH_FILE,
+      help="sandbox and builder's chip runs only: another file of "
+      "BENCHMARK.json's keys (a test's stand-in)")
   args = parser.parse_args()
-  bench, cell, config, traffic = load_cell(args.workload)
+  bench, cell, config, traffic = load_cell(args.workload,
+                                           args.bench_file)
   if args.rehearse_cpu:
     config = rehearsal_config(config)
 
@@ -89,7 +127,7 @@ def main() -> int:
     return 2
   devices = devices[:cell["chips"]]
 
-  driver = importlib.import_module(DRIVERS[traffic["kind"]])
+  driver = driver_of(traffic["kind"])
   marks["import_program_s"] = time.perf_counter() - CLOCK_START
   work_dir = tempfile.mkdtemp(prefix="t2r_bench_")
   try:
@@ -117,15 +155,19 @@ def main() -> int:
       breakdown = {"device_ops": trace["device_ops"],
                    "idle_gaps": trace["idle_gaps"]}
     print("setup split:", json.dumps({**marks, **run["setup_split"]}))
+    print("window:", json.dumps({
+        key: run.get(key) for key in ("steps", "window_s",
+                                      "checkpoint_stalls_ms")}))
     # The reference runs now, after the program's state is freed, so
     # that memory_peak_bytes above is the program's alone.
     from benchmark.harness import check
     t_check = time.perf_counter()
-    limits = config.get("limits")  # only a rehearsal's stand-in has them
-    if limits:
-      limits = {k: v for k, v in limits.items() if not k.startswith("_")}
-    correct = getattr(check, CHECKS[traffic["kind"]])(
-        cell["name"], config, run, limits)
+    if "limits" in config:  # only a rehearsal's tiny sizes have them
+      limits = {k: v for k, v in config["limits"].items()
+                if not k.startswith("_")}
+    else:
+      limits = check.load_limits(data_dir(bench), cell["name"])
+    correct = driver.check(cell["name"], config, run, limits)
     print(f"check took {time.perf_counter() - t_check:.1f} s")
   finally:
     shutil.rmtree(work_dir, ignore_errors=True)
@@ -149,13 +191,21 @@ def main() -> int:
             "device": device}
   if breakdown:
     result["breakdown"] = breakdown
+  # Each number compared beside its limit: the last lines of standard
+  # error, and the last key of the result's line.
+  compared = run.get("compared", {})
+  for name, pair in compared.items():
+    print(f"check {name}: {pair['value']!r} limit {pair['limit']!r}",
+          file=sys.stderr)
+  result["check"] = compared
   if args.rehearse_cpu:
     # A CPU's numbers never go under a device metric's name.
     result = {"rehearsal_on": device["platform"],
               "correct": result["correct"],
               "attempted": result["attempted"],
               "failed": result["failed"],
-              "metric_names": sorted(metrics)}
+              "metric_names": sorted(metrics),
+              "check": compared}
   print(json.dumps(result))
   return 0
 

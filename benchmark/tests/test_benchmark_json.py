@@ -15,6 +15,7 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter",
            "host_clock"}
+WIDTHS_DIR = os.path.join(HERE, "tests", "data", "widths")
 WIDTHS = re.compile(r"(hidden|intermediate|latent|state|projection)"
                     r"_size|_dim$|_rank$|head_size|filters|dense_sizes")
 
@@ -97,9 +98,12 @@ def test_every_entry_resolves_to_its_files(bench):
     with open(os.path.join(HERE, "traffic",
                            f"{cell['traffic']}.json")) as f:
       traffic = json.load(f)
+    # A kind is valid when its driver module imports and has the
+    # contract's three functions.
     run = importlib.import_module("benchmark.run")
-    assert traffic["kind"] in run.DRIVERS
-    importlib.import_module(run.DRIVERS[traffic["kind"]])
+    driver = run.driver_of(traffic["kind"])
+    assert all(callable(getattr(driver, name))
+               for name in ("run", "check", "numbers"))
     with open(os.path.join(HERE, "limits",
                            f"{cell['name']}.json")) as f:
       limits = json.load(f)
@@ -117,20 +121,47 @@ def test_every_entry_resolves_to_its_files(bench):
     assert entry["layer"] in layers
 
 
+def _holds(pinned, stated) -> bool:
+  """`stated` has every entry of `pinned`, group by group."""
+  if isinstance(pinned, dict):
+    return isinstance(stated, dict) and all(
+        key in stated and _holds(value, stated[key])
+        for key, value in pinned.items() if not key.startswith("_"))
+  return pinned == stated
+
+
+def _unpinned(configs, widths_dir):
+  """The configurations whose file differs from its pin
+  `<widths_dir>/<config>.json`, or that have none."""
+  wrong = []
+  for entry in configs:
+    pin = os.path.join(widths_dir, f"{entry['name']}.json")
+    if not os.path.exists(pin):
+      wrong.append(f"{entry['name']}: no pin file {pin}")
+      continue
+    with open(pin) as f, open(os.path.join(ROOT, entry["file"])) as g:
+      if not _holds(json.load(f), json.load(g)):
+        wrong.append(f"{entry['name']}: differs from {pin}")
+  return wrong
+
+
 def test_widths_are_the_sources(bench):
   """No width, image size or CEM size of a configuration differs from
-  its source: the paper's 472x472 input and the width-64 stack, and
-  the shipped file's 64x64 network, CEM 2 x 64 with 6 elites."""
-  by_name = {}
-  for entry in bench["configs"]:
-    with open(os.path.join(ROOT, entry["file"])) as f:
-      by_name[entry["name"]] = json.load(f)
-  assert by_name["qtopt_472"]["model"]["image_size"] == 472
-  assert by_name["qtopt_64"]["model"]["image_size"] == 64
-  for config in by_name.values():
-    assert config["cem"] == {"iterations": 2, "population": 64,
-                             "elites": 6}
-    model = config["model"]
-    assert set(model["head_filters"] + model["dense_sizes"]) == {64}
-    assert model["torso_filters"][-1] == 64
-    assert model["action_dim"] == 4
+  its source. Each configuration brings a pin file
+  `tests/data/widths/<config>.json` whose entries its file must have
+  (QT-Opt's: the paper's 472x472 input and the width-64 stack, the
+  shipped file's 64x64 network, CEM 2 x 64 with 6 elites, four action
+  dimensions), so that a later change to either side is an edit."""
+  assert _unpinned(bench["configs"], WIDTHS_DIR) == []
+
+
+def test_a_configuration_without_a_pin_fails(bench, tmp_path):
+  first = bench["configs"][0]["name"]
+  with open(os.path.join(WIDTHS_DIR, f"{first}.json")) as f:
+    pin = json.load(f)
+  pin["model"]["action_dim"] += 1  # a pin its file does not hold
+  (tmp_path / f"{first}.json").write_text(json.dumps(pin))
+  wrong = _unpinned(bench["configs"], str(tmp_path))
+  assert len(wrong) == len(bench["configs"])
+  assert "differs" in wrong[0]
+  assert all("no pin file" in line for line in wrong[1:])

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.harness import check, program, weights
+from benchmark.harness import check, program, train_driver, weights
 from benchmark.reference import qnet
 from tensor2robot_tpu.research.qtopt import GraspingQModel, QTOptLearner
 from tensor2robot_tpu.specs import TensorSpecStruct
@@ -73,9 +73,11 @@ def seeded():
 def _numbers(learner, seeded, quant=qnet.REFERENCE):
   params, stats, batches = seeded
   inputs = _program_numbers(learner, params, stats, batches)
-  ref_state, ref_metrics = check.follow_reference(CONFIG, inputs, SEED,
-                                                  quant)
-  return check.train_numbers(inputs, ref_state, ref_metrics)
+  ref_state, ref_metrics = train_driver.follow_reference(
+      CONFIG, inputs, SEED, quant)
+  return check.numbers_between(
+      check.program_state(inputs["first_state"]),
+      inputs["first_metrics"], inputs["params"], ref_state, ref_metrics)
 
 
 def test_weights_cover_the_program_tree(seeded):
@@ -114,3 +116,27 @@ def test_control_precision_moves_the_numbers(seeded):
   assert control["adam_mu_worst_leaf_gap"] > \
       10 * exact["adam_mu_worst_leaf_gap"], (exact, control)
   assert control["q_next_mean_gap"] > 10 * exact["q_next_mean_gap"]
+
+
+def test_a_number_held_against_the_control(seeded):
+  """`<number>_vs_control`: the program's number over the control's on
+  the same rows. The float32 program stands far closer to the reference
+  than the control does; the control in the program's place reads 1."""
+  params, stats, batches = seeded
+  inputs = _program_numbers(_learner(jnp.float32), params, stats,
+                            batches)
+  run = {"check_inputs": inputs, "seed32": SEED, "k": STEPS}
+  limits = {"q_next_mean_gap_vs_control": 0.3,
+            "adam_mu_worst_leaf_gap_vs_control": 0.35}
+  lines = []
+  assert train_driver.check("tiny", CONFIG, run, limits, lines.append)
+  assert all(pair["value"] < 0.1 for pair in run["compared"].values())
+  # The control's own numbers where the program's stood.
+  numbers = train_driver.numbers(CONFIG, run, control=True)
+
+  def control_in_place(config, run, control=False):
+    return dict(numbers)
+
+  assert not check.decide(control_in_place, CONFIG, run, limits,
+                          lines.append)
+  assert all(pair["value"] == 1.0 for pair in run["compared"].values())

@@ -45,6 +45,23 @@ def test_idle_gaps_are_named_by_the_host_event_inside_them():
   assert gaps == [["$prefetch.py:1 __next__", 300 / 1e9]]
 
 
+def test_a_gap_goes_to_the_innermost_of_nested_spans():
+  """The program's stage spans nest (`loop.log` > `loop.log_sync`,
+  `loop.save` > `loop.save_d2h`, `loop.save_write`): a gap is named by
+  the innermost span that covers most of it, not by its parents."""
+  modules = [("jit_k_steps(1)", 0, 100), ("jit_k_steps(1)", 400, 100),
+             ("jit_k_steps(1)", 900, 100)]
+  host = [("loop.wait_feed", 100, 20), ("qtopt.dispatch", 120, 30),
+          ("loop.log", 150, 240),                 # parent
+          ("loop.log_sync", 155, 230),            # covers the first gap
+          ("loop.save", 500, 390),                # parent
+          ("loop.save_d2h", 505, 40),
+          ("loop.save_write", 545, 340)]          # covers the second
+  gaps = dict(tr.idle_gaps(modules, host, (0, 1000)))
+  assert gaps == {"loop.log_sync": 300 / 1e9,
+                  "loop.save_write": 400 / 1e9}
+
+
 @pytest.fixture(scope="module")
 def recorded():
   return tr.reduce_trace(RECORDED, 1, program="jit_prog")
