@@ -15,9 +15,6 @@ import numpy as np
 from benchmark.harness import weights
 
 
-VS_CONTROL = "_vs_control"
-
-
 def load_limits(data_dir: str, cell_name: str) -> Dict[str, float]:
   """`<data_dir>/limits/<cell>.json` without its notes (keys that
   start with `_`)."""
@@ -56,6 +53,26 @@ def worst_leaf_gap(program: Dict[str, np.ndarray],
   return max(abs(p[k] - r[k]) / max(r[k], floor, 1e-30) for k in r)
 
 
+def rel_err(program: Dict[str, np.ndarray],
+            reference: Dict[str, np.ndarray]) -> float:
+  """sqrt(Σ_leaves ‖p − r‖²) / sqrt(Σ_leaves ‖r‖²) in float64: the
+  norm of the difference over all elements of the tree, not the gap
+  between two norms. A sum of squares, one for every element, cannot
+  cancel: it is 0 only where every element agrees, and the rounding
+  of an element can only raise it, whatever the seed. A gap
+  between norms (`worst_leaf_gap`) or between means is the absolute
+  value of a signed difference, which passes through 0 as the seed
+  varies, so its smallest reading shrinks with the number of seeds
+  read and nothing can be held against it (PERF.md §2)."""
+  diff = size = 0.0
+  for k, r in reference.items():
+    r = np.asarray(r, np.float64)
+    diff += float(np.sum(np.square(
+        np.asarray(program[k], np.float64) - r)))
+    size += float(np.sum(np.square(r)))
+  return float(np.sqrt(diff / max(size, 1e-300)))
+
+
 def _adam_mu(opt_state) -> Dict[str, np.ndarray]:
   for part in jax.tree_util.tree_leaves(
       opt_state, is_leaf=lambda x: hasattr(x, "mu")):
@@ -72,8 +89,11 @@ def numbers_between(got_state: dict, got_metrics: Dict[str, float],
   reference after the same steps, `start` the weights both began at.
   `<name>_rel_gap` for every scalar of the reference's metrics
   (`loss`, `grad_norm` and what a family's reference adds),
-  `q_next_mean_gap` only where the reference has a CEM target,
-  `bn_stats_worst_leaf_gap` only where it has running statistics."""
+  `q_next_mean_gap` only where the reference has a CEM target, the
+  two `bn_stats_*` only where it has running statistics. The
+  `*_rel_err` are norms of differences (`rel_err`): what a precision
+  limit can be held on; the others are gaps between scalars or norms,
+  held against gross faults."""
   delta = {k: np.asarray(got_state["params"][k], np.float64) - start[k]
            for k in start}
   ref_delta = {k: np.asarray(ref_state["params"][k], np.float64)
@@ -90,9 +110,13 @@ def numbers_between(got_state: dict, got_metrics: Dict[str, float],
                                                      ref_state["mu"])
   numbers["param_change_worst_leaf_gap"] = worst_leaf_gap(delta,
                                                           ref_delta)
+  numbers["adam_mu_rel_err"] = rel_err(got_state["mu"], ref_state["mu"])
+  numbers["param_change_rel_err"] = rel_err(delta, ref_delta)
   if ref_state["stats"]:
     numbers["bn_stats_worst_leaf_gap"] = worst_leaf_gap(
         got_state["stats"], ref_state["stats"])
+    numbers["bn_stats_rel_err"] = rel_err(got_state["stats"],
+                                          ref_state["stats"])
   return numbers
 
 
@@ -105,18 +129,20 @@ def program_state(state) -> dict:
 
 
 def numbers_of(follow, config: dict, run: dict,
-               control: bool = False) -> Dict[str, float]:
+               control=False) -> Dict[str, float]:
   """`numbers_between` for the loop's first dispatch (its last step's
   metrics and the state it checkpointed) against the reference after
-  the same K steps, or with `control` for the control in the program's
-  place against that same reference. `follow(config, inputs, seed32,
+  the same K steps, or with `control` (True, or the name of one of a
+  kind's partial controls) for that control in the program's place
+  against that same reference. `follow(config, inputs, seed32,
   control)` is a kind's own: (state, metrics) of the reference or of
   the control after the K steps."""
   inputs = run["check_inputs"]
   if "reference" not in run:  # the control is held against the same
     run["reference"] = follow(config, inputs, run["seed32"], False)
   if control:
-    got_state, got_metrics = follow(config, inputs, run["seed32"], True)
+    got_state, got_metrics = follow(config, inputs, run["seed32"],
+                                    control)
   else:
     got_state, got_metrics = (program_state(inputs["first_state"]),
                               inputs["first_metrics"])
@@ -129,27 +155,14 @@ def decide(numbers, config: dict, run: dict,
   """`correct` of a run of a train loop: `numbers(config, run)` (a
   driver's) under `limits`, once the loop gave what they are read
   from. Leaves each number beside its limit under `run["compared"]`,
-  for the result's line.
-
-  A limit named `<number>_vs_control` holds the program's `<number>`
-  over the control's on the same rows and weights: how far the program
-  stands from the reference in units of how far the next lower
-  precision stands from it. Seeds differ tenfold in how much a rounding
-  moves the numbers, the program's and the control's together, so the
-  quotient is steady where the number itself is not (PERF.md §2); the
-  control in the program's place reads 1. Only a cell whose limits name
-  such a number pays for the control's K steps."""
+  for the result's line. No limit is held against the control: a run
+  follows the reference alone (the control lives in
+  `tools/read_limits.py` and the tests)."""
   inputs = run["check_inputs"]
   if inputs["first_state"] is None or len(inputs["batches"]) != run["k"]:
     out("check: the loop gave no first checkpoint or too few batches")
     return False
   read = numbers(config, run)
-  quotients = [name for name in limits if name.endswith(VS_CONTROL)]
-  if quotients:
-    control = numbers(config, run, control=True)
-    for name in quotients:
-      base = name[:-len(VS_CONTROL)]
-      read[name] = read[base] / control[base]
   # A number that is not finite goes as text: the result's line has to
   # stay JSON that any reader takes.
   run["compared"] = {

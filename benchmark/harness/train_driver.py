@@ -113,12 +113,20 @@ def run(config: dict, traffic: dict, *, seed: int,
                     "batches": buffer.kept})
 
 
-def control_quant():
-  """The control of the outputs check: the reference one precision
-  below the configuration's (int8 for the bf16 critic update, int4 for
-  the int8 CEM tower)."""
+# The controls that lower one part alone (`tools/read_limits.py` reads
+# what the check catches of each).
+PARTIAL_CONTROLS = ("critic_only", "tower_only")
+
+
+def control_quant(control=True):
+  """The control of the outputs check (`control` True): the reference
+  one precision below the configuration's (int8 for the bf16 critic
+  update, int4 for the int8 CEM tower); or, by name, one of
+  `PARTIAL_CONTROLS`."""
   from benchmark.reference import qnet
-  return qnet.Quant(critic_bits=8, tower_bits=4)
+  return {True: qnet.Quant(critic_bits=8, tower_bits=4),
+          "critic_only": qnet.Quant(critic_bits=8),
+          "tower_only": qnet.Quant(tower_bits=4)}[control]
 
 
 def follow_reference(config: dict, inputs: dict, seed32: int,
@@ -155,14 +163,15 @@ def follow_reference(config: dict, inputs: dict, seed32: int,
   return state, metrics
 
 
-def numbers(config: dict, run: dict,
-            control: bool = False) -> Dict[str, float]:
-  """The numbers the check compares, for the program or for the
-  control in its place (`check.numbers_of`)."""
+def numbers(config: dict, run: dict, control=False) -> Dict[str, float]:
+  """The numbers the check compares, for the program or for a control
+  in its place: True is the full control, a name one of
+  `PARTIAL_CONTROLS` (`check.numbers_of`)."""
   from benchmark.harness import check
   return check.numbers_of(
-      lambda config, inputs, seed32, lowered: follow_reference(
-          config, inputs, seed32, control_quant() if lowered else None),
+      lambda config, inputs, seed32, control: follow_reference(
+          config, inputs, seed32,
+          control_quant(control) if control else None),
       config, run, control)
 
 
