@@ -15,7 +15,7 @@ import collections
 import queue
 import threading
 import time
-from typing import Any, Iterator, Optional
+from typing import Any, Callable, Iterator, Optional
 
 import jax
 import numpy as np
@@ -107,28 +107,53 @@ class StackedBatchStream:
   that copies every dispatch off the host (`ShardedPrefetcher`, onto
   devices that do not alias host memory) and says through
   `transfer_started` which arrays are being made from the dispatch it
-  was last given. The stream then stacks into a ring of
+  was last given. The stream then assembles each dispatch in a ring of
   `_RING_SLOTS` host buffers of its own, allocated per leaf from the
   shapes of the first dispatch, and writes a slot again only when the
   arrays that were reading it are ready: memory that is written a
   second time costs a copy, memory fresh from the allocator a page
   fault for every 4 KB. A dispatch whose tree, shapes or dtypes are
-  not the ring's gets fresh arrays. The K batches are never touched:
-  they stay the inner stream's own.
+  not the ring's gets fresh arrays. With the ring in use a dispatch is
+  handed out only once the arrays made from the one before are ready
+  (`_wait_for_transfers`): one transfer out of the ring at a time.
+
+  Whose the K batches are. Without `lend` they are never touched: they
+  stay the inner stream's own, and the stack copies them. `lend` is
+  how a source takes a destination, beside the iterator protocol
+  (which carries nothing towards the source, so a wrapper between the
+  two that forwards only `next` and `close` need not know): a callable
+  that is handed, before pull `i` of a dispatch that goes into the
+  ring, a batch-shaped pytree of views `slot_leaf[i]`, for that one
+  pull. A source that writes its batch there and yields those very
+  arrays (`ReplayBuffer.gather_next_into`) has put it in place: no
+  fresh arrays, no first touch, no second copy. Such a batch is a
+  window onto the slot, which is written again `_RING_SLOTS`
+  dispatches later: whoever keeps it beyond its own dispatch copies
+  it. Any other batch is copied into its slice as
+  before, so a source may ignore the lend. The slot is then claimed,
+  and its readers waited for, before the K pulls and not after. The
+  first dispatch (the ring has no shapes yet) and one of another
+  signature run as without `lend`.
 
   Spans (docs/OBSERVABILITY.md): `feed.sample` around each of the K
-  pulls, `feed.buffer_wait` around the wait for a slot's readers,
-  `feed.stack` around the stack. `seq` counts the stacks this stream
-  has yielded; the prefetcher that consumes it counts the same pulls,
-  and the loop's `TimedIterator` what comes out of the one FIFO
+  pulls, `feed.buffer_wait` around each wait for arrays being made
+  (a slot's readers before it is written, the dispatch before at the
+  end), `feed.stack` around what assembly the pulls left (`bytes`: those
+  copied, 0 when all K landed in place). `seq` counts the stacks this
+  stream has yielded; the prefetcher that consumes it counts the same
+  pulls, and the loop's `TimedIterator` what comes out of the one FIFO
   between them, so the three agree without being passed along.
-  Counters: `feed.stack.reused_dispatches` (stacked into the ring) and
-  `feed.stack.fresh_dispatches`.
+  Counters: `feed.stack.reused_dispatches` (assembled in the ring) and
+  `feed.stack.fresh_dispatches`; with a ring in use,
+  `feed.gather.in_place_batches` (arrived in their slice) and
+  `feed.gather.copied_batches`.
   """
 
-  def __init__(self, stream: Iterator[Any], k: int):
+  def __init__(self, stream: Iterator[Any], k: int,
+               lend: Optional[Callable[[Any], None]] = None):
     self._it = iter(stream)
     self._k = int(k)
+    self._lend_to_source = lend
     self._exhausted = False
     self._seq = 0
     # The ring, once a consumer asked for it: per slot None, or the
@@ -160,41 +185,71 @@ class StackedBatchStream:
       slot, leaves = lent
       ring[slot] = (leaves, placed)
 
-  def _claim_slot(self, batches) -> Any:
-    """A pytree of host arrays to stack `batches` into, or None for
-    fresh ones. Waits until nothing reads them any more."""
+  def _claim_slot(self) -> Optional[list]:
+    """The host leaves, of the ring's signature, that the next dispatch
+    is assembled in, or None without a ring. Waits until nothing reads
+    them any more."""
     ring = self._ring  # `close` on another thread unbinds it
-    self._lent = None  # what was yielded and not reported stays away
     if ring is None:
       return None
-    signature = _dispatch_signature(batches)
-    if self._signature is None:
-      self._signature = signature
-    if signature is None or signature != self._signature:
-      return None
-    tree, avals = signature
     slot, self._slot = self._slot, (self._slot + 1) % len(ring)
     # Taken out of the ring: the device arrays a slot waits on live,
     # for the ring's part, no longer than this call.
     held, ring[slot] = ring[slot], None
     if held is None:
       leaves = [np.empty((self._k,) + shape, dtype)
-                for shape, dtype in avals]
+                for shape, dtype in self._signature[1]]
     else:
       leaves, placed = held
       with tracing.span("feed.buffer_wait", seq=self._seq):
         jax.block_until_ready(placed)
     self._lent = (slot, leaves)
-    return jax.tree_util.tree_unflatten(tree, leaves)
+    return leaves
+
+  def _wait_for_transfers(self) -> None:
+    """One transfer out of the ring at a time: a dispatch is handed
+    out only when the arrays made from the one before are ready. On a
+    TPU v5e a second transfer of a dispatch's size, started while the
+    first was under way, left the runtime's fast path and took 6.3–7.3
+    s instead of 0.3–0.5 (PERF.md §6, PR 29). The gather or stack of
+    this dispatch has overlapped that transfer already."""
+    for held in self._ring or ():
+      if held is not None:
+        with tracing.span("feed.buffer_wait", seq=self._seq):
+          jax.block_until_ready(held[1])
+
+  def _return_slot(self) -> None:
+    """Puts back the slot claimed last, which nothing reads: the
+    dispatch it was claimed for turned out not to be the ring's."""
+    ring, lent = self._ring, self._lent
+    self._lent = None
+    if ring is not None and lent is not None:
+      slot, leaves = lent
+      ring[slot], self._slot = (leaves, ()), slot
 
   def __next__(self):
     if self._exhausted:
       raise StopIteration
-    batches = []
-    for i in range(self._k):
+    k = self._k
+    self._lent = None  # what was yielded and not reported stays away
+    # Where the source takes a destination and the ring knows its
+    # shapes, the slot is claimed before the pulls.
+    into = (self._claim_slot()
+            if self._lend_to_source is not None
+            and self._signature is not None else None)
+    batches, in_place = [], [False] * k
+    for i in range(k):
       try:
         with tracing.span("feed.sample", seq=self._seq, i=i):
+          if into is not None:
+            views = [leaf[i] for leaf in into]
+            self._lend_to_source(jax.tree_util.tree_unflatten(
+                self._signature[0], views))
           batches.append(next(self._it))
+        if into is not None:
+          got = jax.tree_util.tree_leaves(batches[-1])
+          in_place[i] = len(got) == len(views) and all(
+              x is view for x, view in zip(got, views))
       except StopIteration:
         self._exhausted = True
         if batches:
@@ -207,17 +262,38 @@ class StackedBatchStream:
               "than K=1 would.", self._k, len(batches), len(batches))
         self.close()  # the inner stream is done: release it now
         raise
-    into = self._claim_slot(batches)
+    ring_in_use = self._ring is not None
+    if ring_in_use:
+      signature = _dispatch_signature(batches)
+      if self._signature is None:
+        self._signature = signature
+      if signature is None or signature != self._signature:
+        if into is not None:  # np.stack copies what landed there too
+          self._return_slot()
+          into, in_place = None, [False] * k
+      elif into is None:
+        into = self._claim_slot()
+    copied = [b for b, placed in zip(batches, in_place) if not placed]
     with tracing.span("feed.stack", seq=self._seq,
-                      bytes=tree_nbytes(batches)):
+                      bytes=tree_nbytes(copied)):
       if into is None:
         stacked = jax.tree_util.tree_map(
             lambda *xs: np.stack(xs), *batches)
       else:
-        stacked = jax.tree_util.tree_map(
-            lambda out, *xs: np.stack(xs, out=out), into, *batches)
+        for i, batch in enumerate(batches):
+          if not in_place[i]:
+            for leaf, x in zip(into,
+                               jax.tree_util.tree_leaves(batch)):
+              leaf[i] = x
+        stacked = jax.tree_util.tree_unflatten(
+            self._signature[0], into)
+    self._wait_for_transfers()
     tmetrics.counter("feed.stack.fresh_dispatches" if into is None
                      else "feed.stack.reused_dispatches").inc()
+    if ring_in_use:
+      tmetrics.counter("feed.gather.in_place_batches").inc(
+          k - len(copied))
+      tmetrics.counter("feed.gather.copied_batches").inc(len(copied))
     self._seq += 1
     return stacked
 
@@ -244,8 +320,10 @@ def _dispatch_signature(batches) -> Optional[tuple]:
   return signatures.pop() if len(signatures) == 1 else None
 
 
-def stack_batches(stream: Iterator[Any], k: int) -> StackedBatchStream:
-  return StackedBatchStream(stream, k)
+def stack_batches(stream: Iterator[Any], k: int,
+                  lend: Optional[Callable[[Any], None]] = None
+                  ) -> StackedBatchStream:
+  return StackedBatchStream(stream, k, lend)
 
 
 def scan_k_steps(step_fn, state, stacked_batches, rng, step0):

@@ -30,7 +30,7 @@ from typing import Dict, Iterator, Optional, Tuple
 import numpy as np
 
 from tensor2robot_tpu import config as gin
-from tensor2robot_tpu.replay.store import ReplayStore
+from tensor2robot_tpu.replay.store import ReplayStore, to_flat_arrays
 from tensor2robot_tpu.specs import TensorSpecStruct
 from tensor2robot_tpu.telemetry import metrics as tmetrics
 
@@ -64,6 +64,7 @@ class ReplayBatchSampler:
     # tracks the live distribution on long runs.
     self._recent_means = np.zeros(65536, np.float64)
     self._recent_count = 0
+    self._lent: Optional[Dict[str, np.ndarray]] = None
     self._tm_staleness = tmetrics.histogram(
         "replay.staleness_steps", tmetrics.DEFAULT_STEP_BOUNDS)
 
@@ -80,9 +81,22 @@ class ReplayBatchSampler:
     """The fixed wire spec every emitted batch conforms to."""
     return self._store.transition_spec
 
+  def gather_next_into(self, batch) -> None:
+    """Lends the arrays of `batch` (a batch as `sample` returns one:
+    the wire spec's keys → `[batch_size, ...]` arrays) to the next
+    `sample`, which gathers its rows into them and returns them
+    (`ReplayStore.sample_with_ages(out=)`). For that one sample only,
+    whether it succeeds or raises. It passes beside the iterator
+    protocol, which carries nothing towards the source, so a wrapper
+    that forwards only `next` need not know; the caller is the thread
+    that pulls the stream."""
+    self._lent = to_flat_arrays(batch)
+
   def sample(self) -> TensorSpecStruct:
     """One batch; staleness and (optionally) the schedule recorded."""
-    batch, ages, row_ids = self._store.sample_with_ages(self._batch_size)
+    out, self._lent = self._lent, None
+    batch, ages, row_ids = self._store.sample_with_ages(
+        self._batch_size, out=out)
     with self._lock:
       self._counts += np.bincount(
           np.searchsorted(STALENESS_BUCKETS, ages, side="left"),

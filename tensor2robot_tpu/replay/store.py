@@ -309,7 +309,8 @@ class ReplayStore:
     batch, _, _ = self.sample_with_ages(batch_size)
     return batch
 
-  def sample_with_ages(self, batch_size: int
+  def sample_with_ages(self, batch_size: int,
+                       out: Optional[Dict[str, np.ndarray]] = None
                        ) -> Tuple[TensorSpecStruct, np.ndarray,
                                   np.ndarray]:
     """(batch, ages_in_learner_steps [B], global_row_ids [B]).
@@ -328,7 +329,20 @@ class ReplayStore:
     order (its contract) at the cost of one permutation. The gather
     itself is already striped across cores inside `native.gather_rows`,
     which is why there is no per-shard thread fan-out here.
+
+    Without `out` the batch is a set of fresh arrays that belong to
+    the caller. With `out`, a flat dict with every key of the wire
+    spec → a C-contiguous `[batch_size, ...]` array of the store's
+    dtype, the rows are gathered into those arrays and the batch's
+    leaves ARE them: same draw, same rows, same order, no allocation
+    (memory the caller has written before costs a copy; memory fresh
+    from the allocator a page fault for every 4 KB). An `out` that does
+    not fit raises before the draw and before any write. Multi-shard
+    FIFO, which ends on a permutation, returns its own arrays whatever
+    it is given: the caller tells by identity.
     """
+    if out is not None:
+      self._check_out(batch_size, out)
     with tracing.span("replay.draw", rows=batch_size):
       shard_ids, local = self._draw(batch_size)
     # `native`: the library is loaded; a store's arrays are contiguous,
@@ -337,13 +351,34 @@ class ReplayStore:
     with tracing.span("replay.gather", rows=batch_size,
                       bytes=batch_size * self._row_bytes,
                       native=native.native_available()):
-      out, ages, row_ids = self._gather(batch_size, shard_ids, local)
+      out, ages, row_ids = self._gather(batch_size, shard_ids, local,
+                                        out)
     with self._stats_lock:
       self.samples_total += batch_size
       self.sample_calls += 1
     self._tm_samples.inc(batch_size)
     np.maximum(ages, 0, out=ages)  # adds race the step tag by design
     return TensorSpecStruct.from_flat_dict(out), ages, row_ids
+
+  def _check_out(self, batch_size: int,
+                 out: Dict[str, np.ndarray]) -> None:
+    """Raises unless `out` can take a batch as it is: checked for every
+    key before the first row is written to any."""
+    if set(out) != set(self._flat_spec):
+      raise ValueError(
+          f"sample_with_ages: out has keys {sorted(out)}, the store "
+          f"{sorted(self._flat_spec)}.")
+    for key, store in self._shards[0].storage.items():
+      arr = out[key]
+      want = (batch_size,) + store.shape[1:]
+      if (not isinstance(arr, np.ndarray) or arr.shape != want
+          or arr.dtype != store.dtype or not arr.flags.c_contiguous
+          or not arr.flags.writeable):
+        raise ValueError(
+            f"sample_with_ages: out[{key!r}] must be a writable "
+            f"C-contiguous array of {want}/{store.dtype}, got "
+            f"{getattr(arr, 'shape', None)}/"
+            f"{getattr(arr, 'dtype', type(arr).__name__)}.")
 
   def _draw(self, batch_size: int) -> Tuple[np.ndarray, np.ndarray]:
     """(shard ids, slots in the shard) of one batch, under the
@@ -375,24 +410,30 @@ class ReplayStore:
     return shard_ids, local
 
   def _gather(self, batch_size: int, shard_ids: np.ndarray,
-              local: np.ndarray):
+              local: np.ndarray,
+              out: Optional[Dict[str, np.ndarray]] = None):
     """(rows by key, ages, global row ids) of the drawn slots, each
-    shard's slice under that shard's lock only."""
+    shard's slice under that shard's lock only; the rows in `out`'s
+    arrays where it is given (`_check_out` has passed it)."""
     now = self._learner_step
     if self._num_shards == 1:
       # The legacy-exact path: one gather, draw order preserved.
       shard = self._shards[0]
       with shard.lock:
-        out = {key: native.gather_rows(store, local)
+        out = {key: native.gather_rows(
+            store, local, out=None if out is None else out[key])
                for key, store in shard.storage.items()}
         ages = now - shard.add_step[local]
         row_ids = local.copy()
     else:
       order = np.argsort(shard_ids, kind="stable")
       sorted_local = local[order]
-      out = {key: np.empty((batch_size,) + store.shape[1:],
-                           dtype=store.dtype)
-             for key, store in self._shards[0].storage.items()}
+      if out is None or self._sampling == "fifo":
+        out = {key: np.empty((batch_size,) + store.shape[1:],
+                             dtype=store.dtype)
+               for key, store in self._shards[0].storage.items()}
+      else:  # in the store's key order, as without `out`
+        out = {key: out[key] for key in self._shards[0].storage}
       ages = np.empty((batch_size,), np.int64)
       row_ids = np.empty((batch_size,), np.int64)
       counts = np.bincount(shard_ids, minlength=self._num_shards)

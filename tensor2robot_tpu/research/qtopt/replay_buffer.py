@@ -73,6 +73,14 @@ class ReplayBuffer:
     self._stream_sampler = ReplayBatchSampler(self._store, batch_size)
     return iter(self._stream_sampler)
 
+  def gather_next_into(self, batch: TensorSpecStruct) -> None:
+    """Lends `batch`'s arrays to the next sample of the stream that
+    `as_stream` made last (`ReplayBatchSampler.gather_next_into`):
+    how `data.prefetch.StackedBatchStream` has a batch gathered
+    straight into its slice of a dispatch."""
+    if self._stream_sampler is not None:
+      self._stream_sampler.gather_next_into(batch)
+
   def wait_until_size(self, min_size: int,
                       timeout_secs: Optional[float] = None) -> bool:
     """Blocks until `min_size` transitions are buffered (actor warmup)."""

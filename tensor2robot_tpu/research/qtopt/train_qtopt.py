@@ -272,8 +272,11 @@ def train_qtopt(
         out_shardings=(state_sharding, repl),
         donate_argnums=(0,),
     )
+    # A buffer that can gather a batch where it is told to puts each
+    # straight into its slice of the dispatch (`RemoteReplay` cannot).
     stream = prefetch_lib.stack_batches(
-        replay_buffer.as_stream(batch_size), k)
+        replay_buffer.as_stream(batch_size), k,
+        lend=getattr(replay_buffer, "gather_next_into", None))
     stream_sharding = stacked_sharding
 
   # buffer_size is forwarded ONLY when the caller set it: a positional

@@ -247,6 +247,93 @@ class TestReplayStore:
     assert not np.array_equal(draw(3), draw(4))
 
 
+def _filled_store(num_shards, sampling, seed=11):
+  store = ReplayStore(_spec(), capacity=96, num_shards=num_shards,
+                      seed=seed, sampling=sampling)
+  for i in range(6):
+    store.add(make_random_tensors(_spec(), batch_size=16, seed=i),
+              priority=float(1 + i))
+    store.set_learner_step(10 * (i + 1))
+  return store
+
+
+def _out_arrays(store, batch_size, fill=0):
+  return {key: np.full((batch_size,) + tuple(leaf.shape), fill,
+                       dtype=leaf.dtype)
+          for key, leaf in store.transition_spec.to_flat_dict().items()}
+
+
+class TestSampleIntoGivenArrays:
+  """`sample_with_ages(out=)` (ISSUE 29): the same draw and the same
+  rows, in arrays the caller brought."""
+
+  @pytest.mark.parametrize("sampling",
+                           ["uniform", "prioritized", "fifo"])
+  @pytest.mark.parametrize("num_shards", [1, 3])
+  def test_same_bytes_ages_row_ids_and_schedule(self, num_shards,
+                                                sampling):
+    plain = _filled_store(num_shards, sampling)
+    given = _filled_store(num_shards, sampling)
+    keeps_out = not (sampling == "fifo" and num_shards > 1)
+    # The same arrays every call, full of the previous call's rows.
+    out = _out_arrays(given, 24)
+    for call in range(4):
+      want, ages, ids = plain.sample_with_ages(24)
+      got, ages_out, ids_out = given.sample_with_ages(24, out=out)
+      assert list(got.to_flat_dict()) == list(want.to_flat_dict())
+      for key, x in want.to_flat_dict().items():
+        assert got[key].dtype == x.dtype and got[key].shape == x.shape
+        assert got[key].tobytes() == x.tobytes(), (call, key)
+        assert (got[key] is out[key]) == keeps_out
+      np.testing.assert_array_equal(ages_out, ages)
+      np.testing.assert_array_equal(ids_out, ids)
+    # Through the sampler's one-shot lend: the schedule it digests and
+    # the staleness it accounts are those of the plain stream.
+    plain, given = (
+        ReplayBatchSampler(_filled_store(num_shards, sampling), 24,
+                           record_schedule=True) for _ in range(2))
+    for call in range(4):
+      if call != 2:  # a sample without a lend in between
+        given.gather_next_into(TensorSpecStruct.from_flat_dict(out))
+      want, got = plain.sample(), given.sample()
+      assert (got["image"] is out["image"]) == (keeps_out and call != 2)
+      assert got["image"].tobytes() == want["image"].tobytes()
+    assert given.schedule_digest() == plain.schedule_digest()
+    assert given.staleness_snapshot() == plain.staleness_snapshot()
+
+  @pytest.mark.parametrize(
+      "wrong", ["shape", "dtype", "missing_key", "extra_key",
+                "not_contiguous", "read_only"])
+  @pytest.mark.parametrize("num_shards", [1, 3])
+  def test_an_out_that_does_not_fit_raises_before_any_write(
+      self, num_shards, wrong):
+    store = _filled_store(num_shards, "uniform")
+    twin = _filled_store(num_shards, "uniform")
+    out = _out_arrays(store, 24, fill=7)
+    last = list(out)[-1]  # the keys before it would be written first
+    if wrong == "shape":
+      out[last] = out[last][:23]
+    elif wrong == "dtype":
+      out[last] = out[last].astype(np.float64)
+    elif wrong == "missing_key":
+      del out[last]
+    elif wrong == "extra_key":
+      out["extra"] = np.zeros((24,), np.float32)
+    elif wrong == "not_contiguous":
+      out["image"] = np.full((24, 16, 16, 6), 7, np.uint8)[..., ::2]
+    else:
+      out["image"].flags.writeable = False
+    with pytest.raises(ValueError, match="sample_with_ages: out"):
+      store.sample_with_ages(24, out=out)
+    for key, arr in out.items():
+      if key != "extra":
+        assert (arr == 7).all(), key
+    # Nothing was drawn either: the next sample is the twin's first.
+    got, want = store.sample_with_ages(24), twin.sample_with_ages(24)
+    np.testing.assert_array_equal(got[2], want[2])
+    assert got[0]["image"].tobytes() == want[0]["image"].tobytes()
+
+
 class TestReplayWriteService:
 
   def test_put_flush_commits(self):

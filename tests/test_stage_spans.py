@@ -87,7 +87,8 @@ def ring(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def lending_ring(tmp_path_factory):
-  """The ring, and the `feed.stack.` counters, of the same run on
+  """The ring, and the `feed.stack.` and `feed.gather.` counters, of
+  the same run on
   devices whose placement copies the bytes off the host, as an
   accelerator's does: the prefetcher then lends the stream's buffers
   (ISSUE 25). The CPU client may alias a host array, so here the copy
@@ -105,7 +106,8 @@ def lending_ring(tmp_path_factory):
             jax.tree_util.tree_map(np.array, batch), sharding))
     _train(tmp_path_factory.mktemp("lending"))
   spans = telemetry.get_tracer().snapshot_spans()
-  counts = telemetry.registry().scalars("feed.stack.")
+  counts = {**telemetry.registry().scalars("feed.stack."),
+            **telemetry.registry().scalars("feed.gather.")}
   _reset()
   return spans, counts
 
@@ -177,9 +179,12 @@ class TestSpansOfARun:
     stacks = _by_name(spans, "feed.stack")
     seqs = [s["args"]["seq"] for s in stacks]
     assert seqs == list(range(len(seqs))) and len(seqs) >= 8
+    # The first dispatch, which gives the ring its shapes, is copied
+    # into its slot; the replay buffer gathers every later batch
+    # straight into its slice (ISSUE 29), and the stack copies nothing.
     gather = _by_name(spans, "replay.gather")[0]
-    for stack in stacks:
-      assert stack["args"]["bytes"] == K * gather["args"]["bytes"] > 0
+    assert [s["args"]["bytes"] for s in stacks] == \
+        [K * gather["args"]["bytes"]] + [0] * (len(seqs) - 1)
     for seq in range(8):  # the dispatches the loop consumed
       of = lambda name: [s for s in _by_name(spans, name)  # noqa: E731
                          if s["args"]["seq"] == seq]
@@ -187,19 +192,33 @@ class TestSpansOfARun:
       for name in ("feed.pull", "feed.stack", "feed.device_put",
                    "feed.queue_put", "loop.wait_feed"):
         assert len(of(name)) == 1, (name, seq)
-    # Every dispatch went into the ring; each but the two that found
-    # their slot new waited for the slot's readers, inside the pull
-    # and before the stack.
-    assert counts == {"feed.stack.reused_dispatches": float(len(seqs))}
+    # Every dispatch went into the ring. Inside its pull, each but the
+    # first waited, after its stack, for the arrays made from the one
+    # before (one transfer out of the ring at a time), and each but the
+    # two that found their slot new waited for the slot's readers
+    # before the first batch was gathered into it.
+    assert counts == {
+        "feed.stack.reused_dispatches": float(len(seqs)),
+        "feed.gather.copied_batches": float(K),
+        "feed.gather.in_place_batches": float(K * (len(seqs) - 1))}
     waits = _by_name(spans, "feed.buffer_wait")
-    assert [w["args"]["seq"] for w in waits] == seqs[2:]
+    assert sorted(w["args"]["seq"] for w in waits) == sorted(
+        seqs[1:] + seqs[2:])
     pulls = {p["args"]["seq"]: p for p in _by_name(spans, "feed.pull")}
     by_seq = {s["args"]["seq"]: s for s in stacks}
-    for wait in waits:
-      pull, stack = pulls[wait["args"]["seq"]], by_seq[wait["args"]["seq"]]
-      assert wait["tid"] == pull["tid"]
-      assert pull["ts"] <= wait["ts"]
-      assert wait["ts"] + wait["dur"] <= stack["ts"] + 1e-9
+    samples = _by_name(spans, "feed.sample")
+    for seq in seqs[1:]:
+      of = sorted((w for w in waits if w["args"]["seq"] == seq),
+                  key=lambda w: w["ts"])
+      pull, stack = pulls[seq], by_seq[seq]
+      first = min(s["ts"] for s in samples if s["args"]["seq"] == seq)
+      assert all(w["tid"] == pull["tid"] and pull["ts"] <= w["ts"]
+                 and w["ts"] + w["dur"] <= pull["ts"] + pull["dur"] + 1e-9
+                 for w in of)
+      assert stack["ts"] + stack["dur"] <= of[-1]["ts"] + 1e-9
+      if seq >= 2:
+        assert len(of) == 2
+        assert of[0]["ts"] + of[0]["dur"] <= first + 1e-9
 
   def test_stack_counters_add_up_to_the_dispatches(self, ring):
     spans, state = ring
@@ -417,6 +436,15 @@ class TestSpanWindow:
     assert feed_stack_reuse_share.read({}) == pytest.approx(100.0)
     tmetrics.counter("feed.stack.fresh_dispatches").inc(1)
     assert feed_stack_reuse_share.read({}) == pytest.approx(75.0)
+
+  def test_gather_in_place_share_reads_the_two_counters(self,
+                                                       clean_plane):
+    from benchmark.layer_metrics import feed_gather_in_place_share
+    assert feed_gather_in_place_share.read({}) is None  # the parent
+    tmetrics.counter("feed.gather.copied_batches").inc(4)
+    assert feed_gather_in_place_share.read({}) == pytest.approx(0.0)
+    tmetrics.counter("feed.gather.in_place_batches").inc(12)
+    assert feed_gather_in_place_share.read({}) == pytest.approx(75.0)
 
   def test_none_after_a_rolled_ring_never_a_partial_number(self):
     spans = _made_up_run()
