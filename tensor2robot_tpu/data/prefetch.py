@@ -63,8 +63,7 @@ def validate_steps_per_dispatch(k: int, **cadences: Optional[int]
 
   Every named cadence (log/checkpoint/eval/max-steps) must be a
   multiple of K — boundaries are only observable between dispatches.
-  Shared by both trainers so the contract cannot silently diverge.
-  Returns k. None-valued cadences are skipped.
+  `train_loop.TrainLoop` checks it for every trainer. Returns k. None-valued cadences are skipped.
   """
   k = int(k)
   if k < 1:
@@ -582,11 +581,11 @@ class ShardedPrefetcher:
 class TimedIterator:
   """Iterator wrapper accumulating wall time spent blocked in `next()`.
 
-  The `input_wait_fraction` measurement both trainers log: near 0 the
-  feed keeps up (the device is the bottleneck); toward 1 the chip
-  starves — the continuously-measured form of the bench's `feeds_chip`
-  verdict. Shared here so the two train loops' feed-boundness metric
-  cannot drift apart. Raise `TFRecordInputGenerator.num_workers` (the
+  The `input_wait_fraction` measurement of every trainer with a feed
+  (`train_loop.TrainLoop.attach_feed` wraps the prefetcher in one):
+  near 0 the feed keeps up (the device is the bottleneck); toward 1
+  the chip starves — the continuously-measured form of the bench's
+  `feeds_chip` verdict. Raise `TFRecordInputGenerator.num_workers` (the
   process-parallel data plane, docs/DATA.md) when it climbs.
   """
 

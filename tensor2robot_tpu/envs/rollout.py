@@ -31,8 +31,6 @@ Three layers, composable separately:
 from __future__ import annotations
 
 import logging
-import os
-import time
 from typing import Callable, Dict, Iterable, Optional, Tuple
 
 import jax
@@ -40,7 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from tensor2robot_tpu import config as gin
-from tensor2robot_tpu import telemetry
 from tensor2robot_tpu.envs.core import (
     AutoResetEnv,
     BatchedEnv,
@@ -357,17 +354,11 @@ def train_anakin(
   fleet's lag dashboards stay comparable); replay staleness is bounded
   by ``capacity / (num_envs · rollout_length)`` iterations.
   """
-  from tensor2robot_tpu.data import prefetch as prefetch_lib
-  from tensor2robot_tpu.hooks import HookList
+  from tensor2robot_tpu import train_loop
   from tensor2robot_tpu.specs import TensorSpecStruct
-  from tensor2robot_tpu.train_eval import MetricLogger
   from tensor2robot_tpu.utils import checkpoints as ckpt_lib
+  from tensor2robot_tpu.utils import profiling
 
-  k = prefetch_lib.validate_steps_per_dispatch(
-      train_batches_per_iter,
-      log_every_steps=log_every_steps,
-      save_checkpoints_steps=save_checkpoints_steps,
-      max_train_steps=max_train_steps)
   if env is None:
     env = _build_env(env_family, learner.model)
 
@@ -398,21 +389,16 @@ def train_anakin(
   _check_wire_spec(learner)
   spec = learner.transition_specification().to_flat_dict()
 
-  os.makedirs(model_dir, exist_ok=True)
   # The anakin trainer's records carry its own envelope role without
   # touching the process-global tracer identity.
-  metric_logger = MetricLogger(model_dir, role="anakin")
-  hook_list = HookList(list(hooks))
-  from tensor2robot_tpu.startup import compile_cache
-  compile_cache.configure_compilation_cache()
-  # The always-on perf plane (ISSUE 15): resource watermarks + alert
-  # sentinel per process, live MFU gauges at log cadence below.
-  from tensor2robot_tpu.telemetry import perf as perf_lib
-  from tensor2robot_tpu.telemetry import sentinel as sentinel_lib
-  from tensor2robot_tpu.utils import profiling
-  perf_lib.start_resource_sampler(
-      sources=[profiling.device_memory_source()])
-  watch_sentinel = sentinel_lib.build_for_run(model_dir)
+  loop = train_loop.TrainLoop(
+      model_dir, hooks, dispatch_span="anakin.dispatch",
+      steps_per_dispatch=train_batches_per_iter,
+      max_train_steps=max_train_steps,
+      log_every_steps=log_every_steps,
+      save_checkpoints_steps=save_checkpoints_steps,
+      max_checkpoints_to_keep=max_checkpoints_to_keep, role="anakin")
+  k = loop.k
 
   from tensor2robot_tpu.parallel import mesh as mesh_lib
 
@@ -466,11 +452,6 @@ def train_anakin(
   # same program but are not model flops; docs/PERF.md).
   per_device_flops = profiling.qtopt_step_flops(
       learner, batch_size, params=state.train_state.params)
-  perf_meter = perf_lib.PerfMeter(
-      flops_per_step=(per_device_flops * d
-                      if per_device_flops else None),
-      peak_flops=profiling.device_peak_flops(),
-      devices=d)
   resume_step = ckpt_lib.latest_step(model_dir)
   if resume_step is not None:
     log.info("Resuming anakin QT-Opt from step %d", resume_step)
@@ -519,12 +500,6 @@ def train_anakin(
       state_shardings = jax.tree_util.tree_map(lambda _: repl, state)
     state = jax.device_put(state, state_shardings)
   step = int(np.asarray(jax.device_get(state.step)))
-  if k > 1 and step % k and step < max_train_steps:
-    metric_logger.close()
-    raise ValueError(
-        f"Resumed at step {step}, not a multiple of "
-        f"train_batches_per_iter={k}: the checkpoint/log boundaries "
-        "would never align.")
 
   init_fn, collect_fn = make_collect_fn(
       learner, env, per_env, rollout_length, epsilon=epsilon,
@@ -780,93 +755,52 @@ def train_anakin(
       return tree
     return jax.tree_util.tree_map(lambda x: x[0], tree)
 
-  hook_list.begin(learner.model, model_dir)
-  writer = ckpt_lib.CheckpointWriter(
-      model_dir, max_to_keep=max_checkpoints_to_keep)
+  def own_scalars(scalars, steps, dt, stall_secs):
+    del stall_secs  # the rates are the interval's, saves and all
+    if spmd and not use_shard_map:
+      # shard_map metrics are already global scalars, and its params
+      # are ONE logical replicated array — there are no per-replica
+      # copies to checksum-compare.
+      checks = np.asarray(scalars.pop("param_checksum"))
+      if np.unique(checks).size != 1:
+        raise RuntimeError(
+            "pod replicas diverged: per-device param checksums "
+            f"{checks.tolist()} at step {loop.step} — a gradient or "
+            "state update escaped the pmean")
+      for name in scalars:
+        scalars[name] = scalars[name][0]
+    scalars["grad_steps_per_sec"] = steps / max(dt, 1e-9)
+    scalars["env_steps_per_sec"] = (steps // k * rows) / max(dt, 1e-9)
+    if spmd:
+      scalars["devices"] = d
+      scalars["global_batch_size"] = d * batch_size
+      # Bellman THROUGHPUT: each optimizer step consumed one
+      # batch_size-row batch per device.
+      scalars["bellman_batches_per_sec"] = (
+          scalars["grad_steps_per_sec"] * d)
+    # Zero BY CONSTRUCTION (acting params == training params in one
+    # program) — logged so fleet-mode dashboards compare.
+    scalars["param_refresh_lag_steps"] = 0.0
+    return "grad_steps_per_sec"
+
   carry = (state, env_states, replay, size0, ptr0)
+  loop.begin(
+      learner.model, step,
+      flops_per_step=per_device_flops * d if per_device_flops else None,
+      devices=d,
+      save_payload=lambda: train_loop.host_payload(device0(carry[0])),
+      hook_state=lambda: device0(carry[0]).train_state,
+      hook_metrics=device0,
+      own_scalars=own_scalars)
   iter_key = jax.random.PRNGKey(seed + 4)
-  t_last = time.time()
-  steps_since_log = 0
-  last_saved = resume_step
-  try:
-    while step < max_train_steps:
-      # Per-dispatch timing span: one collect-and-learn device program
-      # (rollout segment + ring insert + K Bellman steps).
-      with perf_meter.dispatch("anakin.dispatch", step=step, k=k,
-                               devices=d):
+  with loop:
+    for _ in loop.dispatches():
+      # One collect-and-learn device program: rollout segment + ring
+      # insert + K Bellman steps.
+      with loop.dispatch():
         carry, metrics = anakin_step(
-            carry, jax.random.fold_in(iter_key, step))
-      step += k
-      steps_since_log += k
-      hook_list.after_step(step, device0(metrics))
-      if step % log_every_steps == 0 or step == max_train_steps:
-        scalars = jax.device_get(metrics)
-        if spmd and not use_shard_map:
-          # shard_map metrics are already global scalars, and its
-          # params are ONE logical replicated array — there are no
-          # per-replica copies to checksum-compare.
-          checks = np.asarray(scalars.pop("param_checksum"))
-          if np.unique(checks).size != 1:
-            raise RuntimeError(
-                "pod replicas diverged: per-device param checksums "
-                f"{checks.tolist()} at step {step} — a gradient or "
-                "state update escaped the pmean")
-          scalars = {name: value[0] for name, value in
-                     scalars.items()}
-        dt = time.time() - t_last
-        iters = steps_since_log // k
-        scalars["grad_steps_per_sec"] = steps_since_log / max(dt, 1e-9)
-        scalars["env_steps_per_sec"] = (iters * rows) / max(dt, 1e-9)
-        if spmd:
-          scalars["devices"] = d
-          scalars["global_batch_size"] = d * batch_size
-          # Bellman THROUGHPUT: each optimizer step consumed one
-          # batch_size-row batch per device.
-          scalars["bellman_batches_per_sec"] = (
-              scalars["grad_steps_per_sec"] * d)
-        # Zero BY CONSTRUCTION (acting params == training params in
-        # one program) — logged so fleet-mode dashboards compare.
-        scalars["param_refresh_lag_steps"] = 0.0
-        scalars.update(telemetry.registry().scalars("compile_cache."))
-        # Resource watermarks persist with the run (report tool).
-        scalars.update(telemetry.registry().scalars("rsrc."))
-        telemetry.registry().gauge("train.grad_steps_per_sec").set(
-            scalars["grad_steps_per_sec"])
-        # Live utilization (perf.mfu / flops_per_sec) — bench's
-        # denominator, pod-aware.
-        scalars.update(perf_meter.publish(
-            scalars["grad_steps_per_sec"]))
-        metric_logger.write("train", step, scalars)
-        if watch_sentinel is not None:
-          watch_sentinel.evaluate(
-              {**telemetry.registry().scalars(), **scalars},
-              step=step)
-        t_last = time.time()
-        steps_since_log = 0
-      if step % save_checkpoints_steps == 0 or step == max_train_steps:
-        host_state = jax.device_get(device0(carry[0]))
-        writer.save(step, host_state,
-                    params=host_state.train_state.params,
-                    batch_stats=host_state.train_state.batch_stats)
-        last_saved = step
-        hook_list.after_checkpoint(step, device0(carry[0]).train_state,
-                                   model_dir)
-    if last_saved != step:
-      host_state = jax.device_get(device0(carry[0]))
-      writer.save(step, host_state,
-                  params=host_state.train_state.params,
-                  batch_stats=host_state.train_state.batch_stats)
-      hook_list.after_checkpoint(step, device0(carry[0]).train_state,
-                                 model_dir)
-  finally:
-    try:
-      hook_list.end(step, device0(carry[0]).train_state, model_dir)
-    except Exception:  # noqa: BLE001 — don't mask the original error
-      log.exception("hook end() failed during teardown")
-    writer.close()
-    if watch_sentinel is not None:
-      watch_sentinel.close()
-    metric_logger.close()
+            carry, jax.random.fold_in(iter_key, loop.step))
+      loop.after_dispatch(metrics)
   return device0(carry[0])
 
 

@@ -98,7 +98,7 @@ def mfu_value(steps_per_sec: float,
 class PerfMeter:
   """Per-process live performance attribution (one per train loop).
 
-  Usage (the three trainers):
+  Usage (`train_loop.TrainLoop`, which the three trainers drive):
 
       meter = perf.PerfMeter(flops_per_step=..., peak_flops=...,
                              devices=D)

@@ -2,7 +2,7 @@
 
 Every metrics sink in the repo — the supervised trainer, the anakin
 trainer, the fleet learner's train_qtopt, the success-eval hooks —
-writes through `train_eval.MetricLogger`, and as of ISSUE 11 every
+writes through `train_loop.MetricLogger`, and as of ISSUE 11 every
 record it emits is ONE envelope::
 
     {"step": int, "wall": float, "role": str, "payload": {name: float}}

@@ -18,30 +18,30 @@ orbax; resume is automatic from the latest checkpoint in `model_dir`.
 
 from __future__ import annotations
 
-import json
 import logging
-import os
 import time
 from typing import Any, Callable, Dict, Iterable, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from tensor2robot_tpu import config as gin
 from tensor2robot_tpu import telemetry
+from tensor2robot_tpu import train_loop
 from tensor2robot_tpu.data.abstract_input_generator import (
     AbstractInputGenerator,
     Mode,
 )
 from tensor2robot_tpu.data import prefetch as prefetch_lib
-from tensor2robot_tpu.hooks import Hook, HookList
+from tensor2robot_tpu.hooks import Hook
 from tensor2robot_tpu.models.model_interface import ModelInterface
 from tensor2robot_tpu.parallel import mesh as mesh_lib
 from tensor2robot_tpu.parallel import state_sharding
 from tensor2robot_tpu.startup import compile_cache
 from tensor2robot_tpu.startup import orchestrator
+from tensor2robot_tpu.train_loop import MetricLogger
 from tensor2robot_tpu.utils import checkpoints as ckpt_lib
+from tensor2robot_tpu.utils import profiling
 
 log = logging.getLogger(__name__)
 
@@ -49,42 +49,6 @@ log = logging.getLogger(__name__)
 # readable by default (users can re-raise the level explicitly).
 for _noisy in ("orbax", "absl"):
   logging.getLogger(_noisy).setLevel(logging.WARNING)
-
-
-class MetricLogger:
-  """Scalar metric sink: stdout + JSONL file per tag (train/eval).
-
-  Every record is the unified telemetry envelope
-  ``{"step", "wall", "role", "payload"}`` (telemetry.records — the
-  ISSUE 11 schema every producer shares: this trainer, anakin, the
-  fleet learner, the success-eval hooks). ``role`` defaults to the
-  process's telemetry role; read back with
-  `telemetry.records.read_records`, which also normalizes pre-envelope
-  files.
-  """
-
-  def __init__(self, model_dir: str, role: Optional[str] = None):
-    self._model_dir = model_dir
-    self._role = role
-    os.makedirs(model_dir, exist_ok=True)
-    self._files: Dict[str, Any] = {}
-
-  def write(self, tag: str, step: int, metrics: Dict[str, Any]) -> None:
-    scalars = {k: float(np.asarray(v)) for k, v in metrics.items()}
-    if tag not in self._files:
-      self._files[tag] = open(
-          os.path.join(self._model_dir, f"metrics_{tag}.jsonl"), "a")
-    record = telemetry.records.make_record(step, scalars,
-                                           role=self._role)
-    self._files[tag].write(json.dumps(record) + "\n")
-    self._files[tag].flush()
-    rendered = ", ".join(f"{k}={v:.5g}" for k, v in scalars.items())
-    log.info("[%s] step %d: %s", tag, step, rendered)
-
-  def close(self) -> None:
-    for f in self._files.values():
-      f.close()
-    self._files.clear()
 
 
 def _compile_steps(model: ModelInterface, mesh, donate: bool = True,
@@ -270,19 +234,17 @@ def train_eval_model(
 
   Returns the final TrainState (on device, placed per the strategy).
   """
-  compile_cache.configure_compilation_cache()
   if mesh is None:
     mesh = mesh_lib.create_mesh()
-  # Validate the dispatch quantization BEFORE any side effects.
-  k = prefetch_lib.validate_steps_per_dispatch(
-      steps_per_dispatch,
+  loop = train_loop.TrainLoop(
+      model_dir, hooks, dispatch_span="train.dispatch",
+      steps_per_dispatch=steps_per_dispatch,
+      max_train_steps=max_train_steps,
       log_every_steps=log_every_steps,
       save_checkpoints_steps=save_checkpoints_steps,
-      max_train_steps=max_train_steps,
+      max_checkpoints_to_keep=max_checkpoints_to_keep,
       eval_every_steps=eval_every_steps)
-  os.makedirs(model_dir, exist_ok=True)
-  metric_logger = MetricLogger(model_dir)
-  hook_list = HookList(list(hooks))
+  k = loop.k
 
   # --- bind generators to the model's wire specs ---
   if input_generator_train is not None:
@@ -397,7 +359,6 @@ def train_eval_model(
     return out
 
   aot: Optional[Dict[str, Any]] = None
-  train_prefetcher = None
   phases: Dict[str, Any] = {}
   if overlap_startup:
     if will_train or input_generator_eval is not None:
@@ -415,11 +376,14 @@ def train_eval_model(
       # A failed phase must not leak a sibling's resources: the input
       # prefetcher pins buffered sharded batches in device memory.
       orchestrator.close_quietly(report.results.get("input"))
-      metric_logger.close()
+      loop.close()
       report.raise_first(order=("restore", "input", "compile"))
     aot = report.results.get("compile")
     state = report.results.get("restore", state)
-    train_prefetcher = report.results.get("input")
+    if report.results.get("input") is not None:
+      # The loop's from here on: whatever fails, its teardown closes
+      # the worker (it pins buffered sharded batches in HBM).
+      loop.attach_feed(report.results["input"])
     try:
       report.write(model_dir)
     except OSError:
@@ -431,20 +395,6 @@ def train_eval_model(
              model_dir)
     state = _restore_phase()
 
-  writer = ckpt_lib.CheckpointWriter(
-      model_dir, max_to_keep=max_checkpoints_to_keep)
-  # Resume-alignment check BEFORE hooks begin: raising later would
-  # leak whatever begin() started past hook_list.end().
-  step = int(np.asarray(jax.device_get(state.step)))
-  if k > 1 and step % k and step < max_train_steps:
-    if train_prefetcher is not None:
-      train_prefetcher.close()
-    writer.close()
-    metric_logger.close()
-    raise ValueError(
-        f"Resumed at step {step}, not a multiple of "
-        f"steps_per_dispatch={k}: boundaries would never align.")
-
   if aot:
     train_callable = _checked_aot(
         aot.get("train"), train_step, *aot.get("train_avals", (None, None)),
@@ -455,158 +405,80 @@ def train_eval_model(
   else:
     train_callable, eval_callable = train_step, eval_step
 
-  # The always-on perf plane (ISSUE 15): resource sampler + sentinel
-  # per process, and live MFU attribution at log cadence. The generic
-  # trainer has no analytic model-flops formula (arbitrary models), so
-  # the denominator is XLA's cost analysis of the AOT-compiled train
-  # program (÷ K for the scanned dispatch) — approximate but stable
-  # for the run; absent (lazy-jit fallback), perf.mfu is simply not
-  # published.
-  from tensor2robot_tpu.telemetry import perf as perf_lib
-  from tensor2robot_tpu.telemetry import sentinel as sentinel_lib
-  from tensor2robot_tpu.utils import profiling
-  perf_lib.start_resource_sampler(
-      sources=[profiling.device_memory_source()])
-  watch_sentinel = sentinel_lib.build_for_run(model_dir)
+  # Live MFU attribution: the generic trainer has no analytic
+  # model-flops formula (arbitrary models), so the denominator is XLA's
+  # cost analysis of the AOT-compiled train program (÷ K for the
+  # scanned dispatch) — approximate but stable for the run; absent
+  # (lazy-jit fallback), perf.mfu is simply not published.
   train_flops = None
   if aot and aot.get("train") is not None:
     flops_per_call = profiling.compiled_flops_per_call(aot["train"])
     if flops_per_call:
       train_flops = flops_per_call / k
-  perf_meter = perf_lib.PerfMeter(
-      flops_per_step=train_flops,
-      peak_flops=profiling.device_peak_flops(),
-      devices=mesh.size)
 
-  final_metrics: Dict[str, Any] = {}
-  try:
-    # Inside the try: with overlapped startup the prefetcher is
-    # already live, and a hook whose begin() raises must not leak its
-    # worker (the finally below closes it along with writer/logger).
-    hook_list.begin(model, model_dir)
-    if input_generator_train is not None and step < max_train_steps:
-      if train_prefetcher is None:
+  def own_scalars(scalars, steps, dt, stall_secs):
+    # `steps_per_sec` is the PURE train-loop rate (checkpoint saves
+    # and interleaved evals excluded); `stall_fraction` is the
+    # interval's share lost to them — the restart/save regressions
+    # the cold-start bench axis watches.
+    scalars["steps_per_sec"] = steps / max(dt - stall_secs, 1e-9)
+    scalars["stall_fraction"] = min(
+        max(stall_secs / max(dt, 1e-9), 0.0), 1.0)
+    telemetry.registry().gauge("train.stall_fraction").set(
+        scalars["stall_fraction"])
+    return "steps_per_sec"
+
+  def run_eval():
+    return _run_eval(
+        model, eval_callable, state, input_generator_eval, mesh,
+        eval_steps, eval_batch_size or batch_size)
+
+  def interleaved_eval(step):
+    # On its own cadence, independent of the checkpoint interval.
+    if (input_generator_eval is not None and eval_every_steps
+        and step % eval_every_steps == 0 and step != max_train_steps):
+      loop.write("eval", step, run_eval())
+
+  # Sharded state saves AS-IS: orbax copies device shards to host
+  # before save() returns (so the next step's donation is safe),
+  # serializes asynchronously, and each process writes only its
+  # addressable shards — a host-side device_get here would block,
+  # materialize the unsharded state, and crash on a multi-process pod.
+  loop.begin(
+      model, int(np.asarray(jax.device_get(state.step))),
+      flops_per_step=train_flops, devices=mesh.size,
+      save_payload=lambda: (state,), hook_state=lambda: state,
+      own_scalars=own_scalars, boundary_work=interleaved_eval)
+  with loop:
+    if (input_generator_train is not None
+        and loop.step < max_train_steps):
+      if loop.feed is None:
         # Serial path (or resume landed short of max_train_steps with
         # no overlapped input phase): spin up the pipeline here.
-        train_prefetcher = _input_phase()
-      prefetcher = train_prefetcher
+        loop.attach_feed(_input_phase())
       step_rng = jax.random.PRNGKey(seed + 1)
-      t_last = time.time()
-      steps_since_log = 0
-      # Stall accounting: wall spent in checkpoint saves, interleaved
-      # evals, and metric writes per log interval. `steps_per_sec` is
-      # the PURE train-loop rate (stalls excluded); `stall_fraction`
-      # is the interval's share lost to them — the restart/save
-      # regressions this PR's bench axis watches.
-      stall_secs = 0.0
-      last_saved_step = resume_step
-      # Input-boundness accounting (input_wait_fraction): the shared
-      # TimedIterator measures wall blocked in the prefetcher's
-      # __next__ per log interval.
-      prefetch_iter = prefetch_lib.TimedIterator(prefetcher)
-      for features, labels in prefetch_iter:
-        if step >= max_train_steps:
-          break
-        with perf_meter.dispatch("train.dispatch", step=step):
+      for features, labels in loop.dispatches():
+        with loop.dispatch():
           if k == 1:
             state, metrics = train_callable(
                 state, features, labels,
-                jax.random.fold_in(step_rng, step))
+                jax.random.fold_in(step_rng, loop.step))
           else:
-            state, metrics = train_callable(state, features, labels,
-                                            step_rng, np.int32(step))
-        step += k
-        steps_since_log += k
-        hook_list.after_step(step, metrics)
-
-        if step % log_every_steps == 0 or step == max_train_steps:
-          # One blocking device read per log interval only.
-          scalars = jax.device_get(metrics)
-          dt = time.time() - t_last
-          scalars["steps_per_sec"] = steps_since_log / max(
-              dt - stall_secs, 1e-9)
-          scalars["stall_fraction"] = min(
-              max(stall_secs / max(dt, 1e-9), 0.0), 1.0)
-          scalars["input_wait_fraction"] = prefetch_iter.wait_fraction(dt)
-          # Compile-cache traffic rides the train log (the CompileWatch
-          # tap publishes into the registry): a nonzero miss delta
-          # AFTER the first interval is a warm-path recompile.
-          scalars.update(telemetry.registry().scalars("compile_cache."))
-          # Resource watermarks persist with the run (the report
-          # tool's watermark section reads them back).
-          scalars.update(telemetry.registry().scalars("rsrc."))
-          telemetry.registry().gauge("train.steps_per_sec").set(
-              scalars["steps_per_sec"])
-          telemetry.registry().gauge("train.stall_fraction").set(
-              scalars["stall_fraction"])
-          # Live utilization (perf.mfu / flops_per_sec): the
-          # always-on perf plane.
-          scalars.update(perf_meter.publish(scalars["steps_per_sec"]))
-          final_metrics = scalars
-          t_last = time.time()
-          steps_since_log = 0
-          t_write = time.perf_counter()
-          metric_logger.write("train", step, scalars)
-          if watch_sentinel is not None:
-            watch_sentinel.evaluate(
-                {**telemetry.registry().scalars(), **scalars},
-                step=step)
-          # The write itself is logging stall, charged to the
-          # interval that just began.
-          stall_secs = time.perf_counter() - t_write
-
-        if step % save_checkpoints_steps == 0 or step == max_train_steps:
-          # Sharded state saves AS-IS: orbax copies device shards to
-          # host before save() returns (so the next step's donation
-          # is safe), serializes asynchronously, and each process
-          # writes only its addressable shards — a host-side
-          # device_get here would block, materialize the unsharded
-          # state, and crash on a multi-process pod.
-          t_save = time.perf_counter()
-          writer.save(step, state)
-          last_saved_step = step
-          hook_list.after_checkpoint(step, state, model_dir)
-          stall_secs += time.perf_counter() - t_save
-
-        # Interleaved eval runs on its own cadence, independent of the
-        # checkpoint interval.
-        if (input_generator_eval is not None and eval_every_steps and
-            step % eval_every_steps == 0 and step != max_train_steps):
-          t_eval = time.perf_counter()
-          eval_metrics = _run_eval(
-              model, eval_callable, state, input_generator_eval, mesh,
-              eval_steps, eval_batch_size or batch_size)
-          metric_logger.write("eval", step, eval_metrics)
-          stall_secs += time.perf_counter() - t_eval
-
-      # Final checkpoint if the loop ended off-interval.
-      if last_saved_step != step:
-        writer.save(step, state)
-        hook_list.after_checkpoint(step, state, model_dir)
+            state, metrics = train_callable(
+                state, features, labels, step_rng,
+                np.int32(loop.step))
+        loop.after_dispatch(metrics)
 
     # --- final eval ---
     if input_generator_eval is not None:
-      eval_metrics = _run_eval(
-          model, eval_callable, state, input_generator_eval, mesh,
-          eval_steps, eval_batch_size or batch_size)
+      eval_metrics = run_eval()
       if eval_metrics:
-        metric_logger.write("eval", step, eval_metrics)
+        loop.write("eval", loop.step, eval_metrics)
 
     # --- exporters ---
     if create_exporters_fn is not None:
       for exporter in create_exporters_fn(model):
         exporter.export(model, state, model_dir)
-
-    hook_list.end(step, state, model_dir)
-  finally:
-    # Close in finally: an exception mid-training must not leak the
-    # prefetch worker (it pins buffered sharded batches in HBM).
-    if train_prefetcher is not None:
-      train_prefetcher.close()
-    writer.close()
-    if watch_sentinel is not None:
-      watch_sentinel.close()
-    metric_logger.close()
   return state
 
 

@@ -20,6 +20,8 @@ checkpoints.
 
 from __future__ import annotations
 
+import functools
+import importlib.metadata
 import os
 import re
 import time
@@ -27,7 +29,14 @@ from typing import Any, List, Optional
 
 import jax
 import numpy as np
-import orbax.checkpoint as ocp
+
+# orbax's cloud logger imports google.cloud packages; each asks this for
+# a version warning as it is imported, a stat of every installed file
+# (17,447): the second call took 25-35 s of the trainer's 35-44 s import
+# on the v5e's machine (PERF.md §6, PR 30). One answer serves them all.
+importlib.metadata.packages_distributions = functools.cache(
+    importlib.metadata.packages_distributions)
+import orbax.checkpoint as ocp  # noqa: E402
 
 CKPT_SUBDIR = "ckpt"
 

@@ -1,7 +1,8 @@
-"""The stage spans of the QT-Opt loop thread and of the feed thread
-(ISSUE 24; docs/OBSERVABILITY.md "Standard spans"), the counters and
-named scopes beside them, and the benchmark readers' helper that cuts
-a window out of the ring."""
+"""The stage spans of the train loop's thread, under each of the three
+trainers (ISSUE 24, ISSUE 30; docs/OBSERVABILITY.md "Stage spans of
+the train loop"), and of the feed thread, the counters and named scopes
+beside them, and the benchmark readers' helper that cuts a window out
+of the ring."""
 
 import re
 import time
@@ -11,6 +12,7 @@ import pytest
 
 from tensor2robot_tpu import config as gin
 from tensor2robot_tpu import telemetry
+from tensor2robot_tpu.hooks import Hook
 from tensor2robot_tpu.telemetry import core as tcore
 from tensor2robot_tpu.telemetry import flightrec
 from tensor2robot_tpu.telemetry import metrics as tmetrics
@@ -20,16 +22,39 @@ K = 2
 FEED_SPANS = {"feed.pull", "feed.sample", "replay.draw",
               "replay.gather", "feed.stack", "feed.device_put",
               "feed.queue_put"}
-LOOP_SPANS = {"loop.wait_feed", "qtopt.dispatch", "loop.after_step",
-              "loop.log", "loop.log_sync", "loop.save",
-              "loop.save_d2h", "loop.save_write",
-              "loop.after_checkpoint"}
+LOOP_STAGES = {"loop.after_step", "loop.log", "loop.log_sync",
+               "loop.save", "loop.save_d2h", "loop.save_write",
+               "loop.after_checkpoint"}
+# Trainer -> (its dispatch span, the spans of its feed thread).
+TRAINERS = {
+    "train_qtopt": ("qtopt.dispatch", FEED_SPANS),
+    # Batches from a generator, not drawn from a replay buffer.
+    "train_eval_model": ("train.dispatch",
+                         FEED_SPANS - {"replay.draw", "replay.gather"}),
+    # Collects on the device: no feed, so no `loop.wait_feed` either.
+    "train_anakin": ("anakin.dispatch", frozenset()),
+}
 CHILDREN = {"feed.sample": "feed.pull", "feed.stack": "feed.pull",
             "feed.buffer_wait": "feed.pull",
             "replay.draw": "feed.sample", "replay.gather": "feed.sample",
             "loop.log_sync": "loop.log", "loop.save_d2h": "loop.save",
             "loop.save_write": "loop.save",
             "loop.after_checkpoint": "loop.save"}
+
+
+def _loop_spans(trainer):
+  dispatch, feed = TRAINERS[trainer]
+  return LOOP_STAGES | {dispatch} | (
+      {"loop.wait_feed"} if feed else set())
+
+
+def _children(trainer):
+  """The nestings a trainer's run must show. `feed.buffer_wait` exists
+  only where the prefetcher lends: `train_qtopt`'s `lending_ring`."""
+  if trainer == "train_qtopt":
+    return sorted(CHILDREN)
+  spans = _loop_spans(trainer) | TRAINERS[trainer][1]
+  return sorted(c for c in CHILDREN if c in spans)
 SCOPES = ("torso", "cem_tower", "cem_pool", "q_head", "bellman_loss",
           "backward", "optimizer", "polyak")
 
@@ -71,18 +96,83 @@ def _train(model_dir, **kwargs):
   return train_qtopt(**args)
 
 
+class MockHook(Hook):
+  """Counts what the loop tells it."""
+
+  def __init__(self):
+    self.calls = []
+
+  def begin(self, model, model_dir):
+    self.calls.append("begin")
+
+  def after_step(self, step, metrics):
+    self.calls.append(("after_step", step))
+
+  def after_checkpoint(self, step, state, model_dir):
+    self.calls.append(("after_checkpoint", step))
+
+  def end(self, step, state, model_dir):
+    self.calls.append("end")
+
+
+def _train_eval(model_dir, **kwargs):
+  """The pose-env regression through `train_eval_model`, K=2."""
+  from tensor2robot_tpu import train_eval
+  from tensor2robot_tpu.data.random_input_generator import (
+      RandomInputGenerator,
+  )
+  from tensor2robot_tpu.research.pose_env import PoseEnvRegressionModel
+  args = dict(
+      model=PoseEnvRegressionModel(
+          image_size=16, filters=(8,), embedding_size=16,
+          hidden_sizes=(16,)),
+      model_dir=str(model_dir),
+      input_generator_train=RandomInputGenerator(batch_size=8),
+      max_train_steps=16, log_every_steps=4, save_checkpoints_steps=8,
+      steps_per_dispatch=K, hooks=[MockHook()])
+  args.update(kwargs)
+  return train_eval.train_eval_model(**args)
+
+
+def _train_anakin(model_dir):
+  from tensor2robot_tpu.envs.rollout import train_anakin
+  return train_anakin(
+      learner=_learner(), model_dir=str(model_dir), env_family="pose",
+      num_envs=8, rollout_length=2, train_batches_per_iter=K,
+      batch_size=8, replay_capacity=64, max_train_steps=16,
+      log_every_steps=4, save_checkpoints_steps=8, seed=0,
+      hooks=[MockHook()])
+
+
+RUNS = {"train_qtopt": _train, "train_eval_model": _train_eval,
+        "train_anakin": _train_anakin}
+
+
 @pytest.fixture(scope="module")
-def ring(tmp_path_factory):
+def ring_of(tmp_path_factory):
+  """trainer -> the ring its stand-alone K=2 run leaves behind (each
+  trainer runs once a module)."""
+  rings = {}
+
+  def get(trainer):
+    if trainer not in rings:
+      _reset()
+      RUNS[trainer](tmp_path_factory.mktemp(trainer))
+      tracer = telemetry.get_tracer()
+      rings[trainer] = tracer.snapshot_spans(), {
+          "role": tracer.role, "enabled": tracer.enabled,
+          "path": tracer.trace_path, "dropped": tracer.spans_dropped,
+          "stack_counts": telemetry.registry().scalars("feed.stack.")}
+      _reset()
+    return rings[trainer]
+
+  return get
+
+
+@pytest.fixture(scope="module")
+def ring(ring_of):
   """The ring a stand-alone K=2 `train_qtopt` leaves behind."""
-  _reset()
-  _train(tmp_path_factory.mktemp("spans"))
-  tracer = telemetry.get_tracer()
-  spans = tracer.snapshot_spans()
-  state = {"role": tracer.role, "enabled": tracer.enabled,
-           "path": tracer.trace_path, "dropped": tracer.spans_dropped,
-           "stack_counts": telemetry.registry().scalars("feed.stack.")}
-  _reset()
-  return spans, state
+  return ring_of("train_qtopt")
 
 
 @pytest.fixture(scope="module")
@@ -118,30 +208,46 @@ def _by_name(spans, name):
 
 class TestSpansOfARun:
 
-  def test_the_trainer_configures_memory_mode(self, ring):
-    _, state = ring
+  @pytest.mark.parametrize("trainer", sorted(TRAINERS))
+  def test_the_trainer_configures_memory_mode(self, ring_of, trainer):
+    _, state = ring_of(trainer)
     assert {key: state[key] for key in
             ("role", "enabled", "path", "dropped")} == {
                 "role": "trainer", "enabled": True, "path": None,
                 "dropped": 0}
 
-  @pytest.mark.parametrize("name", sorted(FEED_SPANS | LOOP_SPANS))
-  def test_every_stage_is_in_the_ring_on_its_thread(self, ring, name):
-    spans, _ = ring
-    loop_tid = _by_name(spans, "qtopt.dispatch")[0]["tid"]
-    feed_tid = _by_name(spans, "feed.device_put")[0]["tid"]
-    assert loop_tid != feed_tid
+  @pytest.mark.parametrize("trainer,name", [
+      (trainer, name) for trainer, (_, feed) in sorted(TRAINERS.items())
+      for name in sorted(feed | _loop_spans(trainer))])
+  def test_every_stage_is_in_the_ring_on_its_thread(self, ring_of,
+                                                    trainer, name):
+    spans, _ = ring_of(trainer)
+    dispatch, feed = TRAINERS[trainer]
+    loop_tid = _by_name(spans, dispatch)[0]["tid"]
     found = _by_name(spans, name)
     assert found, name
-    want = feed_tid if name in FEED_SPANS else loop_tid
+    want = loop_tid
+    if feed:
+      feed_tid = _by_name(spans, "feed.device_put")[0]["tid"]
+      assert loop_tid != feed_tid
+      want = feed_tid if name in feed else loop_tid
+    else:
+      assert not [s for s in spans if s["name"].startswith("feed.")
+                  or s["name"] == "loop.wait_feed"]
     assert {s["tid"] for s in found} == {want}
 
-  @pytest.mark.parametrize("child,parent", sorted(CHILDREN.items()))
-  def test_children_lie_inside_their_parents(self, ring, lending_ring,
-                                             child, parent):
-    # `feed.buffer_wait` exists only where the prefetcher lends.
-    assert _by_name(lending_ring[0], child)
-    for spans in (ring[0], lending_ring[0]):
+  @pytest.mark.parametrize("trainer,child", [
+      (trainer, child) for trainer in sorted(TRAINERS)
+      for child in _children(trainer)])
+  def test_children_lie_inside_their_parents(self, ring_of,
+                                             lending_ring, trainer,
+                                             child):
+    parent = CHILDREN[child]
+    rings = [ring_of(trainer)[0]]
+    if trainer == "train_qtopt":
+      rings.append(lending_ring[0])
+    assert _by_name(rings[-1], child)
+    for spans in rings:
       parents = _by_name(spans, parent)
       for c in _by_name(spans, child):
         assert any(p["tid"] == c["tid"] and p["ts"] <= c["ts"]
@@ -226,16 +332,22 @@ class TestSpansOfARun:
         "feed.stack.fresh_dispatches":
             float(len(_by_name(spans, "feed.stack")))}
 
-  def test_loop_thread_is_named_from_first_to_last_dispatch(self, ring):
+  @pytest.mark.parametrize("trainer", sorted(TRAINERS))
+  def test_loop_thread_is_named_from_first_to_last_dispatch(
+      self, ring_of, trainer):
     from benchmark.harness import trace_reduce
-    spans, _ = ring
-    dispatches = _by_name(spans, "qtopt.dispatch")
+    spans, _ = ring_of(trainer)
+    dispatches = _by_name(spans, TRAINERS[trainer][0])
+    assert [d["args"]["step"] for d in dispatches] == list(
+        range(0, 16, K))
+    assert all(d["args"]["k"] == K for d in dispatches)
     tid = dispatches[0]["tid"]
     t0 = dispatches[0]["ts"]
     t1 = dispatches[-1]["ts"] + dispatches[-1]["dur"]
     covered = trace_reduce.union_ns(
         (max(s["ts"], t0), min(s["ts"] + s["dur"], t1))
-        for s in spans if s["tid"] == tid and s["name"] in LOOP_SPANS
+        for s in spans if s["tid"] == tid
+        and s["name"] in _loop_spans(trainer)
         and s["ts"] < t1 and s["ts"] + s["dur"] > t0)
     assert covered / (t1 - t0) >= 0.95
 
@@ -259,6 +371,45 @@ def test_callers_disabled_tracer_survives_train_qtopt(clean_plane,
   tracer = telemetry.get_tracer()
   assert (tracer.role, tracer.enabled) == ("bench_off_arm", False)
   assert tracer.spans_recorded == 0 and not tracer.snapshot_spans()
+
+
+def test_a_step_that_raises_still_tears_train_eval_down(
+    clean_plane, tmp_path, monkeypatch):
+  """`train_eval_model` ran its hooks' `end` inside its `try`: a step
+  that raised never reached it (ISSUE 30). The teardown is the train
+  loop's now: hooks, prefetcher, writer, (sentinel,) logger."""
+  from tensor2robot_tpu import train_eval
+  from tensor2robot_tpu.data import prefetch
+  from tensor2robot_tpu.data.random_input_generator import (
+      RandomInputGenerator,
+  )
+  from tensor2robot_tpu.utils import checkpoints
+  torn_down = []
+  for cls in (prefetch.ShardedPrefetcher, checkpoints.CheckpointWriter,
+              train_eval.MetricLogger):
+    def close(self, _real=cls.close, _name=cls.__name__):
+      torn_down.append(_name)
+      _real(self)
+    monkeypatch.setattr(cls, "close", close)
+
+  class LosesItsLabels(RandomInputGenerator):
+    """The third batch comes without labels: the step that gets it
+    fails where it traces the loss."""
+
+    def create_dataset(self, mode, batch_size=None):
+      for i, (features, labels) in enumerate(
+          super().create_dataset(mode, batch_size)):
+        yield features, (None if i == 2 else labels)
+
+  hook = MockHook()
+  hook.end = lambda *args: torn_down.append("end")
+  with pytest.raises(Exception) as raised:
+    _train_eval(tmp_path, steps_per_dispatch=1, hooks=[hook],
+                input_generator_train=LosesItsLabels(batch_size=8))
+  assert not isinstance(raised.value, AssertionError)
+  assert hook.calls == ["begin", ("after_step", 1), ("after_step", 2)]
+  assert torn_down == ["end", "ShardedPrefetcher", "CheckpointWriter",
+                       "MetricLogger"]
 
 
 def test_sentinel_page_dumps_the_loops_spans(clean_plane, tmp_path):
