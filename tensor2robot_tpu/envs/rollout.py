@@ -788,8 +788,9 @@ def train_anakin(
       learner.model, step,
       flops_per_step=per_device_flops * d if per_device_flops else None,
       devices=d,
-      save_payload=lambda: train_loop.host_payload(device0(carry[0])),
-      hook_state=lambda: device0(carry[0]).train_state,
+      state=lambda: device0(carry[0]),
+      save_payload=train_loop.host_payload,
+      hook_state=lambda st: st.train_state,
       hook_metrics=device0,
       own_scalars=own_scalars)
   iter_key = jax.random.PRNGKey(seed + 4)

@@ -23,11 +23,20 @@ class Hook:
     """Called once before the first step."""
 
   def after_step(self, step: int, metrics: dict) -> None:
-    """Called after every train step (metrics are device arrays)."""
+    """Called after every train step (metrics are device arrays), for
+    every step in turn. The train loop runs one dispatch ahead
+    (`train_loop.TrainLoop`): the call for `step` may come while the
+    dispatch after it executes, so `metrics` are that step's, and a
+    hook that raises ends the run with one more dispatch enqueued. A
+    hook that declares `drives_online_collection` gets each call
+    before the next dispatch is enqueued."""
 
   def after_checkpoint(self, step: int, state: Any,
                        model_dir: str) -> None:
-    """Called after a checkpoint save is initiated at `step`."""
+    """Called after a checkpoint save is initiated at `step`. `state`
+    is the loop's snapshot of the state after exactly `step`: arrays
+    of its own on the device, which no later dispatch donates; keep
+    them and they stay allocated."""
 
   def end(self, step: int, state: Any, model_dir: str) -> None:
     """Called once after training finishes."""

@@ -102,7 +102,10 @@ def train_qtopt(
   updates ago. Two things bound this now: `prefetch_buffer_size`
   (None = auto via `prefetch_lib.prefetch_buffer_size`, gin-tunable:
   depth 1 when any hook drives online collection — the round-5
-  finding — else the throughput-friendly 2), and the replay data
+  finding — else the throughput-friendly 2; the same predicate keeps
+  the loop from running a dispatch ahead of its hooks, log and save,
+  which is one more dispatch of lead, and where the loop does run
+  ahead that dispatch counts as one of the depth), and the replay data
   plane MEASURES it — when the buffer exposes `set_learner_step` /
   `metrics_scalars` (the `replay/` plane and its `ReplayBuffer`
   adapter do), every sampled batch's age-in-steps lands in a
@@ -193,8 +196,8 @@ def train_qtopt(
           learner, batch_size * jax.process_count(),
           params=state.train_state.params),
       devices=mesh.size,
-      save_payload=lambda: train_loop.host_payload(state),
-      hook_state=lambda: state.train_state,
+      state=lambda: state, save_payload=train_loop.host_payload,
+      hook_state=lambda st: st.train_state,
       own_scalars=own_scalars,
       tag_step=getattr(replay_buffer, "set_learner_step", None))
   replay_buffer.wait_until_size(min_replay_size or batch_size)
@@ -242,6 +245,13 @@ def train_qtopt(
       online=loop.hook_list.drives_online_collection,
       **({} if prefetch_buffer_size is None
          else {"buffer_size": prefetch_buffer_size}))
+  if loop.runs_ahead:
+    # The dispatch the loop keeps in flight is one of those resident
+    # ahead of compute, and takes its place among them: beside it a
+    # queue of two held five dispatches on the device, 13.73 of the
+    # v5e's 16.9 GB in qtopt_472 with the step program's 3 GB of
+    # temporaries still to come (PERF.md §6, PR 31).
+    depth = max(depth - 1, 1)
   loop.attach_feed(prefetch_lib.ShardedPrefetcher(
       stream, stream_sharding, buffer_size=depth))
   step_rng = jax.random.PRNGKey(seed + 1)

@@ -435,8 +435,7 @@ def train_eval_model(
 
   def interleaved_eval(step):
     # On its own cadence, independent of the checkpoint interval.
-    if (input_generator_eval is not None and eval_every_steps
-        and step % eval_every_steps == 0 and step != max_train_steps):
+    if step % eval_every_steps == 0 and step != max_train_steps:
       loop.write("eval", step, run_eval())
 
   # Sharded state saves AS-IS: orbax copies device shards to host
@@ -447,8 +446,12 @@ def train_eval_model(
   loop.begin(
       model, int(np.asarray(jax.device_get(state.step))),
       flops_per_step=train_flops, devices=mesh.size,
-      save_payload=lambda: (state,), hook_state=lambda: state,
-      own_scalars=own_scalars, boundary_work=interleaved_eval)
+      state=lambda: state, save_payload=lambda st: (st,),
+      hook_state=lambda st: st, own_scalars=own_scalars,
+      # The eval reads the live state between two dispatches: a run
+      # that has one finishes each dispatch before the next.
+      boundary_work=(interleaved_eval if input_generator_eval is not None
+                     and eval_every_steps else None))
   with loop:
     if (input_generator_train is not None
         and loop.step < max_train_steps):

@@ -5,8 +5,9 @@ state, feed and step program, and drive one `TrainLoop` each:
 
     loop = TrainLoop(model_dir, hooks, dispatch_span="qtopt.dispatch",
                      steps_per_dispatch=..., max_train_steps=..., ...)
-    loop.begin(model, step, save_payload=lambda: (state,),
-               hook_state=lambda: state, own_scalars=..., ...)
+    loop.begin(model, step, state=lambda: state,
+               save_payload=train_loop.host_payload,
+               hook_state=lambda st: st.train_state, ...)
     loop.attach_feed(prefetcher)            # Anakin has none
     with loop:                              # the one teardown
       for batch in loop.dispatches():
@@ -14,12 +15,25 @@ state, feed and step program, and drive one `TrainLoop` each:
           state, metrics = train_step(state, batch, ...)
         loop.after_dispatch(metrics)
 
+The loop runs one dispatch ahead of its own bookkeeping: what follows
+dispatch k (`after_step` hooks, the log with its wait for the device,
+the save, `after_checkpoint` hooks) runs after dispatch k + 1 has been
+enqueued, so the device has its next program queued when one ends and
+the host's work lies in that program's shadow. Where a save is due at
+k the loop copies the state on the device before the trainer hands it,
+donated, to k + 1; the deferred save and hooks get that snapshot. A run
+whose trainer does work between dispatches on the live state
+(`boundary_work`) or whose hooks drive online collection (one more
+dispatch in flight is K more steps of sampling lead) finishes every
+dispatch before the next is enqueued.
+
 The jitted call stays in the trainer's frame: a loop that took it as a
 callback would stand in the location of every operation its first call
 traces (PR 26: two such frames took that call from 4.5 to 7.6 s and
-doubled the peak of host memory). The state stays there too, read
-through the trainer's closures: a reference kept here would hold every
-donated state until the dispatch after it had returned.
+doubled the peak of host memory). The live state stays there too, read
+through the trainer's closure when a snapshot or the teardown needs it:
+a reference kept here would hold every donated state until the dispatch
+after it had returned.
 """
 
 from __future__ import annotations
@@ -31,6 +45,7 @@ import time
 from typing import Any, Callable, Dict, Iterable, Iterator, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from tensor2robot_tpu import telemetry
@@ -88,10 +103,32 @@ def host_payload(state) -> tuple:
   return host, host.train_state.params, host.train_state.batch_stats
 
 
+@jax.jit
+def _copy_on_device(state):
+  """A copy of every leaf in one program, queued behind the dispatch
+  that made `state`; each copy has its leaf's sharding. `jnp.copy` and
+  not the identity, whose outputs `jit` forwards from its inputs."""
+  return jax.tree_util.tree_map(jnp.copy, state)
+
+
+def _on_every_rank(holds: bool) -> bool:
+  """Whether `holds` on every rank of a multi-process group: hooks
+  differ by rank (the fleet's chief alone publishes), and the ranks
+  have to run the same programs and the collective saves in one
+  order."""
+  if jax.process_count() == 1:
+    return holds
+  from jax.experimental import multihost_utils
+  return bool(np.all(multihost_utils.process_allgather(
+      np.asarray(holds))))
+
+
 class TrainLoop:
   """Wait → dispatch → hooks → log → save → teardown, once: the run's
   host-side services and their teardown order, the cadences, the stage
   spans (docs/OBSERVABILITY.md) and the common part of the log record.
+  Hooks, log and save of a dispatch trail its enqueue by one dispatch
+  (the module's docstring).
 
   Construction validates the dispatch quantization BEFORE any side
   effect (a hook's begin() starts actor threads; a late ValueError
@@ -150,11 +187,15 @@ class TrainLoop:
                            if self.chief else None)
     self.feed: Optional[prefetch_lib.TimedIterator] = None
     self._prefetcher = self._writer = None
+    # (step, metrics, snapshot) of the dispatch enqueued last, until
+    # the one after it is enqueued.
+    self._pending: Optional[tuple] = None
 
   def begin(self, model, step: int, *,
             flops_per_step: Optional[float], devices: int,
-            save_payload: Callable[[], tuple],
-            hook_state: Callable[[], Any],
+            state: Callable[[], Any],
+            save_payload: Callable[[Any], tuple],
+            hook_state: Callable[[Any], Any],
             own_scalars: Callable[[dict, int, float, float], str],
             hook_metrics: Callable[[Any], Any] = lambda metrics: metrics,
             tag_step: Optional[Callable[[int], None]] = None,
@@ -164,18 +205,26 @@ class TrainLoop:
     (before any hook begins), opens the writer and begins the hooks; a
     failure closes what the run had opened.
 
-    The trainer's own, each reading the state as the trainer holds it
-    when called: `save_payload()` is what `CheckpointWriter.save` takes
-    after the step; `hook_state()` and `hook_metrics(metrics)` are what
-    the hooks see; `own_scalars(scalars, steps, dt, stall_secs)` adds
-    its scalars to a log record and returns the key of its rate
-    (`stall_secs`: what saves and boundary work took of the `dt`
-    seconds since the last record); `tag_step(step)` runs on the chief
-    before the first dispatch and after each; `boundary_work(step)`
-    after each dispatch's save. `flops_per_step` (of one GLOBAL step)
-    and `devices` are the `PerfMeter`'s."""
+    The trainer's own: `state()` is the state as the trainer holds it
+    when called, which the next dispatch donates; of that state or of
+    the loop's snapshot of it after a save step,
+    `save_payload(state)` is what `CheckpointWriter.save` takes
+    (`host_payload` itself where the save gathers to the host: the
+    loop then starts a snapshot's copy to the host as it takes it) and
+    `hook_state(state)` what the hooks see, as `hook_metrics(metrics)`
+    is of a dispatch's metrics; `own_scalars(scalars, steps, dt,
+    stall_secs)` adds its scalars to a log record and returns the key
+    of its rate (`stall_secs`: what saves and boundary work took of the
+    `dt` seconds since the last record, less, in a run ahead, what the
+    loop waited for the device anyway); `tag_step(step)` runs on the
+    chief before the first dispatch and after each enqueue;
+    `boundary_work(step)` after each dispatch's save, on the live
+    state: a trainer that passes it gives up the run ahead.
+    `flops_per_step` (of one GLOBAL step) and `devices` are the
+    `PerfMeter`'s."""
     self.step = step
-    self._save_payload, self._hook_state = save_payload, hook_state
+    self._state, self._save_payload = state, save_payload
+    self._hook_state = hook_state
     self._own_scalars, self._hook_metrics = own_scalars, hook_metrics
     self._tag_step = tag_step if self.chief else None
     self._boundary_work = boundary_work
@@ -192,6 +241,15 @@ class TrainLoop:
       self._meter = perf_lib.PerfMeter(
           flops_per_step=flops_per_step,
           peak_flops=profiling.device_peak_flops(), devices=devices)
+      # Whether this run keeps a second dispatch in flight (the
+      # module's docstring); the trainer sizes its feed's queue by it.
+      self.runs_ahead = _on_every_rank(
+          boundary_work is None
+          and not self.hook_list.drives_online_collection)
+      if self.runs_ahead:
+        # The snapshot's program compiles here, with the run's others:
+        # at the first save it would read as a warm-path recompile.
+        _copy_on_device(self._state())
       self.hook_list.begin(model, self.model_dir)
     except BaseException:
       self.close()
@@ -205,8 +263,8 @@ class TrainLoop:
 
   def dispatches(self) -> Iterator[Any]:
     """One item of the feed (None without one) for every dispatch up
-    to `max_train_steps`, then the final save if the loop ended off
-    the save interval."""
+    to `max_train_steps`, then what is left of the last dispatch and
+    the final save if the loop ended off the save interval."""
     if self._tag_step is not None:
       # The data plane tags rows with the learner step at add time;
       # seed the tag before actors race the first dispatch. Chief-only:
@@ -224,8 +282,10 @@ class TrainLoop:
         if self.step >= self.max_train_steps:
           break
         yield item
+    self._finish_pending()
     if self._last_saved != self.step:
-      self._save()
+      # Nothing donates the live state any more: it is its own snapshot.
+      self._save(self.step, self._state())
 
   def dispatch(self):
     """The span of the enqueue, for the `with` around the jitted call."""
@@ -235,43 +295,91 @@ class TrainLoop:
     return self._meter.dispatch(self._dispatch_span, **args)
 
   def after_dispatch(self, metrics) -> None:
-    """Step tag, `after_step`, log (with its sync), save, boundary
-    work: in this order. Hooks get the un-synced device metrics."""
+    """Step tag and, on a save step, the snapshot; then `after_step`,
+    log (with its sync), save and boundary work, in this order, of the
+    dispatch BEFORE this one, and of this one when the next has been
+    enqueued (or the loop ends). Hooks get the un-synced device
+    metrics. A run that does not run ahead (`begin`) finishes this
+    dispatch here."""
     self.step += self.k
-    self._steps_since_log += self.k
     step = self.step
     if self._tag_step is not None:
       self._tag_step(step)  # one int store; actors tag adds with it
-    with telemetry.span("loop.after_step", step=step):
-      self.hook_list.after_step(step, self._hook_metrics(metrics))
-    if self.chief and self._due(self._log_every):
-      self._log(metrics)
-    if self._due(self._save_every):
-      self._save()
-    if self._boundary_work is not None:
-      t0 = time.perf_counter()
-      self._boundary_work(step)
-      self._stall_secs += time.perf_counter() - t0
+    snapshot = (self._snapshot(step)
+                if self._due(step, self._save_every) else None)
+    # Enqueued with the after-work of the one before still owed, or
+    # with nothing owed: the two add up to the dispatches.
+    telemetry.registry().counter(
+        "loop.dispatches.drained" if self._pending is None
+        else "loop.dispatches.ran_ahead").inc()
+    self._finish_pending()
+    self._pending = (step, metrics, snapshot)
+    if not self.runs_ahead:
+      self._finish_pending()
 
   def write(self, tag: str, step: int, scalars: Dict[str, Any]) -> None:
     """A record of the trainer's own (eval metrics), on the chief."""
     if self.metric_logger is not None:
       self.metric_logger.write(tag, step, scalars)
 
-  def _due(self, every: int) -> bool:
-    return self.step % every == 0 or self.step == self.max_train_steps
+  def _due(self, step: int, every: int) -> bool:
+    return step % every == 0 or step == self.max_train_steps
 
-  def _log(self, metrics) -> None:
-    step = self.step
+  def _snapshot(self, step: int):
+    """The state after `step` as the deferred save will find it: a
+    copy on the device, since the next dispatch donates the live one
+    (the copy to the host starts here where the save gathers there);
+    the live state itself in a run that finishes each dispatch before
+    the next."""
+    with telemetry.span("loop.snapshot", step=step):
+      if not self.runs_ahead:
+        return self._state()
+      snapshot = _copy_on_device(self._state())
+      if self._save_payload is host_payload:
+        for leaf in jax.tree_util.tree_leaves(snapshot):
+          leaf.copy_to_host_async()
+      return snapshot
+
+  def _finish_pending(self) -> None:
+    """The after-work of the dispatch enqueued last, if it is still
+    owed. An exception out of it (a hook's) leaves the rest undone."""
+    if self._pending is None:
+      return
+    (step, metrics, snapshot), self._pending = self._pending, None
+    self._steps_since_log += self.k
+    with telemetry.span("loop.after_step", step=step):
+      self.hook_list.after_step(step, self._hook_metrics(metrics))
+    if self.chief and self._due(step, self._log_every):
+      self._log(step, metrics)
+    if snapshot is not None:
+      self._save(step, snapshot)
+      # Letting go of its arrays gives up the interpreter lock leaf by
+      # leaf while the writer's thread is busy taking it: a stage of
+      # its own, or the time would stand between two spans.
+      with telemetry.span("loop.snapshot_free", step=step):
+        del snapshot
+    if self._boundary_work is not None:
+      t0 = time.perf_counter()
+      self._boundary_work(step)
+      self._stall_secs += time.perf_counter() - t0
+
+  def _log(self, step: int, metrics) -> None:
     registry = telemetry.registry()
     with telemetry.span("loop.log", step=step):
-      # The one place the loop waits for the device: the dispatch
-      # enqueued last has to finish before its metrics exist.
+      # The one place the loop waits for the device: the dispatch has
+      # to finish before its metrics exist. In a run ahead the next one
+      # is queued behind it, and this wait is the host's slack.
       with telemetry.span("loop.log_sync", step=step):
+        t0 = time.perf_counter()
         scalars = jax.device_get(metrics)
+        waited = time.perf_counter() - t0
       dt = time.time() - self._t_last
+      # A run ahead saves beside the next program: a save held the
+      # loop only by what this wait for the device did not cover.
+      stall_secs = (max(self._stall_secs - waited, 0.0)
+                    if self.runs_ahead else self._stall_secs)
       rate_key = self._own_scalars(scalars, self._steps_since_log, dt,
-                                   self._stall_secs)
+                                   stall_secs)
       if self.feed is not None:
         scalars["input_wait_fraction"] = self.feed.wait_fraction(dt)
       # A compile-cache miss delta after the first interval is a
@@ -289,16 +397,15 @@ class TrainLoop:
       self._steps_since_log = 0
       self._stall_secs = 0.0
 
-  def _save(self) -> None:
-    step = self.step
+  def _save(self, step: int, state) -> None:
     t0 = time.perf_counter()
     with telemetry.span("loop.save", step=step):
       with telemetry.span("loop.save_d2h", step=step):
-        payload = self._save_payload()
+        payload = self._save_payload(state)
       with telemetry.span("loop.save_write", step=step):
         self._writer.save(step, *payload)
       with telemetry.span("loop.after_checkpoint", step=step):
-        self.hook_list.after_checkpoint(step, self._hook_state(),
+        self.hook_list.after_checkpoint(step, self._hook_state(state),
                                         self.model_dir)
     self._last_saved = step
     self._stall_secs += time.perf_counter() - t0
@@ -318,7 +425,8 @@ class TrainLoop:
     # end() in the teardown: hooks own real teardown (actor threads);
     # a training-loop exception must not leak collectors.
     try:
-      self.hook_list.end(self.step, self._hook_state(), self.model_dir)
+      self.hook_list.end(self.step, self._hook_state(self._state()),
+                         self.model_dir)
     except Exception:  # noqa: BLE001 — don't mask the original error
       log.exception("hook end() failed during teardown")
     self.close()

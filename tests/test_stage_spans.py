@@ -22,9 +22,9 @@ K = 2
 FEED_SPANS = {"feed.pull", "feed.sample", "replay.draw",
               "replay.gather", "feed.stack", "feed.device_put",
               "feed.queue_put"}
-LOOP_STAGES = {"loop.after_step", "loop.log", "loop.log_sync",
-               "loop.save", "loop.save_d2h", "loop.save_write",
-               "loop.after_checkpoint"}
+LOOP_STAGES = {"loop.snapshot", "loop.snapshot_free", "loop.after_step",
+               "loop.log", "loop.log_sync", "loop.save", "loop.save_d2h",
+               "loop.save_write", "loop.after_checkpoint"}
 # Trainer -> (its dispatch span, the spans of its feed thread).
 TRAINERS = {
     "train_qtopt": ("qtopt.dispatch", FEED_SPANS),
@@ -351,6 +351,54 @@ class TestSpansOfARun:
         and s["ts"] < t1 and s["ts"] + s["dur"] > t0)
     assert covered / (t1 - t0) >= 0.95
 
+  @pytest.mark.parametrize("trainer", sorted(TRAINERS))
+  def test_after_work_carries_its_own_dispatchs_step(self, ring_of,
+                                                     trainer):
+    """The loop runs one dispatch ahead (ISSUE 31): the stages that
+    follow a dispatch start once the next has been enqueued, under the
+    step of the dispatch they belong to, which is how the benchmark's
+    readers pick them; the snapshot alone lies between the two."""
+    spans, _ = ring_of(trainer)
+    ending_at = {d["args"]["step"] + K: d
+                 for d in _by_name(spans, TRAINERS[trainer][0])}
+    saves = ("loop.snapshot", "loop.snapshot_free", "loop.save",
+             "loop.save_d2h", "loop.save_write",
+             "loop.after_checkpoint")
+    for name in sorted(LOOP_STAGES):
+      found = _by_name(spans, name)
+      every = (8 if name in saves
+               else K if name == "loop.after_step" else 4)
+      assert [s["args"]["step"] for s in found] == list(
+          range(every, 17, every)), name
+      for s in found:
+        own = ending_at[s["args"]["step"]]
+        after = ending_at.get(s["args"]["step"] + K)
+        assert own["ts"] + own["dur"] <= s["ts"] + 1e-9
+        if after is None:  # the last dispatch's: nothing to trail
+          continue
+        if name == "loop.snapshot":
+          assert s["ts"] + s["dur"] <= after["ts"] + 1e-9
+        else:
+          assert after["ts"] + after["dur"] <= s["ts"] + 1e-9, name
+
+  def test_span_window_of_a_run_that_ran_ahead(self, ring):
+    """Records at 8 and 12 cover the dispatches from 4 to 10: one save
+    and two log syncs, as when every dispatch was finished before the
+    next."""
+    from benchmark.layer_metrics import span_window
+    spans, _ = ring
+    window = span_window.select(spans, [8, 12], 4, K)
+    assert window is not None and window["steps"] == 8
+    picked = {name: [s["args"]["step"] for s in found]
+              for name, found in window["spans"].items()
+              if name.startswith("loop.") and name != "loop.wait_feed"}
+    assert picked == {
+        "loop.after_step": [6, 8, 10, 12],
+        "loop.log": [8, 12], "loop.log_sync": [8, 12],
+        "loop.snapshot": [8], "loop.snapshot_free": [8],
+        "loop.save": [8], "loop.save_d2h": [8],
+        "loop.save_write": [8], "loop.after_checkpoint": [8]}
+
   def test_k1_names_the_prefetchers_own_pull(self, clean_plane,
                                              tmp_path):
     _train(tmp_path, steps_per_dispatch=1, max_train_steps=4)
@@ -407,7 +455,9 @@ def test_a_step_that_raises_still_tears_train_eval_down(
     _train_eval(tmp_path, steps_per_dispatch=1, hooks=[hook],
                 input_generator_train=LosesItsLabels(batch_size=8))
   assert not isinstance(raised.value, AssertionError)
-  assert hook.calls == ["begin", ("after_step", 1), ("after_step", 2)]
+  # The second step's `after_step` was owed until the third was enqueued
+  # (the loop runs one dispatch ahead): it goes with the run.
+  assert hook.calls == ["begin", ("after_step", 1)]
   assert torn_down == ["end", "ShardedPrefetcher", "CheckpointWriter",
                        "MetricLogger"]
 
