@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import numpy as np
@@ -37,9 +37,52 @@ def verdict(numbers: Dict[str, float], limits: Dict[str, float],
   return ok
 
 
-def _leaf_norms(tree: Dict[str, np.ndarray]) -> Dict[str, float]:
-  return {k: float(np.linalg.norm(np.asarray(v, np.float64)))
-          for k, v in tree.items()}
+def _leaf_terms(program: Dict[str, np.ndarray],
+                reference: Dict[str, np.ndarray],
+                start: Optional[Dict[str, np.ndarray]] = None):
+  """(‖p‖, ‖r‖, Σ(p − r)², Σr²) for every leaf of `reference`, in its
+  order: p and r are the program's leaf and the reference's in
+  float64, each less `start`'s leaf where `start` is given (a
+  parameter's change over the steps). A leaf at a time, in two buffers
+  of the largest leaf's size that every leaf is read into and worked
+  on in place: a state of any size costs the host 16 bytes for each
+  element of its largest leaf, and those pages fault in once. Two
+  whole trees of changes in float64, and a fresh array for every
+  intermediate, cost 16 bytes a parameter and, on the chip machine's
+  host (4 KB pages), 50 s at 369 M parameters against 8 s at 721 M
+  here (PERF.md §6, PR 33). The arithmetic is that of the whole-tree
+  form, operation for operation, so every number is the same to the
+  last digit."""
+  largest = max((np.size(leaf) for leaf in reference.values()),
+                default=0)
+  buffers = np.empty((2, max(largest, 1)), np.float64)
+  for key, leaf in reference.items():
+    p, r = (buffer[:np.size(leaf)].reshape(np.shape(leaf))
+            for buffer in buffers)
+    np.copyto(p, program[key])
+    np.copyto(r, leaf)
+    if start is not None:
+      np.subtract(p, start[key], out=p)
+      np.subtract(r, start[key], out=r)
+    p_norm, r_norm = float(np.linalg.norm(p)), float(np.linalg.norm(r))
+    np.subtract(p, r, out=p)
+    diff = float(np.sum(np.square(p, out=p)))
+    size = float(np.sum(np.square(r, out=r)))
+    yield p_norm, r_norm, diff, size
+
+
+def _gap_and_err(program, reference, start=None):
+  """(`worst_leaf_gap`, `rel_err`) of two trees in one pass over their
+  leaves; with `start`, of their changes from it."""
+  terms = list(_leaf_terms(program, reference, start))
+  floor = float(np.median([r_norm for _, r_norm, _, _ in terms]))
+  gap = max(abs(p_norm - r_norm) / max(r_norm, floor, 1e-30)
+            for p_norm, r_norm, _, _ in terms)
+  diff = size = 0.0
+  for _, _, leaf_diff, leaf_size in terms:
+    diff += leaf_diff
+    size += leaf_size
+  return gap, float(np.sqrt(diff / max(size, 1e-300)))
 
 
 def worst_leaf_gap(program: Dict[str, np.ndarray],
@@ -48,9 +91,7 @@ def worst_leaf_gap(program: Dict[str, np.ndarray],
   gap between the norms, not the norm of the difference, on the scale
   of the leaf or of the median leaf, whichever is larger (some leaves
   are all but zero)."""
-  p, r = _leaf_norms(program), _leaf_norms(reference)
-  floor = float(np.median(list(r.values())))
-  return max(abs(p[k] - r[k]) / max(r[k], floor, 1e-30) for k in r)
+  return _gap_and_err(program, reference)[0]
 
 
 def rel_err(program: Dict[str, np.ndarray],
@@ -64,13 +105,7 @@ def rel_err(program: Dict[str, np.ndarray],
   value of a signed difference, which passes through 0 as the seed
   varies, so its smallest reading shrinks with the number of seeds
   read and nothing can be held against it (PERF.md §2)."""
-  diff = size = 0.0
-  for k, r in reference.items():
-    r = np.asarray(r, np.float64)
-    diff += float(np.sum(np.square(
-        np.asarray(program[k], np.float64) - r)))
-    size += float(np.sum(np.square(r)))
-  return float(np.sqrt(diff / max(size, 1e-300)))
+  return _gap_and_err(program, reference)[1]
 
 
 def _adam_mu(opt_state) -> Dict[str, np.ndarray]:
@@ -94,10 +129,6 @@ def numbers_between(got_state: dict, got_metrics: Dict[str, float],
   `*_rel_err` are norms of differences (`rel_err`): what a precision
   limit can be held on; the others are gaps between scalars or norms,
   held against gross faults."""
-  delta = {k: np.asarray(got_state["params"][k], np.float64) - start[k]
-           for k in start}
-  ref_delta = {k: np.asarray(ref_state["params"][k], np.float64)
-               - start[k] for k in start}
   # Every scalar both sides report, loss and gradient norm first.
   numbers = {
       f"{name}_rel_gap":
@@ -106,17 +137,19 @@ def numbers_between(got_state: dict, got_metrics: Dict[str, float],
   if "q_next_mean" in ref_metrics:  # a mean of probabilities
     numbers["q_next_mean_gap"] = abs(
         got_metrics["q_next_mean"] - ref_metrics["q_next_mean"])
-  numbers["adam_mu_worst_leaf_gap"] = worst_leaf_gap(got_state["mu"],
-                                                     ref_state["mu"])
-  numbers["param_change_worst_leaf_gap"] = worst_leaf_gap(delta,
-                                                          ref_delta)
-  numbers["adam_mu_rel_err"] = rel_err(got_state["mu"], ref_state["mu"])
-  numbers["param_change_rel_err"] = rel_err(delta, ref_delta)
+  mu_gap, mu_err = _gap_and_err(got_state["mu"], ref_state["mu"])
+  # Of the parameters' change from `start`, not of the parameters.
+  change_gap, change_err = _gap_and_err(
+      got_state["params"],
+      {key: ref_state["params"][key] for key in start}, start)
+  numbers["adam_mu_worst_leaf_gap"] = mu_gap
+  numbers["param_change_worst_leaf_gap"] = change_gap
+  numbers["adam_mu_rel_err"] = mu_err
+  numbers["param_change_rel_err"] = change_err
   if ref_state["stats"]:
-    numbers["bn_stats_worst_leaf_gap"] = worst_leaf_gap(
-        got_state["stats"], ref_state["stats"])
-    numbers["bn_stats_rel_err"] = rel_err(got_state["stats"],
-                                          ref_state["stats"])
+    (numbers["bn_stats_worst_leaf_gap"],
+     numbers["bn_stats_rel_err"]) = _gap_and_err(got_state["stats"],
+                                                 ref_state["stats"])
   return numbers
 
 

@@ -17,6 +17,7 @@ that it can be checked on made-up events as well as on a recording.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -167,6 +168,38 @@ def idle_gaps(modules: Sequence[Event], host: Sequence[Event],
   return [[name, ns / 1e9] for name, ns in ranked]
 
 
+def whole_runs(runs: Sequence[Tuple[float, float]],
+               ops: Sequence[Event]) -> List[Tuple[float, float]]:
+  """Those of `runs` ([start, end) of a program's executions on one
+  chip) that the recording holds whole; `ops` is that chip's line of
+  operations.
+
+  A loop that keeps a dispatch in flight is inside a program when the
+  harness starts and stops its recording, and the recording then holds
+  the tail of one execution and the head of another, each as an
+  execution of its own. Counted as such they give 4 runs for the busy
+  time of three, and a time per step 4/3 short (PERF.md §6, PR 31 and
+  33). Only an execution that holds the chip's first or last recorded
+  operation can be cut. Such a one counts where it holds as many
+  operations as another execution of the program does: whole
+  executions of one program agree in that to the operation (10,696
+  each in `qtopt_64.train`, where the cut ones held 9,639 and 1,264),
+  a part agrees with nothing. Lengths would not do: whole executions
+  differ by a tenth where the feed sets the pace, and a tail can be
+  nine tenths of a whole. An execution clear of both edges is whole,
+  whatever it holds.
+  """
+  if not ops:
+    return []
+  starts = sorted(start for _, start, _ in ops)
+  first, last = starts[0], max(start + dur for _, start, dur in ops)
+  held = [bisect.bisect_left(starts, end)
+          - bisect.bisect_left(starts, start) for start, end in runs]
+  return [run for i, run in enumerate(runs)
+          if (run[0] > first and run[1] < last)
+          or held[i] in held[:i] + held[i + 1:]]
+
+
 def reduce_trace(path: str, chips: int,
                  window: Optional[Tuple[float, float]] = None,
                  program: Optional[str] = None,
@@ -179,11 +212,22 @@ def reduce_trace(path: str, chips: int,
     has finished, so that window holds as many waits for the next
     dispatch as it holds dispatches.
   program: count only executions of programs whose name starts with
-    this (`jit_k_steps`) in `program_runs` / `program_busy_s`.
+    this (`jit_k_steps`) in `program_runs` / `program_busy_s`, and of
+    those only the ones the recording holds whole (`whole_runs`).
+    `busy_s`, `window_s`, `device_ops` and `idle_gaps` keep every
+    operation, as the idle share must.
   host_events: the harness's own host spans on the trace's clock,
     beside whatever host events the recording holds.
   """
-  planes = load(path)
+  return reduce_planes(load(path), chips, window, program, host_events)
+
+
+def reduce_planes(planes: Dict[str, Dict[str, List[Event]]], chips: int,
+                  window: Optional[Tuple[float, float]] = None,
+                  program: Optional[str] = None,
+                  host_events: Sequence[Event] = ()) -> dict:
+  """`reduce_trace` on what `load` gives: {plane: {line: [Event]}}, so
+  that it can be checked on a made-up recording."""
   devices = sorted(
       (int(m.group(1)), name) for name in planes
       if (m := DEVICE_PLANE.match(name)))
@@ -205,9 +249,11 @@ def reduce_trace(path: str, chips: int,
     lines = planes[name]
     summary = device_summary(lines.get(OPS_LINE, []), window)
     modules = lines.get(MODULES_LINE, [])
-    runs = [(s, s + d) for n, s, d in modules
-            if (program is None or n.startswith(program))
-            and s >= window[0] and s + d <= window[1]]
+    runs = whole_runs(
+        [(s, s + d) for n, s, d in modules
+         if (program is None or n.startswith(program))
+         and s >= window[0] and s + d <= window[1]],
+        lines.get(OPS_LINE, []))
     prog_busy = sum(
         device_summary(lines.get(OPS_LINE, []), run)["busy_ns"]
         for run in runs)

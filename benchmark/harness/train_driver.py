@@ -108,7 +108,8 @@ def run(config: dict, traffic: dict, *, seed: int,
   return window.record(
       hook, kind="train", config=config, devices=devices, k=k,
       batch=batch, seed32=seed32, resume_step=resume_step,
-      model_dir=model_dir, trace_program="jit_k_steps", marks=marks,
+      model_dir=model_dir, trace_program="jit_k_steps",
+      dispatch_span="qtopt.dispatch", marks=marks,
       check_inputs={"params": host_params, "stats": host_stats,
                     "batches": buffer.kept})
 

@@ -103,7 +103,8 @@ def run(config: dict, traffic: dict, *, seed: int,
       batch=batch, seed32=seed32, resume_step=resume_step,
       model_dir=model_dir,
       # `train_eval_model` jits its K-step scan as `k_steps`.
-      trace_program="jit_k_steps", marks=marks,
+      trace_program="jit_k_steps", dispatch_span="train.dispatch",
+      marks=marks,
       check_inputs={"params": host_params, "stats": host_stats,
                     "batches": rows.kept})
 

@@ -279,8 +279,8 @@ def _stage_spans(hook: WindowHook) -> List[tuple]:
 
 def record(hook: WindowHook, *, kind: str, config: dict, devices,
            k: int, batch: int, seed32: int, resume_step: int,
-           model_dir: str, trace_program: str, marks: dict,
-           check_inputs: dict) -> dict:
+           model_dir: str, trace_program: str, dispatch_span: str,
+           marks: dict, check_inputs: dict) -> dict:
   """The run's record, as `run.py` and the per-layer readers take it
   (benchmark/README.md, "The driver contract")."""
   peak = max(d.memory_stats()["peak_bytes_in_use"] for d in devices) \
@@ -300,6 +300,7 @@ def record(hook: WindowHook, *, kind: str, config: dict, devices,
       "trace_dir": hook.trace_dir, "trace_span": hook.trace_span,
       "host_spans": _stage_spans(hook) or hook.host_spans,
       "trace_program": trace_program,
+      "dispatch_span": dispatch_span,
       "memory_peak_bytes": peak,
       "setup_split": marks,
       "check_inputs": {

@@ -8,8 +8,11 @@ window out of it and cuts each thread's time into named parts:
 
   * a dispatch belongs to the window when its first step lies in the
     range the window's log records cover (`run["records"]`);
-  * `seq`, which `qtopt.dispatch` carries beside `step`, ties the feed
-    thread's spans (`feed.*`) and `loop.wait_feed` to it;
+  * `seq`, which the loop's dispatch span carries beside `step`, ties
+    the feed thread's spans (`feed.*`) and `loop.wait_feed` to it. Each
+    train loop names that span after itself (`qtopt.dispatch`,
+    `train.dispatch`): the kind's driver states the name on the run's
+    record, `run["dispatch_span"]`;
   * a thread's window runs from the first span of the first such
     dispatch's cycle to the first span of the cycle after the last, so
     it holds whole cycles and no span straddles its edges;
@@ -29,7 +32,7 @@ from typing import Dict, List, Optional, Sequence
 
 from benchmark.harness import trace_reduce
 
-DISPATCH = "qtopt.dispatch"
+DISPATCH = "qtopt.dispatch"  # where a record states no other
 WAIT = "loop.wait_feed"
 PULL, SAMPLE, STACK = "feed.pull", "feed.sample", "feed.stack"
 DEVICE_PUT, QUEUE_PUT = "feed.device_put", "feed.queue_put"
@@ -52,8 +55,10 @@ def _thread_part(spans: Sequence[dict], tid: int, t0: float,
 
 
 def select(spans: Sequence[dict], record_steps: Sequence[int],
-           log_every_steps: int, k: int) -> Optional[dict]:
-  """The window's dispatches out of `spans` (`Tracer.snapshot_spans`).
+           log_every_steps: int, k: int,
+           dispatch: str = DISPATCH) -> Optional[dict]:
+  """The window's dispatches out of `spans` (`Tracer.snapshot_spans`);
+  `dispatch` is the name of the loop's dispatch span.
 
   Returns None where any span of the window is missing, else
   `{"steps", "seqs", "spans" (name -> the window's spans of that
@@ -64,7 +69,7 @@ def select(spans: Sequence[dict], record_steps: Sequence[int],
   lo = min(record_steps) - log_every_steps
   hi = max(record_steps)
   dispatches = sorted(
-      (s for s in spans if s["name"] == DISPATCH
+      (s for s in spans if s["name"] == dispatch
        and lo <= s.get("args", {}).get("step", lo - 1) < hi),
       key=lambda s: s["args"]["step"])
   if len(dispatches) * k != hi - lo:
@@ -72,7 +77,7 @@ def select(spans: Sequence[dict], record_steps: Sequence[int],
   seqs = [s["args"]["seq"] for s in dispatches]
   first, last, inside = seqs[0], seqs[-1], set(seqs)
 
-  by_name: Dict[str, List[dict]] = {DISPATCH: dispatches}
+  by_name: Dict[str, List[dict]] = {dispatch: dispatches}
   after: Dict[str, float] = {}  # thread -> start of the cycle after
   for s in spans:
     args = s.get("args", {})
@@ -120,7 +125,8 @@ def of_run(run: dict) -> Optional[dict]:
     run["span_window"] = select(
         telemetry.get_tracer().snapshot_spans(),
         [rec["step"] for rec in run["records"]],
-        run["config"]["train"]["log_every_steps"], run["k"])
+        run["config"]["train"]["log_every_steps"], run["k"],
+        run.get("dispatch_span", DISPATCH))
   return run["span_window"]
 
 

@@ -17,11 +17,11 @@ BENCH_FILE = os.path.join(run_lib.HERE, "tests", "data", "standin",
                           "BENCHMARK.json")
 
 
-def _run(capsys, monkeypatch):
+def _run(capsys, monkeypatch, trace="0"):
   monkeypatch.setattr(sys, "argv", [
       "run.py", "--bench-file", BENCH_FILE, "--workload",
       "standin.train_eval", "--seed", "2147483659", "--seconds", "1",
-      "--trace", "0", "--rehearse-cpu"])
+      "--trace", trace, "--rehearse-cpu"])
   assert run_lib.main() == 0
   captured = capsys.readouterr()
   lines = captured.out.strip().splitlines()
@@ -41,6 +41,25 @@ def test_sound_run_is_correct(capsys, monkeypatch):
   last = err.strip().splitlines()[-len(result["check"]):]
   assert [line.split()[1].rstrip(":") for line in last] \
       == list(result["check"])
+
+
+def test_span_readers_find_train_eval_models_dispatches(capsys,
+                                                        monkeypatch):
+  """`train_eval_model` names its dispatch span `train.dispatch`; the
+  driver states that on the run's record, and the readers of the
+  program's spans cut their window by it (they looked for
+  `qtopt.dispatch` and found nothing; ISSUE 33)."""
+  from tensor2robot_tpu.telemetry import core, metrics
+  # One run a process on the chip; here the runs of the tests before
+  # left their spans, of the same steps, in the process's ring.
+  core.reset_for_tests()
+  metrics.reset_for_tests()
+  result, lines, _ = _run(capsys, monkeypatch, trace="1")
+  assert result["correct"] is True, lines
+  assert {"feed_sample_ms_per_step", "feed_stack_ms_per_step",
+          "feed_device_put_ms_per_step", "feed_queue_full_share",
+          "loop_log_sync_ms", "loop_save_ms", "loop_run_ahead_share",
+          "host_unnamed_share"} <= set(result["metric_names"])
 
 
 def test_step_that_returns_its_state_unchanged(capsys, monkeypatch):

@@ -62,6 +62,96 @@ def test_a_gap_goes_to_the_innermost_of_nested_spans():
                   "loop.save_write": 400 / 1e9}
 
 
+def _made_up_recording(runs, ops_per_run=7, gap=2_000.0):
+  """Planes of one chip on which `jit_k_steps` ran back to back, as a
+  loop that keeps a dispatch in flight leaves it. `runs` is a list of
+  `whole`, `head` (the recording stopped inside it) and `tail` (it
+  started inside it); an operation takes 10 us of every 12, and a
+  1 us copy stands between two executions."""
+  modules, ops, t = [], [], 50_000.0
+  for kind in runs:
+    names = [f"%fusion.{i} = f32[8]" for i in range(ops_per_run)]
+    if kind == "head":
+      names = names[:3]
+    elif kind == "tail":
+      names = names[2:]
+    start = t
+    for name in names:
+      ops.append((name, t, 10_000.0))
+      t += 12_000.0
+    # A whole execution's event stands a little clear of its
+    # operations; a cut one's runs from its first to its last.
+    lead = 500.0 if kind == "whole" else 0.0
+    modules.append(("jit_k_steps(17)", start - lead,
+                    t - 2_000.0 - start + 2 * lead))
+    modules.append(("jit__copy_on_device(3)", t, 1_000.0))
+    ops.append(("%copy.1 = f32[8]", t, 1_000.0))
+    t += gap
+  modules.pop(), ops.pop()  # nothing follows the last execution
+  return {"/device:TPU:0": {tr.MODULES_LINE: modules, tr.OPS_LINE: ops},
+          tr.HOST_PLANE: {}}
+
+
+def _step_device_ms(trace, k=7):
+  from benchmark.layer_metrics import step_device_ms
+  return step_device_ms.read({"trace": trace, "k": k})
+
+
+def test_a_recording_that_cuts_programs_counts_the_whole_ones():
+  """Three whole programs, the tail of one before and the head of one
+  after: 3 runs and their busy time alone, where every part used to
+  count as a run (5 runs; ISSUE 33). Busy and idle keep every
+  operation."""
+  planes = _made_up_recording(["tail", "whole", "whole", "whole", "head"])
+  trace = tr.reduce_planes(planes, 1, program="jit_k_steps")
+  assert trace["program_runs"] == 3
+  assert trace["program_busy_s"] == pytest.approx(3 * 7 * 10e-6)
+  assert _step_device_ms(trace) == pytest.approx(10e-3)
+  every_op = (5 + 3 * 7 + 3) * 10e-6 + 4 * 1e-6
+  assert trace["busy_s"] == pytest.approx(every_op)
+  per_op = dict(trace["device_ops"])  # the tail holds 2-6, the head 0-2
+  assert per_op["fusion.2"] == pytest.approx(5 * 10e-6)
+  assert per_op["fusion.6"] == pytest.approx(4 * 10e-6)
+  assert trace["window_s"] == pytest.approx(
+      max(s + d for _, s, d in planes["/device:TPU:0"][tr.MODULES_LINE])
+      / 1e9)
+
+
+@pytest.mark.parametrize("runs,whole", [
+    (["whole", "whole", "whole"], 3),  # a loop that waits: none is cut
+    (["whole", "whole"], 2),           # both at an edge, and they agree
+    (["tail", "whole", "whole"], 2),   # the last one ends the recording
+    (["whole", "whole", "head"], 2),
+    (["tail", "whole", "head"], 1),    # clear of both edges
+    (["tail", "head"], 0),             # parts only: they agree on nothing
+    (["tail"], 0), (["head"], 0),
+    (["whole"], 0),                    # nothing says that it is whole
+])
+def test_which_executions_are_whole(runs, whole):
+  trace = tr.reduce_planes(_made_up_recording(runs), 1,
+                           program="jit_k_steps")
+  assert trace["program_runs"] == whole
+  assert trace["program_busy_s"] == pytest.approx(whole * 7 * 10e-6)
+  if not whole:  # never a partial number
+    assert _step_device_ms(trace) is None
+    assert trace["busy_s"] > 0
+
+
+def test_whole_runs_differ_in_length_and_still_count():
+  """Where the feed sets the pace whole executions differ by a tenth
+  in length (0.123-0.136 s in `qtopt_472.train`) and a tail can be nine
+  tenths of a whole: the count of operations tells them apart, a
+  length would not."""
+  ops = [(f"%fusion.{i} = f32[8]", 100.0 * i, 90.0) for i in range(4)]
+  slow = [(f"%fusion.{i} = f32[8]", 1000.0 + 120.0 * i, 110.0)
+          for i in range(4)]
+  tail = [(f"%fusion.{i} = f32[8]", 2000.0 + 130.0 * i, 120.0)
+          for i in range(1, 4)]
+  runs = [(0.0, 390.0), (1000.0, 1470.0), (2000.0 + 130.0, 2510.0)]
+  assert tr.whole_runs(runs, ops + slow + tail) == runs[:2]
+  assert tr.whole_runs(runs, []) == []
+
+
 @pytest.fixture(scope="module")
 def recorded():
   return tr.reduce_trace(RECORDED, 1, program="jit_prog")
