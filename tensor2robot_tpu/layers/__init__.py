@@ -34,8 +34,15 @@ from tensor2robot_tpu.layers.snail import (
 )
 from tensor2robot_tpu.layers.transformer import (
     CausalTransformer,
+    GatedAttention,
     MultiHeadAttention,
+    RMSNorm,
+    SequenceTrunk,
     TransformerBlock,
+)
+from tensor2robot_tpu.layers.gated_delta import (
+    GatedDeltaNet,
+    gated_delta_rule,
 )
 from tensor2robot_tpu.layers.pipelined_transformer import (
     PipelinedCausalTransformer,

@@ -19,11 +19,19 @@ makes first-class: the same module scales from short demo episodes to
 All backends compute EXACT attention in forward AND backward, so
 checkpoints are portable across them (train with ring on a pod, serve
 with flash on one chip).
+
+A block is data (docs/SEQUENCE.md): `TransformerBlock` takes its norm
+by name and, as module attributes, its sequence mixer and its
+feed-forward (`MultiHeadAttention`, `GatedAttention`,
+`layers/gated_delta.GatedDeltaNet`; a dense MLP, `parallel/moe.MoEMLP`,
+`parallel/moe.SparseMoE`), so a trunk that alternates layer kinds is a
+tuple of blocks (`SequenceTrunk`). Left out, they are the pre-LN
+attention block that `CausalTransformer` has always stacked.
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -87,18 +95,102 @@ class MultiHeadAttention(nn.Module):
     return nn.Dense(x.shape[-1], dtype=self.dtype, name="proj")(out)
 
 
-class TransformerBlock(nn.Module):
-  """Pre-LN block: x + MHA(LN(x)); x + MLP(LN(x)).
+class RMSNorm(nn.Module):
+  """x / rms(x) * (1 + weight) over the last axis, in float32: the
+  learnt weight is stored less one (zero-centred), so that w = 0 is
+  the identity scale."""
 
-  With `moe_experts > 0` the dense MLP becomes a MoE layer
-  (`parallel/moe.py`): routed capacity scales with expert count, not
-  per-token FLOPs, and with a mesh `expert` axis the experts run
-  expert-parallel. Dropped-token rows pass through on the residual —
-  the Switch-transformer semantics.
+  eps: float = 1e-6
+
+  @nn.compact
+  def __call__(self, x: jax.Array) -> jax.Array:
+    x = x.astype(jnp.float32)
+    weight = self.param("weight", nn.initializers.zeros,
+                        (x.shape[-1],), jnp.float32)
+    x = x * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps)
+    return x * (1.0 + weight)
+
+
+def rotary(x: jax.Array, rotary_dim: int, theta: float) -> jax.Array:
+  """Rotary position embedding (rotate-half) on the first `rotary_dim`
+  dims of each head of x [B, T, H, D]; positions are 0..T-1."""
+  t = x.shape[1]
+  half = rotary_dim // 2
+  inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+  angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq
+  cos = jnp.cos(angles)[None, :, None, :]
+  sin = jnp.sin(angles)[None, :, None, :]
+  x1, x2, rest = jnp.split(x, [half, rotary_dim], axis=-1)
+  return jnp.concatenate(
+      [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+
+
+class GatedAttention(nn.Module):
+  """Grouped-query softmax attention with a sigmoid gate on its output.
+
+  `num_heads` query heads over `num_kv_heads` key-value heads of
+  `head_dim`; `q_proj` gives per query head the query and a gate
+  (columns: all queries, then all gates); zero-centred RMS norms over
+  the head dimension of q and of k; rotary on the first `rotary_dim`
+  dims; out = attention * sigmoid(gate) under `o_proj`. The key-value
+  heads are repeated to the query heads before the backend, which
+  takes as many of each.
   """
 
   num_heads: int
+  num_kv_heads: int
   head_dim: int
+  rotary_dim: int
+  rope_theta: float = 1e7
+  eps: float = 1e-6
+  attention_impl: str = "auto"
+  mesh: Optional[Any] = None
+  dtype: Any = jnp.bfloat16
+
+  @nn.compact
+  def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+    b, t, width = x.shape
+    h, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
+    x = x.astype(self.dtype)
+
+    def proj(name, heads):
+      return nn.Dense(heads * d, use_bias=False, dtype=self.dtype,
+                      name=name)(x).reshape(b, t, heads, d)
+
+    with jax.named_scope("gated_attention"):
+      q, gate = jnp.split(proj("q_proj", 2 * h), 2, axis=2)
+      k, v = proj("k_proj", kv), proj("v_proj", kv)
+      q = RMSNorm(self.eps, name="q_norm")(q)
+      k = RMSNorm(self.eps, name="k_norm")(k)
+      q, k = (rotary(y, self.rotary_dim, self.rope_theta
+                     ).astype(self.dtype) for y in (q, k))
+      k, v = (jnp.repeat(y, h // kv, axis=2) for y in (k, v))
+      out = _attend(q, k, v, impl=self.attention_impl, causal=True,
+                    mesh=self.mesh)
+      out = out * jax.nn.sigmoid(gate.astype(jnp.float32)
+                                 ).astype(self.dtype)
+      return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                      name="o_proj")(out.reshape(b, t, h * d))
+
+
+class TransformerBlock(nn.Module):
+  """A pre-norm residual block as data: x + mixer(norm(x)), then
+  x + ffn(norm(x)).
+
+  `norm` names the two norms (`layer`: LayerNorm; `rms`: zero-centred
+  `RMSNorm` in float32, the residual stream then stays float32).
+  `mixer` and `ffn` are the block's two modules, any that map
+  [B, T, M] to [B, T, M]. Left out, they are what the block has always
+  built from its other attributes, under the names it has always
+  given them: `MultiHeadAttention(num_heads, head_dim)`, and a GELU MLP
+  of `mlp_ratio` or, with `moe_experts > 0`, a capacity-routed
+  `MoEMLP` (`parallel/moe.py`: dropped tokens pass through on the
+  residual, the Switch-transformer semantics).
+  """
+
+  num_heads: int = 0
+  head_dim: int = 0
   mlp_ratio: int = 4
   attention_impl: str = "reference"
   causal: bool = True
@@ -107,17 +199,32 @@ class TransformerBlock(nn.Module):
   moe_experts: int = 0
   moe_k: int = 2
   moe_capacity_factor: float = 2.0
+  norm: str = "layer"
+  norm_eps: float = 1e-6
+  mixer: Optional[nn.Module] = None
+  ffn: Optional[nn.Module] = None
+
+  def _norm(self, name: str):
+    if self.norm == "rms":
+      return RMSNorm(self.norm_eps, name=name)
+    if self.norm == "layer":
+      return nn.LayerNorm(dtype=self.dtype, name=name)
+    raise ValueError(f"Unknown norm: {self.norm!r}")
 
   @nn.compact
   def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
     width = x.shape[-1]
-    y = nn.LayerNorm(dtype=self.dtype, name="ln_attn")(x)
-    x = x + MultiHeadAttention(
-        num_heads=self.num_heads, head_dim=self.head_dim,
-        attention_impl=self.attention_impl, causal=self.causal,
-        mesh=self.mesh, dtype=self.dtype, name="attn")(y, train=train)
-    y = nn.LayerNorm(dtype=self.dtype, name="ln_mlp")(x)
-    if self.moe_experts:
+    mixer = self.mixer
+    if mixer is None:
+      mixer = MultiHeadAttention(
+          num_heads=self.num_heads, head_dim=self.head_dim,
+          attention_impl=self.attention_impl, causal=self.causal,
+          mesh=self.mesh, dtype=self.dtype, name="attn")
+    x = x + mixer(self._norm("ln_attn")(x), train=train)
+    y = self._norm("ln_mlp")(x)
+    if self.ffn is not None:
+      y = self.ffn(y)
+    elif self.moe_experts:
       from tensor2robot_tpu.parallel.moe import MoEMLP
       y = MoEMLP(
           num_experts=self.moe_experts,
@@ -130,6 +237,34 @@ class TransformerBlock(nn.Module):
       y = nn.gelu(y)
       y = nn.Dense(width, dtype=self.dtype, name="mlp_out")(y)
     return x + y
+
+
+class SequenceTrunk(nn.Module):
+  """`blocks` in order over x [B, T, M], then an `RMSNorm`; no
+  embedding and no learnt positions: its caller embeds, its mixers
+  place (rotary, a convolution, a recurrence). With `remat_policy`
+  every block runs under `jax.checkpoint`: `full` saves nothing of a
+  block, `dots` and `dots_no_batch` as `AbstractT2RModel.remat_policy`
+  names them; the backward pass then holds one block's activations at
+  a time."""
+
+  blocks: Tuple[nn.Module, ...]
+  remat_policy: Optional[str] = None
+
+  @nn.compact
+  def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+    policies = {"full": None, "dots": "checkpoint_dots",
+                "dots_no_batch": "dots_with_no_batch_dims_saveable"}
+    for block in self.blocks:  # flax names them blocks_0, blocks_1, ...
+      if self.remat_policy in (None, "none"):
+        x = block(x, train)
+      else:
+        policy = policies[self.remat_policy]
+        x = nn.remat(
+            lambda module, y: module(y, train),
+            policy=policy and getattr(jax.checkpoint_policies, policy)
+        )(block, x)
+    return RMSNorm(name="norm_out")(x)
 
 
 class CausalTransformer(nn.Module):

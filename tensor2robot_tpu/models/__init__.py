@@ -21,3 +21,6 @@ from tensor2robot_tpu.models.optimizers import (
     create_lr_schedule,
     create_optimizer,
 )
+from tensor2robot_tpu.models.language_model import (
+    NextTokenLanguageModel,
+)

@@ -29,6 +29,23 @@ the standard static-shape GShard/Switch formulation, built TPU-first:
 single-device reference); `MoEMLP` is the flax module that owns the
 params and sows the load-balance auxiliary loss.
 
+**The dropless layer over the experts held** (`SparseMoE`,
+`held_experts_ffn`; docs/SEQUENCE.md) is a second formulation, for
+models whose routing a capacity tensor cannot hold (hundreds of
+experts, ten a token: `[N, E, C]` at 32,768 tokens and 512 experts is
+out of the question) and whose semantics are not Switch's (no token
+is dropped). The router scores every expert and keeps the top k of
+all of them; the layer is told which contiguous range of experts it
+holds, sorts the assignments that fall on those by expert, and runs
+one grouped matrix product (`lax.ragged_dot`) per projection over the
+sorted rows: no dense dispatch tensor, no capacity. It computes the
+part of the layer's sum that its own experts give; on an
+expert-parallel pod the parts of all the shares add up to the layer
+(tests/test_sparse_moe.py), and this module adds nothing that stands
+in for the exchange. The capacity formulation above stays for its
+callers: `CausalTransformer(moe_experts=...)`, the vrgripper MoE gin
+file, the `expert`-axis shard_map path and their tests.
+
 Composition note: EP groups tokens over the data (+expert) axes. In a
 mesh that ALSO has a non-trivial `seq` axis (ring attention), the MoE
 layer still computes correctly, but GSPMD must reshard activations
@@ -263,3 +280,222 @@ def collect_aux_losses(variables: Any) -> jax.Array:
   for leaf in jax.tree_util.tree_leaves(variables.get("aux_loss", {})):
     total = total + jnp.sum(jnp.asarray(leaf, jnp.float32))
   return total
+
+
+def route_top_k(x: jax.Array, router: jax.Array, k: int,
+                normalise: bool = True):
+  """Softmax over ALL of the router's experts in float32, the k
+  largest, their weights divided by their sum where `normalise`.
+  x [N, M], router [M, E] -> (experts [N, k] int32, weights [N, k])."""
+  logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGH)
+  weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+  if normalise:
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+  return experts, weights
+
+
+def round_rows(assignments: int, held: int, num_experts: int) -> int:
+  """Rows of one round of `held_experts_ffn`: twice the share of the
+  assignments that uniform routing sends to the experts held, in
+  whole tiles of 512, at most all of them."""
+  share = -(-2 * assignments * held // num_experts)
+  return min(assignments, -(-share // 512) * 512)
+
+
+def _group_sizes(start, ends, counts, rows):
+  """The rows of each held expert's group within the round
+  `start .. start + rows - 1` of the sorted assignments: what the
+  round's grouped matrix products are told to work off. [H] int32."""
+  return (jnp.clip(ends, start, start + rows)
+          - jnp.clip(ends - counts, start, start + rows)
+          ).astype(jnp.int32)
+
+
+def _round_compute(start, here, token, ends, counts, x, weight, w_gate,
+                   w_up, w_down, rows, dtype):
+  """One round of `held_experts_ffn`: the sorted assignments
+  `start .. start + rows - 1`, gathered, through the grouped gated
+  unit, scattered back onto their tokens. [N, M] float32."""
+  sizes = _group_sizes(start, ends, counts, rows)
+  tok = jax.lax.dynamic_slice(token, (start,), (rows,))
+  # Past the last held assignment a row belongs to no group.
+  valid = (start + jnp.arange(rows) < here)[:, None]
+  wt = jax.lax.dynamic_slice(weight, (start,), (rows,))
+  xs = x[tok]
+
+  def grouped(lhs, rhs):
+    # A row of no group is nobody's to write: the TPU's grouped
+    # product leaves it as the memory was, in the result and in the
+    # gradient that its transpose hands back for `lhs` (PR 34: a first
+    # chip run's gradient norm read 1.5e7 times the reference's, from
+    # such rows scatter-added onto their tokens). Zeros go in and
+    # zeros come out, so the same holds for every cotangent.
+    out = jax.lax.ragged_dot(
+        jnp.where(valid, lhs, 0), rhs.astype(dtype), sizes,
+        preferred_element_type=jnp.float32)
+    return jnp.where(valid, out, 0.0)
+
+  hidden = (jax.nn.silu(grouped(xs, w_gate))
+            * grouped(xs, w_up)).astype(dtype)
+  ys = grouped(hidden, w_down) * wt[:, None]
+  return jnp.zeros(x.shape, jnp.float32).at[tok].add(ys)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(10, 11))
+def _round(start, here, token, ends, counts, x, weight, w_gate, w_up,
+           w_down, rows, dtype):
+  """`_round_compute` where the round holds a held assignment, zeros
+  where it starts past the last one. Its own gradient rule: the
+  backward pass recomputes the round from its inputs (its buffers live
+  once, forward and backward) and skips it as the forward pass did; a
+  `lax.cond` differentiated by JAX would hand every round's inputs on
+  as that round's own residuals."""
+  return jax.lax.cond(
+      start < here,
+      lambda: _round_compute(start, here, token, ends, counts, x, weight,
+                             w_gate, w_up, w_down, rows, dtype),
+      lambda: jnp.zeros(x.shape, jnp.float32))
+
+
+def _round_fwd(start, here, token, ends, counts, x, weight, w_gate, w_up,
+               w_down, rows, dtype):
+  args = (start, here, token, ends, counts, x, weight, w_gate, w_up,
+          w_down)
+  return _round(*args, rows, dtype), args
+
+
+def _round_bwd(rows, dtype, args, cotangent):
+  index, floats = args[:5], args[5:]
+
+  def grads():
+    _, vjp = jax.vjp(
+        lambda *floats: _round_compute(*index, *floats, rows, dtype),
+        *floats)
+    return vjp(cotangent)
+
+  out = jax.lax.cond(
+      index[0] < index[1], grads,
+      lambda: tuple(jnp.zeros_like(value) for value in floats))
+  return (None,) * len(index) + tuple(out)
+
+
+_round.defvjp(_round_fwd, _round_bwd)
+
+
+def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, *,
+                     first_expert: int, num_experts: int,
+                     dtype: Any = jnp.bfloat16):
+  """sum over a token's chosen experts THAT ARE HELD HERE of weight *
+  down_e(silu(gate_e x) * up_e x). x [N, M]; experts, weights [N, k]
+  from `route_top_k`; w_gate, w_up [H, M, F], w_down [H, F, M]: the H
+  experts `first_expert .. first_expert + H - 1` of `num_experts`.
+  Returns ([N, M] float32, counters).
+
+  Dropless with static shapes: the N * k assignments are sorted by
+  expert (those not held here sort last), and the sorted rows are
+  worked off in rounds of `round_rows` rows, each one gather, three
+  grouped matrix products and one scatter-add; a round that starts
+  past the last held assignment is skipped, so uniform routing runs
+  one round and a router that sends everything here runs them all.
+  """
+  n, k = experts.shape
+  held = w_gate.shape[0]
+  total = n * k
+  rows = round_rows(total, held, num_experts)
+  local = experts.reshape(-1) - first_expert
+  local = jnp.where((local >= 0) & (local < held), local, held)
+  order = jnp.argsort(local, stable=True)
+  token = (order // k).astype(jnp.int32)
+  weight = weights.reshape(-1)[order]
+  counts = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+  ends = jnp.cumsum(counts)
+  here = ends[-1]
+  x = x.astype(dtype)
+
+  def body(carry, start):
+    out, done = carry
+    out = out + _round(start, here, token, ends, counts, x, weight,
+                       w_gate, w_up, w_down, rows, dtype)
+    # The rows that this round's grouped products were given, if it
+    # ran: what they leave out of a group they do not write.
+    given = jnp.sum(_group_sizes(start, ends, counts, rows))
+    return (out, done + jnp.where(start < here, given, 0)), None
+
+  (out, done), _ = jax.lax.scan(
+      body, (jnp.zeros(x.shape, jnp.float32), jnp.zeros((), jnp.int32)),
+      jnp.arange(0, total, rows, dtype=jnp.int32))
+  load = counts.astype(jnp.float32)
+  counters = {
+      # Of all assignments, those on experts held here.
+      "assignments_here_share": here.astype(jnp.float32) / total,
+      "expert_load_max_over_mean":
+          jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
+      # Held assignments that lay in no group of any round that ran.
+      "dropped_assignments": (here - done).astype(jnp.float32),
+  }
+  return out, counters
+
+
+class SparseMoE(nn.Module):
+  """A dropless top-k expert layer over the experts held here, beside
+  one sigmoid-gated shared expert; all experts are gated units
+  down(silu(gate x) * up x) without biases.
+
+  `num_experts` is the router's width (it routes over all of them),
+  `experts_held` how many of them this module holds, from
+  `first_expert` on: all of them on one chip that holds the layer, a
+  chip's share on an expert-parallel pod. The routed part is the held
+  experts' alone; the shared expert is computed in full (every chip
+  computes it alike). The counters of the routing are sown into the
+  `moe_counters` collection.
+  """
+
+  num_experts: int
+  experts_held: int
+  k: int
+  expert_width: int
+  shared_width: int = 0
+  first_expert: int = 0
+  normalise_top_k: bool = True
+  dtype: Any = jnp.bfloat16
+
+  @nn.compact
+  def __call__(self, x: jax.Array) -> jax.Array:
+    b, t, width = x.shape
+    held, f = self.experts_held, self.expert_width
+    init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                        batch_axis=(0,))
+    router = self.param("router", nn.initializers.lecun_normal(),
+                        (width, self.num_experts), jnp.float32)
+    w_gate = self.param("experts_gate", init, (held, width, f),
+                        jnp.float32)
+    w_up = self.param("experts_up", init, (held, width, f), jnp.float32)
+    w_down = self.param("experts_down", init, (held, f, width),
+                        jnp.float32)
+    tokens = x.reshape(b * t, width)
+    with jax.named_scope("moe/route"):
+      experts, weights = route_top_k(tokens, router, self.k,
+                                     self.normalise_top_k)
+    with jax.named_scope("moe/experts"):
+      out, counters = held_experts_ffn(
+          tokens, experts, weights, w_gate, w_up, w_down,
+          first_expert=self.first_expert,
+          num_experts=self.num_experts, dtype=self.dtype)
+    for name, value in counters.items():
+      self.sow("moe_counters", name, value)
+    if self.shared_width:
+      with jax.named_scope("moe/shared"):
+        y = tokens.astype(self.dtype)
+
+        def dense(name, size):
+          return nn.Dense(size, use_bias=False, dtype=self.dtype,
+                          name=name)
+
+        shared = dense("shared_down", width)(
+            nn.silu(dense("shared_gate", self.shared_width)(y))
+            * dense("shared_up", self.shared_width)(y))
+        gate = jax.nn.sigmoid(
+            dense("shared_expert_gate", 1)(y).astype(jnp.float32))
+        out = out + gate * shared
+    return out.reshape(b, t, width)

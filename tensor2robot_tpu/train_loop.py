@@ -27,6 +27,25 @@ whose trainer does work between dispatches on the live state
 dispatch in flight is K more steps of sampling lead) finishes every
 dispatch before the next is enqueued.
 
+A state that fills the chip has no room for its copy. The loop decides
+from what it can observe, the bytes of the state it was given against
+the device's `memory_stats()["bytes_limit"]` (`state_copy_fits`): state
+and copy together may take half of the device's memory, the other half
+being left to the step (gradients, activations, the feed's dispatches).
+The half is a guess, not a measurement: the loop never sees the step
+program (it stays in the trainer's frame, below), so it cannot ask what
+that program's temporaries take. Two sizes have been run on a 16.9 GB
+chip (PR 34): QT-Opt's states of megabytes, which copy, and a 7.5 GB
+state beside 9.5 GB of temporaries, which cannot; a state of a quarter
+to a half of the limit drains at every save where its copy might have
+fitted, which costs time and never memory. Where state and copy would
+take more, `begin` compiles and makes no copy, and a save
+step finishes its own dispatch and saves the live state before the next
+is enqueued; between saves the loop runs ahead as before
+(`loop.dispatches.drained` counts the save steps' successors with the
+first dispatch, the gauge `loop.state_copy_fits` and the `copied`
+argument of `loop.snapshot` say which way the run decided).
+
 The jitted call stays in the trainer's frame: a loop that took it as a
 callback would stand in the location of every operation its first call
 traces (PR 26: two such frames took that call from 4.5 to 7.6 s and
@@ -109,6 +128,31 @@ def _copy_on_device(state):
   that made `state`; each copy has its leaf's sharding. `jnp.copy` and
   not the identity, whose outputs `jit` forwards from its inputs."""
   return jax.tree_util.tree_map(jnp.copy, state)
+
+
+def _bytes_limit(devices) -> Optional[int]:
+  """The smallest `bytes_limit` the runtime reports for `devices`; None
+  where it reports none (a CPU)."""
+  limits = [stats["bytes_limit"] for stats in
+            (device.memory_stats() for device in devices)
+            if stats and "bytes_limit" in stats]
+  return min(limits) if limits else None
+
+
+def state_copy_fits(state) -> bool:
+  """Whether a copy of `state` fits on its devices beside it: the
+  bytes one device holds of the state, twice, against half of that
+  device's `bytes_limit` (a guess at the step's own need: the module's
+  docstring). True where the runtime reports no limit."""
+  leaves = [leaf for leaf in jax.tree_util.tree_leaves(state)
+            if isinstance(leaf, jax.Array)]
+  if not leaves:
+    return True
+  limit = _bytes_limit(leaves[0].devices())
+  if limit is None:
+    return True
+  held = sum(leaf.addressable_shards[0].data.nbytes for leaf in leaves)
+  return 2 * held <= limit // 2
 
 
 def _on_every_rank(holds: bool) -> bool:
@@ -246,7 +290,13 @@ class TrainLoop:
       self.runs_ahead = _on_every_rank(
           boundary_work is None
           and not self.hook_list.drives_online_collection)
-      if self.runs_ahead:
+      # Whether a save step's snapshot is a copy on the device or the
+      # live state of a dispatch that the loop finishes first.
+      self.copies_state = self.runs_ahead and _on_every_rank(
+          state_copy_fits(self._state()))
+      telemetry.registry().gauge("loop.state_copy_fits").set(
+          float(self.copies_state))
+      if self.copies_state:
         # The snapshot's program compiles here, with the run's others:
         # at the first save it would read as a warm-path recompile.
         _copy_on_device(self._state())
@@ -305,8 +355,8 @@ class TrainLoop:
     step = self.step
     if self._tag_step is not None:
       self._tag_step(step)  # one int store; actors tag adds with it
-    snapshot = (self._snapshot(step)
-                if self._due(step, self._save_every) else None)
+    save_due = self._due(step, self._save_every)
+    snapshot = self._snapshot(step) if save_due else None
     # Enqueued with the after-work of the one before still owed, or
     # with nothing owed: the two add up to the dispatches.
     telemetry.registry().counter(
@@ -314,7 +364,8 @@ class TrainLoop:
         else "loop.dispatches.ran_ahead").inc()
     self._finish_pending()
     self._pending = (step, metrics, snapshot)
-    if not self.runs_ahead:
+    if not self.runs_ahead or (save_due and not self.copies_state):
+      # The snapshot is the live state: saved before it is donated.
       self._finish_pending()
 
   def write(self, tag: str, step: int, scalars: Dict[str, Any]) -> None:
@@ -329,10 +380,11 @@ class TrainLoop:
     """The state after `step` as the deferred save will find it: a
     copy on the device, since the next dispatch donates the live one
     (the copy to the host starts here where the save gathers there);
-    the live state itself in a run that finishes each dispatch before
-    the next."""
-    with telemetry.span("loop.snapshot", step=step):
-      if not self.runs_ahead:
+    the live state itself in a run that finishes each dispatch, or this
+    one, before the next (`copies_state`)."""
+    with telemetry.span("loop.snapshot", step=step,
+                        copied=self.copies_state):
+      if not self.copies_state:
         return self._state()
       snapshot = _copy_on_device(self._state())
       if self._save_payload is host_payload:

@@ -1,0 +1,243 @@
+"""The sequence trunk's layer kinds (ISSUE 34; docs/SEQUENCE.md) against
+the plain reference of `benchmark/reference/qwen3_next.py`, at small
+sizes in float32 on seeded random weights: the chunked Gated DeltaNet
+against the recurrence over positions, gated grouped-query attention
+against attention materialised by blocks, the block as data."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+from benchmark.reference import qwen3_next as ref  # noqa: E402
+from tensor2robot_tpu.layers import gated_delta  # noqa: E402
+from tensor2robot_tpu.layers.transformer import (  # noqa: E402
+    CausalTransformer,
+    GatedAttention,
+    RMSNorm,
+    SequenceTrunk,
+    TransformerBlock,
+    rotary,
+)
+
+MODEL = {
+    "hidden_size": 32, "rms_norm_eps": 1e-6,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "partial_rotary_factor": 0.25, "rope_theta": 1e7,
+    "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+    "linear_key_head_dim": 8, "linear_value_head_dim": 8,
+    "linear_conv_kernel_dim": 4,
+}
+
+
+def _rule_inputs(seed, t, heads=3, dk=8, dv=8, g_scale=1.0):
+  keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+  q = gated_delta.l2_normalize(
+      jax.random.normal(keys[0], (2, t, heads, dk))) * dk ** -0.5
+  k = gated_delta.l2_normalize(
+      jax.random.normal(keys[1], (2, t, heads, dk)))
+  v = jax.random.normal(keys[2], (2, t, heads, dv))
+  g = -g_scale * jax.random.uniform(keys[3], (2, t, heads), minval=0.5,
+                                    maxval=4.0)
+  beta = jax.nn.sigmoid(jax.random.normal(keys[4], (2, t, heads)))
+  return q, k, v, g, beta
+
+
+def _recurrence(q, k, v, g, beta):
+  return jax.vmap(lambda *row: ref._delta_rule(*row, control=False))(
+      q, k, v, g, beta)
+
+
+# 150 is no multiple of the chunk; g down to -4 a position decays a
+# chunk of 16 by exp(-64), and g of -40 underflows float32 inside one.
+@pytest.mark.parametrize("t,chunk,g_scale", [
+    (150, 64, 1.0), (150, 16, 1.0), (64, 64, 1.0), (7, 8, 1.0),
+    (40, 16, 10.0), (33, 32, 0.01)])
+def test_chunked_delta_rule_equals_the_recurrence(t, chunk, g_scale):
+  args = _rule_inputs(t, t, g_scale=g_scale)
+  got = gated_delta.gated_delta_rule(*args, chunk=chunk)
+  want = _recurrence(*args)
+  np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
+  assert np.all(np.isfinite(got))
+
+
+@pytest.mark.parametrize("g_scale", [1.0, 10.0])
+def test_chunked_delta_rule_gradients_equal_the_recurrences(g_scale):
+  args = _rule_inputs(3, 150, g_scale=g_scale)
+  probe = jax.random.normal(jax.random.PRNGKey(9), (2, 150, 3, 8))
+
+  def through(rule):
+    return jax.grad(lambda *a: jnp.sum(rule(*a) * probe),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+
+  got = through(lambda *a: gated_delta.gated_delta_rule(*a, chunk=64))
+  want = through(_recurrence)
+  for name, a, b in zip("q k v g beta".split(), got, want):
+    assert np.all(np.isfinite(a)), name
+    np.testing.assert_allclose(a, b, atol=3e-5, rtol=1e-3, err_msg=name)
+
+
+def test_unit_lower_inverse_and_its_gradient():
+  a = jnp.tril(jax.random.normal(jax.random.PRNGKey(0), (3, 16, 16)), -1)
+  want = jnp.linalg.inv(jnp.eye(16) + a)
+  np.testing.assert_allclose(gated_delta._unit_lower_inverse(a), want,
+                             atol=1e-4, rtol=1e-4)
+  probe = jax.random.normal(jax.random.PRNGKey(1), (3, 16, 16))
+  got = jax.grad(lambda a: jnp.sum(
+      gated_delta._unit_lower_inverse(a) * probe))(a)
+  want = jax.grad(lambda a: jnp.sum(
+      jnp.linalg.inv(jnp.eye(16) + a) * probe))(a)
+  np.testing.assert_allclose(got, want, atol=2e-3, rtol=2e-3)
+
+
+def test_causal_depthwise_conv_sees_the_last_four_positions():
+  x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 5))
+  kernel = jax.random.normal(jax.random.PRNGKey(1), (4, 5))
+  got = gated_delta.causal_depthwise_conv(x, kernel)
+  want = np.zeros((2, 9, 5), np.float32)
+  for t in range(9):
+    for j in range(4):
+      if t - 3 + j >= 0:
+        want[:, t] += np.asarray(x[:, t - 3 + j] * kernel[j])
+  np.testing.assert_allclose(got, want, atol=1e-6)
+
+
+def _flat(tree, prefix=""):
+  out = {}
+  for key, value in tree.items():
+    if isinstance(value, dict):
+      out.update(_flat(value, f"{prefix}{key}/"))
+    else:
+      out[f"{prefix}{key}"] = value
+  return out
+
+
+def _randomised(params, seed):
+  leaves, treedef = jax.tree_util.tree_flatten(params)
+  keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+  return jax.tree_util.tree_unflatten(treedef, [
+      leaf + 0.3 * jax.random.normal(key, leaf.shape)
+      for leaf, key in zip(leaves, keys)])
+
+
+@pytest.mark.parametrize("t", [50, 130])
+def test_gated_delta_net_layer_equals_the_reference(t):
+  layer = gated_delta.GatedDeltaNet(
+      num_k_heads=2, num_v_heads=4, head_k_dim=8, head_v_dim=8,
+      chunk=64, dtype=jnp.float32)
+  x = jax.random.normal(jax.random.PRNGKey(0), (2, t, 32))
+  params = _randomised(layer.init(jax.random.PRNGKey(1), x)["params"], 2)
+  got = layer.apply({"params": params}, x)
+  want = jax.vmap(lambda row: ref._gated_delta_net(
+      row, _flat(params), MODEL, False))(x)
+  np.testing.assert_allclose(got, want, atol=3e-5, rtol=1e-3)
+
+
+@pytest.mark.parametrize("t", [40, 1024 + 40])
+def test_gated_attention_equals_the_reference(t):
+  """Partial rotary (4 of 16 dims), two key-value heads under four
+  query heads, the sigmoid gate; 1,064 positions is more than one of
+  the reference's blocks of queries would hold, so it takes them all
+  at once, and 2,048 goes block by block."""
+  layer = GatedAttention(num_heads=4, num_kv_heads=2, head_dim=16,
+                         rotary_dim=4, attention_impl="reference",
+                         dtype=jnp.float32)
+  x = jax.random.normal(jax.random.PRNGKey(0), (2, t, 32))
+  params = _randomised(layer.init(jax.random.PRNGKey(1), x)["params"], 3)
+  got = layer.apply({"params": params}, x)
+  want = jax.vmap(lambda row: ref._gated_attention(
+      row, _flat(params), MODEL, False))(x)
+  np.testing.assert_allclose(got, want, atol=3e-5, rtol=1e-3)
+
+
+def test_reference_attention_by_blocks_equals_all_at_once(monkeypatch):
+  x = jax.random.normal(jax.random.PRNGKey(0), (64, 32))
+  layer = GatedAttention(num_heads=4, num_kv_heads=2, head_dim=16,
+                         rotary_dim=4, attention_impl="reference",
+                         dtype=jnp.float32)
+  params = _flat(_randomised(
+      layer.init(jax.random.PRNGKey(1), x[None])["params"], 4))
+  whole = ref._gated_attention(x, params, MODEL, False)
+  monkeypatch.setattr(ref, "QUERY_BLOCK", 16)
+  np.testing.assert_allclose(
+      ref._gated_attention(x, params, MODEL, False), whole, atol=1e-5)
+
+
+def test_rotary_turns_only_its_share_of_a_head():
+  x = jax.random.normal(jax.random.PRNGKey(0), (1, 6, 2, 16))
+  y = rotary(x, 4, 1e4)
+  np.testing.assert_array_equal(y[..., 4:], x[..., 4:])
+  np.testing.assert_allclose(y[:, 0], x[:, 0], atol=1e-6)  # position 0
+  np.testing.assert_allclose(
+      jnp.linalg.norm(y[..., :4], axis=-1),
+      jnp.linalg.norm(x[..., :4], axis=-1), rtol=1e-5)
+  assert not np.allclose(y[:, 1:, :, :4], x[:, 1:, :, :4])
+
+
+def test_rms_norm():
+  x = 3.0 * jax.random.normal(jax.random.PRNGKey(0), (4, 8))
+  norm = RMSNorm()
+  params = norm.init(jax.random.PRNGKey(1), x)
+  np.testing.assert_allclose(  # w = 0 is the identity scale
+      jnp.sqrt(jnp.mean(jnp.square(norm.apply(params, x)), -1)), 1.0,
+      rtol=1e-4)
+  weight = jax.random.normal(jax.random.PRNGKey(2), (8,))
+  got = norm.apply({"params": {"weight": weight}}, x)
+  want = ref._rms_norm(x, weight, 1e-6, False)
+  np.testing.assert_allclose(got, want, rtol=1e-5)
+
+
+def _hybrid_blocks():
+  def mixer(i):
+    if i == 1:
+      return GatedAttention(num_heads=4, num_kv_heads=2, head_dim=8,
+                            rotary_dim=4, attention_impl="reference",
+                            dtype=jnp.float32)
+    return gated_delta.GatedDeltaNet(
+        num_k_heads=2, num_v_heads=4, head_k_dim=8, head_v_dim=8,
+        chunk=8, dtype=jnp.float32)
+
+  return tuple(TransformerBlock(norm="rms", mixer=mixer(i), mlp_ratio=2,
+                                dtype=jnp.float32) for i in range(2))
+
+
+@pytest.mark.parametrize("policy", ["full", "dots", "dots_no_batch"])
+def test_a_trunk_of_blocks_as_data_remats_to_the_same_numbers(policy):
+  x = jax.random.normal(jax.random.PRNGKey(0), (2, 20, 16))
+  plain = SequenceTrunk(blocks=_hybrid_blocks())
+  params = plain.init(jax.random.PRNGKey(1), x)
+  assert set(params["params"]["blocks_0"]["mixer"]) >= {"A_log", "conv"}
+  assert set(params["params"]["blocks_1"]["mixer"]) >= {"q_proj",
+                                                        "k_norm"}
+  remat = SequenceTrunk(blocks=_hybrid_blocks(), remat_policy=policy)
+
+  def loss(trunk, params):
+    return jnp.sum(jnp.square(trunk.apply(params, x)))
+
+  want, want_grad = jax.value_and_grad(lambda p: loss(plain, p))(params)
+  got, got_grad = jax.value_and_grad(lambda p: loss(remat, p))(params)
+  np.testing.assert_allclose(got, want, rtol=1e-6)
+  for a, b in zip(jax.tree_util.tree_leaves(got_grad),
+                  jax.tree_util.tree_leaves(want_grad)):
+    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+def test_a_block_without_mixer_or_ffn_is_the_block_it_always_was():
+  """`CausalTransformer` still stacks pre-LN attention blocks under
+  the names its checkpoints have."""
+  model = CausalTransformer(width=16, depth=2, num_heads=2, max_len=8,
+                            dtype=jnp.float32)
+  x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 5))
+  params = model.init(jax.random.PRNGKey(1), x)["params"]
+  assert set(params["block0"]) == {"ln_attn", "attn", "ln_mlp",
+                                   "mlp_in", "mlp_out"}
+  assert set(params["block0"]["ln_attn"]) == {"scale", "bias"}
+  with pytest.raises(ValueError, match="Unknown norm"):
+    TransformerBlock(num_heads=2, head_dim=8, norm="batch").init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4, 16)))

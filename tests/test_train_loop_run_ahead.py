@@ -378,3 +378,92 @@ def test_the_dispatch_in_flight_is_one_of_the_feeds_depth(
   monkeypatch.setattr(prefetch.ShardedPrefetcher, "__init__", init)
   _qtopt_run(tmp_path, hooks, prefetch_buffer_size=asked)
   assert depths == [depth]
+
+
+# --- a state that fills the chip (ISSUE 34) ---
+
+
+def _expected_without_a_copy(last, k):
+  """A run ahead whose save steps finish their own dispatch: what a
+  save step owes comes right after its enqueue (and after what the
+  dispatch before it still owed)."""
+  events, owed = [], []
+  for step in range(k, last + 1, k):
+    events.append(("enqueue", step))
+    events += owed
+    owed = _after_work(step, k, last)
+    if step % (SAVE_EVERY * k) == 0 or step == last:
+      events, owed = events + owed, []
+  return events + owed + [("end", last, float(last))]
+
+
+@pytest.mark.parametrize("bytes_limit,copies", [
+    (1 << 30, True),   # 20 B of state beside a GB
+    (79, False),       # 2 x 20 B do not fit in half of 79 B
+    (80, True),        # ... and just fit in half of 80 B
+    (None, True),      # a runtime that reports no limit (a CPU)
+])
+def test_the_loop_decides_from_the_states_bytes_whether_to_copy_it(
+    tmp_path, events, monkeypatch, bytes_limit, copies):
+  """`begin` holds the state's bytes (4 floats and a counter: 20)
+  against the device's `bytes_limit`, nothing else. Where a copy does
+  not fit no copy is ever made, a save step's dispatch is finished and
+  its live state saved before the next is enqueued, and between saves
+  the loop runs ahead as it did."""
+  monkeypatch.setattr(train_loop, "_bytes_limit",
+                      lambda devices: bytes_limit)
+  copied = []
+  real_copy = train_loop._copy_on_device
+  monkeypatch.setattr(
+      train_loop, "_copy_on_device",
+      lambda state: copied.append(1) or real_copy(state))
+  last = 12
+  state = _stub_trainer(tmp_path, events, hooks=[Recorder(events)],
+                        max_train_steps=last)
+  assert float(state["w"][0]) == last
+  gauge = telemetry.registry().scalars("loop.state_copy_fits")
+  assert gauge == {"loop.state_copy_fits": float(copies)}
+  if copies:
+    assert events == _expected(last, 1, ahead=True)
+    assert len(copied) == 1 + last // SAVE_EVERY  # begin's, the saves'
+    assert _counts() == {"loop.dispatches.drained": 1.0,
+                         "loop.dispatches.ran_ahead": last - 1.0}
+  else:
+    assert events == _expected_without_a_copy(last, 1)
+    assert copied == []
+    # The first dispatch and each save step's successor found nothing
+    # owed; the last save step has no successor.
+    saves = last // SAVE_EVERY
+    assert _counts() == {"loop.dispatches.drained": float(saves),
+                         "loop.dispatches.ran_ahead":
+                             float(last - saves)}
+  # What went to disk is the state after exactly that step, either way.
+  for step in ckpt_lib.list_steps(str(tmp_path)):
+    restored = ckpt_lib.restore_state(
+        str(tmp_path), like=jax.device_get(state), step=step)
+    assert float(restored["w"][0]) == step
+
+
+def test_state_copy_fits_reads_one_devices_share_of_the_state(
+    monkeypatch):
+  state = {"w": jnp.zeros((1024,), jnp.float32), "n": 3, "name": "x"}
+  bytes_limit = train_loop._bytes_limit  # the real one
+  for limit, fits in ((4 * 4096, True), (4 * 4096 - 2, False),
+                      (None, True)):
+    monkeypatch.setattr(train_loop, "_bytes_limit",
+                        lambda devices, limit=limit: limit)
+    assert train_loop.state_copy_fits(state) is fits
+  assert train_loop.state_copy_fits({"n": 3}) is True
+  # The runtime's own report, where it has one (a CPU has none).
+  assert (bytes_limit(jax.devices()[:1]) or 1) > 0
+
+  class Device:
+    def __init__(self, stats):
+      self._stats = stats
+
+    def memory_stats(self):
+      return self._stats
+
+  assert bytes_limit([Device({"bytes_limit": 7}),
+                      Device({"bytes_limit": 5}), Device(None)]) == 5
+  assert bytes_limit([Device(None), Device({})]) is None
