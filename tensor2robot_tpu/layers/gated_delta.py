@@ -29,12 +29,16 @@ the state between chunks are float32 whatever `dtype` is.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from tensor2robot_tpu.ops import delta_rule_walk
+from tensor2robot_tpu.telemetry import metrics as tmetrics
 
 HIGH = jax.lax.Precision.HIGH
 
@@ -80,14 +84,57 @@ _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd,
                            _unit_lower_inverse_bwd)
 
 
+def scan_walk(writes, k_decayed, q_decayed, k_to_end, end_decay):
+  """The walk over the chunks as a `lax.scan` carrying the float32
+  state [B, H, Dk, Dv]: the plain path (a CPU, shapes that do not tile)
+  and the oracle of `ops/delta_rule_walk.walk`, which has this
+  signature. `writes` [N, B, H, C, Dv] float32; `k_decayed`,
+  `q_decayed`, `k_to_end` [N, B, H, C, Dk] in the products' dtype;
+  `end_decay` [N, B, H]. Returns (`new`, `carried`): what each chunk
+  writes given the state it starts from, and what its queries read of
+  that state, [N, B, H, C, Dv] float32."""
+  dtype = k_decayed.dtype
+
+  def mm(x, y, spec):
+    return jnp.einsum(spec, x.astype(dtype), y.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+  def step(state, xs):
+    writes_i, k_decayed_i, q_decayed_i, k_to_end_i, end_decay_i = xs
+    new = writes_i - mm(k_decayed_i, state, "bhik,bhkv->bhiv")
+    carried = mm(q_decayed_i, state, "bhik,bhkv->bhiv")
+    state = (state * end_decay_i[..., None, None]
+             + mm(k_to_end_i, new, "bhik,bhiv->bhkv"))
+    return state, (new, carried)
+
+  _, b, h, _, dv = writes.shape
+  state0 = jnp.zeros((b, h, k_decayed.shape[-1], dv), jnp.float32)
+  _, (new, carried) = jax.lax.scan(
+      step, state0, (writes, k_decayed, q_decayed, k_to_end, end_decay))
+  return new, carried
+
+
+def _on_tpu() -> bool:
+  return jax.devices()[0].platform == "tpu"
+
+
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
-                     dtype: Any = jnp.float32) -> jax.Array:
+                     dtype: Any = jnp.float32,
+                     interpret: bool = False) -> jax.Array:
   """The gated delta rule over [B, T, H, D] in chunks of `chunk`
   positions (a power of two). q, k [B, T, H, Dk] (already normalised
   and scaled as the layer wants them), v [B, T, H, Dv], g and beta
   [B, T, H] float32. Returns o [B, T, H, Dv] float32. Matrix products
   against q, k, v and the state take `dtype` operands and accumulate in
-  float32."""
+  float32.
+
+  The walk over the chunks is `ops/delta_rule_walk`'s kernel pair where
+  the platform is a TPU and the shapes tile, `scan_walk` everywhere
+  else: read off the input, nobody sets it. The registry's counters
+  `gated_delta.walk.kernel_traces` and `.scan_traces` count the traced
+  calls that took each (a compiled program runs what was traced).
+  `interpret` is the tests': the kernels in the Pallas interpreter,
+  whatever the platform and the shapes."""
   b, t, h, dk = q.shape
   pad = -t % chunk
   if pad:
@@ -122,7 +169,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
   a = jnp.where(strict, mm(k_beta, k, "...id,...jd->...ij") * decay, 0.0)
   solve = _unit_lower_inverse(a)
   writes = mm(solve, v * beta[..., None], "...ij,...jd->...id")
-  # Operands of the scan's products only: kept in `dtype`.
+  # Operands of the walk's products only: kept in `dtype`.
   k_decayed = mm(solve, k_beta * jnp.exp(g)[..., None],
                  "...ij,...jd->...id").astype(dtype)
   within = jnp.where(lower, mm(q, k, "...id,...jd->...ij") * decay, 0.0)
@@ -131,17 +178,13 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
   k_to_end = (k * jnp.exp(g[..., -1:] - g)[..., None]).astype(dtype)
   end_decay = jnp.exp(g[..., -1])  # [N, B, H]
 
-  def step(state, xs):
-    writes_i, k_decayed_i, q_decayed_i, k_to_end_i, end_decay_i = xs
-    new = writes_i - mm(k_decayed_i, state, "bhik,bhkv->bhiv")
-    carried = mm(q_decayed_i, state, "bhik,bhkv->bhiv")
-    state = (state * end_decay_i[..., None, None]
-             + mm(k_to_end_i, new, "bhik,bhiv->bhkv"))
-    return state, (new, carried)
-
-  state0 = jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32)
-  _, (new, carried) = jax.lax.scan(
-      step, state0, (writes, k_decayed, q_decayed, k_to_end, end_decay))
+  kernel = interpret or (_on_tpu() and delta_rule_walk.tiles(
+      chunk, dk, v.shape[-1], dtype))
+  tmetrics.counter("gated_delta.walk.kernel_traces" if kernel
+                   else "gated_delta.walk.scan_traces").inc()
+  walk = (functools.partial(delta_rule_walk.walk, interpret=interpret)
+          if kernel else scan_walk)
+  new, carried = walk(writes, k_decayed, q_decayed, k_to_end, end_decay)
   out = carried + mm(within, new, "...ij,...jd->...id")
   # [N, B, H, C, Dv] -> [B, T, H, Dv]
   out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3)

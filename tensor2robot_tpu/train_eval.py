@@ -452,36 +452,44 @@ def train_eval_model(
       # that has one finishes each dispatch before the next.
       boundary_work=(interleaved_eval if input_generator_eval is not None
                      and eval_every_steps else None))
-  with loop:
-    if (input_generator_train is not None
-        and loop.step < max_train_steps):
-      if loop.feed is None:
-        # Serial path (or resume landed short of max_train_steps with
-        # no overlapped input phase): spin up the pipeline here.
-        loop.attach_feed(_input_phase())
-      step_rng = jax.random.PRNGKey(seed + 1)
-      for features, labels in loop.dispatches():
-        with loop.dispatch():
-          if k == 1:
-            state, metrics = train_callable(
-                state, features, labels,
-                jax.random.fold_in(step_rng, loop.step))
-          else:
-            state, metrics = train_callable(
-                state, features, labels, step_rng,
-                np.int32(loop.step))
-        loop.after_dispatch(metrics)
+  try:
+    with loop:
+      if (input_generator_train is not None
+          and loop.step < max_train_steps):
+        if loop.feed is None:
+          # Serial path (or resume landed short of max_train_steps with
+          # no overlapped input phase): spin up the pipeline here.
+          loop.attach_feed(_input_phase())
+        step_rng = jax.random.PRNGKey(seed + 1)
+        for features, labels in loop.dispatches():
+          with loop.dispatch():
+            if k == 1:
+              state, metrics = train_callable(
+                  state, features, labels,
+                  jax.random.fold_in(step_rng, loop.step))
+            else:
+              state, metrics = train_callable(
+                  state, features, labels, step_rng,
+                  np.int32(loop.step))
+          loop.after_dispatch(metrics)
 
-    # --- final eval ---
-    if input_generator_eval is not None:
-      eval_metrics = run_eval()
-      if eval_metrics:
-        loop.write("eval", loop.step, eval_metrics)
+      # --- final eval ---
+      if input_generator_eval is not None:
+        eval_metrics = run_eval()
+        if eval_metrics:
+          loop.write("eval", loop.step, eval_metrics)
 
-    # --- exporters ---
-    if create_exporters_fn is not None:
-      for exporter in create_exporters_fn(model):
-        exporter.export(model, state, model_dir)
+      # --- exporters ---
+      if create_exporters_fn is not None:
+        for exporter in create_exporters_fn(model):
+          exporter.export(model, state, model_dir)
+  except BaseException:
+    # The exception's traceback holds this frame, and a caller that
+    # handles it can leave both in a reference cycle until a full
+    # collection (a generator-based context manager's `__exit__` does):
+    # the frame must not keep the state on the device until then.
+    state = None
+    raise
   return state
 
 

@@ -234,6 +234,57 @@ def test_a_hook_that_raises_drops_the_pending_save(tmp_path, events):
   assert ckpt_lib.list_steps(str(tmp_path)) == [4, 8, 12]
 
 
+@pytest.mark.parametrize("raise_at", [8, None])
+def test_the_teardown_waits_for_the_dispatch_in_flight(
+    tmp_path, events, monkeypatch, raise_at):
+  """A loop that ends, on a hook's exception with dispatch 9 enqueued or
+  at its last step, hands back a device that is done: it waited for the
+  live state after the hooks' `end` and before it closed its services
+  (ISSUE 35: the benchmark's check lost the device's memory to the
+  program still in flight)."""
+  waited = []
+
+  class Jax:
+    def __getattr__(self, name):
+      return getattr(jax, name)
+
+    def block_until_ready(self, tree):
+      waited.append(float(np.asarray(tree["w"])[0]))
+      events.append(("wait", waited[-1]))
+      return jax.block_until_ready(tree)
+
+  monkeypatch.setattr(train_loop, "jax", Jax())
+  hooks = [Recorder(events, raise_at=raise_at)]
+  if raise_at is None:
+    _stub_trainer(tmp_path, events, hooks=hooks, max_train_steps=12)
+  else:
+    with pytest.raises(RuntimeError, match="hook fails at 8"):
+      _stub_trainer(tmp_path, events, hooks=hooks, max_train_steps=12)
+  last = 12.0 if raise_at is None else 9.0
+  assert events[-2:] == [("end", int(last), last), ("wait", last)]
+
+
+def test_the_teardown_shows_the_calls_error_not_a_deleted_states(
+    tmp_path, events):
+  """A jitted call that raises has taken its donated state with it; the
+  teardown's wait finds it deleted and says nothing over that error."""
+  loop = train_loop.TrainLoop(
+      str(tmp_path), [], dispatch_span="stub.dispatch",
+      steps_per_dispatch=1, max_train_steps=4, log_every_steps=LOG_EVERY,
+      save_checkpoints_steps=SAVE_EVERY, max_checkpoints_to_keep=5)
+  state = {"w": jnp.zeros((4,), jnp.float32),
+           "step": jnp.zeros((), jnp.int32)}
+  loop.begin(None, 0, flops_per_step=None, devices=1,
+             state=lambda: state, save_payload=lambda st: (st,),
+             hook_state=lambda st: st,
+             own_scalars=lambda scalars, steps, dt, stall: None)
+  with pytest.raises(ValueError, match="the call fails"):
+    with loop:
+      for _ in loop.dispatches():
+        state["w"].delete()
+        raise ValueError("the call fails")
+
+
 class _BusyDevice:
   """`jax`, with a device that takes 0.2 s to finish a dispatch."""
 
