@@ -37,18 +37,32 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tensor2robot_tpu.telemetry import metrics as tmetrics
+
+
+def _on_tpu() -> bool:
+  return jax.devices()[0].platform == "tpu"
+
+
+def _resolve_impl(impl: str) -> str:
+  """`auto` is the flash kernel on a TPU and materialised attention
+  elsewhere: read off the platform, nobody sets it."""
+  if impl == "auto":
+    return "flash" if _on_tpu() else "reference"
+  return impl
+
 
 def _attend(q, k, v, *, impl: str, causal: bool, mesh) -> jax.Array:
-  """Dispatches [B, T, H, D] attention to the chosen backend."""
+  """Dispatches [B, T, H, D] attention to the chosen backend. `flash`
+  and `reference` take values of another width than the keys'."""
   from tensor2robot_tpu.ops import flash_attention
   from tensor2robot_tpu.parallel import (
       attention_reference,
       ring_attention,
   )
 
-  on_tpu = jax.devices()[0].platform == "tpu"
-  if impl == "auto":
-    impl = "flash" if on_tpu else "reference"
+  on_tpu = _on_tpu()
+  impl = _resolve_impl(impl)
   if impl == "flash":
     return flash_attention(q, k, v, causal=causal)
   if impl in ("ring", "ring_flash"):
@@ -112,18 +126,29 @@ class RMSNorm(nn.Module):
     return x * (1.0 + weight)
 
 
-def rotary(x: jax.Array, rotary_dim: int, theta: float) -> jax.Array:
-  """Rotary position embedding (rotate-half) on the first `rotary_dim`
-  dims of each head of x [B, T, H, D]; positions are 0..T-1."""
+def rotary(x: jax.Array, rotary_dim: int, theta: float,
+           interleaved: bool = False) -> jax.Array:
+  """Rotary position embedding on the first `rotary_dim` dims of each
+  head of x [B, T, H, D]; positions are 0..T-1. Pair i of a head is
+  turned by position * theta^(-i / half): dims (i, i + half) in the
+  rotate-half layout, dims (2 i, 2 i + 1) where `interleaved`."""
   t = x.shape[1]
   half = rotary_dim // 2
   inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
   angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq
   cos = jnp.cos(angles)[None, :, None, :]
   sin = jnp.sin(angles)[None, :, None, :]
-  x1, x2, rest = jnp.split(x, [half, rotary_dim], axis=-1)
-  return jnp.concatenate(
-      [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+  turned, rest = jnp.split(x, [rotary_dim], axis=-1)
+  if interleaved:
+    pairs = turned.reshape(turned.shape[:-1] + (half, 2))
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                       axis=-1).reshape(turned.shape)
+  else:
+    x1, x2 = jnp.split(turned, 2, axis=-1)
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                             axis=-1)
+  return jnp.concatenate([turned, rest], axis=-1)
 
 
 class GatedAttention(nn.Module):
@@ -172,6 +197,102 @@ class GatedAttention(nn.Module):
                                  ).astype(self.dtype)
       return nn.Dense(width, use_bias=False, dtype=self.dtype,
                       name="o_proj")(out.reshape(b, t, h * d))
+
+
+class LatentAttention(nn.Module):
+  """Multi-head latent attention (docs/SEQUENCE.md): queries and
+  key-values go through low-rank latents with an RMS norm on each, and
+  a head's key is its own `qk_nope_head_dim` dims beside
+  `qk_rope_head_dim` dims that ALL heads share and that alone carry
+  the position (rotary, pairs interleaved as published, or rotate-half).
+
+    c_q = norm(x W_qa);  q = c_q W_qb -> [T, H, nope + rope]
+    x W_kva -> c_kv (kv_lora_rank) | k_r (rope, one head)
+    norm(c_kv) W_kvb -> [T, H, nope + v]:  k_n | v
+    out = softmax_causal([q_n; rot(q_r)] [k_n; rot(k_r)]^T
+                         / sqrt(nope + rope)) v  under `o_proj`
+
+  Keys and queries are `nope + rope` wide and values `v_head_dim`: the
+  backend takes the two widths as they are (`ops/flash_attention.py`
+  on a TPU, materialised attention elsewhere: `_resolve_impl`; the
+  registry's counters `mla.attend.kernel_traces` and
+  `.materialised_traces` count the traced calls that took each). The
+  materialised form is the published training form; the absorbed
+  (latent-space) form that serving wants is not here.
+  """
+
+  num_heads: int
+  q_lora_rank: int
+  kv_lora_rank: int
+  qk_nope_head_dim: int
+  qk_rope_head_dim: int
+  v_head_dim: int
+  rope_theta: float = 1e4
+  rope_interleave: bool = True
+  eps: float = 1e-6
+  attention_impl: str = "auto"
+  mesh: Optional[Any] = None
+  dtype: Any = jnp.bfloat16
+
+  @nn.compact
+  def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+    b, t, width = x.shape
+    h, nope, rope = (self.num_heads, self.qk_nope_head_dim,
+                     self.qk_rope_head_dim)
+    x = x.astype(self.dtype)
+
+    def dense(name, size):
+      return nn.Dense(size, use_bias=False, dtype=self.dtype, name=name)
+
+    def turn(y):
+      return rotary(y, rope, self.rope_theta,
+                    self.rope_interleave).astype(self.dtype)
+
+    with jax.named_scope("mla/q_proj"):
+      c_q = RMSNorm(self.eps, name="q_a_norm")(
+          dense("q_a_proj", self.q_lora_rank)(x))
+      q = dense("q_b_proj", h * (nope + rope))(
+          c_q.astype(self.dtype)).reshape(b, t, h, nope + rope)
+      q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+    with jax.named_scope("mla/kv_proj"):
+      c_kv, k_r = jnp.split(
+          dense("kv_a_proj", self.kv_lora_rank + rope)(x),
+          [self.kv_lora_rank], axis=-1)
+      c_kv = RMSNorm(self.eps, name="kv_a_norm")(c_kv)
+      k_n, v = jnp.split(
+          dense("kv_b_proj", h * (nope + self.v_head_dim))(
+              c_kv.astype(self.dtype)
+          ).reshape(b, t, h, nope + self.v_head_dim), [nope], axis=-1)
+      k_r = jnp.broadcast_to(turn(k_r[:, :, None, :]), (b, t, h, rope))
+      k = jnp.concatenate([k_n, k_r], axis=-1)
+    with jax.named_scope("mla/attend"):
+      impl = _resolve_impl(self.attention_impl)
+      tmetrics.counter("mla.attend.materialised_traces"
+                       if impl == "reference"
+                       else "mla.attend.kernel_traces").inc()
+      out = _attend(q, k, v, impl=impl, causal=True, mesh=self.mesh)
+    with jax.named_scope("mla/o_proj"):
+      return dense("o_proj", width)(
+          out.reshape(b, t, h * self.v_head_dim))
+
+
+class GatedMLP(nn.Module):
+  """The dense gated unit as a block's `ffn`:
+  down(silu(gate x) * up x) at `width`, no biases."""
+
+  width: int
+  dtype: Any = jnp.bfloat16
+
+  @nn.compact
+  def __call__(self, x: jax.Array) -> jax.Array:
+    def dense(name, size):
+      return nn.Dense(size, use_bias=False, dtype=self.dtype, name=name)
+
+    with jax.named_scope("dense_ffn"):
+      x = x.astype(self.dtype)
+      return dense("down_proj", x.shape[-1])(
+          nn.silu(dense("gate_proj", self.width)(x))
+          * dense("up_proj", self.width)(x))
 
 
 class TransformerBlock(nn.Module):
@@ -253,18 +374,24 @@ class SequenceTrunk(nn.Module):
 
   @nn.compact
   def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
-    policies = {"full": None, "dots": "checkpoint_dots",
-                "dots_no_batch": "dots_with_no_batch_dims_saveable"}
     for block in self.blocks:  # flax names them blocks_0, blocks_1, ...
-      if self.remat_policy in (None, "none"):
-        x = block(x, train)
-      else:
-        policy = policies[self.remat_policy]
-        x = nn.remat(
-            lambda module, y: module(y, train),
-            policy=policy and getattr(jax.checkpoint_policies, policy)
-        )(block, x)
+      x = apply_block(block, x, train, self.remat_policy)
     return RMSNorm(name="norm_out")(x)
+
+
+def apply_block(block: nn.Module, x: jax.Array, train: bool,
+                remat_policy: Optional[str]) -> jax.Array:
+  """`block(x, train)`, under `jax.checkpoint` where `remat_policy`
+  names one (`SequenceTrunk`)."""
+  if remat_policy in (None, "none"):
+    return block(x, train)
+  policy = {"full": None, "dots": "checkpoint_dots",
+            "dots_no_batch": "dots_with_no_batch_dims_saveable"
+            }[remat_policy]
+  return nn.remat(
+      lambda module, y: module(y, train),
+      policy=policy and getattr(jax.checkpoint_policies, policy)
+  )(block, x)
 
 
 class CausalTransformer(nn.Module):

@@ -22,5 +22,6 @@ from tensor2robot_tpu.models.optimizers import (
     create_optimizer,
 )
 from tensor2robot_tpu.models.language_model import (
+    LatentAttentionLanguageModel,
     NextTokenLanguageModel,
 )

@@ -3,16 +3,28 @@ cross-entropy of every position's successor out, trained by
 `train_eval_model` like every other family.
 
 The network is an embedding, a `layers/transformer.SequenceTrunk` whose
-blocks are data, a final norm and an untied head. The block pattern is
-the hybrid one of Qwen3-Next (the shipped gin file,
-`models/configs/train_qwen3_next.gin`, binds its published widths):
-layer i mixes with gated grouped-query attention where
-`(i + 1) % full_attention_interval == 0` and with a Gated DeltaNet
-otherwise (`layers/gated_delta.py`), and every layer's feed-forward is
-a dropless mixture of experts beside a gated shared expert
-(`parallel/moe.SparseMoE`). The constructor's arguments carry the
-names of the published configuration's keys, so a configuration file
-and the model read alike.
+blocks are data, a final norm and an untied head. Two families build
+it, each with the names of its published configuration's keys as
+constructor arguments, so a configuration file and the model read
+alike; what they share (specs, the loss in blocks, the reduction of the
+routing counters, the per-layer checkpointing) is `_LanguageModel`'s:
+
+- `NextTokenLanguageModel`, the hybrid pattern of Qwen3-Next (the
+  shipped `models/configs/train_qwen3_next.gin` binds its published
+  widths): layer i mixes with gated grouped-query attention where
+  `(i + 1) % full_attention_interval == 0` and with a Gated DeltaNet
+  otherwise (`layers/gated_delta.py`), and every layer's feed-forward
+  is a dropless mixture of experts beside a gated shared expert
+  (`parallel/moe.SparseMoE`).
+- `LatentAttentionLanguageModel`, the DeepSeek-V3 pattern as
+  JoyAI-LLM-Flash publishes it (`train_joyai_llm_flash.gin`): every
+  layer mixes with multi-head latent attention
+  (`layers/transformer.LatentAttention`); the first
+  `first_k_dense_replace` feed-forwards are dense gated units, the
+  others experts chosen by sigmoid score plus a selection bias beside
+  an ungated shared expert; and a multi-token-prediction module
+  (`MultiTokenPrediction`) adds `mtp_loss_weight` times the loss of
+  predicting each position's successor's successor.
 
 A chip's share of an expert-parallel deployment (docs/SEQUENCE.md):
 `num_experts` is the router's width, `experts_held` how many of them
@@ -38,8 +50,12 @@ from tensor2robot_tpu.data.abstract_input_generator import Mode
 from tensor2robot_tpu.layers.gated_delta import GatedDeltaNet
 from tensor2robot_tpu.layers.transformer import (
     GatedAttention,
+    GatedMLP,
+    LatentAttention,
+    RMSNorm,
     SequenceTrunk,
     TransformerBlock,
+    apply_block,
 )
 from tensor2robot_tpu.models.abstract_model import AbstractT2RModel
 from tensor2robot_tpu.parallel.moe import SparseMoE
@@ -51,38 +67,76 @@ MOE_COUNTERS = "moe_counters"
 
 
 def next_token_loss(hidden: jax.Array, head: jax.Array,
-                    targets: jax.Array, block: int,
-                    dtype: Any) -> jax.Array:
+                    targets: jax.Array, block: int, dtype: Any,
+                    counted: Optional[jax.Array] = None) -> jax.Array:
   """Mean over N positions of logsumexp(h W) - (h W)[target], a block
   of `block` positions at a time (all at once where `block` does not
-  divide N). hidden [N, M], head [M, V], targets [N]."""
+  divide N). hidden [N, M], head [M, V], targets [N]; with `counted`
+  [N] bool, the mean over the positions it marks."""
   n, width = hidden.shape
   if n % block:
     block = n
 
   @jax.checkpoint
-  def block_loss(h, t):
+  def block_loss(h, t, c):
     logits = jnp.dot(h.astype(dtype), head.astype(dtype),
                      preferred_element_type=jnp.float32)
     picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
-    return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - picked)
+    each = jax.nn.logsumexp(logits, axis=-1) - picked
+    return jnp.sum(each if c is None else jnp.where(c, each, 0.0))
 
-  sums = jax.lax.map(
-      lambda ht: block_loss(*ht),
-      (hidden.reshape(n // block, block, width),
-       targets.reshape(n // block, block)))
-  return jnp.sum(sums) / n
+  def blocks(x):
+    return None if x is None else x.reshape((n // block, block)
+                                            + x.shape[1:])
+
+  sums = jax.lax.map(lambda htc: block_loss(*htc),
+                     (blocks(hidden), blocks(targets), blocks(counted)))
+  return jnp.sum(sums) / (n if counted is None else jnp.sum(counted))
+
+
+class MultiTokenPrediction(nn.Module):
+  """Multi-token prediction at depth 1 (DeepSeek-V3, arXiv:2412.19437
+  section 2.2): position i's trunk output and the embedding of its
+  successor, each under its own RMS norm, side by side through
+  `eh_proj` (2 M -> M), then one more `block` and a final norm; what
+  comes out predicts the successor's successor through the model's own
+  head. `block` runs under `remat_policy` as the trunk's blocks do."""
+
+  block: nn.Module
+  remat_policy: Optional[str] = None
+  eps: float = 1e-6
+  dtype: Any = jnp.bfloat16
+
+  @nn.compact
+  def __call__(self, hidden: jax.Array, next_embedded: jax.Array,
+               train: bool = False) -> jax.Array:
+    with jax.named_scope("mtp/combine"):
+      both = jnp.concatenate(
+          [RMSNorm(self.eps, name="hnorm")(hidden),
+           RMSNorm(self.eps, name="enorm")(next_embedded)], axis=-1)
+      x = nn.Dense(hidden.shape[-1], use_bias=False, dtype=self.dtype,
+                   name="eh_proj")(both.astype(self.dtype))
+      x = x.astype(jnp.float32)  # the residual stream's dtype
+    with jax.named_scope("mtp/block"):
+      x = apply_block(self.block, x, train, self.remat_policy)
+    return RMSNorm(self.eps, name="norm_out")(x)
 
 
 class LanguageModelNetwork(nn.Module):
   """token ids [B, T + 1] -> the loss of predicting ids[:, 1:] from
-  ids[:, :-1], and the logits after the last input position."""
+  ids[:, :-1], and the logits after the last input position. With
+  `mtp` (a `MultiTokenPrediction`) the loss is `loss_main +
+  mtp_loss_weight * loss_mtp`, the second the mean over the T - 1
+  positions that have a successor's successor, through the same
+  embedding and head."""
 
   vocab_size: int
   hidden_size: int
   trunk: nn.Module
   loss_block: int = 4096
   dtype: Any = jnp.bfloat16
+  mtp: Optional[nn.Module] = None
+  mtp_loss_weight: float = 0.0
 
   @nn.compact
   def __call__(self, features, train: bool = False):
@@ -100,11 +154,121 @@ class LanguageModelNetwork(nn.Module):
           self.loss_block, self.dtype)
       last = jnp.dot(x[:, -1].astype(self.dtype), head.astype(self.dtype),
                      preferred_element_type=jnp.float32)
-    return {"loss": loss, NEXT_TOKEN_LOGITS: last}
+    outputs = {"loss": loss, NEXT_TOKEN_LOGITS: last}
+    if self.mtp is not None:
+      # All T positions go through the block (a length the attention
+      # kernel tiles); the last has no target and is not counted, and
+      # under a causal mixer it reaches no other.
+      y = self.mtp(x, jnp.take(embed, targets, axis=0), train)
+      with jax.named_scope("mtp_head_loss"):
+        t = targets.shape[1]
+        loss_mtp = next_token_loss(
+            y.reshape(-1, self.hidden_size), head,
+            jnp.pad(ids[:, 2:], ((0, 0), (0, 1))).reshape(-1),
+            self.loss_block, self.dtype,
+            jnp.broadcast_to(jnp.arange(t) < t - 1,
+                             targets.shape).reshape(-1))
+      outputs.update(
+          loss=loss + self.mtp_loss_weight * loss_mtp,
+          loss_main=loss, loss_mtp=loss_mtp)
+    return outputs
+
+
+class _LanguageModel(AbstractT2RModel):
+  """What the language-model families share: ids of `sequence_length +
+  1` positions in, `LanguageModelNetwork` over the family's blocks
+  (`_block(layer)`; `_mtp()` where it has a multi-token-prediction
+  module), each block under `remat_policy`, the routing counters of
+  the expert layers reduced into the step's metrics."""
+
+  _mtp_loss_weight = 0.0  # a family with a module sets its own
+
+  def __init__(self, *, vocab_size: int, sequence_length: int,
+               hidden_size: int, num_hidden_layers: int,
+               rms_norm_eps: float, attention_impl: str,
+               loss_block: int, device_dtype, remat_policy, **kwargs):
+    """`remat_policy` is applied to each layer of the trunk, not to the
+    whole loss: the backward pass holds one layer's activations at a
+    time (`SequenceTrunk`)."""
+    super().__init__(device_dtype=device_dtype,
+                     remat_policy=remat_policy, **kwargs)
+    self._vocab_size = vocab_size
+    self._sequence_length = sequence_length
+    self._hidden_size = hidden_size
+    self._num_hidden_layers = num_hidden_layers
+    self._rms_norm_eps = rms_norm_eps
+    self._attention_impl = attention_impl
+    self._loss_block = loss_block
+
+  def get_feature_specification(self, mode: Mode) -> TensorSpecStruct:
+    st = TensorSpecStruct()
+    st[TOKEN_IDS] = ExtendedTensorSpec(
+        shape=(self._sequence_length + 1,), dtype=np.int32,
+        name=TOKEN_IDS)
+    return st
+
+  def get_label_specification(self, mode: Mode) -> TensorSpecStruct:
+    return TensorSpecStruct()  # a position's label is its successor
+
+  def _block(self, layer: int) -> TransformerBlock:
+    raise NotImplementedError
+
+  def _mtp(self) -> Optional[MultiTokenPrediction]:
+    return None
+
+  def create_network(self) -> nn.Module:
+    return LanguageModelNetwork(
+        vocab_size=self._vocab_size, hidden_size=self._hidden_size,
+        trunk=SequenceTrunk(
+            blocks=tuple(self._block(i)
+                         for i in range(self._num_hidden_layers)),
+            remat_policy=self._remat_policy),
+        loss_block=self._loss_block, dtype=self.device_dtype,
+        mtp=self._mtp(), mtp_loss_weight=self._mtp_loss_weight)
+
+  def _loss_for_grad(self):
+    return self.loss_fn  # the trunk checkpoints layer by layer
+
+  def inference_network_fn(self, variables, features, mode: Mode,
+                           rng: Optional[jax.Array] = None):
+    """The network's outputs with the routing counters of its expert
+    layers beside them (`parallel/moe.held_experts_ffn`, `SparseMoE`),
+    reduced over the layers: the mean share of assignments that fall
+    on held experts (and of those a selection bias moved), the worst
+    load imbalance, the sum of dropped assignments."""
+    del rng  # the network draws nothing
+    outputs, sown = self.network.apply(
+        variables, features, train=mode == Mode.TRAIN,
+        mutable=[MOE_COUNTERS])
+    per_layer: Dict[str, list] = {}
+    for path, value in jax.tree_util.tree_flatten_with_path(
+        sown.get(MOE_COUNTERS, {}))[0]:
+      name = next(p.key for p in reversed(path) if hasattr(p, "key"))
+      per_layer.setdefault(name, []).append(value)
+    reduce = {"assignments_here_share": jnp.mean,
+              "bias_moved_choice_share": jnp.mean,
+              "expert_load_max_over_mean": jnp.max,
+              "dropped_assignments": jnp.sum}
+    outputs[MOE_COUNTERS] = {
+        f"moe.{name}": reduce[name](jnp.stack(values))
+        for name, values in per_layer.items()}
+    return outputs, variables.get("batch_stats", {})
+
+  def model_train_fn(self, features, labels, outputs, mode
+                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    scalars = dict(outputs[MOE_COUNTERS])
+    scalars.update({f"lm.{name}": outputs[name]
+                    for name in ("loss_main", "loss_mtp")
+                    if name in outputs})
+    return outputs["loss"], scalars
+
+  def predict_step(self, state, features):
+    outputs = super().predict_step(state, features)
+    return {NEXT_TOKEN_LOGITS: outputs[NEXT_TOKEN_LOGITS]}
 
 
 @gin.configurable
-class NextTokenLanguageModel(AbstractT2RModel):
+class NextTokenLanguageModel(_LanguageModel):
   """A hybrid linear-attention / attention mixture-of-experts language
   model trained on next-token cross-entropy (the module's docstring)."""
 
@@ -137,16 +301,13 @@ class NextTokenLanguageModel(AbstractT2RModel):
                device_dtype=jnp.bfloat16,
                remat_policy: Optional[str] = "full",
                **kwargs):
-    """`remat_policy` is applied to each layer of the trunk, not to the
-    whole loss: the backward pass holds one layer's activations at a
-    time (`SequenceTrunk`). `experts_held` defaults to all
-    `num_experts`."""
-    super().__init__(device_dtype=device_dtype,
-                     remat_policy=remat_policy, **kwargs)
-    self._vocab_size = vocab_size
-    self._sequence_length = sequence_length
-    self._hidden_size = hidden_size
-    self._num_hidden_layers = num_hidden_layers
+    """`experts_held` defaults to all `num_experts`."""
+    super().__init__(
+        vocab_size=vocab_size, sequence_length=sequence_length,
+        hidden_size=hidden_size, num_hidden_layers=num_hidden_layers,
+        rms_norm_eps=rms_norm_eps, attention_impl=attention_impl,
+        loss_block=loss_block, device_dtype=device_dtype,
+        remat_policy=remat_policy, **kwargs)
     self._full_attention_interval = full_attention_interval
     self._num_attention_heads = num_attention_heads
     self._num_key_value_heads = num_key_value_heads
@@ -167,19 +328,6 @@ class NextTokenLanguageModel(AbstractT2RModel):
     self._moe_intermediate_size = moe_intermediate_size
     self._shared_expert_intermediate_size = (
         shared_expert_intermediate_size)
-    self._rms_norm_eps = rms_norm_eps
-    self._attention_impl = attention_impl
-    self._loss_block = loss_block
-
-  def get_feature_specification(self, mode: Mode) -> TensorSpecStruct:
-    st = TensorSpecStruct()
-    st[TOKEN_IDS] = ExtendedTensorSpec(
-        shape=(self._sequence_length + 1,), dtype=np.int32,
-        name=TOKEN_IDS)
-    return st
-
-  def get_label_specification(self, mode: Mode) -> TensorSpecStruct:
-    return TensorSpecStruct()  # a position's label is its successor
 
   def _block(self, layer: int) -> TransformerBlock:
     dtype, eps = self.device_dtype, self._rms_norm_eps
@@ -211,46 +359,128 @@ class NextTokenLanguageModel(AbstractT2RModel):
     return TransformerBlock(norm="rms", norm_eps=eps, mixer=mixer,
                             ffn=ffn, dtype=dtype)
 
-  def create_network(self) -> nn.Module:
-    return LanguageModelNetwork(
-        vocab_size=self._vocab_size, hidden_size=self._hidden_size,
-        trunk=SequenceTrunk(
-            blocks=tuple(self._block(i)
-                         for i in range(self._num_hidden_layers)),
-            remat_policy=self._remat_policy),
-        loss_block=self._loss_block, dtype=self.device_dtype)
 
-  def _loss_for_grad(self):
-    return self.loss_fn  # the trunk checkpoints layer by layer
+@gin.configurable
+class LatentAttentionLanguageModel(_LanguageModel):
+  """A latent-attention mixture-of-experts language model with a
+  multi-token-prediction loss (the module's docstring); the defaults
+  are JoyAI-LLM-Flash's published configuration."""
 
-  def inference_network_fn(self, variables, features, mode: Mode,
-                           rng: Optional[jax.Array] = None):
-    """The network's outputs with the routing counters of its expert
-    layers beside them (`parallel/moe.held_experts_ffn`), reduced over
-    the layers: the mean share of assignments that fall on held
-    experts, the worst load imbalance, the sum of dropped
-    assignments."""
-    del rng  # the network draws nothing
-    outputs, sown = self.network.apply(
-        variables, features, train=mode == Mode.TRAIN,
-        mutable=[MOE_COUNTERS])
-    per_layer: Dict[str, list] = {}
-    for path, value in jax.tree_util.tree_flatten_with_path(
-        sown.get(MOE_COUNTERS, {}))[0]:
-      name = next(p.key for p in reversed(path) if hasattr(p, "key"))
-      per_layer.setdefault(name, []).append(value)
-    reduce = {"assignments_here_share": jnp.mean,
-              "expert_load_max_over_mean": jnp.max,
-              "dropped_assignments": jnp.sum}
-    outputs[MOE_COUNTERS] = {
-        f"moe.{name}": reduce[name](jnp.stack(values))
-        for name, values in per_layer.items()}
-    return outputs, variables.get("batch_stats", {})
+  def __init__(self,
+               vocab_size: int = 129280,
+               sequence_length: int = 8192,
+               hidden_size: int = 2048,
+               num_hidden_layers: int = 40,
+               num_attention_heads: int = 32,
+               q_lora_rank: int = 1536,
+               kv_lora_rank: int = 512,
+               qk_nope_head_dim: int = 128,
+               qk_rope_head_dim: int = 64,
+               v_head_dim: int = 128,
+               rope_theta: float = 32e6,
+               rope_interleave: bool = True,
+               first_k_dense_replace: int = 1,
+               intermediate_size: int = 7168,
+               n_routed_experts: int = 256,
+               experts_held: Optional[int] = None,
+               first_expert: int = 0,
+               num_experts_per_tok: int = 8,
+               norm_topk_prob: bool = True,
+               scoring_func: str = "sigmoid",
+               topk_method: str = "noaux_tc",
+               n_group: int = 1,
+               topk_group: int = 1,
+               routed_scaling_factor: float = 2.5,
+               n_shared_experts: int = 1,
+               moe_intermediate_size: int = 768,
+               num_nextn_predict_layers: int = 1,
+               mtp_loss_weight: float = 0.3,
+               rms_norm_eps: float = 1e-6,
+               attention_impl: str = "auto",
+               loss_block: int = 4096,
+               device_dtype=jnp.bfloat16,
+               remat_policy: Optional[str] = "full",
+               **kwargs):
+    """`experts_held` defaults to all `n_routed_experts`. `topk_method`
+    `noaux_tc` chooses by score plus the router's bias, `greedy` by
+    score; routing limited to groups of experts (`n_group` > 1) and
+    more than one multi-token-prediction module are not here."""
+    super().__init__(
+        vocab_size=vocab_size, sequence_length=sequence_length,
+        hidden_size=hidden_size, num_hidden_layers=num_hidden_layers,
+        rms_norm_eps=rms_norm_eps, attention_impl=attention_impl,
+        loss_block=loss_block, device_dtype=device_dtype,
+        remat_policy=remat_policy, **kwargs)
+    if topk_method not in ("noaux_tc", "greedy"):
+      raise ValueError(f"Unknown topk_method: {topk_method!r}")
+    if (n_group, topk_group) != (1, 1):
+      raise ValueError("group-limited routing is not implemented: "
+                       f"n_group={n_group}, topk_group={topk_group}")
+    if num_nextn_predict_layers not in (0, 1):
+      raise ValueError("multi-token prediction is implemented at depth "
+                       f"1: num_nextn_predict_layers="
+                       f"{num_nextn_predict_layers}")
+    self._num_attention_heads = num_attention_heads
+    self._q_lora_rank = q_lora_rank
+    self._kv_lora_rank = kv_lora_rank
+    self._qk_nope_head_dim = qk_nope_head_dim
+    self._qk_rope_head_dim = qk_rope_head_dim
+    self._v_head_dim = v_head_dim
+    self._rope_theta = rope_theta
+    self._rope_interleave = rope_interleave
+    self._first_k_dense_replace = first_k_dense_replace
+    self._intermediate_size = intermediate_size
+    self._n_routed_experts = n_routed_experts
+    self._experts_held = (n_routed_experts if experts_held is None
+                          else experts_held)
+    self._first_expert = first_expert
+    self._num_experts_per_tok = num_experts_per_tok
+    self._norm_topk_prob = norm_topk_prob
+    self._scoring_func = scoring_func
+    self._topk_method = topk_method
+    self._n_group = n_group
+    self._topk_group = topk_group
+    self._routed_scaling_factor = routed_scaling_factor
+    self._n_shared_experts = n_shared_experts
+    self._moe_intermediate_size = moe_intermediate_size
+    self._num_nextn_predict_layers = num_nextn_predict_layers
+    self._mtp_loss_weight = mtp_loss_weight
 
-  def model_train_fn(self, features, labels, outputs, mode
-                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    return outputs["loss"], dict(outputs[MOE_COUNTERS])
+  def _block(self, layer: int) -> TransformerBlock:
+    """Layer `layer` of the trunk; any `layer` from
+    `first_k_dense_replace` on is an expert layer."""
+    dtype, eps = self.device_dtype, self._rms_norm_eps
+    mixer = LatentAttention(
+        num_heads=self._num_attention_heads,
+        q_lora_rank=self._q_lora_rank, kv_lora_rank=self._kv_lora_rank,
+        qk_nope_head_dim=self._qk_nope_head_dim,
+        qk_rope_head_dim=self._qk_rope_head_dim,
+        v_head_dim=self._v_head_dim, rope_theta=self._rope_theta,
+        rope_interleave=self._rope_interleave, eps=eps,
+        attention_impl=self._attention_impl, dtype=dtype)
+    if layer < self._first_k_dense_replace:
+      ffn = GatedMLP(width=self._intermediate_size, dtype=dtype)
+    else:
+      ffn = SparseMoE(
+          num_experts=self._n_routed_experts,
+          experts_held=self._experts_held,
+          first_expert=self._first_expert,
+          k=self._num_experts_per_tok,
+          normalise_top_k=self._norm_topk_prob,
+          scoring=self._scoring_func,
+          selection_bias=self._topk_method == "noaux_tc",
+          routed_scaling_factor=self._routed_scaling_factor,
+          expert_width=self._moe_intermediate_size,
+          shared_width=(self._n_shared_experts
+                        * self._moe_intermediate_size),
+          shared_gated=False, dtype=dtype)
+    return TransformerBlock(norm="rms", norm_eps=eps, mixer=mixer,
+                            ffn=ffn, dtype=dtype)
 
-  def predict_step(self, state, features):
-    outputs = super().predict_step(state, features)
-    return {NEXT_TOKEN_LOGITS: outputs[NEXT_TOKEN_LOGITS]}
+  def _mtp(self) -> Optional[MultiTokenPrediction]:
+    if not self._num_nextn_predict_layers:
+      return None
+    return MultiTokenPrediction(
+        block=self._block(self._num_hidden_layers),
+        remat_policy=self._remat_policy, eps=self._rms_norm_eps,
+        dtype=self.device_dtype)

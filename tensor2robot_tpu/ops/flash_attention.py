@@ -26,7 +26,17 @@ gated attention: 16 heads, T=8192, 4 rows, bf16; a v5e, PR 34): the
 forward kernel took 18.9-21.1 ms a call and the backward pair 58.7
 ms, 53-59 % and 67 % of the bf16 peak counting the matrix products of
 the causal half (PERF.md section 5); the f32 score tile, not the head
-size, sets the VMEM budget.
+size, sets the VMEM budget. Keys and values need not be of one width:
+q and k are Dk wide, v, the output and their cotangents Dv, read off
+the arguments, and every tile, scratch and result has its own width.
+At keys of 192 over values of 128 (latent attention: 32 heads, T=8192,
+2 rows, bf16; one call outside the model on a v5e, PR 36) the forward
+kernel took 18.9 ms at the default blocks (17.3 at 1024 x 1024) and
+the backward pair 48.7 ms: 72.5 and 101.6 TFLOP/s over the products of
+the causal half, 37 and 52 % of the bf16 peak. 256 over 128 took the
+same 19.1 and 48.9 ms (a contraction of 192 lanes occupies two passes
+of the 128-wide MXU), 128 over 128 14.2 and 33.1, 256 over 256 24.6 and
+61.8; blocks of (2048, 2048) double the backward pair (91 ms).
 
 Training works end to end, and the backward is Pallas too: two kernels
 in the standard flash-backward formulation, each recomputing score
@@ -191,15 +201,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 def _flash_forward_impl(q, k, v, causal: bool, block_q: int,
                         block_k: int, interpret: bool
                         ) -> Tuple[jax.Array, jax.Array]:
-  """Runs the kernel; returns (out [B,T,H,D], lse [B*H, T])."""
+  """Runs the kernel; returns (out [B,T,H,Dv], lse [B*H, T]). q and
+  k are `d` wide, v and the output `dv`: the two need not be equal."""
   b, t, h, d = q.shape
+  dv = v.shape[-1]
   num_q_blocks = t // block_q
   num_k_blocks = t // block_k
   scale = 1.0 / np.sqrt(d)
 
   # [B, T, H, D] -> [B*H, T, D]: one grid row per (batch, head).
   def fold(x):
-    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
 
   kernel = functools.partial(
       _flash_kernel, scale=scale, causal=causal, block_q=block_q,
@@ -210,10 +222,10 @@ def _flash_forward_impl(q, k, v, causal: bool, block_q: int,
       in_specs=[
           pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0)),
           pl.BlockSpec((1, block_k, d), lambda g, i, j: (g, j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda g, i, j: (g, j, 0)),
+          pl.BlockSpec((1, block_k, dv), lambda g, i, j: (g, j, 0)),
       ],
       out_specs=[
-          pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0)),
+          pl.BlockSpec((1, block_q, dv), lambda g, i, j: (g, i, 0)),
           # lse packed [BH, num_q_blocks, block_q, 1]: sublane-major
           # per-row values, the same (block_q, 1) class as the m/l
           # scratch — T×4 bytes per head, no lane broadcast, no MXU
@@ -221,19 +233,19 @@ def _flash_forward_impl(q, k, v, causal: bool, block_q: int,
           pl.BlockSpec((1, 1, block_q, 1), lambda g, i, j: (g, i, 0, 0)),
       ],
       out_shape=[
-          jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+          jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
           jax.ShapeDtypeStruct((b * h, num_q_blocks, block_q, 1),
                                jnp.float32),
       ],
       scratch_shapes=[
           pltpu.VMEM((block_q, 1), jnp.float32),   # running max
           pltpu.VMEM((block_q, 1), jnp.float32),   # running normalizer
-          pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
+          pltpu.VMEM((block_q, dv), jnp.float32),  # output accumulator
       ],
       compiler_params=_COMPILER_PARAMS,
       interpret=interpret,
   )(fold(q), fold(k), fold(v))
-  return (out.reshape(b, h, t, d).transpose(0, 2, 1, 3),
+  return (out.reshape(b, h, t, dv).transpose(0, 2, 1, 3),
           lse.reshape(b * h, t))
 
 
@@ -373,11 +385,12 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
   the lse-composed ring attention trainable through this kernel.
   """
   b, t, h, d = q.shape
+  dv = v.shape[-1]  # v, out, do and dv are this wide; q, k, dq, dk `d`
   scale = 1.0 / np.sqrt(d)
   nq, nk = t // block_q, t // block_k
 
   def fold(x):  # [B, T, H, D] -> [B*H, T, D]
-    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
 
   q_f, k_f, v_f, do_f, o_f = map(fold, (q, k, v, do, out))
   # δ_i = rowsum(dO·O) − dlse_i: the softmax-jacobian row term, a
@@ -406,8 +419,8 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
       in_specs=[
           pl.BlockSpec((1, block_q, d), lambda g, j, i: (g, i, 0)),
           pl.BlockSpec((1, block_k, d), lambda g, j, i: (g, j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda g, j, i: (g, j, 0)),
-          pl.BlockSpec((1, block_q, d), lambda g, j, i: (g, i, 0)),
+          pl.BlockSpec((1, block_k, dv), lambda g, j, i: (g, j, 0)),
+          pl.BlockSpec((1, block_q, dv), lambda g, j, i: (g, i, 0)),
           pl.BlockSpec((1, 1, block_q, 1),
                        lambda g, j, i: (g, i, 0, 0)),
           pl.BlockSpec((1, 1, block_q, 1),
@@ -415,15 +428,15 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
       ],
       out_specs=[
           pl.BlockSpec((1, block_k, d), lambda g, j, i: (g, j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda g, j, i: (g, j, 0)),
+          pl.BlockSpec((1, block_k, dv), lambda g, j, i: (g, j, 0)),
       ],
       out_shape=[
           jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
-          jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
+          jax.ShapeDtypeStruct((b * h, t, dv), v.dtype),
       ],
       scratch_shapes=[
           pltpu.VMEM((block_k, d), jnp.float32),   # dk accumulator
-          pltpu.VMEM((block_k, d), jnp.float32),   # dv accumulator
+          pltpu.VMEM((block_k, dv), jnp.float32),  # dv accumulator
       ],
       compiler_params=_COMPILER_PARAMS,
       interpret=interpret,
@@ -437,8 +450,8 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
       in_specs=[
           pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0)),
           pl.BlockSpec((1, block_k, d), lambda g, i, j: (g, j, 0)),
-          pl.BlockSpec((1, block_k, d), lambda g, i, j: (g, j, 0)),
-          pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0)),
+          pl.BlockSpec((1, block_k, dv), lambda g, i, j: (g, j, 0)),
+          pl.BlockSpec((1, block_q, dv), lambda g, i, j: (g, i, 0)),
           pl.BlockSpec((1, 1, block_q, 1),
                        lambda g, i, j: (g, i, 0, 0)),
           pl.BlockSpec((1, 1, block_q, 1),
@@ -454,7 +467,7 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
   )(q_f, k_f, v_f, do_f, lse, delta)[0]
 
   def unfold(x):  # [BH, T, D] -> [B, T, H, D]
-    return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return x.reshape(b, h, t, x.shape[-1]).transpose(0, 2, 1, 3)
 
   return unfold(dq_f), unfold(dk_f), unfold(dv_f)
 
@@ -496,7 +509,7 @@ def flash_attention_with_lse(
 ) -> Tuple[jax.Array, jax.Array]:
   """Like `flash_attention` but also returns the logsumexp.
 
-  Returns (out [B, T, H, D], lse [B, H, T]). The lse makes attention
+  Returns (out [B, T, H, Dv], lse [B, H, T]). The lse makes attention
   COMPOSABLE: partial attentions over disjoint key sets combine
   exactly as out = Σ_s softmax_s(lse_s) · out_s — which is how ring
   attention runs this kernel per device and merges blocks arriving
@@ -524,7 +537,12 @@ def flash_attention(
     block_k: int = 2048,
     interpret: bool = False,
 ) -> jax.Array:
-  """Exact attention, O(T) memory both ways. [B, T, H, D] → same.
+  """Exact attention, O(T) memory both ways. q, k [B, T, H, Dk],
+  v [B, T, H, Dv] → [B, T, H, Dv]; the scale is Dk^-1/2. The two
+  widths are read off the arguments and need not be equal (latent
+  attention: keys of 192 over values of 128): every tile, scratch and
+  result has its own, so P·V, dO·Vᵀ and dV are Dv wide and no value is
+  padded to the keys' width.
 
   Block sizes auto-shrink to divide T (`_auto_block`), so any static
   T works; power-of-two T keeps the large overhead-amortizing blocks.

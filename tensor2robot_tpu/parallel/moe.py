@@ -282,17 +282,44 @@ def collect_aux_losses(variables: Any) -> jax.Array:
   return total
 
 
-def route_top_k(x: jax.Array, router: jax.Array, k: int,
-                normalise: bool = True):
-  """Softmax over ALL of the router's experts in float32, the k
-  largest, their weights divided by their sum where `normalise`.
-  x [N, M], router [M, E] -> (experts [N, k] int32, weights [N, k])."""
+def router_scores(x: jax.Array, router: jax.Array,
+                  scoring: str = "softmax") -> jax.Array:
+  """x [N, M], router [M, E] -> scores [N, E] over ALL of the router's
+  experts in float32: their `softmax`, or each one's own `sigmoid`."""
   logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                    precision=jax.lax.Precision.HIGH)
-  weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+  if scoring == "softmax":
+    return jax.nn.softmax(logits, axis=-1)
+  if scoring == "sigmoid":
+    return jax.nn.sigmoid(logits)
+  raise ValueError(f"Unknown scoring: {scoring!r}")
+
+
+def choose_top_k(scores: jax.Array, k: int, normalise: bool = True,
+                 bias: Optional[jax.Array] = None, scale: float = 1.0):
+  """The k largest of scores [N, E], their weights divided by their
+  sum where `normalise`, times `scale`. With `bias` [E] the k are
+  chosen by score + bias and weighted by the score alone (the
+  selection bias of auxiliary-loss-free balancing: it steers load and
+  no gradient reaches it). -> (experts [N, k] int32, weights [N, k])."""
+  if bias is None:
+    weights, experts = jax.lax.top_k(scores, k)
+  else:
+    _, experts = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
   if normalise:
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-  return experts, weights
+  return experts, (weights * scale if scale != 1.0 else weights)
+
+
+def route_top_k(x: jax.Array, router: jax.Array, k: int,
+                normalise: bool = True, *, scoring: str = "softmax",
+                bias: Optional[jax.Array] = None, scale: float = 1.0):
+  """`choose_top_k` of `router_scores`.
+  x [N, M], router [M, E] -> (experts [N, k] int32, weights [N, k])."""
+  return choose_top_k(router_scores(x, router, scoring), k, normalise,
+                      bias, scale)
 
 
 def round_rows(assignments: int, held: int, num_experts: int) -> int:
@@ -439,8 +466,12 @@ def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, *,
 
 class SparseMoE(nn.Module):
   """A dropless top-k expert layer over the experts held here, beside
-  one sigmoid-gated shared expert; all experts are gated units
-  down(silu(gate x) * up x) without biases.
+  one shared expert (sigmoid-gated where `shared_gated`); all experts
+  are gated units down(silu(gate x) * up x) without biases. The router
+  is `router_scores` under `choose_top_k`: `scoring`,
+  `routed_scaling_factor` and, with `selection_bias`, a per-expert bias
+  `router_bias` on the choice alone (a parameter that no gradient
+  reaches; nothing updates it here).
 
   `num_experts` is the router's width (it routes over all of them),
   `experts_held` how many of them this module holds, from
@@ -458,6 +489,10 @@ class SparseMoE(nn.Module):
   shared_width: int = 0
   first_expert: int = 0
   normalise_top_k: bool = True
+  scoring: str = "softmax"
+  selection_bias: bool = False
+  routed_scaling_factor: float = 1.0
+  shared_gated: bool = True
   dtype: Any = jnp.bfloat16
 
   @nn.compact
@@ -473,10 +508,22 @@ class SparseMoE(nn.Module):
     w_up = self.param("experts_up", init, (held, width, f), jnp.float32)
     w_down = self.param("experts_down", init, (held, f, width),
                         jnp.float32)
+    bias = self.param("router_bias", nn.initializers.zeros,
+                      (self.num_experts,), jnp.float32
+                      ) if self.selection_bias else None
     tokens = x.reshape(b * t, width)
     with jax.named_scope("moe/route"):
-      experts, weights = route_top_k(tokens, router, self.k,
-                                     self.normalise_top_k)
+      scores = router_scores(tokens, router, self.scoring)
+      experts, weights = choose_top_k(
+          scores, self.k, self.normalise_top_k, bias,
+          self.routed_scaling_factor)
+      if bias is not None:
+        # Of the assignments, those the unbiased scores would not have
+        # chosen.
+        unbiased, _ = choose_top_k(scores, self.k, False)
+        kept = jnp.any(experts[:, :, None] == unbiased[:, None, :], -1)
+        self.sow("moe_counters", "bias_moved_choice_share",
+                 1.0 - jnp.mean(kept.astype(jnp.float32)))
     with jax.named_scope("moe/experts"):
       out, counters = held_experts_ffn(
           tokens, experts, weights, w_gate, w_up, w_down,
@@ -495,7 +542,9 @@ class SparseMoE(nn.Module):
         shared = dense("shared_down", width)(
             nn.silu(dense("shared_gate", self.shared_width)(y))
             * dense("shared_up", self.shared_width)(y))
-        gate = jax.nn.sigmoid(
-            dense("shared_expert_gate", 1)(y).astype(jnp.float32))
-        out = out + gate * shared
+        if self.shared_gated:
+          gate = jax.nn.sigmoid(
+              dense("shared_expert_gate", 1)(y).astype(jnp.float32))
+          shared = gate * shared
+        out = out + shared
     return out.reshape(b, t, width)

@@ -17,11 +17,16 @@ from tensor2robot_tpu.parallel import attention_reference
 B, T, H, D = 2, 256, 2, 64
 
 
-def _qkv(seed=0, dtype=jnp.float32):
+def _qkv(seed=0, dtype=jnp.float32, dk=D, dv=D):
   rng = np.random.default_rng(seed)
   return tuple(
-      jnp.asarray(rng.standard_normal((B, T, H, D)), dtype)
-      for _ in range(3))
+      jnp.asarray(rng.standard_normal((B, T, H, d)), dtype)
+      for d in (dk, dk, dv))
+
+
+# Keys and queries of one width over values of another (latent
+# attention: 192 over 128), beside the equal widths.
+WIDTHS = [(64, 64), (48, 32), (16, 40)]
 
 
 class TestFlashAttention:
@@ -34,6 +39,66 @@ class TestFlashAttention:
     ref = attention_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-6, rtol=2e-6)
+
+  @pytest.mark.parametrize("dk,dv", WIDTHS)
+  def test_key_width_other_than_value_width(self, dk, dv):
+    """Forward and all three gradients against materialised attention;
+    the scale is the keys' width's."""
+    q, k, v = _qkv(9, dk=dk, dv=dv)
+    probe = jnp.asarray(np.random.default_rng(10).standard_normal(
+        (B, T, H, dv)), jnp.float32)
+
+    def flash_loss(q, k, v):
+      out = flash_attention(q, k, v, causal=True, block_q=64,
+                            block_k=128, interpret=True)
+      assert out.shape == (B, T, H, dv)
+      return jnp.sum(out * probe), out
+
+    def ref_loss(q, k, v):
+      out = attention_reference(q, k, v, causal=True)
+      return jnp.sum(out * probe), out
+
+    (_, out), got = jax.value_and_grad(
+        flash_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, ref), want = jax.value_and_grad(
+        ref_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-6, rtol=2e-6)
+    for g, w in zip(got, want):
+      assert g.shape == w.shape
+      np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                 atol=5e-5, rtol=5e-5)
+
+  def test_no_value_is_padded_to_the_keys_width(self):
+    """Every operand and result of the three Pallas programs has its
+    own width: v, o, dO and dv the values', q, k, dq and dk the keys'
+    (P V and dV are then `dv` wide, in VMEM and in HBM)."""
+    dk, dv = 48, 32
+    q, k, v = _qkv(11, dk=dk, dv=dv)
+
+    def loss(q, k, v):
+      return jnp.sum(flash_attention(q, k, v, causal=True, block_q=64,
+                                     block_k=64, interpret=True))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    calls = []
+
+    def walk(jaxpr):
+      for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+          calls.append((
+              [a.aval.shape[-1] for a in eqn.invars
+               if a.aval.shape[-1] != 1],
+              [a.aval.shape[-1] for a in eqn.outvars
+               if a.aval.shape[-1] != 1]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+          walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert calls == [
+        ([dk, dk, dv], [dv]),              # forward: q k v -> o
+        ([dk, dk, dv, dv], [dk, dv]),      # q k v dO -> dk dv
+        ([dk, dk, dv, dv], [dk])]          # q k v dO -> dq
 
   def test_block_size_independence(self):
     """The online softmax must not depend on the tiling."""

@@ -254,3 +254,35 @@ def test_kernels_compile_for_a_v5e_at_the_cells_widths(one_chip, dtype,
   states = n * h * d * d * 4
   assert states <= compiled.memory_analysis().temp_size_in_bytes < (
       3 * states)
+
+
+def test_flash_kernels_compile_for_a_v5e_at_the_latent_widths(one_chip):
+  """The flash kernel's three programs at keys of 192 over values of
+  128 (latent attention; ISSUE 36): 2 rows of 8,192 positions and 32
+  heads in bfloat16, the default blocks. In this file because one
+  process describes the chip (its fixture)."""
+  from jax.experimental.compilation_cache import compilation_cache
+
+  from tensor2robot_tpu.ops import flash_attention
+
+  def aval(width):
+    return jax.ShapeDtypeStruct((2, 8192, 32, width), jnp.bfloat16,
+                                sharding=one_chip)
+
+  def loss(q, k, v):
+    return jnp.sum(flash_attention(q, k, v, causal=True)
+                   .astype(jnp.float32))
+
+  enabled = jax.config.jax_enable_compilation_cache
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  try:
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        aval(192), aval(192), aval(128)).compile()
+  finally:
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+  assert compiled.as_text().count("tpu_custom_call") >= 3
+  dq, dk, dv = jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)),
+                              aval(192), aval(192), aval(128))
+  assert (dq.shape[-1], dk.shape[-1], dv.shape[-1]) == (192, 192, 128)
