@@ -364,10 +364,18 @@ class SequenceTrunk(nn.Module):
   """`blocks` in order over x [B, T, M], then an `RMSNorm`; no
   embedding and no learnt positions: its caller embeds, its mixers
   place (rotary, a convolution, a recurrence). With `remat_policy`
-  every block runs under `jax.checkpoint`: `full` saves nothing of a
-  block, `dots` and `dots_no_batch` as `AbstractT2RModel.remat_policy`
-  names them; the backward pass then holds one block's activations at
-  a time."""
+  every block runs under `jax.checkpoint`, so the backward pass holds
+  one block's activations at a time. Four policies: `full` saves
+  nothing of a block (the choice at the memory limit); `dots` and
+  `dots_no_batch` as `AbstractT2RModel.remat_policy` names them;
+  `save_attention` saves what the flash kernel returned and nothing
+  else (`ops/flash_attention.SAVED_RESIDUAL_NAMES`: the output,
+  2 B x tokens x heads x value width in bfloat16, and the float32
+  logsumexp, 4 B x tokens x heads, a block), so the backward pass
+  runs every line of the block again but the forward kernel, whose
+  two results are its backward's residuals. A block whose mixer took
+  materialised attention, or is no attention, carries no such name:
+  under `save_attention` it is the program of `full`."""
 
   blocks: Tuple[nn.Module, ...]
   remat_policy: Optional[str] = None
@@ -382,16 +390,26 @@ class SequenceTrunk(nn.Module):
 def apply_block(block: nn.Module, x: jax.Array, train: bool,
                 remat_policy: Optional[str]) -> jax.Array:
   """`block(x, train)`, under `jax.checkpoint` where `remat_policy`
-  names one (`SequenceTrunk`)."""
+  names one (`SequenceTrunk`, which says what each saves). The
+  registry's counters `trunk.checkpoint.attention_saved_blocks` and
+  `.recomputed_blocks` count the traced blocks whose policy keeps the
+  flash kernel's residuals, and those whose policy does not."""
   if remat_policy in (None, "none"):
     return block(x, train)
-  policy = {"full": None, "dots": "checkpoint_dots",
-            "dots_no_batch": "dots_with_no_batch_dims_saveable"
-            }[remat_policy]
-  return nn.remat(
-      lambda module, y: module(y, train),
-      policy=policy and getattr(jax.checkpoint_policies, policy)
-  )(block, x)
+  from tensor2robot_tpu.ops.flash_attention import SAVED_RESIDUAL_NAMES
+  policies = jax.checkpoint_policies
+  policy = {
+      "full": None,
+      "dots": policies.checkpoint_dots,
+      "dots_no_batch": policies.dots_with_no_batch_dims_saveable,
+      "save_attention": policies.save_only_these_names(
+          *SAVED_RESIDUAL_NAMES),
+  }[remat_policy]
+  tmetrics.counter("trunk.checkpoint.attention_saved_blocks"
+                   if remat_policy == "save_attention"
+                   else "trunk.checkpoint.recomputed_blocks").inc()
+  return nn.remat(lambda module, y: module(y, train),
+                  policy=policy)(block, x)
 
 
 class CausalTransformer(nn.Module):

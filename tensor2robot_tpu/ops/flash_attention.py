@@ -67,10 +67,16 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+# The names under which the forward's output and logsumexp become the
+# backward's residuals (`_flash_lse_fwd`): what a checkpoint around a
+# caller of the kernel saves to keep the backward pass from running
+# the forward kernel a second time (`layers/transformer.apply_block`).
+SAVED_RESIDUAL_NAMES = ("flash_attention_out", "flash_attention_lse")
 # Mosaic's default scoped-VMEM budget is 16 MiB; at the default blocks
 # the f32 score tile alone is 8 MiB and the dk/dv kernel needs 18.5 MiB
 # (T=32k, D=64, bf16). Half of a v5e core's 128 MiB leaves room for
@@ -481,6 +487,14 @@ def _flash_lse(q, k, v, causal, block_q, block_k, interpret):
 def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
   out, lse = _flash_forward_impl(q, k, v, causal, block_q, block_k,
                                  interpret)
+  # Named HERE, where they become residuals, and both: a
+  # `jax.checkpoint` whose policy saves `SAVED_RESIDUAL_NAMES` then
+  # hands the backward these two arrays and does not run the forward
+  # kernel again; one left unnamed would force the re-run. The lse in
+  # its [B*H, T] form (the kernel's [..., block_q, 1] pads 128-fold
+  # in HBM). Identities under any other policy and outside a checkpoint.
+  out = checkpoint_name(out, SAVED_RESIDUAL_NAMES[0])
+  lse = checkpoint_name(lse, SAVED_RESIDUAL_NAMES[1])
   return (out, lse), (q, k, v, out, lse)
 
 

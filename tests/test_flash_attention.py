@@ -5,6 +5,9 @@ driver); here the pallas interpreter verifies the math — exactness
 against the reference oracle, causal masking, block-size independence.
 """
 
+import importlib
+import re
+
 import numpy as np
 import pytest
 
@@ -198,3 +201,47 @@ class TestFlashAttention:
                             block_k=64, interpret=True)
     np.testing.assert_allclose(np.asarray(ring), np.asarray(flash),
                                atol=2e-5, rtol=2e-5)
+
+
+def _with_out(q, k, v):
+  return flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                         interpret=True)
+
+
+def _with_lse(q, k, v):
+  from tensor2robot_tpu.ops.flash_attention import (
+      flash_attention_with_lse)
+  return flash_attention_with_lse(q, k, v, causal=True, block_q=64,
+                                  block_k=64, interpret=True)
+
+
+@pytest.mark.parametrize("attend", [_with_out, _with_lse])
+@pytest.mark.parametrize("dk,dv", [(64, 64), (48, 32)])
+def test_residual_names_are_identities_outside_a_checkpoint(
+    monkeypatch, attend, dk, dv):
+  """The forward rule names its two results for a checkpoint's policy
+  (`SAVED_RESIDUAL_NAMES`); with no such checkpoint around it the
+  gradient, which is what traces the rule, lowers to the HLO text it
+  lowers to without the names."""
+  module = importlib.import_module(
+      "tensor2robot_tpu.ops.flash_attention")
+  qkv = _qkv(dk=dk, dv=dv)
+  seen = []
+
+  def lowered():
+    grad = jax.grad(lambda *args: sum(
+        jnp.sum(jnp.sin(out)) for out in jax.tree_util.tree_leaves(
+            attend(*args))), argnums=(0, 1, 2))
+    # MLIR numbers its private functions as they come: not compared.
+    return re.sub(r"(@\w+?)_\d+\b", r"\1",
+                  jax.jit(grad).lower(*qkv).as_text())
+
+  named = lowered()
+  with monkeypatch.context() as patch:
+    patch.setattr(module, "checkpoint_name",
+                  lambda x, name: seen.append(name) or x)
+    jax.clear_caches()  # or the rule's trace with the names is reused
+    unnamed = lowered()
+  jax.clear_caches()  # no trace made without the names outlives this
+  assert unnamed == named
+  assert tuple(seen) == module.SAVED_RESIDUAL_NAMES  # the rule ran

@@ -220,11 +220,25 @@ def one_chip():
   return SingleDeviceSharding(topo.devices[0])
 
 
+def _compile_for_the_chip(fn, *avals):
+  """`fn` compiled for the described chip of its arguments' shardings.
+  Such a compile cannot be read back from the persistent cache: keep
+  it out."""
+  from jax.experimental.compilation_cache import compilation_cache
+  enabled = jax.config.jax_enable_compilation_cache
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  try:
+    return jax.jit(fn).lower(*avals).compile()
+  finally:
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("dtype,block", [
     (jnp.bfloat16, None), (jnp.float32, None), (jnp.bfloat16, 5)])
 def test_kernels_compile_for_a_v5e_at_the_cells_widths(one_chip, dtype,
                                                        block):
-  from jax.experimental.compilation_cache import compilation_cache
   n, b, h, c, d = 128, 1, 32, 64, 128
 
   def aval(shape, dtype):
@@ -237,17 +251,8 @@ def test_kernels_compile_for_a_v5e_at_the_cells_widths(one_chip, dtype,
     new, carried = delta_rule_walk.walk(*operands, block=block)
     return jnp.sum(new * new) + jnp.sum(carried)
 
-  # A compile for a described chip cannot be read back from the
-  # persistent cache: keep it out.
-  enabled = jax.config.jax_enable_compilation_cache
-  jax.config.update("jax_enable_compilation_cache", False)
-  compilation_cache.reset_cache()
-  try:
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        *avals).compile()
-  finally:
-    jax.config.update("jax_enable_compilation_cache", enabled)
-    compilation_cache.reset_cache()
+  compiled = _compile_for_the_chip(
+      jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *avals)
   assert compiled.as_text().count("tpu_custom_call") >= 2
   # The states for the backward pass, N x H x Dk x Dv float32, and no
   # second copy of them.
@@ -261,8 +266,6 @@ def test_flash_kernels_compile_for_a_v5e_at_the_latent_widths(one_chip):
   128 (latent attention; ISSUE 36): 2 rows of 8,192 positions and 32
   heads in bfloat16, the default blocks. In this file because one
   process describes the chip (its fixture)."""
-  from jax.experimental.compilation_cache import compilation_cache
-
   from tensor2robot_tpu.ops import flash_attention
 
   def aval(width):
@@ -273,16 +276,40 @@ def test_flash_kernels_compile_for_a_v5e_at_the_latent_widths(one_chip):
     return jnp.sum(flash_attention(q, k, v, causal=True)
                    .astype(jnp.float32))
 
-  enabled = jax.config.jax_enable_compilation_cache
-  jax.config.update("jax_enable_compilation_cache", False)
-  compilation_cache.reset_cache()
-  try:
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        aval(192), aval(192), aval(128)).compile()
-  finally:
-    jax.config.update("jax_enable_compilation_cache", enabled)
-    compilation_cache.reset_cache()
+  compiled = _compile_for_the_chip(
+      jax.grad(loss, argnums=(0, 1, 2)), aval(192), aval(192), aval(128))
   assert compiled.as_text().count("tpu_custom_call") >= 3
   dq, dk, dv = jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)),
                               aval(192), aval(192), aval(128))
   assert (dq.shape[-1], dk.shape[-1], dv.shape[-1]) == (192, 192, 128)
+
+
+@pytest.mark.parametrize("policy,forward_calls", [
+    ("full", 2), ("save_attention", 1)])
+def test_a_checkpointed_latent_block_compiles_for_a_v5e(
+    one_chip, policy, forward_calls):
+  """One block of the JoyAI cell (latent attention at 192 over 128, the
+  dense FFN; 2 rows of 8,192 positions) under a checkpoint, its
+  gradient compiled for the chip: the forward kernel once under
+  `save_attention`, twice under `full`, beside the backward pair
+  (ISSUE 37; the jaxpr's side of it: tests/test_sequence_layers.py)."""
+  from tensor2robot_tpu.layers import transformer
+
+  trunk = transformer.SequenceTrunk(blocks=(transformer.TransformerBlock(
+      norm="rms", mixer=transformer.LatentAttention(
+          num_heads=32, q_lora_rank=1536, kv_lora_rank=512,
+          qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+          attention_impl="flash"),
+      ffn=transformer.GatedMLP(width=7168)),), remat_policy=policy)
+  x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.float32,
+                           sharding=one_chip)
+  params = jax.tree_util.tree_map(
+      lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                        sharding=one_chip),
+      jax.eval_shape(trunk.init, jax.random.PRNGKey(0), x))
+
+  def loss(params, x):
+    return jnp.sum(jnp.square(trunk.apply(params, x)))
+
+  compiled = _compile_for_the_chip(jax.grad(loss), params, x)
+  assert compiled.as_text().count("tpu_custom_call") == forward_calls + 2

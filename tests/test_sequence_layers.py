@@ -4,7 +4,10 @@ sizes in float32 on seeded random weights: the chunked Gated DeltaNet
 against the recurrence over positions, gated grouped-query attention
 against attention materialised by blocks, the block as data."""
 
+import collections
+import functools
 import os
+import re
 import sys
 
 import jax
@@ -15,16 +18,21 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+from benchmark.layer_metrics import (  # noqa: E402
+    lm_attention_saved_share)
 from benchmark.reference import qwen3_next as ref  # noqa: E402
+from tensor2robot_tpu import ops  # noqa: E402
 from tensor2robot_tpu.layers import gated_delta  # noqa: E402
 from tensor2robot_tpu.layers.transformer import (  # noqa: E402
     CausalTransformer,
     GatedAttention,
+    LatentAttention,
     RMSNorm,
     SequenceTrunk,
     TransformerBlock,
     rotary,
 )
+from tensor2robot_tpu.telemetry import metrics as tmetrics  # noqa: E402
 
 MODEL = {
     "hidden_size": 32, "rms_norm_eps": 1e-6,
@@ -207,7 +215,8 @@ def _hybrid_blocks():
                                 dtype=jnp.float32) for i in range(2))
 
 
-@pytest.mark.parametrize("policy", ["full", "dots", "dots_no_batch"])
+@pytest.mark.parametrize("policy", ["full", "dots", "dots_no_batch",
+                                    "save_attention"])
 def test_a_trunk_of_blocks_as_data_remats_to_the_same_numbers(policy):
   x = jax.random.normal(jax.random.PRNGKey(0), (2, 20, 16))
   plain = SequenceTrunk(blocks=_hybrid_blocks())
@@ -241,3 +250,128 @@ def test_a_block_without_mixer_or_ffn_is_the_block_it_always_was():
   with pytest.raises(ValueError, match="Unknown norm"):
     TransformerBlock(num_heads=2, head_dim=8, norm="batch").init(
         jax.random.PRNGKey(0), jnp.zeros((1, 4, 16)))
+
+
+# A block's checkpoint that keeps what the flash kernel returned
+# (ISSUE 37): one block under each policy, its mixer through the Pallas
+# kernel (interpreted), through materialised attention, or no attention.
+SAVED = "trunk.checkpoint.attention_saved_blocks"
+RECOMPUTED = "trunk.checkpoint.recomputed_blocks"
+
+
+def _gated(impl):  # keys and values of one width
+  return GatedAttention(num_heads=4, num_kv_heads=2, head_dim=8,
+                        rotary_dim=4, attention_impl=impl,
+                        dtype=jnp.float32)
+
+
+def _latent(impl):  # keys of 8 + 4 over values of 6: 192 over 128
+  return LatentAttention(num_heads=4, q_lora_rank=12, kv_lora_rank=8,
+                         qk_nope_head_dim=8, qk_rope_head_dim=4,
+                         v_head_dim=6, attention_impl=impl,
+                         dtype=jnp.float32)
+
+
+def _delta_net(impl):
+  del impl  # no attention, no kernel of it
+  return gated_delta.GatedDeltaNet(
+      num_k_heads=2, num_v_heads=4, head_k_dim=8, head_v_dim=8, chunk=8,
+      dtype=jnp.float32)
+
+
+def _block_gradient(monkeypatch, mixer, impl):
+  """(policy -> the gradient function of one block's trunk, its
+  parameters)."""
+  monkeypatch.setattr(ops, "flash_attention", functools.partial(
+      ops.flash_attention, block_q=32, block_k=32, interpret=True))
+  x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 16))
+
+  def trunk(policy):
+    return SequenceTrunk(blocks=(TransformerBlock(
+        norm="rms", mixer=mixer(impl), mlp_ratio=2,
+        dtype=jnp.float32),), remat_policy=policy)
+
+  def gradient(policy):
+    return jax.grad(lambda params: jnp.sum(jnp.square(
+        trunk(policy).apply(params, x))))
+
+  return gradient, trunk(None).init(jax.random.PRNGKey(1), x)
+
+
+def _kernel_calls(jaxpr, found=None):
+  """The `pallas_call`s of a jaxpr and of every jaxpr inside it,
+  counted by the kernel function's name."""
+  found = collections.Counter() if found is None else found
+  for eqn in jaxpr.eqns:
+    if eqn.primitive.name == "pallas_call":
+      found[eqn.params["jaxpr"].debug_info.func_name] += 1
+    else:
+      for inner in jax.core.jaxprs_in_params(eqn.params):
+        _kernel_calls(inner, found)
+  return found
+
+
+@pytest.mark.parametrize("mixer", [_gated, _latent])
+def test_save_attention_runs_the_forward_kernel_once(monkeypatch, mixer):
+  gradient, params = _block_gradient(monkeypatch, mixer, "flash")
+  calls = {policy: _kernel_calls(
+      jax.make_jaxpr(gradient(policy))(params).jaxpr)
+           for policy in (None, "full", "save_attention")}
+  backward = {"_dkdv_kernel": 1, "_dq_kernel": 1}
+  assert calls["full"] == {"_flash_kernel": 2, **backward}
+  assert calls["save_attention"] == {"_flash_kernel": 1, **backward}
+  assert calls[None] == calls["save_attention"]
+
+
+@pytest.mark.parametrize("mixer", [_gated, _latent])
+def test_gradients_under_save_attention_are_those_under_full(
+    monkeypatch, mixer):
+  """Bit for bit: the saved arrays are what the second run of the
+  kernel gives. Without a checkpoint XLA fuses the block otherwise on
+  a CPU, as it does against `full`: the last bits."""
+  gradient, params = _block_gradient(monkeypatch, mixer, "flash")
+  full, saved, plain = (jax.tree_util.tree_leaves(
+      gradient(policy)(params))
+                        for policy in ("full", "save_attention", None))
+  for a, b, c in zip(saved, full, plain):
+    np.testing.assert_array_equal(a, b)
+    np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("mixer,impl", [
+    (_gated, "reference"), (_latent, "reference"), (_delta_net, None)])
+def test_save_attention_without_the_kernel_is_full(monkeypatch, mixer,
+                                                   impl):
+  """Nothing of such a block carries the kernel's names: the gradient's
+  jaxpr is that of `full` but for the policy's own printed name."""
+  gradient, params = _block_gradient(monkeypatch, mixer, impl)
+  full, saved = (str(jax.make_jaxpr(gradient(policy))(params))
+                 for policy in ("full", "save_attention"))
+  assert "policy=None" in full and "pallas_call" not in full
+  assert "policy=<function" in saved and "policy=<function" not in full
+  assert re.sub(r"policy=<function.*", "policy=None", saved) == full
+
+
+@pytest.mark.parametrize("policies,saved,recomputed,share", [
+    ((), 0, 0, None), (("save_attention",) * 3, 3, 0, 100.0),
+    (("full",) * 2, 0, 2, 0.0), (("dots", "dots_no_batch"), 0, 2, 0.0),
+    ((None, "none"), 0, 0, None),
+    (("save_attention", "full", "full", "full"), 1, 3, 25.0)])
+def test_checkpointed_blocks_are_counted_by_their_policy(
+    policies, saved, recomputed, share):
+  """One count a traced block under a checkpoint, none without one;
+  `lm_attention_saved_share` reads the two, None where neither is."""
+  registry = tmetrics.registry()
+  registry.reset()
+  try:
+    x = jnp.zeros((1, 8, 16))
+    for policy in policies:
+      SequenceTrunk(blocks=(TransformerBlock(
+          num_heads=2, head_dim=8, dtype=jnp.float32),),
+                    remat_policy=policy).init(jax.random.PRNGKey(0), x)
+    assert registry.scalars("trunk.checkpoint.") == {
+        name: float(n) for name, n in ((SAVED, saved),
+                                       (RECOMPUTED, recomputed)) if n}
+    assert lm_attention_saved_share.read({}) == share
+  finally:
+    registry.reset()
