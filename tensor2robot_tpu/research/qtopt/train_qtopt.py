@@ -29,6 +29,7 @@ from tensor2robot_tpu.research.qtopt.qtopt_learner import (
 )
 from tensor2robot_tpu.research.qtopt.replay_buffer import ReplayBuffer
 from tensor2robot_tpu.specs import make_random_tensors
+from tensor2robot_tpu.startup import orchestrator
 from tensor2robot_tpu.utils import checkpoints as ckpt_lib
 from tensor2robot_tpu.utils import profiling
 
@@ -137,40 +138,45 @@ def train_qtopt(
       max_checkpoints_to_keep=max_checkpoints_to_keep)
   k = loop.k
 
-  if replay_buffer is None:
-    replay_buffer = ReplayBuffer(learner.transition_specification())
-  if prefill_random:
-    fill = make_random_tensors(
-        learner.transition_specification(),
-        batch_size=min(replay_buffer.capacity, 4 * batch_size),
-        seed=seed)
-    replay_buffer.add(fill)
-  rng = jax.random.PRNGKey(seed)
-  # Keyed re-wrap on EVERY invocation (identity when the flag is off):
-  # a reused learner must not keep a previous run's mesh-pinned ZeRO
-  # wrapper. Wrap BEFORE the state exists so tx is final when the step
-  # traces; init stays untouched (shardings come from placement).
-  swu_wrapper = lambda tx: tx  # noqa: E731
-  if shard_weight_update:
-    from tensor2robot_tpu.models import optimizers as opt_lib
-    swu_wrapper = lambda tx: opt_lib.shard_weight_update(tx, mesh)  # noqa: E731
-  learner.model.wrap_optimizer(swu_wrapper, key="shard_weight_update")
-  state = learner.create_state(rng, batch_size=2)
-  repl = mesh_lib.replicated(mesh)
-  data_sharding = mesh_lib.batch_sharding(mesh)
-  # The carried-state sharding: fully replicated, or — under
-  # shard_weight_update — optimizer moments sharded over the data
-  # axis (they must STAY sharded across steps, so this pytree is used
-  # for placement and both jit sharding sides).
-  state_sharding = (
-      sharding_lib.train_state_update_sharding(mesh, state)
-      if shard_weight_update else repl)
-  state = jax.device_put(state, state_sharding)
+  with orchestrator.Phase("init_state") as init_state:
+    if replay_buffer is None:
+      replay_buffer = ReplayBuffer(learner.transition_specification())
+    if prefill_random:
+      fill = make_random_tensors(
+          learner.transition_specification(),
+          batch_size=min(replay_buffer.capacity, 4 * batch_size),
+          seed=seed)
+      replay_buffer.add(fill)
+    rng = jax.random.PRNGKey(seed)
+    # Keyed re-wrap on EVERY invocation (identity when the flag is
+    # off): a reused learner must not keep a previous run's mesh-pinned
+    # ZeRO wrapper. Wrap BEFORE the state exists so tx is final when
+    # the step traces; init stays untouched (shardings come from
+    # placement).
+    swu_wrapper = lambda tx: tx  # noqa: E731
+    if shard_weight_update:
+      from tensor2robot_tpu.models import optimizers as opt_lib
+      swu_wrapper = lambda tx: opt_lib.shard_weight_update(tx, mesh)  # noqa: E731
+    learner.model.wrap_optimizer(swu_wrapper, key="shard_weight_update")
+    state = learner.create_state(rng, batch_size=2)
+    repl = mesh_lib.replicated(mesh)
+    data_sharding = mesh_lib.batch_sharding(mesh)
+    # The carried-state sharding: fully replicated, or — under
+    # shard_weight_update — optimizer moments sharded over the data
+    # axis (they must STAY sharded across steps, so this pytree is used
+    # for placement and both jit sharding sides).
+    state_sharding = (
+        sharding_lib.train_state_update_sharding(mesh, state)
+        if shard_weight_update else repl)
+    state = jax.device_put(state, state_sharding)
+    init_state.args["bytes"] = train_loop.state_bytes(state)
   resume_step = ckpt_lib.latest_step(model_dir)
   if resume_step is not None:
     log.info("Resuming QT-Opt from step %d", resume_step)
-    state = ckpt_lib.restore_state(model_dir, like=state,
-                                   step=resume_step)
+    with orchestrator.Phase("restore", step=resume_step,
+                            bytes=init_state.args["bytes"]):
+      state = ckpt_lib.restore_state(model_dir, like=state,
+                                     step=resume_step)
 
   def own_scalars(scalars, steps, dt, stall_secs):
     del stall_secs  # the rate is the interval's, saves and all
@@ -200,15 +206,17 @@ def train_qtopt(
       hook_state=lambda st: st.train_state,
       own_scalars=own_scalars,
       tag_step=getattr(replay_buffer, "set_learner_step", None))
-  replay_buffer.wait_until_size(min_replay_size or batch_size)
+  with orchestrator.Phase("wait_replay"):
+    replay_buffer.wait_until_size(min_replay_size or batch_size)
 
   # int8 CEM tower: activation scales calibrate on a real held-out
   # replay batch BEFORE the step is traced (the scales are trace-time
   # constants; see QTOptLearner.calibrate / docs/PERF.md).
   if getattr(learner, "needs_calibration", False):
-    _calibrate_once_per_run(learner, state,
-                            replay_buffer.sample(batch_size), model_dir,
-                            write=loop.chief)
+    with orchestrator.Phase("calibrate"):
+      _calibrate_once_per_run(learner, state,
+                              replay_buffer.sample(batch_size),
+                              model_dir, write=loop.chief)
 
   if k == 1:
     train_step = jax.jit(
@@ -252,8 +260,9 @@ def train_qtopt(
     # v5e's 16.9 GB in qtopt_472 with the step program's 3 GB of
     # temporaries still to come (PERF.md §6, PR 31).
     depth = max(depth - 1, 1)
-  loop.attach_feed(prefetch_lib.ShardedPrefetcher(
-      stream, stream_sharding, buffer_size=depth))
+  with orchestrator.Phase("input", k=k):
+    loop.attach_feed(prefetch_lib.ShardedPrefetcher(
+        stream, stream_sharding, buffer_size=depth))
   step_rng = jax.random.PRNGKey(seed + 1)
   with loop:
     for transitions in loop.dispatches():

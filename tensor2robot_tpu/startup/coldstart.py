@@ -14,8 +14,10 @@ survive between the cold and warm runs. A probe prints one
   * `compile_watch` — `CompileWatch` counts; a warm probe must report
     `cache_misses == 0` (every program deserialized, zero XLA
     compilations) — the proof the bench section pins.
-  * trainer probes embed the trainer's own `startup_timings.json`
-    (per-phase compile/restore/input wall, overlap saving).
+  * trainer probes embed the trainer's own account of its start: the
+    `startup.*` scalars of the run's first log record (per-phase
+    compile/restore/input wall, the join, jax's own seconds, programs
+    and cache hits; docs/OBSERVABILITY.md, "Start-up").
 
 Probe topology (same for `--tiny`, just smaller nets):
 
@@ -99,7 +101,7 @@ def trainer_probe(model_dir: str, cache_dir: str, tiny: bool) -> dict:
       cache_entry_count,
       configure_compilation_cache,
   )
-  from tensor2robot_tpu.startup.orchestrator import STARTUP_TIMINGS_FILE
+  from tensor2robot_tpu.telemetry import records
 
   configure_compilation_cache(cache_dir=cache_dir)
   t0 = time.perf_counter()
@@ -127,17 +129,20 @@ def trainer_probe(model_dir: str, cache_dir: str, tiny: bool) -> dict:
         log_every_steps=SETUP_STEPS + PROBE_STEPS,
         hooks=[timer],
     )
-  try:
-    with open(os.path.join(model_dir, STARTUP_TIMINGS_FILE)) as f:
-      startup_timings = json.load(f)
-  except (OSError, ValueError):
-    startup_timings = None
+  # The probe's run is the last in the file to have written a first
+  # record (the set-up run wrote one of its own).
+  startup = None
+  for record in records.read_records(
+      os.path.join(model_dir, "metrics_train.jsonl")):
+    if "startup.to_first_metrics_s" in record:
+      startup = {key: value for key, value in record.items()
+                 if key.startswith("startup.")}
   return {
       "probe": "trainer",
       "tiny": tiny,
       "device_kind": jax.devices()[0].device_kind,
       "time_to_first_step_secs": round(timer.ttfs, 3),
-      "startup_timings": startup_timings,
+      "startup": startup,
       "compile_watch": watch.counts(),
       "cache_entries_after": cache_entry_count(cache_dir),
   }

@@ -31,6 +31,7 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from typing import Optional
 
 import jax
@@ -39,6 +40,7 @@ from jax.experimental.compilation_cache import (
 )
 
 from tensor2robot_tpu import config as gin
+from tensor2robot_tpu.telemetry import core as tcore
 from tensor2robot_tpu.telemetry import metrics as tmetrics
 
 log = logging.getLogger(__name__)
@@ -53,6 +55,31 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 _CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 _BACKEND_COMPILE_DURATION = "/jax/core/compile/backend_compile_duration"
+# What jax times of a jitted function on its way to an executable
+# (`jax/_src/dispatch.py`), each with the function's name: event ->
+# (span, counter of its seconds). jax holds the last one around
+# `compile_or_get_cached`, so on a cache hit it is the read and the
+# deserialisation.
+_TRACE_DURATION = "/jax/core/compile/jaxpr_trace_duration"
+_COMPILE_STAGES = {
+    _TRACE_DURATION: ("jit.trace", "compile.trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("jit.lower", "compile.lower_s"),
+    _BACKEND_COMPILE_DURATION: ("jit.compile", "compile.backend_s"),
+}
+# The persistent cache's own seconds (`jax/_src/compiler.py`): event ->
+# counter. `saved_s` is what the compile took when the entry was
+# written, less this read.
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "compile_cache.retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec":
+        "compile_cache.saved_s",
+}
+COUNTERS = ("compile_cache.hits", "compile_cache.misses",
+            "compile_cache.requests", "compile_cache.backend_compiles",
+            *(seconds for _, seconds in _COMPILE_STAGES.values()),
+            *_CACHE_SECONDS.values())
 
 
 def aval_of(x):
@@ -194,10 +221,20 @@ class CompileWatch:
           _CACHE_REQUEST_EVENT: "compile_cache.requests",
       }
 
+      # Of the thread an event comes on: `cache`, what the cache
+      # answered to the compile request it is inside of (the hit or
+      # miss event comes before the end of the backend-compile stage
+      # that holds it), and `tracing`, how many traces deep it is.
+      here = threading.local()
+
       def on_event(event: str, **kwargs):
         name = _event_names.get(event)
         if name is not None:
           tmetrics.counter(name).inc()
+        if event == _CACHE_HIT_EVENT:
+          here.cache = "hit"
+        elif event == _CACHE_MISS_EVENT:
+          here.cache = "miss"
         with cls._lock:
           watches = list(cls._active)
         for watch in watches:
@@ -206,13 +243,46 @@ class CompileWatch:
       def on_duration(event: str, duration: float, **kwargs):
         if event == _BACKEND_COMPILE_DURATION:
           tmetrics.counter("compile_cache.backend_compiles").inc()
+        elif event in _CACHE_SECONDS:
+          tmetrics.counter(_CACHE_SECONDS[event]).inc(duration)
         with cls._lock:
           watches = list(cls._active)
         for watch in watches:
           watch._observe_duration(event)
 
+      def on_stage_start(event: str, value: float, **kwargs):
+        if event == _TRACE_DURATION:
+          here.tracing = getattr(here, "tracing", 0) + 1
+
+      def on_time_span(event: str, start: float, end: float,
+                       fun_name: str = "", **kwargs):
+        # A span on the tracer's clock, under whatever span this
+        # thread is in: jax's own stamps are `time.time()`, and the
+        # event comes as the stage ends.
+        stage = _COMPILE_STAGES.get(event)
+        if stage is None:
+          return
+        if event == _TRACE_DURATION:
+          # The trace of a jitted function called inside another's
+          # (every `jnp` helper is one: ten to one in a QT-Opt run) is
+          # part of that one's seconds, and no span of its own.
+          here.tracing = max(getattr(here, "tracing", 0) - 1, 0)
+          if here.tracing:
+            return
+        span, seconds = stage
+        dur = end - start
+        tmetrics.counter(seconds).inc(dur)
+        args = {"fun": str(fun_name)}
+        if event == _BACKEND_COMPILE_DURATION:
+          args["cache"] = getattr(here, "cache", "off")
+          here.cache = "off"  # until the next request's answer
+        tcore.get_tracer().record(span, time.monotonic() - dur, dur,
+                                  **args)
+
       monitoring.register_event_listener(on_event)
       monitoring.register_event_duration_secs_listener(on_duration)
+      monitoring.register_scalar_listener(on_stage_start)
+      monitoring.register_event_time_span_listener(on_time_span)
       cls._installed = True
 
   @classmethod
@@ -224,9 +294,7 @@ class CompileWatch:
     counter names are touched on EVERY call (listener install is
     once-per-process) so the keys exist in the registry — at zero —
     even before the first cache event or after a registry reset."""
-    for name in ("compile_cache.hits", "compile_cache.misses",
-                 "compile_cache.requests",
-                 "compile_cache.backend_compiles"):
+    for name in COUNTERS:
       tmetrics.counter(name)
     cls._install()
 

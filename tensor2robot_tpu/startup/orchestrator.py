@@ -9,21 +9,73 @@ primitive: named thunks, all started together, all joined, per-phase
 wall timings recorded, failures surfaced only AFTER every phase has
 finished (a half-started phase must never leak a worker thread or a
 prefetcher holding device buffers).
+
+`Phase` is how every stretch of a trainer's start is put on the one
+tracer and into the registry (docs/OBSERVABILITY.md, "Start-up"): a
+span `startup.<name>` and a gauge `startup.<name>_s`, from one reading
+of the clock.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import json
 import logging
-import os
 import threading
 import time
 from typing import Any, Callable, Dict, Mapping, Optional
 
+from tensor2robot_tpu.telemetry import core as tcore
+from tensor2robot_tpu.telemetry import metrics as tmetrics
+
 log = logging.getLogger(__name__)
 
-STARTUP_TIMINGS_FILE = "startup_timings.json"
+# Of each thread: how many phases deep it is, and the seconds of the
+# phases it has finished at depth 0. The second is what a trainer's
+# thread has a name for of its own start, tracer on or off.
+_thread = threading.local()
+
+
+class Phase:
+  """`with Phase(name, **args):` is `telemetry.span("startup." + name,
+  **args)` and the gauge `startup.<name>_s` of the same `seconds`,
+  which is set with the tracer off as well. What is known only when
+  the work is done (a state's bytes, the slowest of the joined phases)
+  goes into `args` inside the `with`."""
+
+  def __init__(self, name: str, **args):
+    self.name, self.args = name, args
+    self.seconds: Optional[float] = None
+
+  def __enter__(self) -> "Phase":
+    self._depth = getattr(_thread, "depth", 0)
+    _thread.depth = self._depth + 1
+    self.t0 = time.monotonic()
+    return self
+
+  def __exit__(self, exc_type, exc, tb) -> bool:
+    self.seconds = time.monotonic() - self.t0
+    _thread.depth = self._depth
+    if not self._depth:
+      _thread.top_level_s = top_level_seconds() + self.seconds
+    if exc_type is not None:
+      self.args["error"] = exc_type.__name__  # as `telemetry.span` does
+    tcore.get_tracer().record("startup." + self.name, self.t0,
+                              self.seconds, **self.args)
+    tmetrics.gauge(f"startup.{self.name}_s").set(self.seconds)
+    return False
+
+
+def top_level_seconds() -> float:
+  """The seconds of the phases that the calling thread has finished
+  outside any other phase, since `restart_account` on it."""
+  return getattr(_thread, "top_level_s", 0.0)
+
+
+def restart_account() -> None:
+  """A trainer's entry: the calling thread's `top_level_seconds` start
+  again at 0 (and its depth, which a run that died inside a phase left
+  standing)."""
+  _thread.depth, _thread.top_level_s = 0, 0.0
 
 
 @dataclasses.dataclass
@@ -52,26 +104,11 @@ class StartupReport:
   def overlap_saved_seconds(self) -> float:
     return max(self.serial_seconds - self.total_seconds, 0.0)
 
-  def as_dict(self) -> dict:
-    return {
-        "mode": self.mode,
-        "phase_seconds": {k: round(v, 4) for k, v in
-                          self.seconds.items()},
-        "total_seconds": round(self.total_seconds, 4),
-        "serial_seconds": round(self.serial_seconds, 4),
-        "overlap_saved_seconds": round(self.overlap_saved_seconds, 4),
-    }
-
-  def write(self, model_dir: str) -> str:
-    """Persists the report (bench probes read it back)."""
-    path = os.path.join(model_dir, STARTUP_TIMINGS_FILE)
-    with open(path, "w") as f:
-      json.dump(self.as_dict(), f, indent=2)
-    return path
-
 
 def run_overlapped(phases: Mapping[str, Callable[[], Any]],
-                   overlap: bool = True) -> StartupReport:
+                   overlap: bool = True,
+                   span_args: Optional[Mapping[str, Mapping[str, Any]]]
+                   = None) -> StartupReport:
   """Runs named startup thunks concurrently (or serially) and joins all.
 
   Args:
@@ -82,6 +119,11 @@ def run_overlapped(phases: Mapping[str, Callable[[], Any]],
       reference serial path, kept selectable so equivalence is
       testable and a pathological environment (e.g. a jax backend
       that is not thread-safe) has an escape hatch.
+    span_args: {name: arguments of that phase's span}.
+
+  Each thunk runs inside `Phase(name)` on the thread that runs it, the
+  start-and-join inside `Phase("join")` on the caller's, whose self
+  time is the wait for the slowest phase.
 
   Returns a StartupReport; failures land in `report.errors` (never
   raised here) so the caller can release any sibling phase's
@@ -93,32 +135,33 @@ def run_overlapped(phases: Mapping[str, Callable[[], Any]],
   errors: Dict[str, BaseException] = {}
 
   def run_one(name: str, fn: Callable[[], Any]) -> None:
-    t0 = time.perf_counter()
+    phase = Phase(name, **(span_args or {}).get(name, {}))
     try:
-      results[name] = fn()
+      with phase:
+        results[name] = fn()
     except BaseException as e:  # re-raised below, never swallowed
       errors[name] = e
-    finally:
-      seconds[name] = time.perf_counter() - t0
+    seconds[name] = phase.seconds
 
-  t_start = time.perf_counter()
-  if overlap:
-    threads = [
-        threading.Thread(target=run_one, args=(name, fn),
-                         name=f"startup-{name}", daemon=True)
-        for name, fn in phases.items()
-    ]
-    for t in threads:
-      t.start()
-    for t in threads:
-      t.join()
-  else:
-    for name, fn in phases.items():
-      run_one(name, fn)
-  total = time.perf_counter() - t_start
+  with Phase("join", mode="overlapped" if overlap else "serial") as join:
+    if overlap:
+      threads = [
+          threading.Thread(target=run_one, args=(name, fn),
+                           name=f"startup-{name}", daemon=True)
+          for name, fn in phases.items()
+      ]
+      for t in threads:
+        t.start()
+      for t in threads:
+        t.join()
+    else:
+      for name, fn in phases.items():
+        run_one(name, fn)
+    join.args["slowest"] = max(seconds, key=seconds.get, default="")
+  total = join.seconds
 
   report = StartupReport(
-      mode="overlapped" if overlap else "serial",
+      mode=join.args["mode"],
       results=results, seconds=seconds, total_seconds=total,
       errors=errors)
   if errors:

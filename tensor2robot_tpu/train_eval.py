@@ -224,11 +224,10 @@ def train_eval_model(
   phases concurrently — AOT `.lower().compile()` of the train/eval
   programs (avals predicted from the generators' wire specs), the
   orbax resume restore, and the input pipeline's spin-up/first-batch
-  prep — and writes per-phase timings to
-  `<model_dir>/startup_timings.json` (see docs/STARTUP.md). False is
-  the reference serial path: restore, then lazy jit at the first
-  step. Both paths are bitwise-identical in results; with a
-  persistent compilation cache configured
+  prep — each a span `startup.<phase>` on its own thread (see
+  docs/STARTUP.md). False is the reference serial path: restore, then
+  lazy jit at the first step. Both paths are bitwise-identical in
+  results; with a persistent compilation cache configured
   (`startup.configure_compilation_cache`), a warm restart skips XLA
   entirely.
 
@@ -253,13 +252,19 @@ def train_eval_model(
     input_generator_eval.set_specification_from_model(model, Mode.EVAL)
 
   # --- init / resume state ---
-  rng = jax.random.PRNGKey(seed)
-  state = model.create_train_state(rng, batch_size=init_batch_size)
-  state_shardings = state_sharding(
-      mesh, state, strategy=sharding_strategy,
-      min_size_to_shard=min_size_to_shard)
-  state = jax.device_put(state, state_shardings)
+  with orchestrator.Phase("init_state") as init_state:
+    rng = jax.random.PRNGKey(seed)
+    state = model.create_train_state(rng, batch_size=init_batch_size)
+    state_shardings = state_sharding(
+        mesh, state, strategy=sharding_strategy,
+        min_size_to_shard=min_size_to_shard)
+    state = jax.device_put(state, state_shardings)
+    init_state.args["bytes"] = train_loop.state_bytes(state)
   resume_step = ckpt_lib.latest_step(model_dir)
+  # A phase's span holds what is known before it starts.
+  span_args = {"restore": {"step": resume_step,
+                           "bytes": init_state.args["bytes"]},
+               "input": {"k": k}}
 
   repl = mesh_lib.replicated(mesh)
   batch_sh = mesh_lib.batch_sharding(mesh)
@@ -371,7 +376,7 @@ def train_eval_model(
     if resume_step is not None:
       log.info("Resuming from checkpoint at step %d in %s", resume_step,
                model_dir)
-    report = orchestrator.run_overlapped(phases)
+    report = orchestrator.run_overlapped(phases, span_args=span_args)
     if report.errors:
       # A failed phase must not leak a sibling's resources: the input
       # prefetcher pins buffered sharded batches in device memory.
@@ -384,16 +389,12 @@ def train_eval_model(
       # The loop's from here on: whatever fails, its teardown closes
       # the worker (it pins buffered sharded batches in HBM).
       loop.attach_feed(report.results["input"])
-    try:
-      report.write(model_dir)
-    except OSError:
-      log.warning("Could not write %s",
-                  orchestrator.STARTUP_TIMINGS_FILE, exc_info=True)
   elif resume_step is not None:
     # Serial reference path (overlap_startup=False).
     log.info("Resuming from checkpoint at step %d in %s", resume_step,
              model_dir)
-    state = _restore_phase()
+    with orchestrator.Phase("restore", **span_args["restore"]):
+      state = _restore_phase()
 
   if aot:
     train_callable = _checked_aot(
@@ -459,7 +460,8 @@ def train_eval_model(
         if loop.feed is None:
           # Serial path (or resume landed short of max_train_steps with
           # no overlapped input phase): spin up the pipeline here.
-          loop.attach_feed(_input_phase())
+          with orchestrator.Phase("input", **span_args["input"]):
+            loop.attach_feed(_input_phase())
         step_rng = jax.random.PRNGKey(seed + 1)
         for features, labels in loop.dispatches():
           with loop.dispatch():

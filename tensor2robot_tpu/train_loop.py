@@ -71,6 +71,7 @@ from tensor2robot_tpu import telemetry
 from tensor2robot_tpu.data import prefetch as prefetch_lib
 from tensor2robot_tpu.hooks import Hook, HookList
 from tensor2robot_tpu.startup import compile_cache
+from tensor2robot_tpu.startup import orchestrator
 from tensor2robot_tpu.telemetry import perf as perf_lib
 from tensor2robot_tpu.telemetry import sentinel as sentinel_lib
 from tensor2robot_tpu.utils import checkpoints as ckpt_lib
@@ -139,20 +140,29 @@ def _bytes_limit(devices) -> Optional[int]:
   return min(limits) if limits else None
 
 
+def _device_leaves(state) -> list:
+  return [leaf for leaf in jax.tree_util.tree_leaves(state)
+          if isinstance(leaf, jax.Array)]
+
+
+def state_bytes(state) -> int:
+  """The bytes one device holds of `state`."""
+  return sum(leaf.addressable_shards[0].data.nbytes
+             for leaf in _device_leaves(state))
+
+
 def state_copy_fits(state) -> bool:
   """Whether a copy of `state` fits on its devices beside it: the
   bytes one device holds of the state, twice, against half of that
   device's `bytes_limit` (a guess at the step's own need: the module's
   docstring). True where the runtime reports no limit."""
-  leaves = [leaf for leaf in jax.tree_util.tree_leaves(state)
-            if isinstance(leaf, jax.Array)]
+  leaves = _device_leaves(state)
   if not leaves:
     return True
   limit = _bytes_limit(leaves[0].devices())
   if limit is None:
     return True
-  held = sum(leaf.addressable_shards[0].data.nbytes for leaf in leaves)
-  return 2 * held <= limit // 2
+  return 2 * state_bytes(state) <= limit // 2
 
 
 def _on_every_rank(holds: bool) -> bool:
@@ -165,6 +175,13 @@ def _on_every_rank(holds: bool) -> bool:
   from jax.experimental import multihost_utils
   return bool(np.all(multihost_utils.process_allgather(
       np.asarray(holds))))
+
+
+# The phases of a start whose seconds the first log record and the
+# benchmark's readers take from their gauges; one that a run does not
+# go through (a restore without a checkpoint) reads 0.
+_PHASE_GAUGES = ("startup.init_state_s", "startup.restore_s",
+                 "startup.join_s", "startup.begin_s")
 
 
 class TrainLoop:
@@ -206,34 +223,49 @@ class TrainLoop:
         steps_per_dispatch, log_every_steps=log_every_steps,
         save_checkpoints_steps=save_checkpoints_steps,
         max_train_steps=max_train_steps, **other_cadences)
-    if telemetry.get_tracer().role is None:
-      telemetry.configure("trainer")
-    self.model_dir = model_dir
-    self.max_train_steps = max_train_steps
-    self._dispatch_span = dispatch_span
-    self._log_every = log_every_steps
-    self._save_every = save_checkpoints_steps
-    self._max_to_keep = max_checkpoints_to_keep
-    os.makedirs(model_dir, exist_ok=True)
-    self.chief = jax.process_index() == 0
-    self.metric_logger = (MetricLogger(model_dir, role)
-                          if self.chief else None)
-    self.hook_list = HookList(list(hooks))
-    # Places the persistent compile cache and taps its traffic into the
-    # telemetry registry: a warm-path recompile lands in the loop's log.
-    compile_cache.configure_compilation_cache()
-    # The always-on perf plane (ISSUE 15): resource watermarks sampled
-    # per process, sentinel rules evaluated at log cadence, and the
-    # live MFU gauges of the `PerfMeter` that `begin` builds.
-    perf_lib.start_resource_sampler(
-        sources=[profiling.device_memory_source()])
-    self.watch_sentinel = (sentinel_lib.build_for_run(model_dir)
-                           if self.chief else None)
-    self.feed: Optional[prefetch_lib.TimedIterator] = None
-    self._prefetcher = self._writer = None
-    # (step, metrics, snapshot) of the dispatch enqueued last, until
-    # the one after it is enqueued.
-    self._pending: Optional[tuple] = None
+    # The run's time zero, and what jax had compiled before it: the
+    # loop closes this account at its first log (`_close_startup`).
+    orchestrator.restart_account()
+    self._compiled_before = telemetry.registry().scalars("compile")
+    with orchestrator.Phase("services") as services:
+      self._t_entry = services.t0
+      if telemetry.get_tracer().role is None:
+        telemetry.configure("trainer")
+      services.args["role"] = telemetry.current_role()
+      self.model_dir = model_dir
+      self.max_train_steps = max_train_steps
+      self._dispatch_span = dispatch_span
+      self._log_every = log_every_steps
+      self._save_every = save_checkpoints_steps
+      self._max_to_keep = max_checkpoints_to_keep
+      os.makedirs(model_dir, exist_ok=True)
+      self.chief = jax.process_index() == 0
+      self.metric_logger = (MetricLogger(model_dir, role)
+                            if self.chief else None)
+      self.hook_list = HookList(list(hooks))
+      # Places the persistent compile cache and taps its traffic into
+      # the telemetry registry: a warm-path recompile lands in the
+      # loop's log, and every trace, lowering and compile in the ring.
+      compile_cache.configure_compilation_cache()
+      # The always-on perf plane (ISSUE 15): resource watermarks
+      # sampled per process, sentinel rules evaluated at log cadence,
+      # and the live MFU gauges of the `PerfMeter` that `begin` builds.
+      perf_lib.start_resource_sampler(
+          sources=[profiling.device_memory_source()])
+      self.watch_sentinel = (sentinel_lib.build_for_run(model_dir)
+                             if self.chief else None)
+      self.feed: Optional[prefetch_lib.TimedIterator] = None
+      self._prefetcher = self._writer = None
+      # (step, metrics, snapshot) of the dispatch enqueued last, until
+      # the one after it is enqueued.
+      self._pending: Optional[tuple] = None
+      # From `dispatches`' start to the return of the first jitted
+      # call; None before and after.
+      self._first_dispatch: Optional[orchestrator.Phase] = None
+      # An earlier run's account in this process is not this run's.
+      registry = telemetry.registry()
+      for name in {*registry.scalars("startup."), *_PHASE_GAUGES}:
+        registry.gauge(name).set(0.0)
 
   def begin(self, model, step: int, *,
             flops_per_step: Optional[float], devices: int,
@@ -279,28 +311,34 @@ class TrainLoop:
             f"steps_per_dispatch={self.k}: the checkpoint/log "
             "boundaries would never align. Resume with K=1 (or a K "
             "dividing the resume step) first.")
-      self._last_saved = ckpt_lib.latest_step(self.model_dir)
-      self._writer = ckpt_lib.CheckpointWriter(
-          self.model_dir, max_to_keep=self._max_to_keep)
-      self._meter = perf_lib.PerfMeter(
-          flops_per_step=flops_per_step,
-          peak_flops=profiling.device_peak_flops(), devices=devices)
-      # Whether this run keeps a second dispatch in flight (the
-      # module's docstring); the trainer sizes its feed's queue by it.
-      self.runs_ahead = _on_every_rank(
-          boundary_work is None
-          and not self.hook_list.drives_online_collection)
-      # Whether a save step's snapshot is a copy on the device or the
-      # live state of a dispatch that the loop finishes first.
-      self.copies_state = self.runs_ahead and _on_every_rank(
-          state_copy_fits(self._state()))
-      telemetry.registry().gauge("loop.state_copy_fits").set(
-          float(self.copies_state))
-      if self.copies_state:
-        # The snapshot's program compiles here, with the run's others:
-        # at the first save it would read as a warm-path recompile.
-        _copy_on_device(self._state())
-      self.hook_list.begin(model, self.model_dir)
+      with orchestrator.Phase("begin") as begin:
+        with orchestrator.Phase("open_writer"):
+          self._last_saved = ckpt_lib.latest_step(self.model_dir)
+          self._writer = ckpt_lib.CheckpointWriter(
+              self.model_dir, max_to_keep=self._max_to_keep)
+        self._meter = perf_lib.PerfMeter(
+            flops_per_step=flops_per_step,
+            peak_flops=profiling.device_peak_flops(), devices=devices)
+        # Whether this run keeps a second dispatch in flight (the
+        # module's docstring); the trainer sizes its feed's queue by it.
+        self.runs_ahead = _on_every_rank(
+            boundary_work is None
+            and not self.hook_list.drives_online_collection)
+        # Whether a save step's snapshot is a copy on the device or the
+        # live state of a dispatch that the loop finishes first.
+        self.copies_state = self.runs_ahead and _on_every_rank(
+            state_copy_fits(self._state()))
+        begin.args["copies_state"] = self.copies_state
+        telemetry.registry().gauge("loop.state_copy_fits").set(
+            float(self.copies_state))
+        if self.copies_state:
+          # The snapshot's program compiles here, with the run's
+          # others: at the first save it would read as a warm-path
+          # recompile.
+          with orchestrator.Phase("snapshot_program"):
+            _copy_on_device(self._state())
+        with orchestrator.Phase("hooks_begin"):
+          self.hook_list.begin(model, self.model_dir)
     except BaseException:
       self.close()
       raise
@@ -315,6 +353,9 @@ class TrainLoop:
     """One item of the feed (None without one) for every dispatch up
     to `max_train_steps`, then what is left of the last dispatch and
     the final save if the loop ended off the save interval."""
+    self._first_dispatch = orchestrator.Phase("first_dispatch",
+                                              step=self.step)
+    self._first_dispatch.__enter__()  # `after_dispatch` ends it
     if self._tag_step is not None:
       # The data plane tags rows with the learner step at add time;
       # seed the tag before actors race the first dispatch. Chief-only:
@@ -351,6 +392,8 @@ class TrainLoop:
     enqueued (or the loop ends). Hooks get the un-synced device
     metrics. A run that does not run ahead (`begin`) finishes this
     dispatch here."""
+    if self._first_dispatch is not None:
+      self._first_enqueue()
     self.step += self.k
     step = self.step
     if self._tag_step is not None:
@@ -367,6 +410,40 @@ class TrainLoop:
     if not self.runs_ahead or (save_due and not self.copies_state):
       # The snapshot is the live state: saved before it is donated.
       self._finish_pending()
+
+  def _first_enqueue(self) -> None:
+    """The first jitted call has returned: the start's last phase
+    ends, and what the start has no name for is known."""
+    phase, self._first_dispatch = self._first_dispatch, None
+    phase.__exit__(None, None, None)
+    telemetry.event("startup.first_enqueue", step=self.step)
+    to_first_enqueue = phase.t0 + phase.seconds - self._t_entry
+    registry = telemetry.registry()
+    registry.gauge("startup.to_first_enqueue_s").set(to_first_enqueue)
+    registry.gauge("startup.unnamed_s").set(
+        to_first_enqueue - orchestrator.top_level_seconds())
+
+  def _close_startup(self, step: int) -> None:
+    """The program's account of its own start, once, when the first
+    dispatch's results are on the host: gauges `startup.*` beside the
+    phases' own (docs/OBSERVABILITY.md, "Start-up"). What jax traced,
+    lowered and compiled is counted from the loop's construction to
+    here, so what a caller compiled before and what anyone compiles
+    after is not in it."""
+    t_metrics = time.monotonic()
+    telemetry.event("startup.first_metrics", step=step)
+    registry = telemetry.registry()
+    now = registry.scalars("compile")
+    before, self._compiled_before = self._compiled_before, None
+    since = lambda key: now.get(key, 0.0) - before.get(key, 0.0)  # noqa: E731
+    for name, value in (
+        ("to_first_metrics_s", t_metrics - self._t_entry),
+        ("jit_s", since("compile.trace_s") + since("compile.lower_s")
+         + since("compile.backend_s")),
+        ("programs", since("compile_cache.backend_compiles")),
+        ("cache_hits", since("compile_cache.hits")),
+        ("cache_misses", since("compile_cache.misses"))):
+      registry.gauge(f"startup.{name}").set(value)
 
   def write(self, tag: str, step: int, scalars: Dict[str, Any]) -> None:
     """A record of the trainer's own (eval metrics), on the chief."""
@@ -425,6 +502,9 @@ class TrainLoop:
         t0 = time.perf_counter()
         scalars = jax.device_get(metrics)
         waited = time.perf_counter() - t0
+      first = self._compiled_before is not None
+      if first:
+        self._close_startup(step)
       dt = time.time() - self._t_last
       # A run ahead saves beside the next program: a save held the
       # loop only by what this wait for the device did not cover.
@@ -439,6 +519,8 @@ class TrainLoop:
       # run (the registry alone dies with the process).
       scalars.update(registry.scalars("compile_cache."))
       scalars.update(registry.scalars("rsrc."))
+      if first:  # this record alone holds the start's account
+        scalars.update(registry.scalars("startup."))
       registry.gauge(f"train.{rate_key}").set(scalars[rate_key])
       scalars.update(self._meter.publish(scalars[rate_key]))
       self.metric_logger.write("train", step, scalars)

@@ -38,6 +38,8 @@ importlib.metadata.packages_distributions = functools.cache(
     importlib.metadata.packages_distributions)
 import orbax.checkpoint as ocp  # noqa: E402
 
+from tensor2robot_tpu import telemetry  # noqa: E402
+
 CKPT_SUBDIR = "ckpt"
 
 
@@ -94,11 +96,8 @@ class CheckpointWriter:
 
   def save(self, step: int, state: Any, params: Optional[Any] = None,
            batch_stats: Optional[Any] = None, force: bool = False) -> None:
-    step_dir = os.path.join(self._root, str(int(step)))
     payloads = ["state"]
-    self._checkpointer.save(
-        os.path.join(step_dir, "state"),
-        args=ocp.args.StandardSave(state), force=force)
+    self._save(self._checkpointer, step, "state", state, force)
     if params is None:
       params = getattr(state, "params", None)
     if batch_stats is None:
@@ -110,12 +109,29 @@ class CheckpointWriter:
       # BN model with fresh-init stats silently degrades predictions,
       # so the stats ride with the weights.
       variables = {"params": params, "batch_stats": batch_stats or {}}
-      self._params_checkpointer.save(
-          os.path.join(step_dir, "params"),
-          args=ocp.args.StandardSave(variables), force=force)
+      self._save(self._params_checkpointer, step, "params", variables,
+                 force)
       payloads.append("params")
     self._pending_steps[int(step)] = payloads
-    self._gc()
+    with telemetry.span("ckpt.gc", step=step):
+      self._gc()
+
+  def _save(self, checkpointer, step: int, payload: str, tree: Any,
+            force: bool) -> None:
+    """One payload's save as two spans. A checkpointer's `save` first
+    waits for its own last save to commit: the same wait, made just
+    before, is a span of its own, and `ckpt.save_<payload>` is the
+    copy to the host and the hand-over to the writing thread."""
+    with telemetry.span("ckpt.wait_previous", step=step,
+                        payload=payload):
+      checkpointer.wait_until_finished()
+    nbytes = sum(getattr(leaf, "nbytes", 0)
+                 for leaf in jax.tree_util.tree_leaves(tree))
+    with telemetry.span(f"ckpt.save_{payload}", step=step,
+                        bytes=nbytes):
+      checkpointer.save(
+          os.path.join(self._root, str(int(step)), payload),
+          args=ocp.args.StandardSave(tree), force=force)
 
   def wait(self) -> None:
     self._checkpointer.wait_until_finished()

@@ -4,9 +4,9 @@ Reference parity: the reference had no in-repo profiling — TPU traces
 were captured with the external `capture_tpu_profile` tool and viewed
 in TensorBoard (SURVEY.md §6 "Tracing/profiling"). TPU-native upgrade:
 `jax.profiler` traces captured programmatically (viewable in
-TensorBoard / Perfetto), a trainer `ProfilerHook` that grabs a trace
-window mid-run, and XLA-cost-analysis-based FLOPs + MFU estimation so
-benchmarks can report fraction-of-peak instead of bare steps/sec.
+TensorBoard / Perfetto), and XLA-cost-analysis-based FLOPs + MFU
+estimation so benchmarks can report fraction-of-peak instead of bare
+steps/sec.
 
 This module also owns THE analytic-FLOPs MFU denominator
 (`analytic_flops`, hoisted from bench.py by ISSUE 15): `bench.py`
@@ -27,7 +27,6 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import numpy as np
 
-from tensor2robot_tpu.hooks.hook import Hook
 from tensor2robot_tpu.telemetry import perf as perf_lib
 
 log = logging.getLogger(__name__)
@@ -270,15 +269,21 @@ def device_memory_source() -> Callable[[], Dict[str, float]]:
 
 
 @contextlib.contextmanager
-def trace(logdir: str, host_tracer_level: int = 2):
+def trace(logdir: str, host_tracer_level: int = 0):
   """Captures a jax.profiler trace into `logdir`.
 
   View with TensorBoard's profile plugin or Perfetto. Wrap the steps of
-  interest; pair with `step_annotation` so per-step spans are visible.
+  interest. The device alone by default: with the host tracer at level
+  1 or 2 (or the Python tracer) the train loop's host side grew by a
+  quarter of a GB a second until no dispatch finished (PR 23;
+  `benchmark/harness/window.py`), and what the host does is on the
+  program's own spans, whose clock is the recording's (PERF.md §5).
+  `step_annotation` is a host event: it shows from level 1 on.
   """
   os.makedirs(logdir, exist_ok=True)
   options = jax.profiler.ProfileOptions()
   options.host_tracer_level = host_tracer_level
+  options.python_tracer_level = 0
   with jax.profiler.trace(logdir, profiler_options=options):
     yield
   log.info("Profiler trace written to %s", logdir)
@@ -287,51 +292,3 @@ def trace(logdir: str, host_tracer_level: int = 2):
 def step_annotation(step: int):
   """Names one training step inside an active trace."""
   return jax.profiler.StepTraceAnnotation("train", step_num=step)
-
-
-class ProfilerHook(Hook):
-  """Captures a jax.profiler trace for a window of training steps.
-
-  The reference delegated this to `capture_tpu_profile` run out-of-band;
-  here the trainer grabs the window itself. The trace lands in
-  `<model_dir>/profile` (or `logdir`), viewable in TensorBoard.
-
-  Args:
-    start_step: first profiled step (absolute step count, so resumed
-      runs profile at the same point in training).
-    num_steps: window length.
-    logdir: override output dir; defaults to `<model_dir>/profile`.
-  """
-
-  def __init__(self, start_step: int = 10, num_steps: int = 5,
-               logdir: Optional[str] = None):
-    self._start = start_step
-    self._num = num_steps
-    self._logdir = logdir
-    self._cm: Optional[Any] = None
-    self._opened = False
-    self._block_on: Optional[Callable] = None
-
-  def begin(self, model, model_dir: str) -> None:
-    if self._logdir is None:
-      self._logdir = os.path.join(model_dir, "profile")
-    self._opened = False
-
-  def after_step(self, step: int, metrics: dict) -> None:
-    # `>=` + the opened flag, not `==`: under steps_per_dispatch > 1
-    # hooks only observe every K-th step, so an exact-match trigger
-    # would silently never fire when start_step isn't a multiple of K.
-    if self._cm is None and not self._opened and step >= self._start:
-      self._opened = True
-      self._cm = trace(self._logdir)
-      self._cm.__enter__()
-    elif self._cm is not None and step >= self._start + self._num:
-      # Drain in-flight device work so the trace covers whole steps.
-      jax.block_until_ready(metrics)
-      self._cm.__exit__(None, None, None)
-      self._cm = None
-
-  def end(self, step: int, state, model_dir: str) -> None:
-    if self._cm is not None:  # run ended inside the window
-      self._cm.__exit__(None, None, None)
-      self._cm = None

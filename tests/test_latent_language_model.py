@@ -434,8 +434,13 @@ def test_rehearsed_cell_is_correct(capsys, monkeypatch):
   assert len(result["check"]) >= 5
   # ISSUE 37's share is read off the checkpoints' policy: the shipped
   # gin file's `save_attention`, whatever backend the mixer took.
-  assert result["metric_names"] == ["lm_attention_saved_share",
-                                    "lm_mla_flash_share"]
+  # ISSUE 38's seven read the account of the start that the loop closed
+  # at its first log.
+  assert result["metric_names"] == [
+      "lm_attention_saved_share", "lm_mla_flash_share",
+      "startup_cache_hit_share", "startup_compile_s",
+      "startup_first_metrics_s", "startup_init_state_s",
+      "startup_programs", "startup_restore_s", "startup_unnamed_share"]
   window = json.loads(next(line for line in lines
                            if line.startswith("window:"))[7:])
   assert window["checkpoint_stalls_ms"] == []
@@ -507,14 +512,18 @@ def test_benchmark_json_has_the_new_entries_and_no_other():
       "name": CELL, "config": "joyai_llm_flash_ep16",
       "traffic": "train_eval", "chips": 1,
       "why": bench["workloads"][-1]["why"]}
-  assert [m["name"] for m in bench["per_layer"][-3:]] == [
+  # ISSUE 38 appended start-up's seven, which every cell reports.
+  startup = [m for m in bench["per_layer"] if m["layer"] == "start-up"]
+  assert bench["per_layer"][-7:] == startup
+  per_layer = bench["per_layer"][:-7]
+  assert [m["name"] for m in per_layer[-3:]] == [
       "lm_mla_step_mfu", "lm_mla_flash_share",
       "lm_attention_saved_share"]
-  assert bench["per_layer"][-1] == {  # ISSUE 37's one entry
+  assert per_layer[-1] == {  # ISSUE 37's one entry
       "name": "lm_attention_saved_share", "unit": "%",
       "better": "higher", "source": "program_counter",
       "layer": "sequence trunk", "moves": "train_steps_per_s",
       "workloads": ["qwen3next_80b_a3b_ep16.train_eval", CELL]}
-  for metric in bench["per_layer"][:-1]:
+  for metric in per_layer[:-1]:
     assert (CELL in metric["workloads"]) == metric["name"].startswith(
         "lm_mla_")

@@ -6,7 +6,8 @@ Pins the contracts docs/STARTUP.md promises:
     program a cache hit) — counted via jax.monitoring, not wall clock;
   * overlap correctness: a resume with overlapped
     restore/compile/input is bitwise-identical to the serial path;
-  * startup phase timings are written for the bench probes to read;
+  * startup phase timings are in the run's first log record for the
+    bench probes to read;
   * `CheckpointWriter.save()` stays async once the retention window is
     full (finished saves are pruned by completion, not only by wait());
   * the trainer's split metrics: pure train-loop steps_per_sec +
@@ -194,17 +195,29 @@ class TestOverlappedStartup:
       np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
   def test_startup_timings_written(self, tmp_path):
+    """The first log record of a run holds its start's account: one
+    exporter of start-up, the registry's (`startup_timings.json` is
+    gone)."""
     model_dir = str(tmp_path / "m")
     self._run(model_dir, max_steps=3, overlap=True)
     self._run(model_dir, max_steps=6, overlap=True)  # resume
-    with open(os.path.join(model_dir,
-                           orchestrator.STARTUP_TIMINGS_FILE)) as f:
-      timings = json.load(f)
-    assert timings["mode"] == "overlapped"
-    # The resume run overlapped all three phases.
-    assert set(timings["phase_seconds"]) == {"compile", "restore",
-                                             "input"}
-    assert timings["total_seconds"] > 0
+    assert not os.path.exists(
+        os.path.join(model_dir, "startup_timings.json"))
+    records = read_records(os.path.join(model_dir,
+                                        "metrics_train.jsonl"))
+    assert [r["step"] for r in records] == [3, 6]
+    timings = {key: value for key, value in records[1].items()
+               if key.startswith("startup.")}
+    # The resume run overlapped all three phases, under one join.
+    assert all(timings[f"startup.{phase}_s"] > 0
+               for phase in ("compile", "restore", "input"))
+    assert timings["startup.join_s"] >= max(
+        timings[f"startup.{phase}_s"]
+        for phase in ("compile", "restore", "input"))
+    assert timings["startup.to_first_metrics_s"] \
+        > timings["startup.join_s"]
+    # The fresh run restored nothing.
+    assert records[0]["startup.restore_s"] == 0
 
   def test_run_overlapped_surfaces_errors_after_join(self):
     def ok():

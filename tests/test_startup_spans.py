@@ -1,0 +1,474 @@
+"""A trainer's start as spans of the one tracer (ISSUE 38;
+docs/OBSERVABILITY.md "Start-up"): the phases from the construction of
+the train loop to the first metrics on their threads, jax's traces,
+lowerings and compiles as spans with their function's name, the
+account the loop closes once at its first log, the first log record
+that holds it, and the benchmark's readers of it."""
+
+import importlib
+import json
+import os
+
+import pytest
+
+from tensor2robot_tpu import telemetry
+from tensor2robot_tpu.startup import compile_cache
+from tensor2robot_tpu.telemetry import core as tcore
+from tensor2robot_tpu.telemetry import metrics as tmetrics
+from tensor2robot_tpu.telemetry.records import read_records
+from test_stage_spans import (  # noqa: F401 — `clean_plane` is a fixture
+    _by_name,
+    _learner,
+    _reset,
+    _train,
+    _train_eval,
+    clean_plane,
+)
+
+# Trainer -> the phases of its start on the trainer's own thread, in
+# the order they start, and those on threads of their own (under
+# `train_eval_model`'s overlapped start; `restore` on a resume only).
+MAIN = {
+    "train_eval_model": (
+        "services", "init_state", "join", "begin", "open_writer",
+        "snapshot_program", "hooks_begin", "first_dispatch"),
+    "train_qtopt": (
+        "services", "init_state", "restore", "begin", "open_writer",
+        "snapshot_program", "hooks_begin", "wait_replay", "calibrate",
+        "input", "first_dispatch"),
+}
+OWN_THREAD = {"train_eval_model": ("compile", "restore", "input"),
+              "train_qtopt": ()}
+DISPATCH = {"train_eval_model": "train.dispatch",
+            "train_qtopt": "qtopt.dispatch"}
+# Child -> parent, on one thread.
+INSIDE = {"open_writer": "begin", "snapshot_program": "begin",
+          "hooks_begin": "begin"}
+GAUGES = ("to_first_enqueue_s", "to_first_metrics_s", "init_state_s",
+          "restore_s", "join_s", "begin_s", "jit_s", "programs",
+          "cache_hits", "cache_misses", "unnamed_s")
+READERS = ("startup_first_metrics_s", "startup_init_state_s",
+           "startup_restore_s", "startup_compile_s", "startup_programs",
+           "startup_cache_hit_share", "startup_unnamed_share")
+
+
+def _run(trainer, model_dir, **kwargs):
+  if trainer == "train_qtopt":
+    # An int8 tower: the start calibrates (and on a resume adopts) its
+    # activation scales.
+    return _train(model_dir, learner=_learner(cem_inference="int8"),
+                  **kwargs)
+  return _train_eval(model_dir, **kwargs)
+
+
+@pytest.fixture(scope="module")
+def starts(tmp_path_factory):
+  """(trainer, "fresh" | "resumed") -> what that start left behind: a
+  run of 16 steps from nothing and an empty compile cache, then one
+  that resumes it to 32."""
+  kept = {}
+
+  def get(trainer, which):
+    if (trainer, which) not in kept:
+      model_dir = tmp_path_factory.mktemp(trainer)
+      compile_cache.configure_compilation_cache(
+          cache_dir=str(tmp_path_factory.mktemp("cache")))
+      try:
+        for name, steps in (("fresh", 16), ("resumed", 32)):
+          _reset()
+          _run(trainer, model_dir, max_train_steps=steps)
+          kept[trainer, name] = {
+              "spans": telemetry.get_tracer().snapshot_spans(),
+              "gauges": telemetry.registry().scalars("startup."),
+              "records": read_records(
+                  os.path.join(model_dir, "metrics_train.jsonl"))}
+      finally:
+        compile_cache.reset_compilation_cache_config()
+        compile_cache.configure_compilation_cache()
+        _reset()
+    return kept[trainer, which]
+
+  return get
+
+
+def _phase(spans, name):
+  return _by_name(spans, "startup." + name)
+
+
+def _inside(child, parent) -> bool:
+  return (parent["ts"] <= child["ts"] and child["ts"] + child["dur"]
+          <= parent["ts"] + parent["dur"] + 1e-9)
+
+
+def _cases(table):
+  return [(trainer, which, name) for trainer in sorted(table)
+          for which in ("fresh", "resumed") for name in table[trainer]]
+
+
+class TestPhasesOfAStart:
+
+  @pytest.mark.parametrize("trainer,which,name", _cases(MAIN))
+  def test_main_thread_phase_is_there_once(self, starts, trainer, which,
+                                           name):
+    spans = starts(trainer, which)["spans"]
+    main = _phase(spans, "services")[0]["tid"]
+    found = _phase(spans, name)
+    if name == "restore" and which == "fresh":
+      assert not found
+      return
+    assert len(found) == 1 and found[0]["tid"] == main, name
+    if name in INSIDE:
+      assert _inside(found[0], _phase(spans, INSIDE[name])[0])
+
+  @pytest.mark.parametrize("trainer,which,name", _cases(OWN_THREAD))
+  def test_overlapped_phase_has_a_thread_of_its_own(self, starts,
+                                                    trainer, which,
+                                                    name):
+    spans = starts(trainer, which)["spans"]
+    join, = _phase(spans, "join")
+    found = _phase(spans, name)
+    if name == "restore" and which == "fresh":
+      assert not found
+      return
+    assert len(found) == 1 and found[0]["tid"] != join["tid"]
+    assert _inside(found[0], join)
+    others = {_phase(spans, other)[0]["tid"]
+              for other in OWN_THREAD[trainer]
+              if other != name and _phase(spans, other)}
+    assert found[0]["tid"] not in others
+
+  @pytest.mark.parametrize("trainer", sorted(MAIN))
+  def test_arguments_say_what_a_phase_worked_on(self, starts, trainer):
+    spans = starts(trainer, "resumed")["spans"]
+    of = lambda name: _phase(spans, name)[0]["args"]  # noqa: E731
+    assert of("services") == {"role": "trainer"}
+    assert of("init_state")["bytes"] > 0
+    assert of("restore") == {"step": 16,
+                             "bytes": of("init_state")["bytes"]}
+    assert of("input") == {"k": 2}
+    assert of("begin") == {"copies_state": True}
+    assert of("first_dispatch") == {"step": 16}
+    if trainer == "train_eval_model":
+      assert of("join") == {"mode": "overlapped", "slowest": max(
+          OWN_THREAD[trainer],
+          key=lambda name: _phase(spans, name)[0]["dur"])}
+
+  @pytest.mark.parametrize("trainer,which", [
+      (trainer, which) for trainer in sorted(MAIN)
+      for which in ("fresh", "resumed")])
+  def test_first_dispatch_holds_the_first_wait_and_enqueue(
+      self, starts, trainer, which):
+    spans = starts(trainer, which)["spans"]
+    first, = _phase(spans, "first_dispatch")
+    wait = [s for s in _by_name(spans, "loop.wait_feed")
+            if s["args"]["seq"] == 0]
+    dispatch = _by_name(spans, DISPATCH[trainer])[0]
+    assert len(wait) == 1 and _inside(wait[0], first)
+    assert _inside(dispatch, first) and dispatch["tid"] == first["tid"]
+    enqueue, = _by_name(spans, "startup.first_enqueue")
+    metrics, = _by_name(spans, "startup.first_metrics")
+    assert enqueue["dur"] == metrics["dur"] == 0
+    assert abs(enqueue["ts"] - (first["ts"] + first["dur"])) < 1e-3
+    # The first dispatch's results on the host: no sync of the
+    # start's own, the end of the loop's first.
+    sync = _by_name(spans, "loop.log_sync")[0]
+    assert 0 <= metrics["ts"] - (sync["ts"] + sync["dur"]) < 1e-3
+    assert metrics["args"]["step"] == sync["args"]["step"]
+
+  @pytest.mark.parametrize("trainer,which", [
+      (trainer, which) for trainer in sorted(MAIN)
+      for which in ("fresh", "resumed")])
+  def test_top_level_phases_and_unnamed_add_up(self, starts, trainer,
+                                               which):
+    start = starts(trainer, which)
+    spans, gauges = start["spans"], start["gauges"]
+    services, = _phase(spans, "services")
+    mine = [s for s in spans if s["name"].startswith("startup.")
+            and s["tid"] == services["tid"] and s["dur"] > 0]
+    top = [s for s in mine
+           if not any(o is not s and _inside(s, o) for o in mine)]
+    assert {s["name"] for s in top} == {
+        "startup." + name for name in MAIN[trainer]
+        if name not in INSIDE
+        and not (name == "restore" and which == "fresh")}
+    named = sum(s["dur"] for s in top)
+    assert named + gauges["startup.unnamed_s"] == pytest.approx(
+        gauges["startup.to_first_enqueue_s"], abs=1e-3)
+    assert 0 <= gauges["startup.unnamed_s"] \
+        < 0.05 * gauges["startup.to_first_enqueue_s"]
+    enqueue, = _by_name(spans, "startup.first_enqueue")
+    metrics, = _by_name(spans, "startup.first_metrics")
+    assert gauges["startup.to_first_enqueue_s"] == pytest.approx(
+        enqueue["ts"] - services["ts"], abs=1e-3)
+    assert gauges["startup.to_first_metrics_s"] == pytest.approx(
+        metrics["ts"] - services["ts"], abs=1e-3)
+
+  @pytest.mark.parametrize("trainer", sorted(MAIN))
+  def test_gauges_follow_the_phases(self, starts, trainer):
+    fresh = starts(trainer, "fresh")["gauges"]
+    resumed = starts(trainer, "resumed")
+    gauges = resumed["gauges"]
+    assert set(fresh) >= {"startup." + name for name in GAUGES}
+    assert fresh["startup.restore_s"] == 0
+    for name in ("init_state", "restore", "begin"):
+      span, = _phase(resumed["spans"], name)
+      assert gauges[f"startup.{name}_s"] == span["dur"]
+    # Every program of the resumed start came out of the cache that
+    # the fresh one filled.
+    assert fresh["startup.programs"] == fresh["startup.cache_misses"] > 0
+    assert fresh["startup.cache_hits"] == 0
+    assert gauges["startup.programs"] == gauges["startup.cache_hits"] > 0
+    assert gauges["startup.cache_misses"] == 0
+    assert gauges["startup.jit_s"] > 0
+
+  @pytest.mark.parametrize("trainer", sorted(MAIN))
+  def test_first_record_alone_holds_the_account(self, starts, trainer):
+    records = starts(trainer, "resumed")["records"]
+    holding = [r["step"] for r in records
+               if any(key.startswith("startup.") for key in r)]
+    # One record a start: the first of the fresh run, and of the
+    # resumed one.
+    assert holding == [4, 20]
+    first = next(r for r in records if r["step"] == 20)
+    gauges = starts(trainer, "resumed")["gauges"]
+    assert {key: first[key] for key in gauges} == gauges
+
+  def test_step_program_is_traced_inside_its_phase(self, starts):
+    """The AOT phase's own thread traces, lowers and compiles the
+    K-step program; `train_qtopt` does so in its first dispatch."""
+    for trainer, phase in (("train_eval_model", "compile"),
+                           ("train_qtopt", "first_dispatch")):
+      spans = starts(trainer, "fresh")["spans"]
+      parent, = _phase(spans, phase)
+      for name, fun in (("jit.trace", "k_steps"),
+                        ("jit.lower", "jit(k_steps)"),
+                        ("jit.compile", "jit(k_steps)")):
+        found = [s for s in _by_name(spans, name)
+                 if s["args"]["fun"] == fun]
+        assert len(found) == 1, (trainer, name)
+        assert found[0]["tid"] == parent["tid"]
+        assert _inside(found[0], parent)
+
+
+@pytest.fixture
+def cache_dir(tmp_path):
+  """An empty persistent cache of the test's own."""
+  compile_cache.configure_compilation_cache(
+      cache_dir=str(tmp_path / "cache"))
+  yield
+  compile_cache.reset_compilation_cache_config()
+  compile_cache.configure_compilation_cache()
+
+
+def _fresh_function():
+  import jax
+  import jax.numpy as jnp
+
+  @jax.jit
+  def never_seen_before(x):
+    return jnp.tanh(x) * 3.0 + 1.0
+
+  return never_seen_before
+
+
+class TestCompileSpans:
+
+  def test_a_jitted_function_is_three_spans_with_its_name(
+      self, clean_plane, cache_dir):
+    import jax
+    import jax.numpy as jnp
+
+    telemetry.configure("trainer")
+    f = _fresh_function()
+    x = jnp.arange(12.0)
+    f(x).block_until_ready()
+    f(x).block_until_ready()  # the in-process cache: nothing new
+    jax.clear_caches()  # what a second process starts with
+    f(x).block_until_ready()
+    spans = telemetry.get_tracer().snapshot_spans()
+    of = lambda name, fun: [  # noqa: E731
+        s for s in _by_name(spans, name) if s["args"]["fun"] == fun]
+    assert len(of("jit.trace", "never_seen_before")) == 2
+    assert len(of("jit.lower", "jit(never_seen_before)")) == 2
+    compiles = of("jit.compile", "jit(never_seen_before)")
+    assert [s["args"]["cache"] for s in compiles] == ["miss", "hit"]
+    counters = telemetry.registry().scalars("compile")
+    for stage, name in (("trace", "jit.trace"), ("lower", "jit.lower"),
+                        ("backend", "jit.compile")):
+      assert counters[f"compile.{stage}_s"] == pytest.approx(
+          sum(s["dur"] for s in _by_name(spans, name)))
+    assert counters["compile_cache.retrieval_s"] > 0
+    assert "compile_cache.saved_s" in counters
+    # The spans lie on the tracer's clock, in the order jax went
+    # through them.
+    first = [of("jit.trace", "never_seen_before")[0],
+             of("jit.lower", "jit(never_seen_before)")[0], compiles[0]]
+    for before, after in zip(first, first[1:]):
+      assert before["ts"] + before["dur"] <= after["ts"] + 1e-3
+
+  def test_a_trace_inside_a_trace_is_not_a_span(self, clean_plane):
+    import jax
+    import jax.numpy as jnp
+
+    telemetry.configure("trainer")
+    compile_cache.CompileWatch.install_tap()
+
+    @jax.jit
+    def inner_of_the_two(x):
+      return x + 1.0
+
+    @jax.jit
+    def outer_of_the_two(x):
+      return inner_of_the_two(x) * 2.0
+
+    outer_of_the_two(jnp.ones(3)).block_until_ready()
+    traced = [s["args"]["fun"] for s in _by_name(
+        telemetry.get_tracer().snapshot_spans(), "jit.trace")]
+    assert "outer_of_the_two" in traced
+    assert "inner_of_the_two" not in traced
+
+  def test_without_the_cache_a_compile_says_off(self, clean_plane):
+    import jax
+    import jax.numpy as jnp
+
+    telemetry.configure("trainer")
+    compile_cache.CompileWatch.install_tap()
+    jax.config.update("jax_enable_compilation_cache", False)
+    compile_cache._reset_jax_cache_latch()
+    try:
+      _fresh_function()(jnp.arange(5.0)).block_until_ready()
+    finally:
+      jax.config.update("jax_enable_compilation_cache", True)
+      compile_cache._reset_jax_cache_latch()
+    compiled, = [s for s in _by_name(
+        telemetry.get_tracer().snapshot_spans(), "jit.compile")
+                 if s["args"]["fun"] == "jit(never_seen_before)"]
+    assert compiled["args"]["cache"] == "off"
+
+  def test_listeners_survive_a_reset_of_registry_and_tracer(
+      self, clean_plane):
+    import jax.numpy as jnp
+
+    compile_cache.CompileWatch.install_tap()
+    tmetrics.reset_for_tests()
+    tcore.reset_for_tests()
+    telemetry.configure("trainer")
+    compile_cache.CompileWatch.install_tap()
+    assert set(compile_cache.COUNTERS) <= set(
+        telemetry.registry().scalars("compile"))
+    _fresh_function()(jnp.arange(7.0)).block_until_ready()
+    counters = telemetry.registry().scalars("compile.")
+    assert all(counters[f"compile.{stage}_s"] > 0
+               for stage in ("trace", "lower", "backend"))
+    assert _by_name(telemetry.get_tracer().snapshot_spans(),
+                    "jit.compile")
+
+
+class TestTheAccountIsClosedOnce:
+
+  def test_a_later_compile_moves_the_counters_alone(self, clean_plane,
+                                                    tmp_path):
+    import jax.numpy as jnp
+
+    _train(tmp_path, max_train_steps=4)
+    closed = telemetry.registry().scalars("startup.")
+    counted = telemetry.registry().scalars("compile")
+    _fresh_function()(jnp.arange(9.0)).block_until_ready()
+    after = telemetry.registry().scalars("compile")
+    assert after["compile.backend_s"] > counted["compile.backend_s"]
+    assert after["compile_cache.backend_compiles"] \
+        > counted["compile_cache.backend_compiles"]
+    assert telemetry.registry().scalars("startup.") == closed
+
+  def test_with_the_tracer_off_the_gauges_are_set_all_the_same(
+      self, clean_plane, tmp_path):
+    telemetry.configure("bench_off_arm", enabled=False)
+    _train(tmp_path, max_train_steps=4)
+    assert telemetry.get_tracer().snapshot_spans() == []
+    gauges = telemetry.registry().scalars("startup.")
+    assert set(gauges) >= {"startup." + name for name in GAUGES}
+    assert 0 < gauges["startup.to_first_enqueue_s"] \
+        < gauges["startup.to_first_metrics_s"]
+    assert 0 <= gauges["startup.unnamed_s"] \
+        < 0.05 * gauges["startup.to_first_enqueue_s"]
+    assert gauges["startup.init_state_s"] > 0
+    assert gauges["startup.programs"] > 0
+    with open(os.path.join(tmp_path, "metrics_train.jsonl")) as f:
+      first = json.loads(f.readline())["payload"]
+    assert {key: first[key] for key in gauges} == gauges
+
+  def test_a_failed_phase_is_a_span_with_its_error(self, clean_plane):
+    from tensor2robot_tpu.startup import orchestrator
+
+    telemetry.configure("trainer")
+
+    def boom():
+      raise RuntimeError("phase failed")
+
+    report = orchestrator.run_overlapped(
+        {"restore": boom, "input": lambda: 3},
+        span_args={"input": {"k": 2}})
+    assert set(report.errors) == {"restore"}
+    spans = telemetry.get_tracer().snapshot_spans()
+    assert _phase(spans, "restore")[0]["args"] == {
+        "error": "RuntimeError"}
+    assert _phase(spans, "input")[0]["args"] == {"k": 2}
+    assert _phase(spans, "join")[0]["args"]["mode"] == "overlapped"
+    assert report.seconds["restore"] == _phase(spans, "restore")[0]["dur"]
+
+
+class TestCheckpointSpans:
+
+  @pytest.mark.parametrize("trainer", sorted(MAIN))
+  def test_a_save_is_spans_inside_save_write(self, starts, trainer):
+    spans = starts(trainer, "fresh")["spans"]
+    writes = _by_name(spans, "loop.save_write")
+    assert [w["args"]["step"] for w in writes] == [8, 16]
+    for write in writes:
+      inside = [s for s in spans if s["name"].startswith("ckpt.")
+                and s["tid"] == write["tid"] and _inside(s, write)]
+      # The state, then the inference payload (a TrainState carries
+      # its own params), each behind the wait for its checkpointer's
+      # last save.
+      assert [s["name"] for s in inside] == [
+          "ckpt.wait_previous", "ckpt.save_state",
+          "ckpt.wait_previous", "ckpt.save_params", "ckpt.gc"]
+      assert all(s["args"]["step"] == write["args"]["step"]
+                 for s in inside)
+      saves = [s for s in inside if s["name"].startswith("ckpt.save_")]
+      assert all(s["args"]["bytes"] > 0 for s in saves)
+      waits = _by_name(inside, "ckpt.wait_previous")
+      assert [w["args"]["payload"] for w in waits] == [
+          s["name"][len("ckpt.save_"):] for s in saves]
+
+
+class TestReaders:
+
+  @pytest.mark.parametrize("name", READERS)
+  def test_reader_is_none_on_an_empty_registry(self, clean_plane, name):
+    reader = importlib.import_module(f"benchmark.layer_metrics.{name}")
+    assert reader.read({}) is None
+
+  def test_readers_return_the_gauges(self, clean_plane):
+    for name, value in (("to_first_metrics_s", 41.5),
+                        ("to_first_enqueue_s", 40.0),
+                        ("unnamed_s", 1.0), ("init_state_s", 7.25),
+                        ("restore_s", 3.5), ("jit_s", 30.0),
+                        ("programs", 241.0), ("cache_hits", 239.0),
+                        ("cache_misses", 2.0)):
+      telemetry.registry().gauge(f"startup.{name}").set(value)
+    read = lambda name: importlib.import_module(  # noqa: E731
+        f"benchmark.layer_metrics.{name}").read({})
+    assert [read(name) for name in READERS] == [
+        41.5, 7.25, 3.5, 30.0, 241.0,
+        pytest.approx(100.0 * 239 / 241), 2.5]
+
+  def test_every_reader_has_its_entry(self):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+      bench = json.load(f)
+    entries = [m for m in bench["per_layer"] if m["layer"] == "start-up"]
+    assert [m["name"] for m in entries] == list(READERS)
+    cells = [w["name"] for w in bench["workloads"]]
+    assert all(m["moves"] == "setup_s" and m["workloads"] == cells
+               for m in entries)

@@ -356,7 +356,7 @@ def test_reader_of_the_two_counters():
 
 # Scalars of a log record that the clock or the machine decides.
 _TIMED = ("grad_steps_per_sec", "input_wait_fraction", "perf.", "rsrc.",
-          "compile_cache.", "replay_")
+          "compile_cache.", "replay_", "startup.")
 
 
 def _qtopt_run(model_dir, hooks, **kwargs):
