@@ -235,7 +235,8 @@ class _LanguageModel(AbstractT2RModel):
     layers beside them (`parallel/moe.held_experts_ffn`, `SparseMoE`),
     reduced over the layers: the mean share of assignments that fall
     on held experts (and of those a selection bias moved), the worst
-    load imbalance, the sum of dropped assignments."""
+    load imbalance, the sum of dropped assignments, the most rounds
+    that a layer's experts ran."""
     del rng  # the network draws nothing
     outputs, sown = self.network.apply(
         variables, features, train=mode == Mode.TRAIN,
@@ -248,7 +249,8 @@ class _LanguageModel(AbstractT2RModel):
     reduce = {"assignments_here_share": jnp.mean,
               "bias_moved_choice_share": jnp.mean,
               "expert_load_max_over_mean": jnp.max,
-              "dropped_assignments": jnp.sum}
+              "dropped_assignments": jnp.sum,
+              "rounds_run": jnp.max}
     outputs[MOE_COUNTERS] = {
         f"moe.{name}": reduce[name](jnp.stack(values))
         for name, values in per_layer.items()}

@@ -339,17 +339,22 @@ def _group_sizes(start, ends, counts, rows):
           ).astype(jnp.int32)
 
 
-def _round_compute(start, here, token, ends, counts, x, weight, w_gate,
-                   w_up, w_down, rows, dtype):
-  """One round of `held_experts_ffn`: the sorted assignments
-  `start .. start + rows - 1`, gathered, through the grouped gated
-  unit, scattered back onto their tokens. [N, M] float32."""
+def _round_index(start, here, token, weight, ends, counts, rows):
+  """What the round `start .. start + rows - 1` of the sorted
+  assignments works on: its groups' sizes [H], its rows' tokens and
+  weights [rows] and which of its rows hold a held assignment
+  [rows, 1]."""
   sizes = _group_sizes(start, ends, counts, rows)
   tok = jax.lax.dynamic_slice(token, (start,), (rows,))
+  wt = jax.lax.dynamic_slice(weight, (start,), (rows,))
   # Past the last held assignment a row belongs to no group.
   valid = (start + jnp.arange(rows) < here)[:, None]
-  wt = jax.lax.dynamic_slice(weight, (start,), (rows,))
-  xs = x[tok]
+  return sizes, tok, wt, valid
+
+
+def _gated_units(xs, wt, w_gate, w_up, w_down, sizes, valid, dtype):
+  """A round's gathered rows through their experts' gated units, times
+  their weights. [rows, M] float32."""
 
   def grouped(lhs, rhs):
     # A row of no group is nobody's to write: the TPU's grouped
@@ -365,49 +370,89 @@ def _round_compute(start, here, token, ends, counts, x, weight, w_gate,
 
   hidden = (jax.nn.silu(grouped(xs, w_gate))
             * grouped(xs, w_up)).astype(dtype)
-  ys = grouped(hidden, w_down) * wt[:, None]
-  return jnp.zeros(x.shape, jnp.float32).at[tok].add(ys)
+  return grouped(hidden, w_down) * wt[:, None]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(10, 11))
-def _round(start, here, token, ends, counts, x, weight, w_gate, w_up,
-           w_down, rows, dtype):
-  """`_round_compute` where the round holds a held assignment, zeros
-  where it starts past the last one. Its own gradient rule: the
-  backward pass recomputes the round from its inputs (its buffers live
-  once, forward and backward) and skips it as the forward pass did; a
-  `lax.cond` differentiated by JAX would hand every round's inputs on
-  as that round's own residuals."""
-  return jax.lax.cond(
-      start < here,
-      lambda: _round_compute(start, here, token, ends, counts, x, weight,
-                             w_gate, w_up, w_down, rows, dtype),
-      lambda: jnp.zeros(x.shape, jnp.float32))
+def _round_compute(out, start, here, token, ends, counts, x, weight,
+                   w_gate, w_up, w_down, rows, dtype):
+  """One round of `held_experts_ffn`: the sorted assignments
+  `start .. start + rows - 1`, gathered, through the grouped gated
+  units, scatter-added onto their tokens' rows of `out` [N, M]
+  float32."""
+  sizes, tok, wt, valid = _round_index(start, here, token, weight, ends,
+                                       counts, rows)
+  ys = _gated_units(x[tok], wt, w_gate, w_up, w_down, sizes, valid, dtype)
+  return out.at[tok].add(ys)
 
 
-def _round_fwd(start, here, token, ends, counts, x, weight, w_gate, w_up,
-               w_down, rows, dtype):
-  args = (start, here, token, ends, counts, x, weight, w_gate, w_up,
-          w_down)
-  return _round(*args, rows, dtype), args
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+def _rounds(here, token, ends, counts, x, weight, w_gate, w_up, w_down,
+            rows, dtype):
+  """Every round that holds a held assignment, one after the other:
+  a loop of as many iterations as that, its carry the layer's result,
+  which each round adds to where its rows land. -> (the result [N, M]
+  float32, the rows that the rounds' grouped products were given, the
+  rounds that ran).
+
+  Its own gradient rule (`_rounds_bwd`): a loop's length that the
+  routing decides cannot be differentiated by JAX, and the rule keeps
+  nothing of a round: the residuals are the inputs, whatever the number
+  of rounds."""
+
+  def body(carry):
+    done, out, given = carry
+    start = done * rows
+    out = _round_compute(out, start, here, token, ends, counts, x,
+                         weight, w_gate, w_up, w_down, rows, dtype)
+    # What the grouped products leave out of a group they do not write.
+    given += jnp.sum(_group_sizes(start, ends, counts, rows))
+    return done + 1, out, given
+
+  done, out, given = jax.lax.while_loop(
+      lambda carry: carry[0] * rows < here, body,
+      (jnp.zeros((), jnp.int32), jnp.zeros(x.shape, jnp.float32),
+       jnp.zeros((), jnp.int32)))
+  return out, given, done
 
 
-def _round_bwd(rows, dtype, args, cotangent):
-  index, floats = args[:5], args[5:]
+def _rounds_fwd(here, token, ends, counts, x, weight, w_gate, w_up,
+                w_down, rows, dtype):
+  args = (here, token, ends, counts, x, weight, w_gate, w_up, w_down)
+  return _rounds(*args, rows, dtype), args
 
-  def grads():
+
+def _rounds_bwd(rows, dtype, args, cotangents):
+  """The same rounds again, the five cotangents as the loop's carry: a
+  round computes its forward pass anew from the inputs, and its
+  contribution is added where it lands: a token's rows of `dx`, the
+  round's own slice of `dweight`, the whole of each expert matrix's."""
+  here, token, ends, counts, x, weight, w_gate, w_up, w_down = args
+  d_out = cotangents[0]  # the two counts have none
+
+  def body(carry):
+    done, dx, dweight, d_experts = carry
+    start = done * rows
+    sizes, tok, wt, valid = _round_index(start, here, token, weight, ends,
+                                         counts, rows)
     _, vjp = jax.vjp(
-        lambda *floats: _round_compute(*index, *floats, rows, dtype),
-        *floats)
-    return vjp(cotangent)
+        lambda xs, wt, *experts: _gated_units(xs, wt, *experts, sizes,
+                                              valid, dtype),
+        x[tok], wt, w_gate, w_up, w_down)
+    dxs, dwt, *d_round = vjp(d_out[tok])
+    return (done + 1, dx.at[tok].add(dxs),
+            jax.lax.dynamic_update_slice(dweight, dwt, (start,)),
+            tuple(total + part for total, part in zip(d_experts,
+                                                      d_round)))
 
-  out = jax.lax.cond(
-      index[0] < index[1], grads,
-      lambda: tuple(jnp.zeros_like(value) for value in floats))
-  return (None,) * len(index) + tuple(out)
+  _, dx, dweight, d_experts = jax.lax.while_loop(
+      lambda carry: carry[0] * rows < here, body,
+      (jnp.zeros((), jnp.int32), jnp.zeros_like(x),
+       jnp.zeros_like(weight),
+       tuple(jnp.zeros_like(w) for w in (w_gate, w_up, w_down))))
+  return (None,) * 4 + (dx, dweight) + d_experts
 
 
-_round.defvjp(_round_fwd, _round_bwd)
+_rounds.defvjp(_rounds_fwd, _rounds_bwd)
 
 
 def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, *,
@@ -422,9 +467,10 @@ def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, *,
   Dropless with static shapes: the N * k assignments are sorted by
   expert (those not held here sort last), and the sorted rows are
   worked off in rounds of `round_rows` rows, each one gather, three
-  grouped matrix products and one scatter-add; a round that starts
-  past the last held assignment is skipped, so uniform routing runs
-  one round and a router that sends everything here runs them all.
+  grouped matrix products and one scatter-add. Only the rounds that
+  hold a held assignment run (`_rounds`: one loop forward, one
+  backward), so uniform routing pays for one round and a router that
+  sends everything here for all of them.
   """
   n, k = experts.shape
   held = w_gate.shape[0]
@@ -433,25 +479,16 @@ def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, *,
   local = experts.reshape(-1) - first_expert
   local = jnp.where((local >= 0) & (local < held), local, held)
   order = jnp.argsort(local, stable=True)
-  token = (order // k).astype(jnp.int32)
-  weight = weights.reshape(-1)[order]
+  # Whole rounds: the last one's rows past the assignments are no
+  # group's (nothing where `rows` divides the assignments).
+  spare = -total % rows
+  token = jnp.pad((order // k).astype(jnp.int32), (0, spare))
+  weight = jnp.pad(weights.reshape(-1)[order], (0, spare))
   counts = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
   ends = jnp.cumsum(counts)
   here = ends[-1]
-  x = x.astype(dtype)
-
-  def body(carry, start):
-    out, done = carry
-    out = out + _round(start, here, token, ends, counts, x, weight,
-                       w_gate, w_up, w_down, rows, dtype)
-    # The rows that this round's grouped products were given, if it
-    # ran: what they leave out of a group they do not write.
-    given = jnp.sum(_group_sizes(start, ends, counts, rows))
-    return (out, done + jnp.where(start < here, given, 0)), None
-
-  (out, done), _ = jax.lax.scan(
-      body, (jnp.zeros(x.shape, jnp.float32), jnp.zeros((), jnp.int32)),
-      jnp.arange(0, total, rows, dtype=jnp.int32))
+  out, given, rounds = _rounds(here, token, ends, counts, x.astype(dtype),
+                               weight, w_gate, w_up, w_down, rows, dtype)
   load = counts.astype(jnp.float32)
   counters = {
       # Of all assignments, those on experts held here.
@@ -459,7 +496,9 @@ def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, *,
       "expert_load_max_over_mean":
           jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
       # Held assignments that lay in no group of any round that ran.
-      "dropped_assignments": (here - done).astype(jnp.float32),
+      "dropped_assignments": (here - given).astype(jnp.float32),
+      # The rounds that held a held assignment, and so ran.
+      "rounds_run": rounds.astype(jnp.float32),
   }
   return out, counters
 

@@ -22,6 +22,7 @@ sys.path.insert(0, ROOT)
 from benchmark.harness import lm_flops  # noqa: E402
 from benchmark.layer_metrics import (  # noqa: E402
     lm_expert_load_max_over_mean,
+    lm_moe_rounds_run,
     lm_step_mfu,
 )
 from benchmark.reference import qwen3_next as ref  # noqa: E402
@@ -148,16 +149,20 @@ def _run_record(records, trace=None):
 def test_lm_readers_on_made_up_records():
   none = _run_record([{"step": 2, "loss": 1.0}])
   assert lm_expert_load_max_over_mean.read(none) is None
+  assert lm_moe_rounds_run.read(none) is None  # the parent's records
   assert lm_step_mfu.read(none) is None  # untraced
   records = [{"moe.expert_load_max_over_mean": 1.1,
-              "moe.assignments_here_share": 0.0625},
+              "moe.assignments_here_share": 0.0625,
+              "moe.rounds_run": 1.0},
              {"moe.expert_load_max_over_mean": 1.3,
-              "moe.assignments_here_share": 0.0625}]
+              "moe.assignments_here_share": 0.0625,
+              "moe.rounds_run": 2.0}]
   # Two whole programs of two steps each in 4.66 s of device time: a
   # step of 45.9 TFLOP in 1.165 s is a fifth of 197 TFLOP/s.
   run = _run_record(records, {"program_runs": 2,
                               "program_busy_s": 4.66})
   assert lm_expert_load_max_over_mean.read(run) == pytest.approx(1.2)
+  assert lm_moe_rounds_run.read(run) == pytest.approx(1.5)
   assert lm_step_mfu.read(run) == pytest.approx(20.0, abs=0.1)
   cut = _run_record(records, {"program_runs": 0, "program_busy_s": 0.0})
   assert lm_step_mfu.read(cut) is None

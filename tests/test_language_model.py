@@ -83,6 +83,7 @@ def test_loss_and_gradients_equal_the_references(remat_policy):
     assert err < 5e-4, (name, err)
   assert float(scalars["moe.dropped_assignments"]) == 0.0
   assert 0.3 < float(scalars["moe.assignments_here_share"]) < 0.7
+  assert float(scalars["moe.rounds_run"]) == 1.0  # the worst layer's
   # One precision lower is another number.
   control = ref.loss(config, params, {},
                      {"features": {"token_ids": ids}}, None,
@@ -169,7 +170,8 @@ def test_rehearsed_cell_is_correct(capsys, monkeypatch):
   assert result["correct"] is True, lines
   assert result["failed"] == 0 and result["attempted"] > 0
   assert len(result["check"]) >= 5
-  assert "lm_expert_load_max_over_mean" in result["metric_names"]
+  assert {"lm_expert_load_max_over_mean", "lm_moe_rounds_run"} <= set(
+      result["metric_names"])
   # The cell saves every 1000 steps: its kind's window closes on a
   # whole log period, and no save falls in it.
   window = json.loads(next(line for line in lines

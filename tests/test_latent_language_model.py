@@ -328,6 +328,7 @@ def test_loss_and_gradients_equal_the_references(monkeypatch,
   assert float(scalars["moe.dropped_assignments"]) == 0.0
   assert 0.3 < float(scalars["moe.assignments_here_share"]) < 0.7
   assert 0.0 < float(scalars["moe.bias_moved_choice_share"]) < 0.3
+  assert float(scalars["moe.rounds_run"]) == 1.0  # the worst layer's
   # One precision lower is another number, as a whole and in parts.
   for control in (True, "attention", "router"):
     lowered = ref.loss(CONFIG, params, {}, batch, None,
@@ -435,10 +436,10 @@ def test_rehearsed_cell_is_correct(capsys, monkeypatch):
   # ISSUE 37's share is read off the checkpoints' policy: the shipped
   # gin file's `save_attention`, whatever backend the mixer took.
   # ISSUE 38's seven read the account of the start that the loop closed
-  # at its first log.
+  # at its first log; ISSUE 39's the rounds that the expert layers ran.
   assert result["metric_names"] == [
       "lm_attention_saved_share", "lm_mla_flash_share",
-      "startup_cache_hit_share", "startup_compile_s",
+      "lm_moe_rounds_run", "startup_cache_hit_share", "startup_compile_s",
       "startup_first_metrics_s", "startup_init_state_s",
       "startup_programs", "startup_restore_s", "startup_unnamed_share"]
   window = json.loads(next(line for line in lines
@@ -512,10 +513,16 @@ def test_benchmark_json_has_the_new_entries_and_no_other():
       "name": CELL, "config": "joyai_llm_flash_ep16",
       "traffic": "train_eval", "chips": 1,
       "why": bench["workloads"][-1]["why"]}
-  # ISSUE 38 appended start-up's seven, which every cell reports.
+  # ISSUE 39 appended the rounds that both families' expert layers
+  # run; ISSUE 38 start-up's seven, which every cell reports.
+  assert bench["per_layer"][-1] == {
+      "name": "lm_moe_rounds_run", "unit": "count", "better": "lower",
+      "source": "program_counter", "layer": "expert layer",
+      "moves": "train_steps_per_s",
+      "workloads": ["qwen3next_80b_a3b_ep16.train_eval", CELL]}
   startup = [m for m in bench["per_layer"] if m["layer"] == "start-up"]
-  assert bench["per_layer"][-7:] == startup
-  per_layer = bench["per_layer"][:-7]
+  assert bench["per_layer"][-8:-1] == startup
+  per_layer = bench["per_layer"][:-8]
   assert [m["name"] for m in per_layer[-3:]] == [
       "lm_mla_step_mfu", "lm_mla_flash_share",
       "lm_attention_saved_share"]

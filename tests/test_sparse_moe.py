@@ -15,6 +15,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+from benchmark.layer_metrics import lm_moe_rounds_run  # noqa: E402
 from benchmark.reference import qwen3_next as ref  # noqa: E402
 from tensor2robot_tpu.parallel import moe  # noqa: E402
 
@@ -143,6 +144,11 @@ def test_nothing_is_dropped_under_a_router_pushed_onto_one_expert(
     rows = moe.round_rows(tokens * K, held, EXPERTS)
     here = counters["assignments_here_share"] * tokens * K
     assert held == EXPERTS or here > rows  # more than one round ran
+    assert counters["rounds_run"] == -(-round(here) // rows)
+    # What the benchmark's reader makes of such steps' log records.
+    records = [{"moe.rounds_run": counters["rounds_run"]}] * 2
+    assert lm_moe_rounds_run.read({"records": records}) == (
+        1.0 if held == EXPERTS else 2.0)
     assert counters["expert_load_max_over_mean"] >= (
         2.0 if held == EXPERTS else 1.0)
 
@@ -225,6 +231,173 @@ def test_gradients_equal_the_references(monkeypatch, unwritten_rows):
   for a, b in zip(jax.tree_util.tree_leaves(got),
                   jax.tree_util.tree_leaves(want)):
     np.testing.assert_allclose(a, b, atol=3e-4, rtol=2e-3)
+
+
+# The loop over rounds alone, 1,500 tokens of 2 choices over 16 experts
+# of which this chip holds 4 and 5: rounds of 1,024 rows, which do not
+# divide the 3,000 assignments.
+ROUNDS = dict(tokens=1500, k=2, experts=16, held=2, first=4)
+
+
+def _routing(case, seed=9):
+  """(experts, weights) [N, k] of one way to route: `uniform` (the top
+  k of random scores), `pushed` (every token's first choice is one of
+  the two held experts, its second is not held) and `all_here` (both
+  choices of every token are the two held experts)."""
+  n, k, e = ROUNDS["tokens"], ROUNDS["k"], ROUNDS["experts"]
+  first, held = ROUNDS["first"], ROUNDS["held"]
+  scores, coin = jax.random.split(jax.random.PRNGKey(seed))
+  weights, experts = jax.lax.top_k(
+      jax.nn.softmax(jax.random.normal(scores, (n, e))), k)
+  heads = first + (jax.random.uniform(coin, (n,)) < 0.6).astype(jnp.int32)
+  if case == "pushed":
+    away = jnp.where(
+        (experts[:, 1] >= first) & (experts[:, 1] < first + held),
+        experts[:, 1] + held, experts[:, 1])
+    experts = jnp.stack([heads, away], axis=1)
+  elif case == "all_here":
+    experts = jnp.stack([heads, 2 * first + 1 - heads], axis=1)
+  return experts.astype(jnp.int32), weights / jnp.sum(weights, -1,
+                                                      keepdims=True)
+
+
+def _expert_weights(seed=10):
+  keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+  held = ROUNDS["held"]
+  return (jax.random.normal(keys[0], (held, WIDTH, F)) * 0.4,
+          jax.random.normal(keys[1], (held, WIDTH, F)) * 0.4,
+          jax.random.normal(keys[2], (held, F, WIDTH)) * 0.4)
+
+
+def _held(x, experts, weights, w_gate, w_up, w_down):
+  return moe.held_experts_ffn(
+      x, experts, weights, w_gate, w_up, w_down,
+      first_expert=ROUNDS["first"], num_experts=ROUNDS["experts"],
+      dtype=jnp.float32)
+
+
+def _plain_held(x, experts, weights, w_gate, w_up, w_down):
+  """The held experts one after the other over every token, each
+  weighted by what the token's choices give it: the reference's loop
+  with masks (`ref._ffn`), the routing handed in."""
+  out = jnp.zeros_like(x)
+  for e in range(ROUNDS["held"]):
+    weight = jnp.sum(
+        jnp.where(experts == ROUNDS["first"] + e, weights, 0.0), axis=-1)
+    out += weight[:, None] * ref._gated_unit(
+        x, w_gate[e], w_up[e], w_down[e], False)
+  return out
+
+
+@pytest.mark.parametrize("unwritten_rows", [False, True])
+@pytest.mark.parametrize("case,rounds", [
+    ("uniform", 1), ("pushed", 2), ("all_here", 3)])
+def test_the_rounds_that_run_equal_the_reference(monkeypatch, case,
+                                                 rounds, unwritten_rows):
+  """Output and the five gradients, whatever the number of rounds that
+  the routing makes run, also where the grouped product leaves the
+  rows of no group unwritten, as the TPU's does; `rounds_run` is the
+  rounds that hold a held assignment."""
+  if unwritten_rows:
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        _unwritten_rows_ragged_dot(jax.lax.ragged_dot))
+  n, k = ROUNDS["tokens"], ROUNDS["k"]
+  x = jax.random.normal(jax.random.PRNGKey(11), (n, WIDTH))
+  experts, weights = _routing(case)
+  args = (x, weights) + _expert_weights()
+  probe = jax.random.normal(jax.random.PRNGKey(12), (n, WIDTH))
+
+  def program(x, weights, *matrices):
+    out, counters = _held(x, experts, weights, *matrices)
+    return jnp.sum(out * probe), (out, counters)
+
+  def reference(x, weights, *matrices):
+    out = _plain_held(x, experts, weights, *matrices)
+    return jnp.sum(out * probe), out
+
+  got_grads, (got, counters) = jax.grad(
+      program, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+  want_grads, want = jax.grad(
+      reference, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+  np.testing.assert_allclose(got, want, atol=3e-5, rtol=2e-4)
+  for name, a, b in zip(("x", "weights", "gate", "up", "down"),
+                        got_grads, want_grads):
+    np.testing.assert_allclose(a, b, atol=3e-4, rtol=2e-3, err_msg=name)
+  rows = moe.round_rows(n * k, ROUNDS["held"], ROUNDS["experts"])
+  local = np.asarray(experts) - ROUNDS["first"]
+  here = int(np.sum((local >= 0) & (local < ROUNDS["held"])))
+  assert rows == 1024 and (n * k) % rows
+  assert counters["rounds_run"] == -(-here // rows) == rounds
+  assert counters["dropped_assignments"] == 0.0
+  assert counters["assignments_here_share"] == pytest.approx(
+      here / (n * k))
+
+
+def _equations(jaxpr):
+  """Every equation of a jaxpr and of the jaxprs inside it."""
+  for eqn in jaxpr.eqns:
+    yield eqn
+    for value in eqn.params.values():
+      for inner in value if isinstance(value, (tuple, list)) else (value,):
+        inner = getattr(inner, "jaxpr", inner)
+        if hasattr(inner, "eqns"):
+          yield from _equations(inner)
+
+
+def test_the_gradients_program_loops_over_the_rounds_that_run():
+  """In the program of the layer's gradient the rounds are two loops
+  whose length the routing decides, one forward and one backward: no
+  scan over all the rounds there could be, no conditional that skips
+  one, and nothing the size of the layer's result made inside a
+  loop."""
+  n = ROUNDS["tokens"]
+  x = jax.random.normal(jax.random.PRNGKey(11), (n, WIDTH))
+  experts, weights = _routing("pushed")
+
+  def program(x, weights, *matrices):
+    return jnp.sum(_held(x, experts, weights, *matrices)[0] ** 2)
+
+  jaxpr = jax.make_jaxpr(jax.grad(program, argnums=(0, 1, 2, 3, 4)))(
+      x, weights, *_expert_weights()).jaxpr
+  equations = list(_equations(jaxpr))
+  names = [eqn.primitive.name for eqn in equations]
+  assert names.count("while") == 2
+  assert "scan" not in names and "cond" not in names
+  assert names.count("ragged_dot_general") == 3 + 9  # one round each way
+  for loop in (eqn for eqn in equations if eqn.primitive.name == "while"):
+    # A loop's length is read off the routing, not a constant.
+    assert loop.params["cond_nconsts"] > 0
+    body = list(_equations(loop.params["body_jaxpr"].jaxpr))
+    assert sum(eqn.primitive.name == "ragged_dot_general"
+               for eqn in body) in (3, 9)
+    made = [eqn for eqn in body
+            if eqn.primitive.name in ("broadcast_in_dim", "iota", "full")]
+    assert made and not any(
+        out.aval.shape == (n, WIDTH) for eqn in made
+        for out in eqn.outvars)
+
+
+def test_one_round_running_is_round_compute_called_once():
+  """Under uniform routing the layer is its first round, to the last
+  bit: nothing is added to it and nothing is summed in another
+  order."""
+  n, k, held = ROUNDS["tokens"], ROUNDS["k"], ROUNDS["held"]
+  x = jax.random.normal(jax.random.PRNGKey(11), (n, WIDTH))
+  experts, weights = _routing("uniform")
+  matrices = _expert_weights()
+  got, counters = _held(x, experts, weights, *matrices)
+  assert counters["rounds_run"] == 1.0
+  local = np.asarray(experts).reshape(-1) - ROUNDS["first"]
+  local = np.where((local >= 0) & (local < held), local, held)
+  order = np.argsort(local, kind="stable")
+  counts = np.bincount(local, minlength=held + 1)[:held].astype(np.int32)
+  rows = moe.round_rows(n * k, held, ROUNDS["experts"])
+  once = moe._round_compute(
+      jnp.zeros((n, WIDTH), jnp.float32), 0, int(counts.sum()),
+      jnp.asarray(order // k, jnp.int32), jnp.cumsum(counts), counts, x,
+      weights.reshape(-1)[order], *matrices, rows, jnp.float32)
+  np.testing.assert_array_equal(np.asarray(got), np.asarray(once))
+  assert float(jnp.max(jnp.abs(got))) > 0.1
 
 
 def test_round_rows_is_twice_the_uniform_share_in_whole_tiles():
