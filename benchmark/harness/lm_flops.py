@@ -90,3 +90,77 @@ def step_flops(model: dict, batch: int,
       model, assignments_here_share).values())
   return 3.0 * forward * batch * model["sequence_length"]
 
+
+
+def attention_kernel_costs(model: dict, batch: int, positions: int,
+                           bytes_per_element: int = 2
+                           ) -> Dict[str, Dict[str, float]]:
+  """One call of each Pallas program of `ops/flash_attention.py` under
+  `GatedAttention`, on `batch` rows of `positions` positions, causal:
+  the FLOPs of the products it makes over the pairs the mask keeps
+  (the backward programs make the scores, and dO V^T, anew each), for
+  all `num_attention_heads` query heads, and the bytes it must move at
+  the least: each operand read once, each result written once, the
+  two row vectors (logsumexp, delta) in float32. Grouped queries: the
+  mathematics has `num_key_value_heads` keys and values of `head_dim`,
+  so k, v, dk and dv count at that many heads, whatever the program
+  repeats before the kernel."""
+  s = _dims(model)
+  d = s["d"]
+  pairs = batch * s["h"] * positions * (positions + 1) / 2
+  queries = batch * s["h"] * positions * d * bytes_per_element
+  keys = batch * s["kv"] * positions * d * bytes_per_element
+  row_vector = batch * s["h"] * positions * 4
+  return {
+      # s = q k^T; o = p v.  Reads q, k, v; writes o and the logsumexp.
+      "forward": {"flops": pairs * 2 * 2 * d,
+                  "bytes": 2 * queries + 2 * keys + row_vector},
+      # s; dv = p^T dO; dp = dO v^T; dk = ds^T q.  Reads q, k, v, dO
+      # and the two row vectors; writes dk and dv.
+      "dkdv": {"flops": pairs * 2 * 4 * d,
+               "bytes": 2 * queries + 4 * keys + 2 * row_vector},
+      # s; dp; dq = ds k.  Reads the same; writes dq.
+      "dq": {"flops": pairs * 2 * 3 * d,
+             "bytes": 3 * queries + 2 * keys + 2 * row_vector},
+  }
+
+
+def walk_kernel_costs(model: dict, rows: int, positions: int,
+                      bytes_per_element: int = 2
+                      ) -> Dict[str, Dict[str, float]]:
+  """One call of each Pallas program of `ops/delta_rule_walk.py` on
+  `rows` rows of `positions` positions, all value heads, in chunks of
+  `CHUNK`: the FLOPs of its products and the bytes it must move at the
+  least, a head and chunk (C = CHUNK, the state [dk, dv] stays on the
+  chip; `writes`, `new`, `carried` and their cotangents are float32
+  [C, dv], the three key operands and their cotangents [C, dk] in the
+  products' type, `end_decay` and its cotangent one float32):
+
+    forward: k_decayed S, q_decayed S, k_to_end^T new: 3 products of
+      2 C dk dv. Reads `writes` and the three operands, writes `new`
+      and `carried`: 144 KB at the cell's widths in bfloat16.
+    forward_saving_states: the same, and the state at the chunk's
+      start written in float32 for the backward program (208 KB): what
+      a forward call costs that a backward call follows.
+    backward: six products of 2 C dk dv. Reads the three operands,
+      `new`, the saved state, d `new`, d `carried`; writes d `writes`
+      and the three operands' cotangents (288 KB).
+  """
+  s = _dims(model)
+  dk, dv, c = s["dk"], s["dv"], CHUNK
+  units = rows * s["hv"] * -(-positions // c)  # heads times chunks
+  product = 2 * c * dk * dv
+  wide = c * dv * 4  # a float32 [C, dv] tile
+  operand = c * dk * bytes_per_element
+  state = dk * dv * 4
+  forward = wide + 3 * operand + 4 + 2 * wide
+  return {
+      "forward": {"flops": units * 3 * product,
+                  "bytes": units * forward},
+      "forward_saving_states": {"flops": units * 3 * product,
+                                "bytes": units * (forward + state)},
+      "backward": {"flops": units * 6 * product,
+                   "bytes": units * (3 * operand + 4 + wide + state
+                                     + 2 * wide + wide + 3 * operand
+                                     + 4)},
+  }

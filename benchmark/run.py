@@ -17,6 +17,7 @@ import time
 CLOCK_START = time.perf_counter()  # set-up counts from here
 
 import argparse
+import gc
 import importlib
 import json
 import os
@@ -159,7 +160,11 @@ def main() -> int:
         key: run.get(key) for key in ("steps", "window_s",
                                       "checkpoint_stalls_ms")}))
     # The reference runs now, after the program's state is freed, so
-    # that memory_peak_bytes above is the program's alone.
+    # that memory_peak_bytes above is the program's alone. Freed: the
+    # exception that closed the window, its traceback and the loop's
+    # frame stand in a reference cycle that holds the state's arrays
+    # until a collection (PERF.md §6, PR 35 and 40).
+    gc.collect()
     from benchmark.harness import check
     t_check = time.perf_counter()
     if "limits" in config:  # only a rehearsal's tiny sizes have them

@@ -2,7 +2,8 @@
 the leaf-by-leaf comparison (`harness/check.py`): the step donates its
 state and computes what it computed undonated; the numbers compared
 equal those of the whole-tree arithmetic they replaced to the last
-digit; `tools/follow_memory.py` runs."""
+digit; `tools/follow_memory.py` runs; the check starts with the loop's
+state freed."""
 
 import jax
 import jax.numpy as jnp
@@ -219,3 +220,45 @@ def test_follow_memory_runs_a_tiny_size():
   if out["platform"] == "cpu":  # no device peak under a CPU's name
     assert out["peak_bytes_in_use"] is None
     assert "device_bytes_per_parameter" not in out
+
+
+def test_the_check_starts_with_the_loops_state_freed(capsys,
+                                                     monkeypatch):
+  """A whole run of the stand-in (`run.main`, rehearsed on the CPU):
+  when `driver.check` starts, no array that the run made is alive. The
+  exception that closes the window, its traceback and the trainer's
+  frame stand in a reference cycle with the state (57 arrays here, 7.6
+  to 8.2 GB in the language-model cells, where the reference then met
+  them on the device; PERF.md §6, PR 35 and 40): `run.py` collects
+  before the check."""
+  import gc
+  import os
+  import sys
+
+  from benchmark.harness import train_eval_driver
+
+  bench_file = os.path.join(run_lib.HERE, "tests", "data", "standin",
+                            "BENCHMARK.json")
+  monkeypatch.setattr(sys, "argv", [
+      "run.py", "--bench-file", bench_file, "--workload",
+      "standin.train_eval", "--seed", "2147483659", "--seconds", "1",
+      "--trace", "0", "--rehearse-cpu"])
+  gc.collect()
+  before = jax.live_arrays()  # other tests': held, so no id comes again
+  known = {id(x) for x in before}
+  alive = []
+  check_of = train_eval_driver.check
+
+  def check_and_count(cell_name, config, run, limits):
+    alive.append([x.shape for x in jax.live_arrays()
+                  if id(x) not in known])
+    return check_of(cell_name, config, run, limits)
+
+  monkeypatch.setattr(train_eval_driver, "check", check_and_count)
+  gc.disable()  # no collection by chance between the loop and the check
+  try:
+    assert run_lib.main() == 0
+  finally:
+    gc.enable()
+  capsys.readouterr()
+  assert alive == [[]]
