@@ -36,11 +36,20 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from tensor2robot_tpu.ops import delta_rule_walk
 from tensor2robot_tpu.telemetry import metrics as tmetrics
 
 HIGH = jax.lax.Precision.HIGH
+
+# What `GatedDeltaNet` names of a block for a checkpoint to keep, as
+# `flash_attention.SAVED_RESIDUAL_NAMES` does for an attention block:
+# the rule's output after the gated norm, as `out_proj` reads it,
+# 2 B x tokens x value width in bfloat16. (The rule's float32 output
+# before the norm is twice that, and rounding it there would change
+# what the norm sees.)
+SAVED_RESIDUAL_NAMES = ("gated_delta_normed_out",)
 
 
 def causal_depthwise_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
@@ -252,17 +261,30 @@ class GatedDeltaNet(nn.Module):
       # A row of the batch at a time, each under `jax.checkpoint`: the
       # rule's intermediates (a dozen arrays of the size of v, the
       # state at every chunk) then stand for one row, forward and
-      # backward; the heads and chunks of one row fill the chip.
+      # backward; the heads and chunks of one row fill the chip. The
+      # gated RMS norm is inside with the rule, so that a row hands on
+      # what `out_proj` reads, [T, value_dim] in `dtype` (the cast is
+      # the one `nn.Dense` makes), and the norm's backward finds the
+      # rule's float32 output in the row's own recomputation.
       @jax.checkpoint
       def rule(row):
-        return gated_delta_rule(*(y[None] for y in row),
-                                chunk=self.chunk, dtype=self.dtype)[0]
+        *operands, z_row = row
+        out = gated_delta_rule(*(y[None] for y in operands),
+                               chunk=self.chunk, dtype=self.dtype)[0]
+        # Gated RMS norm per head: plain weight, gate through SiLU.
+        out = out * jax.lax.rsqrt(
+            jnp.mean(jnp.square(out), -1, keepdims=True) + self.eps)
+        out = norm * out * nn.silu(
+            z_row.reshape(t, hv, dv).astype(jnp.float32))
+        return out.reshape(t, value_dim).astype(self.dtype)
 
-      out = jax.lax.map(rule, (q, k, v.reshape(b, t, hv, dv), g, beta))
-      # Gated RMS norm per head: plain weight, gate through SiLU.
-      out = out * jax.lax.rsqrt(
-          jnp.mean(jnp.square(out), -1, keepdims=True) + self.eps)
-      out = norm * out * nn.silu(
-          z.reshape(b, t, hv, dv).astype(jnp.float32))
+      out = jax.lax.map(
+          rule, (q, k, v.reshape(b, t, hv, dv), g, beta, z))
+      # Named outside the map: a block's checkpoint whose policy saves
+      # the name (`transformer.apply_block`, `save_attention`) then
+      # recomputes the block without the map, so the rule runs forward
+      # twice for a backward pass (here and in `rule`'s own
+      # recomputation) and not three times.
+      out = checkpoint_name(out, SAVED_RESIDUAL_NAMES[0])
     return nn.Dense(width, use_bias=False, dtype=self.dtype,
-                    name="out_proj")(out.reshape(b, t, value_dim))
+                    name="out_proj")(out)

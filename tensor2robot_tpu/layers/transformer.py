@@ -368,14 +368,19 @@ class SequenceTrunk(nn.Module):
   one block's activations at a time. Four policies: `full` saves
   nothing of a block (the choice at the memory limit); `dots` and
   `dots_no_batch` as `AbstractT2RModel.remat_policy` names them;
-  `save_attention` saves what the flash kernel returned and nothing
-  else (`ops/flash_attention.SAVED_RESIDUAL_NAMES`: the output,
+  `save_attention` saves what the mixer's core returned and nothing
+  else: of an attention block what the flash kernel returned
+  (`ops/flash_attention.SAVED_RESIDUAL_NAMES`: the output,
   2 B x tokens x heads x value width in bfloat16, and the float32
-  logsumexp, 4 B x tokens x heads, a block), so the backward pass
-  runs every line of the block again but the forward kernel, whose
-  two results are its backward's residuals. A block whose mixer took
-  materialised attention, or is no attention, carries no such name:
-  under `save_attention` it is the program of `full`."""
+  logsumexp, 4 B x tokens x heads), of a Gated-DeltaNet block the
+  rule's output after its gated norm, as `out_proj` reads it
+  (`gated_delta.SAVED_RESIDUAL_NAMES`: 2 B x tokens x value width in
+  bfloat16). The backward pass then runs every line of the block
+  again but that core: the forward kernel, whose two results are its
+  backward's residuals, or the delta rule's map over the rows, which
+  keeps its own recomputation a row. A block whose mixer took
+  materialised attention carries no such name: under `save_attention`
+  it is the program of `full`."""
 
   blocks: Tuple[nn.Module, ...]
   remat_policy: Optional[str] = None
@@ -392,10 +397,12 @@ def apply_block(block: nn.Module, x: jax.Array, train: bool,
   """`block(x, train)`, under `jax.checkpoint` where `remat_policy`
   names one (`SequenceTrunk`, which says what each saves). The
   registry's counters `trunk.checkpoint.attention_saved_blocks` and
-  `.recomputed_blocks` count the traced blocks whose policy keeps the
-  flash kernel's residuals, and those whose policy does not."""
+  `.recomputed_blocks` count the traced blocks whose policy keeps what
+  the mixer's core returned, and those whose policy does not: by the
+  policy, whatever the block's mixer."""
   if remat_policy in (None, "none"):
     return block(x, train)
+  from tensor2robot_tpu.layers import gated_delta
   from tensor2robot_tpu.ops.flash_attention import SAVED_RESIDUAL_NAMES
   policies = jax.checkpoint_policies
   policy = {
@@ -403,7 +410,7 @@ def apply_block(block: nn.Module, x: jax.Array, train: bool,
       "dots": policies.checkpoint_dots,
       "dots_no_batch": policies.dots_with_no_batch_dims_saveable,
       "save_attention": policies.save_only_these_names(
-          *SAVED_RESIDUAL_NAMES),
+          *SAVED_RESIDUAL_NAMES, *gated_delta.SAVED_RESIDUAL_NAMES),
   }[remat_policy]
   tmetrics.counter("trunk.checkpoint.attention_saved_blocks"
                    if remat_policy == "save_attention"

@@ -284,6 +284,24 @@ def test_flash_kernels_compile_for_a_v5e_at_the_latent_widths(one_chip):
   assert (dq.shape[-1], dk.shape[-1], dv.shape[-1]) == (192, 192, 128)
 
 
+def _compile_block_gradient(one_chip, block, rows, policy):
+  """The gradient of one block's trunk under `policy` over `rows` rows
+  of 8,192 positions at hidden 2048, compiled for the chip."""
+  from tensor2robot_tpu.layers import transformer
+  trunk = transformer.SequenceTrunk(blocks=(block,), remat_policy=policy)
+  x = jax.ShapeDtypeStruct((rows, 8192, 2048), jnp.float32,
+                           sharding=one_chip)
+  params = jax.tree_util.tree_map(
+      lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                        sharding=one_chip),
+      jax.eval_shape(trunk.init, jax.random.PRNGKey(0), x))
+
+  def loss(params, x):
+    return jnp.sum(jnp.square(trunk.apply(params, x)))
+
+  return _compile_for_the_chip(jax.grad(loss), params, x)
+
+
 @pytest.mark.parametrize("policy,forward_calls", [
     ("full", 2), ("save_attention", 1)])
 def test_a_checkpointed_latent_block_compiles_for_a_v5e(
@@ -295,21 +313,39 @@ def test_a_checkpointed_latent_block_compiles_for_a_v5e(
   (ISSUE 37; the jaxpr's side of it: tests/test_sequence_layers.py)."""
   from tensor2robot_tpu.layers import transformer
 
-  trunk = transformer.SequenceTrunk(blocks=(transformer.TransformerBlock(
+  compiled = _compile_block_gradient(one_chip, transformer.TransformerBlock(
       norm="rms", mixer=transformer.LatentAttention(
           num_heads=32, q_lora_rank=1536, kv_lora_rank=512,
           qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
           attention_impl="flash"),
-      ffn=transformer.GatedMLP(width=7168)),), remat_policy=policy)
-  x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.float32,
-                           sharding=one_chip)
-  params = jax.tree_util.tree_map(
-      lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
-                                        sharding=one_chip),
-      jax.eval_shape(trunk.init, jax.random.PRNGKey(0), x))
-
-  def loss(params, x):
-    return jnp.sum(jnp.square(trunk.apply(params, x)))
-
-  compiled = _compile_for_the_chip(jax.grad(loss), params, x)
+      ffn=transformer.GatedMLP(width=7168)), rows=2, policy=policy)
   assert compiled.as_text().count("tpu_custom_call") == forward_calls + 2
+
+
+def test_a_checkpointed_delta_net_block_compiles_for_a_v5e(
+    one_chip, monkeypatch):
+  """One Gated-DeltaNet block at the Qwen3-Next cell's widths (1 row of
+  8,192 positions, 16 key heads under 32 value heads of 128) under a
+  checkpoint, its gradient compiled for the chip: under `full` the
+  walk's forward program three times (the block's forward pass, its
+  recomputation, the row's own recomputation, which writes the states)
+  beside the backward program, under `save_attention` twice (ISSUE 41;
+  the jaxpr's side of it: tests/test_sequence_layers.py). What
+  `save_attention` keeps is one array of 2 B x positions x value width:
+  the program's temporaries grow by no more than it, so there is no
+  second copy. The process that compiles sees a CPU: the test takes
+  the walk's choice of path for it."""
+  from tensor2robot_tpu.layers import transformer
+  monkeypatch.setattr(gated_delta, "_on_tpu", lambda: True)
+  full, saved = (_compile_block_gradient(
+      one_chip, transformer.TransformerBlock(
+          norm="rms", mixer=gated_delta.GatedDeltaNet(
+              num_k_heads=16, num_v_heads=32, head_k_dim=128,
+              head_v_dim=128),
+          ffn=transformer.GatedMLP(width=512)), rows=1, policy=policy)
+                 for policy in ("full", "save_attention"))
+  assert full.as_text().count("tpu_custom_call") == 3 + 1
+  assert saved.as_text().count("tpu_custom_call") == 2 + 1
+  kept = 2 * 8192 * 32 * 128
+  assert saved.memory_analysis().temp_size_in_bytes <= (
+      full.memory_analysis().temp_size_in_bytes + kept)

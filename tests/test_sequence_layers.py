@@ -147,6 +147,67 @@ def test_gated_delta_net_layer_equals_the_reference(t):
   np.testing.assert_allclose(got, want, atol=3e-5, rtol=1e-3)
 
 
+def _norm_after_the_map(layer, params, x):
+  """`GatedDeltaNet` as it stood before ISSUE 41: the rule alone mapped
+  over the rows, its float32 output normalised and gated for the whole
+  batch, and `out_proj` making the cast to `dtype`."""
+  import flax.linen as nn
+  b, t, width = x.shape
+  hk, hv = layer.num_k_heads, layer.num_v_heads
+  dk, dv = layer.head_k_dim, layer.head_v_dim
+  key_dim, value_dim = hk * dk, hv * dv
+
+  def dense(name, y, features):
+    return nn.Dense(features, use_bias=False, dtype=layer.dtype).apply(
+        {"params": params[name]}, y)
+
+  x = x.astype(layer.dtype)
+  qkvz = dense("in_proj_qkvz", x, 2 * key_dim + 2 * value_dim)
+  ba = dense("in_proj_ba", x, 2 * hv).astype(jnp.float32)
+  qkv, z = jnp.split(qkvz, [2 * key_dim + value_dim], axis=-1)
+  qkv = nn.silu(gated_delta.causal_depthwise_conv(
+      qkv, params["conv"].astype(layer.dtype)))
+  q, k, v = jnp.split(qkv, [key_dim, 2 * key_dim], axis=-1)
+  beta = jax.nn.sigmoid(ba[..., :hv])
+  g = -jnp.exp(params["A_log"]) * jax.nn.softplus(
+      ba[..., hv:] + params["dt_bias"])
+  q = gated_delta.l2_normalize(
+      q.reshape(b, t, hk, dk).astype(jnp.float32), layer.eps) * dk ** -0.5
+  k = gated_delta.l2_normalize(
+      k.reshape(b, t, hk, dk).astype(jnp.float32), layer.eps)
+  q, k = (jnp.repeat(y, hv // hk, axis=2) for y in (q, k))
+  out = jax.lax.map(
+      lambda row: gated_delta.gated_delta_rule(
+          *(y[None] for y in row), chunk=layer.chunk,
+          dtype=layer.dtype)[0],
+      (q, k, v.reshape(b, t, hv, dv), g, beta))
+  out = out * jax.lax.rsqrt(
+      jnp.mean(jnp.square(out), -1, keepdims=True) + layer.eps)
+  out = params["norm"] * out * nn.silu(
+      z.reshape(b, t, hv, dv).astype(jnp.float32))
+  return dense("out_proj", out.reshape(b, t, value_dim), width)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_gated_norm_inside_the_mapped_row_is_the_norm_after_the_map(
+    dtype):
+  """ISSUE 41 moved the gated norm and `out_proj`'s cast into the
+  function that is mapped over the rows: the same operations on the
+  same values, so the same bits, in the cell's bfloat16 too. Operation
+  by operation: compiled, XLA fuses a row's norm with the rule's last
+  product on a CPU, which moves float32's last bits."""
+  layer = gated_delta.GatedDeltaNet(
+      num_k_heads=2, num_v_heads=4, head_k_dim=8, head_v_dim=8,
+      chunk=16, dtype=dtype)
+  x = jax.random.normal(jax.random.PRNGKey(0), (3, 50, 32))
+  params = _randomised(layer.init(jax.random.PRNGKey(1), x)["params"], 2)
+  with jax.disable_jit():
+    got = layer.apply({"params": params}, x)
+    want = _norm_after_the_map(layer, params, x)
+  assert got.dtype == want.dtype == dtype
+  np.testing.assert_array_equal(got, want)
+
+
 @pytest.mark.parametrize("t", [40, 1024 + 40])
 def test_gated_attention_equals_the_reference(t):
   """Partial rotary (4 of 16 dims), two key-value heads under four
@@ -252,9 +313,11 @@ def test_a_block_without_mixer_or_ffn_is_the_block_it_always_was():
         jax.random.PRNGKey(0), jnp.zeros((1, 4, 16)))
 
 
-# A block's checkpoint that keeps what the flash kernel returned
-# (ISSUE 37): one block under each policy, its mixer through the Pallas
-# kernel (interpreted), through materialised attention, or no attention.
+# A block's checkpoint that keeps what the mixer's core returned: the
+# flash kernel's two results (ISSUE 37), the delta rule's output after
+# its gated norm (ISSUE 41). One block under each policy, its mixer
+# through its Pallas kernels (interpreted) or without them
+# (materialised attention; the rule's walk as a `lax.scan`).
 SAVED = "trunk.checkpoint.attention_saved_blocks"
 RECOMPUTED = "trunk.checkpoint.recomputed_blocks"
 
@@ -273,17 +336,20 @@ def _latent(impl):  # keys of 8 + 4 over values of 6: 192 over 128
 
 
 def _delta_net(impl):
-  del impl  # no attention, no kernel of it
+  del impl  # no attention: `_block_gradient` picks the walk by it
   return gated_delta.GatedDeltaNet(
       num_k_heads=2, num_v_heads=4, head_k_dim=8, head_v_dim=8, chunk=8,
       dtype=jnp.float32)
 
 
 def _block_gradient(monkeypatch, mixer, impl):
-  """(policy -> the gradient function of one block's trunk, its
-  parameters)."""
+  """(policy -> the function that gives one block's trunk's gradient
+  and output, its parameters)."""
   monkeypatch.setattr(ops, "flash_attention", functools.partial(
       ops.flash_attention, block_q=32, block_k=32, interpret=True))
+  if impl == "flash":  # the rule's walk through its kernel pair too
+    monkeypatch.setattr(gated_delta, "gated_delta_rule", functools.partial(
+        gated_delta.gated_delta_rule, interpret=True))
   x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 16))
 
   def trunk(policy):
@@ -292,8 +358,11 @@ def _block_gradient(monkeypatch, mixer, impl):
         dtype=jnp.float32),), remat_policy=policy)
 
   def gradient(policy):
-    return jax.grad(lambda params: jnp.sum(jnp.square(
-        trunk(policy).apply(params, x))))
+    def loss(params):
+      out = trunk(policy).apply(params, x)
+      return jnp.sum(jnp.square(out)), out
+
+    return jax.grad(loss, has_aux=True)
 
   return gradient, trunk(None).init(jax.random.PRNGKey(1), x)
 
@@ -311,38 +380,53 @@ def _kernel_calls(jaxpr, found=None):
   return found
 
 
-@pytest.mark.parametrize("mixer", [_gated, _latent])
-def test_save_attention_runs_the_forward_kernel_once(monkeypatch, mixer):
+FLASH_BACKWARD = {"_dkdv_kernel": 1, "_dq_kernel": 1}
+
+
+# The delta rule's forward kernel runs once more under every policy:
+# each row's own `jax.checkpoint` inside `GatedDeltaNet` (the program
+# that writes the states for the backward kernel).
+@pytest.mark.parametrize("mixer,forward,kept,backward", [
+    (_gated, "_flash_kernel", 1, FLASH_BACKWARD),
+    (_latent, "_flash_kernel", 1, FLASH_BACKWARD),
+    (_delta_net, "_forward_kernel", 2, {"_backward_kernel": 1})])
+def test_save_attention_runs_the_forward_kernel_once(
+    monkeypatch, mixer, forward, kept, backward):
   gradient, params = _block_gradient(monkeypatch, mixer, "flash")
   calls = {policy: _kernel_calls(
       jax.make_jaxpr(gradient(policy))(params).jaxpr)
            for policy in (None, "full", "save_attention")}
-  backward = {"_dkdv_kernel": 1, "_dq_kernel": 1}
-  assert calls["full"] == {"_flash_kernel": 2, **backward}
-  assert calls["save_attention"] == {"_flash_kernel": 1, **backward}
+  assert calls["full"] == {forward: kept + 1, **backward}
+  assert calls["save_attention"] == {forward: kept, **backward}
   assert calls[None] == calls["save_attention"]
 
 
-@pytest.mark.parametrize("mixer", [_gated, _latent])
+@pytest.mark.parametrize("mixer,impl", [
+    (_gated, "flash"), (_latent, "flash"), (_delta_net, "flash"),
+    (_delta_net, None)])
 def test_gradients_under_save_attention_are_those_under_full(
-    monkeypatch, mixer):
-  """Bit for bit: the saved arrays are what the second run of the
-  kernel gives. Without a checkpoint XLA fuses the block otherwise on
-  a CPU, as it does against `full`: the last bits."""
-  gradient, params = _block_gradient(monkeypatch, mixer, "flash")
-  full, saved, plain = (jax.tree_util.tree_leaves(
-      gradient(policy)(params))
-                        for policy in ("full", "save_attention", None))
-  for a, b, c in zip(saved, full, plain):
+    monkeypatch, mixer, impl):
+  """Bit for bit, and the block's output with them: the saved arrays
+  are what the second run of the mixer's core gives. Without a
+  checkpoint XLA fuses the block otherwise on a CPU, as it does
+  against `full`: the last bits."""
+  gradient, params = _block_gradient(monkeypatch, mixer, impl)
+  (full, out_full), (saved, out_saved), (plain, out_plain) = (
+      gradient(policy)(params)
+      for policy in ("full", "save_attention", None))
+  np.testing.assert_array_equal(out_saved, out_full)
+  np.testing.assert_allclose(out_saved, out_plain, rtol=1e-4, atol=1e-5)
+  for a, b, c in zip(*map(jax.tree_util.tree_leaves,
+                          (saved, full, plain))):
     np.testing.assert_array_equal(a, b)
     np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("mixer,impl", [
-    (_gated, "reference"), (_latent, "reference"), (_delta_net, None)])
+    (_gated, "reference"), (_latent, "reference")])
 def test_save_attention_without_the_kernel_is_full(monkeypatch, mixer,
                                                    impl):
-  """Nothing of such a block carries the kernel's names: the gradient's
+  """Nothing of such a block carries a saved name: the gradient's
   jaxpr is that of `full` but for the policy's own printed name."""
   gradient, params = _block_gradient(monkeypatch, mixer, impl)
   full, saved = (str(jax.make_jaxpr(gradient(policy))(params))
