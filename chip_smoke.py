@@ -78,6 +78,8 @@ class Sizes:
                     (2, 32, 2, 16, "bfloat16", 32)]
       self.select = dict(p=16, b=8, c=16, a=4, e=3, hidden=16)
       self.head = dict(b=2, p=8, c=8, hw=4)
+      # (b, t, heads, d, dtype, chunk) of the delta rule's kernels.
+      self.delta_rule = (1, 64, 3, 8, "float32", 16)
     else:
       self.k, self.dispatches, self.batch = 25, 4, 256
       self.model_bindings = []
@@ -86,6 +88,8 @@ class Sizes:
                     (1, 32768, 4, 64, "bfloat16", 1024)]   # long context
       self.select = dict(p=64, b=256, c=64, a=4, e=6, hidden=64)
       self.head = dict(b=4, p=64, c=64, hw=8)
+      # A row of the Qwen3-Next cell (PERF.md section 4).
+      self.delta_rule = (1, 8192, 32, 128, "bfloat16", 64)
 
 
 def _emit(record: str, **payload) -> None:
@@ -409,6 +413,61 @@ def _check_cem_head(sizes: Sizes, rng) -> None:
     raise RuntimeError(f"fused_cem_head_tail over the bar: {err}")
 
 
+def _check_delta_rule(sizes: Sizes, rng) -> None:
+  """The gated delta rule's three programs against what they replace:
+  the walk's kernel pair (`ops/delta_rule_walk.py`) against
+  `scan_walk`, forward and the five cotangents, and the fused forward
+  program (`ops/delta_rule_fused.py`) against the prepared rule."""
+  import jax
+  import jax.numpy as jnp
+  from tensor2robot_tpu.layers import gated_delta
+  from tensor2robot_tpu.ops import delta_rule_fused, delta_rule_walk
+  b, t, h, d, dtype, chunk = sizes.delta_rule
+  n = t // chunk
+  shape = dict(b=b, t=t, heads=h, d=d, dtype=dtype, chunk=chunk)
+  normal = lambda *s: jnp.asarray(  # noqa: E731
+      rng.standard_normal(s), jnp.float32)
+
+  # Keys a tenth of unit length: the walk's state stays bounded over
+  # 128 chunks of operands that no preparation has matched.
+  keys = [(0.1 * gated_delta.l2_normalize(normal(n, b, h, chunk, d))
+           ).astype(dtype) for _ in range(3)]
+  operands = (normal(n, b, h, chunk, d), *keys,
+              jnp.asarray(rng.uniform(0.5, 0.95, (n, b, h)), jnp.float32))
+  probes = [normal(n, b, h, chunk, d) for _ in range(2)]
+  kernels = lambda *x: delta_rule_walk.walk(  # noqa: E731
+      *x, interpret=sizes.interpret)
+
+  def both(walk):
+    scalar = lambda *x: sum(  # noqa: E731
+        jnp.sum(out * probe) for out, probe in zip(walk(*x), probes))
+    return jax.jit(walk)(*operands) + jax.jit(jax.grad(
+        scalar, argnums=(0, 1, 2, 3, 4)))(*operands)
+
+  names = ("new", "carried", "d_writes", "d_k_decayed", "d_q_decayed",
+           "d_k_to_end", "d_end_decay")
+  errs = {name: _max_err(got, want) for name, got, want in zip(
+      names, both(kernels), both(gated_delta.scan_walk))}
+  _emit("kernel", name="delta_rule_walk fwd+bwd", shape=shape, errs=errs)
+  if not max(errs.values()) < KERNEL_BAR:
+    raise RuntimeError(f"delta_rule_walk over the bar: {errs}")
+
+  q = gated_delta.l2_normalize(normal(b, t, h, d)) * d ** -0.5
+  k = gated_delta.l2_normalize(normal(b, t, h, d))
+  v = normal(b, t, h, d).astype(dtype)
+  g = -jnp.asarray(rng.uniform(0.001, 0.3, (b, t, h)), jnp.float32)
+  beta = jax.nn.sigmoid(normal(b, t, h))
+  got = jax.jit(lambda *x: delta_rule_fused.forward(
+      *x, chunk=chunk, dtype=jnp.dtype(dtype),
+      interpret=sizes.interpret))(q, k, v, g, beta)
+  want = jax.jit(lambda *x: gated_delta._prepared_rule(
+      *x, chunk, jnp.dtype(dtype), sizes.interpret))(q, k, v, g, beta)
+  errs = {"out": _max_err(got, want)}
+  _emit("kernel", name="delta_rule_fused fwd", shape=shape, errs=errs)
+  if not errs["out"] < KERNEL_BAR:
+    raise RuntimeError(f"delta_rule_fused over the bar: {errs}")
+
+
 def phase_kernels(sizes: Sizes, model_dir: str) -> None:
   """No default config runs a Pallas kernel, so the trainer path
   cannot find a Mosaic refusal; this compiles and runs each once."""
@@ -418,6 +477,7 @@ def phase_kernels(sizes: Sizes, model_dir: str) -> None:
   _check_cem_select(sizes, rng)
   _check_cem_head(sizes, rng)
   _check_flash(sizes, rng)
+  _check_delta_rule(sizes, rng)
 
 
 PHASES = {"serve": phase_serve, "kernels": phase_kernels}
@@ -578,7 +638,7 @@ def run_smoke(sizes: Sizes, model_dir: str) -> dict:
   devices.append(_one(records, "device", "serve+kernels"))
   _one(records, "serve", "serve+kernels")
   kernels = [r["name"] for r in records if r["record"] == "kernel"]
-  if len(kernels) != 2 + len(sizes.flash):
+  if len(kernels) != 4 + len(sizes.flash):
     raise SmokeFailure(f"serve+kernels: kernels checked: {kernels}")
 
   devices = [{key: d[key] for key in ("platform", "kind", "count")}

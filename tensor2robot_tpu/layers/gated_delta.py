@@ -14,8 +14,11 @@ over positions is exact and sequential; `gated_delta_rule` computes the
 same numbers a chunk of positions at a time: inside a chunk everything
 is matrix products (the WY form: the chunk's writes are solved for at
 once through the inverse of a unit lower-triangular matrix), and a
-`lax.scan` over the chunks carries the float32 state. With chunk 64 a
-sequence of 8,192 positions is a scan of 128 steps.
+walk over the chunks carries the float32 state. With chunk 64 a
+sequence of 8,192 positions is a walk of 128 steps. On a TPU the walk
+is a Pallas kernel pair (`ops/delta_rule_walk.py`), and a forward pass
+that nothing differentiates through is one fused kernel, preparation,
+walk and all (`ops/delta_rule_fused.py`).
 
 `GatedDeltaNet` is the layer around it: one projection to q, k, v and
 the output gate z, one to the two per-head scalars behind beta and g,
@@ -38,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from tensor2robot_tpu.ops import delta_rule_walk
+from tensor2robot_tpu.ops import delta_rule_fused, delta_rule_walk
 from tensor2robot_tpu.telemetry import metrics as tmetrics
 
 HIGH = jax.lax.Precision.HIGH
@@ -127,31 +130,22 @@ def _on_tpu() -> bool:
   return jax.devices()[0].platform == "tpu"
 
 
-def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
-                     dtype: Any = jnp.float32,
-                     interpret: bool = False) -> jax.Array:
-  """The gated delta rule over [B, T, H, D] in chunks of `chunk`
-  positions (a power of two). q, k [B, T, H, Dk] (already normalised
-  and scaled as the layer wants them), v [B, T, H, Dv], g and beta
-  [B, T, H] float32. Returns o [B, T, H, Dv] float32. Matrix products
-  against q, k, v and the state take `dtype` operands and accumulate in
-  float32.
+def _kernels_run(chunk, dk, dv, dtype, interpret) -> bool:
+  """Whether the rule's Pallas programs run: on a TPU at shapes that
+  tile, and wherever a test asks for the interpreter."""
+  return interpret or (_on_tpu() and delta_rule_walk.tiles(
+      chunk, dk, dv, dtype))
 
-  The walk over the chunks is `ops/delta_rule_walk`'s kernel pair where
-  the platform is a TPU and the shapes tile, `scan_walk` everywhere
-  else: read off the input, nobody sets it. The registry's counters
-  `gated_delta.walk.kernel_traces` and `.scan_traces` count the traced
-  calls that took each (a compiled program runs what was traced).
-  `interpret` is the tests': the kernels in the Pallas interpreter,
-  whatever the platform and the shapes."""
+
+def _prepared_rule(q, k, v, g, beta, chunk, dtype, interpret):
+  """The rule over a whole number of chunks in three stages: every
+  chunk's operands prepared in large XLA products, the walk over the
+  chunks, `within @ new`. Differentiable by autodiff (the walk's
+  kernels bring their own rule): the path of an evaluation that a
+  backward pass follows, on a TPU too, and of every evaluation where
+  the fused program cannot run."""
   b, t, h, dk = q.shape
-  pad = -t % chunk
-  if pad:
-    # beta 0 writes nothing, g 0 decays nothing, k 0 reads nothing.
-    q, k, v, g, beta = (
-        jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        for x in (q, k, v, g, beta))
-  n = (t + pad) // chunk
+  n = t // chunk
 
   def chunks(x):  # [B, T, H, ...] -> [N, B, H, C, ...]
     x = x.reshape((b, n, chunk) + x.shape[2:])
@@ -187,8 +181,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
   k_to_end = (k * jnp.exp(g[..., -1:] - g)[..., None]).astype(dtype)
   end_decay = jnp.exp(g[..., -1])  # [N, B, H]
 
-  kernel = interpret or (_on_tpu() and delta_rule_walk.tiles(
-      chunk, dk, v.shape[-1], dtype))
+  kernel = _kernels_run(chunk, dk, v.shape[-1], dtype, interpret)
   tmetrics.counter("gated_delta.walk.kernel_traces" if kernel
                    else "gated_delta.walk.scan_traces").inc()
   walk = (functools.partial(delta_rule_walk.walk, interpret=interpret)
@@ -197,7 +190,73 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
   out = carried + mm(within, new, "...ij,...jd->...id")
   # [N, B, H, C, Dv] -> [B, T, H, Dv]
   out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3)
-  return out.reshape(b, n * chunk, h, -1)[:, :t]
+  return out.reshape(b, t, h, -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _fused_rule(q, k, v, g, beta, chunk, dtype, interpret):
+  """The rule where the fused program can run. Its value is the fused
+  program's; a backward pass differentiates `_prepared_rule`."""
+  return delta_rule_fused.forward(q, k, v, g, beta, chunk=chunk,
+                                  dtype=dtype, interpret=interpret)
+
+
+def _fused_rule_fwd(q, k, v, g, beta, chunk, dtype, interpret):
+  return jax.vjp(functools.partial(
+      _prepared_rule, chunk=chunk, dtype=dtype, interpret=interpret),
+                 q, k, v, g, beta)
+
+
+def _fused_rule_bwd(chunk, dtype, interpret, back, cotangent):
+  del chunk, dtype, interpret
+  return back(cotangent)
+
+
+# `optimize_remat`, as on `delta_rule_walk._walk`: under the row's
+# `jax.checkpoint` the forward pass, whose residuals nobody keeps, runs
+# `_fused_rule` itself and not `_fused_rule_fwd`; the recomputation,
+# whose residuals the backward pass reads, runs the rule above.
+_fused_rule.defvjp(_fused_rule_fwd, _fused_rule_bwd, optimize_remat=True)
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
+                     dtype: Any = jnp.float32,
+                     interpret: bool = False) -> jax.Array:
+  """The gated delta rule over [B, T, H, D] in chunks of `chunk`
+  positions (a power of two). q, k [B, T, H, Dk] (already normalised
+  and scaled as the layer wants them), v [B, T, H, Dv], g and beta
+  [B, T, H] float32. Returns o [B, T, H, Dv] float32. Matrix products
+  against q, k, v and the state take `dtype` operands and accumulate in
+  float32.
+
+  One algorithm, three programs. Where the platform is a TPU and the
+  shapes tile, an evaluation that nothing differentiates through is
+  `ops/delta_rule_fused`'s one kernel (a chunk's operands built in
+  VMEM, the walk's step, `within @ new`; only `out` goes to HBM), and
+  one that a backward pass follows is `_prepared_rule` with
+  `ops/delta_rule_walk`'s kernel pair, whose intermediates are the
+  backward pass's residuals. Everywhere else it is `_prepared_rule`
+  with `scan_walk`. All of it is read off the input and off what JAX
+  is doing to the call; nobody sets it. The registry's counters say
+  what was traced (a compiled program runs what was traced):
+  `gated_delta.forward.fused_traces` and `.prepared_traces` the calls
+  whose undifferentiated evaluation is the fused program, and is not;
+  `gated_delta.walk.kernel_traces` and `.scan_traces` the traces of
+  `_prepared_rule` that took each walk. `interpret` is the tests': the
+  kernels in the Pallas interpreter, whatever the platform and the
+  shapes."""
+  t, dk = q.shape[1], q.shape[-1]
+  pad = -t % chunk
+  if pad:
+    # beta 0 writes nothing, g 0 decays nothing, k 0 reads nothing.
+    q, k, v, g, beta = (
+        jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        for x in (q, k, v, g, beta))
+  fused = _kernels_run(chunk, dk, v.shape[-1], dtype, interpret)
+  tmetrics.counter("gated_delta.forward.fused_traces" if fused
+                   else "gated_delta.forward.prepared_traces").inc()
+  rule = _fused_rule if fused else _prepared_rule
+  return rule(q, k, v, g, beta, chunk, dtype, interpret)[:, :t]
 
 
 def l2_normalize(x: jax.Array, eps: float = 1e-6) -> jax.Array:

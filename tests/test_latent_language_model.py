@@ -513,22 +513,28 @@ def test_benchmark_json_has_the_new_entries_and_no_other():
       "name": CELL, "config": "joyai_llm_flash_ep16",
       "traffic": "train_eval", "chips": 1,
       "why": bench["workloads"][-1]["why"]}
-  # ISSUE 41 appended the recomputation of the other family's delta
-  # rule; ISSUE 39 the rounds that both families' expert layers run;
-  # ISSUE 38 start-up's seven, which every cell reports.
+  # ISSUE 42 appended the fused forward pass of the other family's
+  # delta rule and ISSUE 41 its recomputation; ISSUE 39 the rounds
+  # that both families' expert layers run; ISSUE 38 start-up's seven,
+  # which every cell reports.
   assert bench["per_layer"][-1] == {
+      "name": "lm_gdn_fused_forward_share", "unit": "%",
+      "better": "higher", "source": "program_counter",
+      "layer": "sequence trunk", "moves": "train_steps_per_s",
+      "workloads": ["qwen3next_80b_a3b_ep16.train_eval"]}
+  assert bench["per_layer"][-2] == {
       "name": "lm_gdn_recompute_device_ms", "unit": "ms",
       "better": "lower", "source": "device_trace",
       "layer": "sequence trunk", "moves": "train_steps_per_s",
       "workloads": ["qwen3next_80b_a3b_ep16.train_eval"]}
-  assert bench["per_layer"][-2] == {
+  assert bench["per_layer"][-3] == {
       "name": "lm_moe_rounds_run", "unit": "count", "better": "lower",
       "source": "program_counter", "layer": "expert layer",
       "moves": "train_steps_per_s",
       "workloads": ["qwen3next_80b_a3b_ep16.train_eval", CELL]}
   startup = [m for m in bench["per_layer"] if m["layer"] == "start-up"]
-  assert bench["per_layer"][-9:-2] == startup
-  per_layer = bench["per_layer"][:-9]
+  assert bench["per_layer"][-10:-3] == startup
+  per_layer = bench["per_layer"][:-10]
   assert [m["name"] for m in per_layer[-3:]] == [
       "lm_mla_step_mfu", "lm_mla_flash_share",
       "lm_attention_saved_share"]

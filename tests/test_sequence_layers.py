@@ -383,13 +383,15 @@ def _kernel_calls(jaxpr, found=None):
 FLASH_BACKWARD = {"_dkdv_kernel": 1, "_dq_kernel": 1}
 
 
-# The delta rule's forward kernel runs once more under every policy:
-# each row's own `jax.checkpoint` inside `GatedDeltaNet` (the program
-# that writes the states for the backward kernel).
+# The delta rule's forward pass is the fused program (ISSUE 42); the
+# walk's forward kernel runs once under every policy, in each row's own
+# `jax.checkpoint` inside `GatedDeltaNet` (the program that writes the
+# states for the backward kernel, under the prepared rule).
 @pytest.mark.parametrize("mixer,forward,kept,backward", [
     (_gated, "_flash_kernel", 1, FLASH_BACKWARD),
     (_latent, "_flash_kernel", 1, FLASH_BACKWARD),
-    (_delta_net, "_forward_kernel", 2, {"_backward_kernel": 1})])
+    (_delta_net, "_fused_kernel", 1,
+     {"_forward_kernel": 1, "_backward_kernel": 1})])
 def test_save_attention_runs_the_forward_kernel_once(
     monkeypatch, mixer, forward, kept, backward):
   gradient, params = _block_gradient(monkeypatch, mixer, "flash")
