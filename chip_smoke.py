@@ -73,9 +73,11 @@ class Sizes:
           "QTOptLearner.cem_population=8",
           "QTOptLearner.cem_elites=2",
       ]
-      # (b, t, heads, d, dtype, chunk) per flash shape; select/head dims.
+      # (b, t, heads, d, dtype, chunk[, kv heads, window]) per flash
+      # shape; select/head dims.
       self.flash = [(1, 64, 2, 16, "float32", 32),
-                    (2, 32, 2, 16, "bfloat16", 32)]
+                    (2, 32, 2, 16, "bfloat16", 32),
+                    (1, 64, 6, 16, "float32", 32, 2, 24)]
       self.select = dict(p=16, b=8, c=16, a=4, e=3, hidden=16)
       self.head = dict(b=2, p=8, c=8, hw=4)
       # (b, t, heads, d, dtype, chunk) of the delta rule's kernels.
@@ -85,7 +87,12 @@ class Sizes:
       self.model_bindings = []
       self.flash = [(2, 1024, 2, 64, "float32", 1024),    # --verify
                     (16, 32, 4, 32, "bfloat16", 32),       # BC episode
-                    (1, 32768, 4, 64, "bfloat16", 1024)]   # long context
+                    (1, 32768, 4, 64, "bfloat16", 1024),   # long context
+                    # A row of the windowed-attention cell (PERF.md
+                    # section 4): a sliding layer's band and groups,
+                    # a full layer's groups.
+                    (1, 8192, 64, 128, "bfloat16", 256, 8, 512),
+                    (1, 8192, 48, 128, "bfloat16", 256, 8, None)]
       self.select = dict(p=64, b=256, c=64, a=4, e=6, hidden=64)
       self.head = dict(b=4, p=64, c=64, hw=8)
       # A row of the Qwen3-Next cell (PERF.md section 4).
@@ -284,13 +291,17 @@ def _max_err(got, want) -> float:
                / max(1.0, float(np.max(np.abs(want)))))
 
 
-def _attention_reference(q, k, v, chunk: int):
-  """Causal attention in plain f32 jnp, materializing one [chunk, T]
-  score slab per step so T=32k fits; returns (out, lse [B, H, T])."""
+def _attention_reference(q, k, v, chunk: int, window=None):
+  """Causal attention in plain f32 jnp (under a band where `window`:
+  a row sees itself and the `window - 1` before it; key-value heads
+  repeated to their groups of query heads), materializing one
+  [chunk, T] score slab per step so T=32k fits; returns (out,
+  lse [B, H, T])."""
   import jax
   import jax.numpy as jnp
   b, t, h, d = q.shape
   q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+  k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
   hi = jax.lax.Precision.HIGHEST
 
   @jax.checkpoint
@@ -298,7 +309,8 @@ def _attention_reference(q, k, v, chunk: int):
     q_rows, row0 = args                                   # [B, c, H, D]
     s = jnp.einsum("bthd,bshd->bhts", q_rows, k,
                    precision=hi) / math.sqrt(d)
-    visible = (row0 + jnp.arange(chunk))[:, None] >= jnp.arange(t)[None]
+    behind = (row0 + jnp.arange(chunk))[:, None] - jnp.arange(t)[None]
+    visible = (behind >= 0) & (behind < (window or t))
     s = jnp.where(visible[None, None], s, -1e30)
     out = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, axis=-1), v,
                      precision=hi)
@@ -317,9 +329,10 @@ def _check_flash(sizes: Sizes, rng) -> None:
   from tensor2robot_tpu.ops.flash_attention import (
       flash_attention_with_lse,
   )
-  for b, t, h, d, dtype, chunk in sizes.flash:
-    q, k, v, do = (jnp.asarray(rng.standard_normal((b, t, h, d)), dtype)
-                   for _ in range(4))
+  for b, t, h, d, dtype, chunk, *grouped in sizes.flash:
+    kv, window = grouped or (h, None)
+    q, k, v, do = (jnp.asarray(rng.standard_normal((b, t, n, d)), dtype)
+                   for n in (h, kv, kv, h))
     dlse = jnp.asarray(rng.standard_normal((b, h, t)) * 0.1,
                        jnp.float32)
 
@@ -329,9 +342,9 @@ def _check_flash(sizes: Sizes, rng) -> None:
               + jnp.sum(lse * dlse))
 
     flash = lambda q, k, v: flash_attention_with_lse(  # noqa: E731
-        q, k, v, causal=True, interpret=sizes.interpret)
+        q, k, v, causal=True, interpret=sizes.interpret, window=window)
     reference = lambda q, k, v: _attention_reference(  # noqa: E731
-        q, k, v, chunk)
+        q, k, v, chunk, window)
     got = flash(q, k, v) + jax.jit(jax.grad(
         lambda *x: scalar(flash, *x), argnums=(0, 1, 2)))(q, k, v)
     want = jax.jit(reference)(q, k, v) + jax.jit(jax.grad(
@@ -339,7 +352,8 @@ def _check_flash(sizes: Sizes, rng) -> None:
     errs = {name: _max_err(g, w)
             for name, g, w in zip(FLASH_BARS, got, want)}
     _emit("kernel", name="flash_attention fwd+bwd",
-          shape=dict(b=b, t=t, heads=h, d=d, dtype=dtype), errs=errs)
+          shape=dict(b=b, t=t, heads=h, kv_heads=kv, window=window, d=d,
+                     dtype=dtype), errs=errs)
     bad = {n: e for n, e in errs.items() if not e < FLASH_BARS[n]}
     if bad:
       raise RuntimeError(f"flash T={t} {dtype}: over the bar: {bad}")

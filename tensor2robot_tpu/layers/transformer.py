@@ -31,13 +31,25 @@ attention block that `CausalTransformer` has always stacked.
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+import math
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from tensor2robot_tpu.telemetry import metrics as tmetrics
+
+
+def _repeat_kv(k, v, heads: int):
+  """k and v [B, T, KV, D] with each key-value head repeated to its
+  group's `heads // KV` query heads, in HBM; counts the traced calls
+  that repeat in `attention.kv_repeat_traces`."""
+  group = heads // k.shape[2]
+  if group == 1:
+    return k, v
+  tmetrics.counter("attention.kv_repeat_traces").inc()
+  return tuple(jnp.repeat(y, group, axis=2) for y in (k, v))
 
 
 def _on_tpu() -> bool:
@@ -52,9 +64,16 @@ def _resolve_impl(impl: str) -> str:
   return impl
 
 
-def _attend(q, k, v, *, impl: str, causal: bool, mesh) -> jax.Array:
-  """Dispatches [B, T, H, D] attention to the chosen backend. `flash`
-  and `reference` take values of another width than the keys'."""
+def _attend(q, k, v, *, impl: str, causal: bool, mesh,
+            window: Optional[int] = None) -> jax.Array:
+  """Dispatches attention of q [B, T, H, D] over k, v [B, T, KV, D] to
+  the chosen backend; KV divides H. `flash` and `reference` take
+  values of another width than the keys', and with `causal` a
+  `window` (query i sees key j iff 0 <= i - j < window). `flash` reads
+  a group's key-value head where it lies; the other backends take as
+  many key-value heads as query heads, so for them the key-value heads
+  are repeated here (the registry's counter `attention.kv_repeat_traces`
+  counts the traced calls that did)."""
   from tensor2robot_tpu.ops import flash_attention
   from tensor2robot_tpu.parallel import (
       attention_reference,
@@ -64,13 +83,18 @@ def _attend(q, k, v, *, impl: str, causal: bool, mesh) -> jax.Array:
   on_tpu = _on_tpu()
   impl = _resolve_impl(impl)
   if impl == "flash":
-    return flash_attention(q, k, v, causal=causal)
+    return flash_attention(q, k, v, causal=causal, window=window)
+  k, v = _repeat_kv(k, v, q.shape[2])
   if impl in ("ring", "ring_flash"):
     if mesh is None:
       raise ValueError(
           f"attention_impl={impl!r} needs a device mesh with a "
           "'seq' axis; pass mesh= (models: the mesh constructor "
           "argument) or use 'flash'/'reference' single-device.")
+    if window is not None:
+      raise ValueError(
+          f"attention_impl={impl!r} has no window: the ring passes "
+          "whole key blocks round; use 'flash' or 'reference'.")
     # On TPU the ring runs the flash kernel within each chip
     # (partials combined by logsumexp over the ICI ring);
     # "ring_flash" forces that composition off-TPU too, via the
@@ -81,7 +105,7 @@ def _attend(q, k, v, *, impl: str, causal: bool, mesh) -> jax.Array:
                           else "reference",
                           flash_interpret=use_flash and not on_tpu)
   if impl == "reference":
-    return attention_reference(q, k, v, causal=causal)
+    return attention_reference(q, k, v, causal=causal, window=window)
   raise ValueError(f"Unknown attention impl: {impl!r}")
 
 
@@ -126,18 +150,69 @@ class RMSNorm(nn.Module):
     return x * (1.0 + weight)
 
 
-def rotary(x: jax.Array, rotary_dim: int, theta: float,
-           interleaved: bool = False) -> jax.Array:
-  """Rotary position embedding on the first `rotary_dim` dims of each
-  head of x [B, T, H, D]; positions are 0..T-1. Pair i of a head is
-  turned by position * theta^(-i / half): dims (i, i + half) in the
-  rotate-half layout, dims (2 i, 2 i + 1) where `interleaved`."""
-  t = x.shape[1]
+class YarnRope(NamedTuple):
+  """YaRN's parameters (arXiv:2309.00071) as a published
+  `rope_parameters` block of `rope_type` `yarn` names them."""
+
+  factor: float
+  original_max_position_embeddings: int
+  beta_fast: float
+  beta_slow: float
+  attention_factor: float  # the amplitude of cos and sin
+
+
+def yarn_correction_range(rotary_dim: int, theta: float,
+                          yarn: YarnRope) -> Tuple[int, int]:
+  """(low, high): the pairs below `low` keep their frequency, those
+  from `high` on are slowed by `factor`, those between are blended. The
+  pair that turns `beta` times over the original context is pair
+  d ln(L / (2 pi beta)) / (2 ln theta); floor for `beta_fast`, ceil for
+  `beta_slow`, clipped to [0, d - 1] (the Hugging Face
+  implementation's bounds)."""
+  def pair(beta):
+    return (rotary_dim * math.log(
+        yarn.original_max_position_embeddings / (beta * 2 * math.pi))
+            / (2 * math.log(theta)))
+  return (max(math.floor(pair(yarn.beta_fast)), 0),
+          min(math.ceil(pair(yarn.beta_slow)), rotary_dim - 1))
+
+
+def rotary_frequencies(rotary_dim: int, theta: float,
+                       yarn: Optional[YarnRope] = None
+                       ) -> Tuple[jax.Array, float]:
+  """(the `rotary_dim // 2` pairs' angular frequencies a position,
+  the amplitude of cos and sin). Plain: f_i = theta^(-2 i / d), 1.
+  YaRN: f'_i = (1 - r_i) f_i + r_i f_i / factor with the ramp
+  r_i = clip((i - low) / (high - low), 0, 1) over
+  `yarn_correction_range`, and `attention_factor`."""
   half = rotary_dim // 2
   inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+  if yarn is None:
+    return inv_freq, 1.0
+  low, high = yarn_correction_range(rotary_dim, theta, yarn)
+  ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                  / max(high - low, 1e-3), 0.0, 1.0)
+  return ((1.0 - ramp) * inv_freq + ramp * inv_freq / yarn.factor,
+          yarn.attention_factor)
+
+
+def rotary(x: jax.Array, rotary_dim: int, theta: float,
+           interleaved: bool = False,
+           yarn: Optional[YarnRope] = None) -> jax.Array:
+  """Rotary position embedding on the first `rotary_dim` dims of each
+  head of x [B, T, H, D]; positions are 0..T-1. Pair i of a head is
+  turned by position * theta^(-i / half), or by YaRN's blended
+  frequency and scaled by its amplitude (`rotary_frequencies`): dims
+  (i, i + half) in the rotate-half layout, dims (2 i, 2 i + 1) where
+  `interleaved`."""
+  t = x.shape[1]
+  half = rotary_dim // 2
+  inv_freq, amplitude = rotary_frequencies(rotary_dim, theta, yarn)
   angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq
   cos = jnp.cos(angles)[None, :, None, :]
   sin = jnp.sin(angles)[None, :, None, :]
+  if yarn is not None:
+    cos, sin = cos * amplitude, sin * amplitude
   turned, rest = jnp.split(x, [rotary_dim], axis=-1)
   if interleaved:
     pairs = turned.reshape(turned.shape[:-1] + (half, 2))
@@ -158,9 +233,20 @@ class GatedAttention(nn.Module):
   `head_dim`; `q_proj` gives per query head the query and a gate
   (columns: all queries, then all gates); zero-centred RMS norms over
   the head dimension of q and of k; rotary on the first `rotary_dim`
-  dims; out = attention * sigmoid(gate) under `o_proj`. The key-value
-  heads are repeated to the query heads before the backend, which
-  takes as many of each.
+  dims at `rope_theta`, plain or with `yarn`'s blended frequencies and
+  amplitude; out = attention * sigmoid(gate) under `o_proj`. With
+  `window`, position i attends over itself and the `window - 1` before
+  it (`ops/flash_attention.py`'s band), under the named scope
+  `window_attention`; without, over all before it, under
+  `gated_attention`. The key-value heads are repeated to the query
+  heads before the backend, unless `grouped_kv`: then they go to it as
+  they are, and the flash kernel reads a group's head where it lies
+  (`_attend`; the hybrid model's layers keep the repeat until their
+  cell has been measured without it, ROADMAP M3). A layer with a
+  window counts its traced calls in `attention.window.kernel_traces`
+  or `.materialised_traces`, and for the kernel the pairs its band
+  holds and the pairs of the tiles its grid computes
+  (`attention.window.band_pairs`, `.tile_pairs`).
   """
 
   num_heads: int
@@ -172,27 +258,49 @@ class GatedAttention(nn.Module):
   attention_impl: str = "auto"
   mesh: Optional[Any] = None
   dtype: Any = jnp.bfloat16
+  window: Optional[int] = None
+  yarn: Optional[YarnRope] = None
+  grouped_kv: bool = False
+
+  def _count_window(self, impl: str, rows: int, t: int) -> None:
+    if impl != "flash":
+      tmetrics.counter("attention.window.materialised_traces").inc()
+      return
+    from tensor2robot_tpu.ops.flash_attention import window_tiling
+    _, _, band, tiles = window_tiling(t, self.window)
+    tmetrics.counter("attention.window.kernel_traces").inc()
+    tmetrics.counter("attention.window.band_pairs").inc(
+        rows * self.num_heads * band)
+    tmetrics.counter("attention.window.tile_pairs").inc(
+        rows * self.num_heads * tiles)
 
   @nn.compact
   def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
     b, t, width = x.shape
     h, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
     x = x.astype(self.dtype)
+    # A window that holds the whole sequence is none.
+    window = self.window if self.window and self.window < t else None
 
     def proj(name, heads):
       return nn.Dense(heads * d, use_bias=False, dtype=self.dtype,
                       name=name)(x).reshape(b, t, heads, d)
 
-    with jax.named_scope("gated_attention"):
+    with jax.named_scope("window_attention" if window
+                         else "gated_attention"):
       q, gate = jnp.split(proj("q_proj", 2 * h), 2, axis=2)
       k, v = proj("k_proj", kv), proj("v_proj", kv)
       q = RMSNorm(self.eps, name="q_norm")(q)
       k = RMSNorm(self.eps, name="k_norm")(k)
-      q, k = (rotary(y, self.rotary_dim, self.rope_theta
-                     ).astype(self.dtype) for y in (q, k))
-      k, v = (jnp.repeat(y, h // kv, axis=2) for y in (k, v))
-      out = _attend(q, k, v, impl=self.attention_impl, causal=True,
-                    mesh=self.mesh)
+      q, k = (rotary(y, self.rotary_dim, self.rope_theta,
+                     yarn=self.yarn).astype(self.dtype) for y in (q, k))
+      if not self.grouped_kv:
+        k, v = _repeat_kv(k, v, h)
+      impl = _resolve_impl(self.attention_impl)
+      if window:
+        self._count_window(impl, b, t)
+      out = _attend(q, k, v, impl=impl, causal=True, mesh=self.mesh,
+                    window=window)
       out = out * jax.nn.sigmoid(gate.astype(jnp.float32)
                                  ).astype(self.dtype)
       return nn.Dense(width, use_bias=False, dtype=self.dtype,

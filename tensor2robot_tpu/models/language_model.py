@@ -3,7 +3,7 @@ cross-entropy of every position's successor out, trained by
 `train_eval_model` like every other family.
 
 The network is an embedding, a `layers/transformer.SequenceTrunk` whose
-blocks are data, a final norm and an untied head. Two families build
+blocks are data, a final norm and an untied head. Three families build
 it, each with the names of its published configuration's keys as
 constructor arguments, so a configuration file and the model read
 alike; what they share (specs, the loss in blocks, the reduction of the
@@ -25,6 +25,16 @@ routing counters, the per-layer checkpointing) is `_LanguageModel`'s:
   an ungated shared expert; and a multi-token-prediction module
   (`MultiTokenPrediction`) adds `mtp_loss_weight` times the loss of
   predicting each position's successor's successor.
+- `WindowedAttentionLanguageModel`, sliding-window attention beside
+  full attention in one trunk as Laguna-XS.2 publishes it
+  (`train_laguna_xs2.gin`): `layer_types[i]` says whether layer i's
+  gated grouped-query attention sees every earlier position or a band
+  of `sliding_window`; a layer's query heads
+  (`num_attention_heads_per_layer[i]`) and its rotary embedding
+  (`rope_parameters[layer_types[i]]`: plain or YaRN, its own base and
+  share of a head's dims) follow its type; `mlp_layer_types[i]` says
+  whether its feed-forward is a dense gated unit or experts chosen by
+  sigmoid score beside an ungated shared expert.
 
 A chip's share of an expert-parallel deployment (docs/SEQUENCE.md):
 `num_experts` is the router's width, `experts_held` how many of them
@@ -38,7 +48,7 @@ never stand whole, forward or backward.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -55,6 +65,7 @@ from tensor2robot_tpu.layers.transformer import (
     RMSNorm,
     SequenceTrunk,
     TransformerBlock,
+    YarnRope,
     apply_block,
 )
 from tensor2robot_tpu.models.abstract_model import AbstractT2RModel
@@ -486,3 +497,150 @@ class LatentAttentionLanguageModel(_LanguageModel):
         block=self._block(self._num_hidden_layers),
         remat_policy=self._remat_policy, eps=self._rms_norm_eps,
         dtype=self.device_dtype)
+
+
+FULL_ATTENTION, SLIDING_ATTENTION = "full_attention", "sliding_attention"
+# Laguna-XS.2's published per-layer lists: 40 layers of period 4.
+_LAGUNA_LAYER_TYPES = (FULL_ATTENTION,) + (SLIDING_ATTENTION,) * 3
+_LAGUNA_ROPE = {
+    FULL_ATTENTION: {
+        "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+        "original_max_position_embeddings": 4096, "beta_slow": 1,
+        "beta_fast": 64, "attention_factor": 1.4158883083359672,
+        "partial_rotary_factor": 0.5},
+    SLIDING_ATTENTION: {
+        "rope_type": "default", "rope_theta": 10000,
+        "partial_rotary_factor": 1},
+    "original_max_position_embeddings": 4096}
+
+
+@gin.configurable
+class WindowedAttentionLanguageModel(_LanguageModel):
+  """A language model whose layers' attention is of two kinds in one
+  trunk, full and sliding-window, each kind with its own number of
+  query heads and its own rotary embedding, over dense and
+  mixture-of-experts feed-forwards (the module's docstring); the
+  defaults are Laguna-XS.2's published configuration, and the
+  per-layer lists are as long as the published depth: a model of fewer
+  layers reads their head."""
+
+  def __init__(self,
+               vocab_size: int = 100352,
+               sequence_length: int = 8192,
+               hidden_size: int = 2048,
+               num_hidden_layers: int = 40,
+               layer_types: Sequence[str] = _LAGUNA_LAYER_TYPES * 10,
+               num_attention_heads_per_layer: Sequence[int] = (
+                   (48, 64, 64, 64) * 10),
+               num_key_value_heads: int = 8,
+               head_dim: int = 128,
+               sliding_window: int = 512,
+               rope_parameters: Optional[Dict[str, Any]] = None,
+               mlp_layer_types: Sequence[str] = (
+                   ("dense",) + ("sparse",) * 39),
+               intermediate_size: int = 8192,
+               num_experts: int = 256,
+               experts_held: Optional[int] = None,
+               first_expert: int = 0,
+               num_experts_per_tok: int = 8,
+               moe_intermediate_size: int = 512,
+               shared_expert_intermediate_size: int = 512,
+               moe_routed_scaling_factor: float = 2.5,
+               scoring_func: str = "sigmoid",
+               norm_topk_prob: bool = True,
+               rms_norm_eps: float = 1e-6,
+               attention_impl: str = "auto",
+               loss_block: int = 4096,
+               device_dtype=jnp.bfloat16,
+               remat_policy: Optional[str] = "full",
+               **kwargs):
+    """`rope_parameters` is the published block: for each layer type
+    its `rope_theta`, `partial_rotary_factor` and `rope_type`
+    (`default`, or `yarn` with `factor`,
+    `original_max_position_embeddings`, `beta_fast`, `beta_slow`,
+    `attention_factor`); it defaults to Laguna-XS.2's. `experts_held`
+    defaults to all `num_experts`. The published file gives `gating:
+    true` and no form, and no score function for its router: every
+    layer has `GatedAttention`'s gate, the router's scores are
+    `scoring_func` of the logits, the chosen weights renormalised
+    where `norm_topk_prob` and multiplied by
+    `moe_routed_scaling_factor`."""
+    super().__init__(
+        vocab_size=vocab_size, sequence_length=sequence_length,
+        hidden_size=hidden_size, num_hidden_layers=num_hidden_layers,
+        rms_norm_eps=rms_norm_eps, attention_impl=attention_impl,
+        loss_block=loss_block, device_dtype=device_dtype,
+        remat_policy=remat_policy, **kwargs)
+    rope_parameters = (_LAGUNA_ROPE if rope_parameters is None
+                       else rope_parameters)
+    for name, per_layer in (
+        ("layer_types", layer_types),
+        ("num_attention_heads_per_layer", num_attention_heads_per_layer),
+        ("mlp_layer_types", mlp_layer_types)):
+      if len(per_layer) < num_hidden_layers:
+        raise ValueError(f"{name} has {len(per_layer)} entries for "
+                         f"{num_hidden_layers} layers")
+    for kind in set(layer_types[:num_hidden_layers]):
+      if kind not in (FULL_ATTENTION, SLIDING_ATTENTION):
+        raise ValueError(f"Unknown layer type: {kind!r}")
+      if rope_parameters[kind]["rope_type"] not in ("default", "yarn"):
+        raise ValueError(f"Unknown rope_type for {kind}: "
+                         f"{rope_parameters[kind]['rope_type']!r}")
+    unknown = set(mlp_layer_types[:num_hidden_layers]) - {"dense",
+                                                          "sparse"}
+    if unknown:
+      raise ValueError(f"Unknown mlp layer types: {sorted(unknown)}")
+    self._layer_types = tuple(layer_types)
+    self._num_attention_heads_per_layer = tuple(
+        num_attention_heads_per_layer)
+    self._num_key_value_heads = num_key_value_heads
+    self._head_dim = head_dim
+    self._sliding_window = sliding_window
+    self._rope_parameters = rope_parameters
+    self._mlp_layer_types = tuple(mlp_layer_types)
+    self._intermediate_size = intermediate_size
+    self._num_experts = num_experts
+    self._experts_held = (num_experts if experts_held is None
+                          else experts_held)
+    self._first_expert = first_expert
+    self._num_experts_per_tok = num_experts_per_tok
+    self._moe_intermediate_size = moe_intermediate_size
+    self._shared_expert_intermediate_size = (
+        shared_expert_intermediate_size)
+    self._moe_routed_scaling_factor = moe_routed_scaling_factor
+    self._scoring_func = scoring_func
+    self._norm_topk_prob = norm_topk_prob
+
+  def _block(self, layer: int) -> TransformerBlock:
+    dtype, eps = self.device_dtype, self._rms_norm_eps
+    kind = self._layer_types[layer]
+    rope = self._rope_parameters[kind]
+    yarn = None
+    if rope["rope_type"] == "yarn":
+      yarn = YarnRope(**{name: rope[name] for name in YarnRope._fields})
+    mixer = GatedAttention(
+        num_heads=self._num_attention_heads_per_layer[layer],
+        num_kv_heads=self._num_key_value_heads,
+        head_dim=self._head_dim,
+        rotary_dim=int(self._head_dim * rope["partial_rotary_factor"]),
+        rope_theta=float(rope["rope_theta"]), yarn=yarn, eps=eps,
+        window=(self._sliding_window if kind == SLIDING_ATTENTION
+                else None),
+        grouped_kv=True, attention_impl=self._attention_impl,
+        dtype=dtype)
+    if self._mlp_layer_types[layer] == "dense":
+      ffn = GatedMLP(width=self._intermediate_size, dtype=dtype)
+    else:
+      ffn = SparseMoE(
+          num_experts=self._num_experts,
+          experts_held=self._experts_held,
+          first_expert=self._first_expert,
+          k=self._num_experts_per_tok,
+          normalise_top_k=self._norm_topk_prob,
+          scoring=self._scoring_func, selection_bias=False,
+          routed_scaling_factor=self._moe_routed_scaling_factor,
+          expert_width=self._moe_intermediate_size,
+          shared_width=self._shared_expert_intermediate_size,
+          shared_gated=False, dtype=dtype)
+    return TransformerBlock(norm="rms", norm_eps=eps, mixer=mixer,
+                            ffn=ffn, dtype=dtype)

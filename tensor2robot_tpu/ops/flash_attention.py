@@ -38,6 +38,28 @@ same 19.1 and 48.9 ms (a contraction of 192 lanes occupies two passes
 of the 128-wide MXU), 128 over 128 14.2 and 33.1, 256 over 256 24.6 and
 61.8; blocks of (2048, 2048) double the backward pair (91 ms).
 
+A window (`causal=True, window=W`: query i sees key j iff 0 <= i - j <
+W) makes the mask a band, and the band sets the blocks: both are one
+size, the largest power of two that is at most W (and the requests, and
+divides T), so the default 1024 x 2048, two and four windows wide at
+W = 512, come down to 512 x 512 (`window_tiling`). The grids then walk
+the band's tiles alone: a row of tiles visits 1 + ceil((W - 1) / block)
+key blocks, whatever T is, through index maps offset by the query
+block (`_band_col`, `_band_row`); nothing outside the band is fetched.
+At the band's trailing edge stands a fourth tile regime beside the
+causal three (`_causal_tile_regimes`). At W = block every visited tile
+straddles one edge and half of the tiles' pairs are masked work; at
+half that block an unmasked tile stands between the two edges (two
+thirds of the pairs are the band's) and the grid has twice the steps.
+A window of T or more lowers to the causal programs.
+
+Keys and values may come with fewer heads than the queries (grouped
+queries): query head h reads key-value head h // group where it lies,
+through the blocks' index maps, so nothing is repeated in HBM; the
+dK/dV program's grid runs over the key-value heads and walks a group's
+query heads in its innermost dimension, so dK and dV are summed over
+the group in the float32 scratch and written once.
+
 Training works end to end, and the backward is Pallas too: two kernels
 in the standard flash-backward formulation, each recomputing score
 tiles from q/k + the saved logsumexp — `_dkdv_kernel` accumulates
@@ -62,7 +84,7 @@ numerics without TPU hardware.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -101,37 +123,96 @@ def _auto_block(requested: int, t: int) -> int:
     # such T (e.g. odd lengths > the default block) cannot tile.
     raise ValueError(
         f"Sequence length {t} has no TPU-tileable block size: need a "
-        f"power-of-two divisor ≥ 8 (or T ≤ {requested}); pad T "
-        "upstream — lengths are static in this framework.")
+        f"power-of-two divisor ≥ 8 (or T ≤ {requested}; under a "
+        "window the request is at most the power of two at or under "
+        "the window, and no less than 8); pad T upstream — lengths "
+        "are static in this framework.")
   return b
 
 
 def _causal_tile_regimes(row_block, col_block, block_q: int,
-                         block_k: int):
-  """(not_future, fully_past) predicates for one causal score tile.
+                         block_k: int, window=None):
+  """(visible, unmasked) predicates for one causal score tile.
 
   Shared by all three kernels so forward and backward can never
-  disagree on which tiles are masked:
-    fully-future (not not_future): every col > every row — all-masked,
+  disagree on which tiles are masked. Without a window:
+    fully-future (not visible): every col > every row — all-masked,
       skip the tile's compute entirely;
-    fully_past: every col <= every row — mask is all-true, run the
-      unmasked update (no iota/select work);
+    unmasked (fully past): every col <= every row — mask is all-true,
+      run the unmasked update (no iota/select work);
     otherwise the tile straddles the diagonal and pays for masking.
+  With `window` (row i sees col j iff 0 <= i - j < window) the band
+  has a trailing edge too, a fourth regime: a tile whose every col is
+  `window` or more behind every row is not visible, and a tile is
+  unmasked only where its first col is also less than `window` behind
+  its last row; what straddles either edge pays for masking
+  (`_tile_mask`).
   """
   last_row = row_block * block_q + block_q - 1
   first_row = row_block * block_q
   first_col = col_block * block_k
   last_col = col_block * block_k + block_k - 1
-  return first_col <= last_row, last_col <= first_row
+  visible, unmasked = first_col <= last_row, last_col <= first_row
+  if window is not None:
+    visible &= last_col > first_row - window
+    unmasked &= first_col > last_row - window
+  return visible, unmasked
+
+
+def _tile_mask(row_block, col_block, block_q: int, block_k: int, window):
+  """[block_q, block_k] bool: which pairs of a straddling tile are
+  seen, `col <= row` and with a window `col > row - window`."""
+  rows = row_block * block_q + jax.lax.broadcasted_iota(
+      jnp.int32, (block_q, block_k), 0)
+  cols = col_block * block_k + jax.lax.broadcasted_iota(
+      jnp.int32, (block_q, block_k), 1)
+  mask = cols <= rows
+  if window is not None:
+    mask &= cols > rows - window
+  return mask
+
+
+def band_blocks(window: int, block: int) -> int:
+  """Key blocks of `block` that the band of a query block of `block`
+  rows touches: its own (the diagonal) and the ceil((window - 1) /
+  block) before it. The same count of query blocks sees a key block."""
+  return 1 + -(-(window - 1) // block)
+
+
+def window_tiling(t: int, window: int, block_q: int = 1024,
+                  block_k: int = 2048):
+  """How a band of `window` over `t` positions is tiled, static:
+  (block, blocks a row of tiles visits, pairs the band holds, pairs of
+  the tiles the grid computes), the last two for one head of one row.
+  With a window both blocks are one size, the largest power-of-two
+  divisor of `t` that is at most the window and both requests: a wider
+  tile computes `block - window` columns a row that the band does not
+  hold (`flash_attention`)."""
+  block = _window_block(block_q, block_k, window, t)
+  visited = band_blocks(window, block)
+  nq = t // block
+  tiles = sum(min(i + 1, visited) for i in range(nq))
+  held = min(window, t)  # the first `held` rows see 1 .. held keys
+  band = held * (held + 1) // 2 + (t - held) * held
+  return block, visited, band, tiles * block * block
+
+
+def _window_block(block_q: int, block_k: int, window: int, t: int) -> int:
+  # The power of two at or under the window; Mosaic tiles no fewer
+  # than 8 rows, so a narrower window still takes blocks of 8.
+  floor = max(8, 1 << (window.bit_length() - 1))
+  return _auto_block(min(block_q, block_k, floor), t)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                   acc_scr, *, scale: float, causal: bool, block_q: int,
-                  block_k: int, num_k_blocks: int):
-  """Grid (batch*heads, T/block_q, T/block_k); innermost dim iterates
-  K/V blocks sequentially (TPU grids are loops), accumulating into
-  VMEM scratch; the last K step normalizes, writes the output and the
-  logsumexp (the backward's residual)."""
+                  block_k: int, num_k_blocks: int, window=None):
+  """Grid (batch*heads, T/block_q, key blocks visited); innermost dim
+  iterates K/V blocks sequentially (TPU grids are loops), accumulating
+  into VMEM scratch; the last K step normalizes, writes the output and
+  the logsumexp (the backward's residual). Without a window the key
+  blocks visited are all T/block_k; with one, the `num_k_blocks` that
+  end at the query block's own (`_band_col`)."""
   j = pl.program_id(2)
 
   @pl.when(j == 0)
@@ -145,6 +226,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
   # itself is built INSIDE the masked branch so unmasked tiles pay
   # for neither the iotas nor the selects.
   i = pl.program_id(1) if causal else None
+  col = j if window is None else _band_col(i, j, num_k_blocks)
 
   def _update_impl(use_mask):
     q = q_ref[0]  # [block_q, D]
@@ -153,11 +235,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale  # [bq, bk]
     if use_mask:
-      rows = i * block_q + jax.lax.broadcasted_iota(
-          jnp.int32, (block_q, block_k), 0)
-      cols = j * block_k + jax.lax.broadcasted_iota(
-          jnp.int32, (block_q, block_k), 1)
-      mask = cols <= rows
+      mask = _tile_mask(i, col, block_q, block_k, window)
       s = jnp.where(mask, s, _NEG_INF)
 
     m_prev = m_scr[...]
@@ -176,13 +254,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     # Tri-regime causal tiling (see _causal_tile_regimes): at T=32k
     # with bq=1024/bk=2048 only ~1 straddling block per q row pays
     # for the mask iotas + selects; fully-future tiles (half the
-    # grid) skip all compute. (`fully_past` implies `not_future`,
+    # grid) skip all compute. (`unmasked` implies `visible`,
     # but the conjunction keeps the two pl.when predicates visibly
-    # disjoint-and-exhaustive over the not-future half.)
-    not_future, fully_past = _causal_tile_regimes(
-        i, j, block_q, block_k)
-    pl.when(not_future & fully_past)(lambda: _update_impl(False))
-    pl.when(not_future & jnp.logical_not(fully_past))(
+    # disjoint-and-exhaustive over the visible tiles.)
+    visible, unmasked = _causal_tile_regimes(
+        i, col, block_q, block_k, window)
+    if window is not None:
+      visible &= col >= 0  # a band's first rows: blocks before the first
+    pl.when(visible & unmasked)(lambda: _update_impl(False))
+    pl.when(visible & jnp.logical_not(unmasked))(
         lambda: _update_impl(True))
   else:
     _update_impl(False)
@@ -204,31 +284,70 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     lse_ref[0, 0] = m_scr[...] + jnp.log(l_final)  # [block_q, 1]
 
 
+def _band_col(row_block, step, visited: int):
+  """The key block that step `step` of `visited` reads for query block
+  `row_block` of a band (both blocks one size): the last step reads
+  the diagonal block, the ones before it the blocks behind. Negative
+  for a band's first rows, whose band starts at position 0: the index
+  map reads block 0 there and the kernel computes nothing."""
+  return row_block - (visited - 1) + step
+
+
+def _band_row(col_block, step):
+  """The query block that step `step` reads for key block `col_block`
+  of a band: the diagonal block first, then the ones after it; past
+  the last block the index map reads the last and nothing is
+  computed."""
+  return col_block + step
+
+
+def _kv_index(g, group: int):
+  """The key-value head's row of the folded [B * KV, T, D] arrays that
+  query head row `g` of [B * H, T, D] reads: heads h of a group of
+  `group` share key-value head h // group."""
+  return g if group == 1 else g // group
+
+
+def _fold(x):
+  """[B, T, H, D] -> [B*H, T, D]: one grid row per (batch, head)."""
+  b, t, h, d = x.shape
+  return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
 def _flash_forward_impl(q, k, v, causal: bool, block_q: int,
-                        block_k: int, interpret: bool
+                        block_k: int, interpret: bool, window=None
                         ) -> Tuple[jax.Array, jax.Array]:
   """Runs the kernel; returns (out [B,T,H,Dv], lse [B*H, T]). q and
-  k are `d` wide, v and the output `dv`: the two need not be equal."""
+  k are `d` wide, v and the output `dv`: the two need not be equal.
+  k and v may come with fewer heads than q (a divisor): query head h
+  reads key-value head h // group from where it lies, through the
+  blocks' index maps, and nothing is repeated."""
   b, t, h, d = q.shape
   dv = v.shape[-1]
+  group = h // k.shape[2]
   num_q_blocks = t // block_q
-  num_k_blocks = t // block_k
+  num_k_blocks = (t // block_k if window is None
+                  else band_blocks(window, block_k))
   scale = 1.0 / np.sqrt(d)
 
-  # [B, T, H, D] -> [B*H, T, D]: one grid row per (batch, head).
-  def fold(x):
-    return x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
+  if window is None:
+    def kv_map(g, i, j):
+      return (_kv_index(g, group), j, 0)
+  else:
+    def kv_map(g, i, j):
+      return (_kv_index(g, group),
+              jnp.maximum(_band_col(i, j, num_k_blocks), 0), 0)
 
   kernel = functools.partial(
       _flash_kernel, scale=scale, causal=causal, block_q=block_q,
-      block_k=block_k, num_k_blocks=num_k_blocks)
+      block_k=block_k, num_k_blocks=num_k_blocks, window=window)
   out, lse = pl.pallas_call(
       kernel,
       grid=(b * h, num_q_blocks, num_k_blocks),
       in_specs=[
           pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0)),
-          pl.BlockSpec((1, block_k, d), lambda g, i, j: (g, j, 0)),
-          pl.BlockSpec((1, block_k, dv), lambda g, i, j: (g, j, 0)),
+          pl.BlockSpec((1, block_k, d), kv_map),
+          pl.BlockSpec((1, block_k, dv), kv_map),
       ],
       out_specs=[
           pl.BlockSpec((1, block_q, dv), lambda g, i, j: (g, i, 0)),
@@ -250,7 +369,7 @@ def _flash_forward_impl(q, k, v, causal: bool, block_q: int,
       ],
       compiler_params=_COMPILER_PARAMS,
       interpret=interpret,
-  )(fold(q), fold(k), fold(v))
+  )(_fold(q), _fold(k), _fold(v))
   return (out.reshape(b, h, t, dv).transpose(0, 2, 1, 3),
           lse.reshape(b * h, t))
 
@@ -258,18 +377,27 @@ def _flash_forward_impl(q, k, v, causal: bool, block_q: int,
 def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
                  causal: bool, block_q: int, block_k: int,
-                 num_q_blocks: int):
-  """Grid (B*H, T/block_k, T/block_q); the innermost dim iterates Q
-  blocks sequentially, accumulating this K-block's dk/dv in VMEM
-  scratch from recomputed p = exp(s − lse) tiles; the last Q step
-  writes out."""
+                 num_q_blocks: int, window=None, group: int = 1,
+                 total_q_blocks: int = 0):
+  """Grid (B*KV, T/block_k, group * query blocks visited); the
+  innermost dim iterates, for each query head of the key-value head's
+  group in turn, the Q blocks sequentially, accumulating this
+  K-block's dk/dv in float32 VMEM scratch from recomputed
+  p = exp(s − lse) tiles; the last step writes out, so a group's sum
+  is made once, in float32. Without a window the query blocks visited
+  are all `num_q_blocks` = T/block_q; with one, the `num_q_blocks`
+  from the key block's own on (`_band_row`), of `total_q_blocks`."""
   j = pl.program_id(1)
-  qi = pl.program_id(2)
+  step = pl.program_id(2)
 
-  @pl.when(qi == 0)
+  @pl.when(step == 0)
   def _init():
     dk_scr[...] = jnp.zeros_like(dk_scr)
     dv_scr[...] = jnp.zeros_like(dv_scr)
+
+  qi = step if group == 1 else step % num_q_blocks
+  if window is not None:
+    qi = _band_row(j, qi)
 
   def _update_impl(use_mask):
     q = q_ref[0]                                   # [bq, D]
@@ -284,11 +412,7 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale  # [bq, bk]
     if use_mask:
-      rows = qi * block_q + jax.lax.broadcasted_iota(
-          jnp.int32, (block_q, block_k), 0)
-      cols = j * block_k + jax.lax.broadcasted_iota(
-          jnp.int32, (block_q, block_k), 1)
-      mask = cols <= rows
+      mask = _tile_mask(qi, j, block_q, block_k, window)
       s = jnp.where(mask, s, _NEG_INF)
     p = jnp.exp(s - lse)
     if use_mask:
@@ -308,17 +432,19 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         preferred_element_type=jnp.float32)
 
   if causal:
-    # Same tri-regime tiling as the forward (shared predicates).
-    not_future, fully_past = _causal_tile_regimes(
-        qi, j, block_q, block_k)
-    pl.when(not_future & fully_past)(
+    # Same regimes as the forward (shared predicates).
+    visible, unmasked = _causal_tile_regimes(
+        qi, j, block_q, block_k, window)
+    if window is not None:
+      visible &= qi < total_q_blocks  # the band's last rows
+    pl.when(visible & unmasked)(
         lambda: _update_impl(False))
-    pl.when(not_future & jnp.logical_not(fully_past))(
+    pl.when(visible & jnp.logical_not(unmasked))(
         lambda: _update_impl(True))
   else:
     _update_impl(False)
 
-  @pl.when(qi == num_q_blocks - 1)
+  @pl.when(step == group * num_q_blocks - 1)
   def _finalize():
     dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
     dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -326,13 +452,16 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, dq_scr, *, scale: float, causal: bool,
-               block_q: int, block_k: int, num_k_blocks: int):
-  """Grid (B*H, T/block_q, T/block_k); innermost iterates K blocks,
-  accumulating this Q-block's dq = Σ_j ds_j·k_j in VMEM scratch."""
+               block_q: int, block_k: int, num_k_blocks: int,
+               window=None):
+  """Grid (B*H, T/block_q, key blocks visited); innermost iterates K
+  blocks, accumulating this Q-block's dq = Σ_j ds_j·k_j in VMEM
+  scratch. The key blocks visited are the forward's."""
   i = pl.program_id(1)
-  kj = pl.program_id(2)
+  step = pl.program_id(2)
+  kj = step if window is None else _band_col(i, step, num_k_blocks)
 
-  @pl.when(kj == 0)
+  @pl.when(step == 0)
   def _init():
     dq_scr[...] = jnp.zeros_like(dq_scr)
 
@@ -347,11 +476,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     if use_mask:
-      rows = i * block_q + jax.lax.broadcasted_iota(
-          jnp.int32, (block_q, block_k), 0)
-      cols = kj * block_k + jax.lax.broadcasted_iota(
-          jnp.int32, (block_q, block_k), 1)
-      mask = cols <= rows
+      mask = _tile_mask(i, kj, block_q, block_k, window)
       s = jnp.where(mask, s, _NEG_INF)
     p = jnp.exp(s - lse)
     if use_mask:
@@ -365,23 +490,26 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         preferred_element_type=jnp.float32)
 
   if causal:
-    # Same tri-regime tiling as the forward (shared predicates).
-    not_future, fully_past = _causal_tile_regimes(
-        i, kj, block_q, block_k)
-    pl.when(not_future & fully_past)(
+    # Same regimes as the forward (shared predicates).
+    visible, unmasked = _causal_tile_regimes(
+        i, kj, block_q, block_k, window)
+    if window is not None:
+      visible &= kj >= 0
+    pl.when(visible & unmasked)(
         lambda: _update_impl(False))
-    pl.when(not_future & jnp.logical_not(fully_past))(
+    pl.when(visible & jnp.logical_not(unmasked))(
         lambda: _update_impl(True))
   else:
     _update_impl(False)
 
-  @pl.when(kj == num_k_blocks - 1)
+  @pl.when(step == num_k_blocks - 1)
   def _finalize():
     dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
-                    block_q: int, block_k: int, interpret: bool):
+                    block_q: int, block_k: int, interpret: bool,
+                    window=None):
   """Pallas flash backward: dkdv kernel + dq kernel.
 
   `dlse` ([BH, T]) is the cotangent of the logsumexp output — zeros
@@ -389,16 +517,23 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
   folds into the softmax-jacobian diagonal as ds = p·(dp − (δ − g)) —
   one subtraction in the precomputed per-row term, which is what makes
   the lse-composed ring attention trainable through this kernel.
+
+  Where k and v have fewer heads than q, dk and dv come out with k's
+  and v's heads: the dkdv kernel's grid runs over the key-value heads
+  and walks each one's group of query heads in its innermost
+  dimension, so the group's sum is made in the float32 scratch.
   """
   b, t, h, d = q.shape
+  kv = k.shape[2]
+  group = h // kv
   dv = v.shape[-1]  # v, out, do and dv are this wide; q, k, dq, dk `d`
   scale = 1.0 / np.sqrt(d)
   nq, nk = t // block_q, t // block_k
+  # Blocks visited along the inner dimension: all of them, or a band's.
+  visited = None if window is None else band_blocks(window, block_k)
+  nq_in, nk_in = (nq, nk) if window is None else (visited, visited)
 
-  def fold(x):  # [B, T, H, D] -> [B*H, T, D]
-    return x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
-
-  q_f, k_f, v_f, do_f, o_f = map(fold, (q, k, v, do, out))
+  q_f, k_f, v_f, do_f, o_f = map(_fold, (q, k, v, do, out))
   # δ_i = rowsum(dO·O) − dlse_i: the softmax-jacobian row term, a
   # cheap elementwise reduce XLA fuses. Both per-row vectors enter
   # the kernels in the forward's SUBLANE-major [BH, nq, block_q, 1]
@@ -417,28 +552,47 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
   lse = tile_cols(lse)
   delta = tile_cols(delta)
 
+  # The dkdv grid's rows are key-value heads; step `s` of its inner
+  # dimension is query head `s // nq_in` of the group, at the
+  # `s % nq_in`-th query block visited.
+  if group == 1 and window is None:
+    def q_row(g, j, s):
+      return g, s
+  else:
+    def q_row(g, j, s):
+      head = g if group == 1 else g * group + s // nq_in
+      block = s if group == 1 else s % nq_in
+      if window is not None:
+        block = jnp.minimum(_band_row(j, block), nq - 1)
+      return head, block
+
+  def q_map(g, j, s):
+    return (*q_row(g, j, s), 0)
+
+  def row_map(g, j, s):
+    return (*q_row(g, j, s), 0, 0)
+
   dk_f, dv_f = pl.pallas_call(
       functools.partial(_dkdv_kernel, scale=scale, causal=causal,
                         block_q=block_q, block_k=block_k,
-                        num_q_blocks=nq),
-      grid=(b * h, nk, nq),
+                        num_q_blocks=nq_in, window=window, group=group,
+                        total_q_blocks=nq),
+      grid=(b * kv, nk, group * nq_in),
       in_specs=[
-          pl.BlockSpec((1, block_q, d), lambda g, j, i: (g, i, 0)),
+          pl.BlockSpec((1, block_q, d), q_map),
           pl.BlockSpec((1, block_k, d), lambda g, j, i: (g, j, 0)),
           pl.BlockSpec((1, block_k, dv), lambda g, j, i: (g, j, 0)),
-          pl.BlockSpec((1, block_q, dv), lambda g, j, i: (g, i, 0)),
-          pl.BlockSpec((1, 1, block_q, 1),
-                       lambda g, j, i: (g, i, 0, 0)),
-          pl.BlockSpec((1, 1, block_q, 1),
-                       lambda g, j, i: (g, i, 0, 0)),
+          pl.BlockSpec((1, block_q, dv), q_map),
+          pl.BlockSpec((1, 1, block_q, 1), row_map),
+          pl.BlockSpec((1, 1, block_q, 1), row_map),
       ],
       out_specs=[
           pl.BlockSpec((1, block_k, d), lambda g, j, i: (g, j, 0)),
           pl.BlockSpec((1, block_k, dv), lambda g, j, i: (g, j, 0)),
       ],
       out_shape=[
-          jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
-          jax.ShapeDtypeStruct((b * h, t, dv), v.dtype),
+          jax.ShapeDtypeStruct((b * kv, t, d), k.dtype),
+          jax.ShapeDtypeStruct((b * kv, t, dv), v.dtype),
       ],
       scratch_shapes=[
           pltpu.VMEM((block_k, d), jnp.float32),   # dk accumulator
@@ -448,15 +602,23 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
       interpret=interpret,
   )(q_f, k_f, v_f, do_f, lse, delta)
 
+  if window is None:
+    def kv_map(g, i, j):
+      return (_kv_index(g, group), j, 0)
+  else:
+    def kv_map(g, i, j):
+      return (_kv_index(g, group),
+              jnp.maximum(_band_col(i, j, nk_in), 0), 0)
+
   dq_f = pl.pallas_call(
       functools.partial(_dq_kernel, scale=scale, causal=causal,
                         block_q=block_q, block_k=block_k,
-                        num_k_blocks=nk),
-      grid=(b * h, nq, nk),
+                        num_k_blocks=nk_in, window=window),
+      grid=(b * h, nq, nk_in),
       in_specs=[
           pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0)),
-          pl.BlockSpec((1, block_k, d), lambda g, i, j: (g, j, 0)),
-          pl.BlockSpec((1, block_k, dv), lambda g, i, j: (g, j, 0)),
+          pl.BlockSpec((1, block_k, d), kv_map),
+          pl.BlockSpec((1, block_k, dv), kv_map),
           pl.BlockSpec((1, block_q, dv), lambda g, i, j: (g, i, 0)),
           pl.BlockSpec((1, 1, block_q, 1),
                        lambda g, i, j: (g, i, 0, 0)),
@@ -472,21 +634,21 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
       interpret=interpret,
   )(q_f, k_f, v_f, do_f, lse, delta)[0]
 
-  def unfold(x):  # [BH, T, D] -> [B, T, H, D]
-    return x.reshape(b, h, t, x.shape[-1]).transpose(0, 2, 1, 3)
+  def unfold(x, heads):  # [B*heads, T, D] -> [B, T, heads, D]
+    return x.reshape(b, heads, t, x.shape[-1]).transpose(0, 2, 1, 3)
 
-  return unfold(dq_f), unfold(dk_f), unfold(dv_f)
+  return unfold(dq_f, h), unfold(dk_f, kv), unfold(dv_f, kv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse(q, k, v, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse(q, k, v, causal, block_q, block_k, interpret, window):
   return _flash_forward_impl(q, k, v, causal, block_q, block_k,
-                             interpret)
+                             interpret, window)
 
 
-def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret, window):
   out, lse = _flash_forward_impl(q, k, v, causal, block_q, block_k,
-                                 interpret)
+                                 interpret, window)
   # Named HERE, where they become residuals, and both: a
   # `jax.checkpoint` whose policy saves `SAVED_RESIDUAL_NAMES` then
   # hands the backward these two arrays and does not run the forward
@@ -498,20 +660,44 @@ def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
   return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_lse_bwd(causal, block_q, block_k, interpret, residuals,
-                   cotangents):
+def _flash_lse_bwd(causal, block_q, block_k, interpret, window,
+                   residuals, cotangents):
   q, k, v, out, lse = residuals
   do, dlse = cotangents
   return _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal, block_q,
-                         block_k, interpret)
+                         block_k, interpret, window)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+def _blocks_and_window(q, k, causal: bool, block_q: int, block_k: int,
+                       window: Optional[int]):
+  """(block_q, block_k, window) as the programs take them. A window
+  needs `causal`; one that holds the whole sequence is no window, and
+  lowers to the causal programs; under a narrower one both blocks are
+  `window_tiling`'s."""
+  t = q.shape[1]
+  if q.shape[2] % k.shape[2]:
+    raise ValueError(f"{q.shape[2]} query heads over {k.shape[2]} "
+                     "key-value heads: not a whole group each")
+  if window is not None:
+    if not causal:
+      raise ValueError("a window is a band under the causal mask: "
+                       "pass causal=True")
+    if window < 1:
+      raise ValueError(f"window {window}: a query sees itself at least")
+    if window >= t:
+      window = None
+  if window is None:
+    return _auto_block(block_q, t), _auto_block(block_k, t), None
+  block = _window_block(block_q, block_k, window, t)
+  return block, block, window
+
+
 @functools.partial(
     jax.jit, static_argnames=("causal", "block_q", "block_k",
-                              "interpret"))
+                              "interpret", "window"))
 def flash_attention_with_lse(
     q: jax.Array,
     k: jax.Array,
@@ -520,6 +706,7 @@ def flash_attention_with_lse(
     block_q: int = 1024,
     block_k: int = 2048,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
   """Like `flash_attention` but also returns the logsumexp.
 
@@ -533,15 +720,16 @@ def flash_attention_with_lse(
   ring's merge — is exact.
   """
   b, t, h, d = q.shape
-  block_q = _auto_block(block_q, t)
-  block_k = _auto_block(block_k, t)
-  out, lse = _flash_lse(q, k, v, causal, block_q, block_k, interpret)
+  block_q, block_k, window = _blocks_and_window(q, k, causal, block_q,
+                                                block_k, window)
+  out, lse = _flash_lse(q, k, v, causal, block_q, block_k, interpret,
+                        window)
   return out, lse.reshape(b, h, t)
 
 
 @functools.partial(
     jax.jit, static_argnames=("causal", "block_q", "block_k",
-                              "interpret"))
+                              "interpret", "window"))
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -550,13 +738,24 @@ def flash_attention(
     block_q: int = 1024,
     block_k: int = 2048,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
-  """Exact attention, O(T) memory both ways. q, k [B, T, H, Dk],
-  v [B, T, H, Dv] → [B, T, H, Dv]; the scale is Dk^-1/2. The two
-  widths are read off the arguments and need not be equal (latent
-  attention: keys of 192 over values of 128): every tile, scratch and
-  result has its own, so P·V, dO·Vᵀ and dV are Dv wide and no value is
-  padded to the keys' width.
+  """Exact attention, O(T) memory both ways. q [B, T, H, Dk], k
+  [B, T, KV, Dk], v [B, T, KV, Dv] → [B, T, H, Dv]; the scale is
+  Dk^-1/2. The two widths are read off the arguments and need not be
+  equal (latent attention: keys of 192 over values of 128): every
+  tile, scratch and result has its own, so P·V, dO·Vᵀ and dV are Dv
+  wide and no value is padded to the keys' width. KV divides H: query
+  head h attends over key-value head h // (H / KV), read where it
+  lies; dk and dv come back KV heads wide, a group's sum made in
+  float32.
+
+  With `causal` and `window`, query i sees key j iff 0 <= i - j <
+  window (itself and the window - 1 before it). The grids then walk
+  the band's tiles alone (`window_tiling`): both blocks are the
+  largest power of two that is at most the window, the requests and
+  divides T, and a row of tiles visits 1 + ceil((window - 1) / block)
+  key blocks, whatever T is. A window of T or more is `causal`.
 
   Block sizes auto-shrink to divide T (`_auto_block`), so any static
   T works; power-of-two T keeps the large overhead-amortizing blocks.
@@ -565,8 +764,8 @@ def flash_attention(
   dropped lse output contributes a zero cotangent, so there is exactly
   ONE backward implementation to keep correct.
   """
-  b, t, h, d = q.shape
-  block_q = _auto_block(block_q, t)
-  block_k = _auto_block(block_k, t)
-  out, _ = _flash_lse(q, k, v, causal, block_q, block_k, interpret)
+  block_q, block_k, window = _blocks_and_window(q, k, causal, block_q,
+                                                block_k, window)
+  out, _ = _flash_lse(q, k, v, causal, block_q, block_k, interpret,
+                      window)
   return out

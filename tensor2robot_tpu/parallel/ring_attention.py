@@ -47,17 +47,24 @@ _NEG_INF = -1e30  # finite sentinel: avoids -inf - -inf = nan paths
 
 
 def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
-                        causal: bool = False) -> jax.Array:
+                        causal: bool = False,
+                        window: Optional[int] = None) -> jax.Array:
   """Plain softmax attention (f32 accumulation), the exactness oracle.
 
-  q, k, v: [B, T, H, D] → [B, T, H, D].
+  q, k [B, T, H, D], v [B, T, H, Dv] → [B, T, H, Dv]. With `causal`
+  and `window`, query i sees key j iff 0 <= i - j < window.
   """
   scale = 1.0 / np.sqrt(q.shape[-1])
   s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                  k.astype(jnp.float32)) * scale
+  if window is not None and not causal:
+    raise ValueError("a window is a band under the causal mask")
   if causal:
     t = q.shape[1]
-    mask = jnp.tril(jnp.ones((t, t), bool))
+    behind = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    mask = behind >= 0
+    if window is not None:
+      mask &= behind < window
     s = jnp.where(mask[None, None], s, _NEG_INF)
   p = jax.nn.softmax(s, axis=-1)
   out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))

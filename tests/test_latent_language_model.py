@@ -508,11 +508,16 @@ def test_lm_mla_step_mfu_on_made_up_records():
 def test_benchmark_json_has_the_new_entries_and_no_other():
   with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     bench = json.load(f)
-  assert bench["configs"][-1]["name"] == "joyai_llm_flash_ep16"
-  assert bench["workloads"][-1] == {
+  # ISSUE 43 appended a third family's configuration, its cell and
+  # its seven metrics (tests/test_windowed_language_model.py).
+  assert bench["configs"][-2]["name"] == "joyai_llm_flash_ep16"
+  assert bench["workloads"][-2] == {
       "name": CELL, "config": "joyai_llm_flash_ep16",
       "traffic": "train_eval", "chips": 1,
-      "why": bench["workloads"][-1]["why"]}
+      "why": bench["workloads"][-2]["why"]}
+  assert all(m["name"].startswith("lm_swa_")
+             for m in bench["per_layer"][-7:])
+  bench["per_layer"] = bench["per_layer"][:-7]
   # ISSUE 42 appended the fused forward pass of the other family's
   # delta rule and ISSUE 41 its recomputation; ISSUE 39 the rounds
   # that both families' expert layers run; ISSUE 38 start-up's seven,

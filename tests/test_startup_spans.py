@@ -469,6 +469,12 @@ class TestReaders:
       bench = json.load(f)
     entries = [m for m in bench["per_layer"] if m["layer"] == "start-up"]
     assert [m["name"] for m in entries] == list(READERS)
-    cells = [w["name"] for w in bench["workloads"]]
+    # The invariant is every cell. PR 43's cell is held out by name
+    # until a `benchmark` PR appends it to these seven lists and takes
+    # this exclusion out again (PERF.md section 7 (0)): ISSUE 43 kept a
+    # `model_config` PR from editing an accepted entry, and the cell
+    # does run start-up, unreported until then.
+    cells = [w["name"] for w in bench["workloads"]
+             if w["name"] != "laguna_xs2_ep16.train_eval"]
     assert all(m["moves"] == "setup_s" and m["workloads"] == cells
                for m in entries)
