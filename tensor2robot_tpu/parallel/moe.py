@@ -455,6 +455,50 @@ def _rounds_bwd(rows, dtype, args, cotangents):
 _rounds.defvjp(_rounds_fwd, _rounds_bwd)
 
 
+@jax.custom_vjp
+def _sort_by_expert(local, flat):
+  """The assignments' stable order by `local` [A] (the held expert's
+  number; `held` where the expert is not held here) and their weights
+  `flat` [A] in that order, out of ONE sort: the positions and the
+  weights ride the keys. -> (order [A] int32, `flat` in that order).
+
+  Its own gradient rule: JAX's rule for a sort of several operands
+  gathers the tangents by the order, and its transpose scatter-adds
+  them; `order` is a permutation, so a sort of the cotangent by
+  `order` puts each value where the scatter-add would have (nothing is
+  summed). A gather or a scatter of scalars runs as a serial loop on
+  the TPU, 7-9 ns an element, where a sort of the same 1.3 MB takes a
+  third of a millisecond (PERF.md section 6, PR 44)."""
+  _, order, riding = jax.lax.sort(
+      (local, jax.lax.iota(jnp.int32, local.shape[0]), flat),
+      num_keys=1, is_stable=True)
+  return order, riding
+
+
+def _sort_by_expert_fwd(local, flat):
+  order, riding = _sort_by_expert(local, flat)
+  return (order, riding), order
+
+
+def _sort_by_expert_bwd(order, cotangents):
+  # A permutation has no ties: a stable sort would carry a third
+  # operand on the TPU to keep apart what cannot meet.
+  _, d_flat = jax.lax.sort((order, cotangents[1]), num_keys=1,
+                           is_stable=False)
+  return None, d_flat  # the keys have none
+
+
+_sort_by_expert.defvjp(_sort_by_expert_fwd, _sort_by_expert_bwd)
+
+
+def _held_counts(local, held):
+  """The assignments on each held expert, [held] int32: `local` [A]
+  compared with every held expert's number and summed, one pass over
+  the keys (counting into bins is a scatter-add of A ones)."""
+  return jnp.sum(local[None, :] == jnp.arange(held)[:, None], axis=1,
+                 dtype=jnp.int32)
+
+
 def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, *,
                      first_expert: int, num_experts: int,
                      dtype: Any = jnp.bfloat16):
@@ -465,7 +509,8 @@ def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, *,
   Returns ([N, M] float32, counters).
 
   Dropless with static shapes: the N * k assignments are sorted by
-  expert (those not held here sort last), and the sorted rows are
+  expert (those not held here sort last; their positions and weights
+  ride the one sort, `_sort_by_expert`), and the sorted rows are
   worked off in rounds of `round_rows` rows, each one gather, three
   grouped matrix products and one scatter-add. Only the rounds that
   hold a held assignment run (`_rounds`: one loop forward, one
@@ -478,13 +523,13 @@ def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, *,
   rows = round_rows(total, held, num_experts)
   local = experts.reshape(-1) - first_expert
   local = jnp.where((local >= 0) & (local < held), local, held)
-  order = jnp.argsort(local, stable=True)
+  order, weight = _sort_by_expert(local, weights.reshape(-1))
   # Whole rounds: the last one's rows past the assignments are no
   # group's (nothing where `rows` divides the assignments).
   spare = -total % rows
-  token = jnp.pad((order // k).astype(jnp.int32), (0, spare))
-  weight = jnp.pad(weights.reshape(-1)[order], (0, spare))
-  counts = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+  token = jnp.pad(order // k, (0, spare))
+  weight = jnp.pad(weight, (0, spare))
+  counts = _held_counts(local, held)
   ends = jnp.cumsum(counts)
   here = ends[-1]
   out, given, rounds = _rounds(here, token, ends, counts, x.astype(dtype),

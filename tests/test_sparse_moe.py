@@ -333,15 +333,19 @@ def test_the_rounds_that_run_equal_the_reference(monkeypatch, case,
       here / (n * k))
 
 
-def _equations(jaxpr):
-  """Every equation of a jaxpr and of the jaxprs inside it."""
+def _equations(jaxpr, into_loops=True):
+  """Every equation of a jaxpr and of the jaxprs inside it; without
+  `into_loops` a `while` stands for itself, its condition and body
+  unopened."""
   for eqn in jaxpr.eqns:
     yield eqn
+    if eqn.primitive.name == "while" and not into_loops:
+      continue
     for value in eqn.params.values():
       for inner in value if isinstance(value, (tuple, list)) else (value,):
         inner = getattr(inner, "jaxpr", inner)
         if hasattr(inner, "eqns"):
-          yield from _equations(inner)
+          yield from _equations(inner, into_loops)
 
 
 def test_the_gradients_program_loops_over_the_rounds_that_run():
@@ -375,6 +379,143 @@ def test_the_gradients_program_loops_over_the_rounds_that_run():
     assert made and not any(
         out.aval.shape == (n, WIDTH) for eqn in made
         for out in eqn.outvars)
+
+
+def test_the_gradients_program_gathers_and_scatters_inside_its_loops_alone():
+  """Outside its two loops over rounds the program of the layer's
+  gradient moves no scalar by index: no `gather`, no `scatter`, no
+  `scatter-add` (each a serial loop on the TPU, 7-9 ns an element:
+  ISSUE 44). What it sorts is the assignments by expert, once, with
+  their positions and weights riding, and the weights' cotangent back
+  by the order; the counts come from a compare and a sum."""
+  n = ROUNDS["tokens"]
+  x = jax.random.normal(jax.random.PRNGKey(11), (n, WIDTH))
+  experts, weights = _routing("pushed")
+
+  def program(x, weights, *matrices):
+    return jnp.sum(_held(x, experts, weights, *matrices)[0] ** 2)
+
+  jaxpr = jax.make_jaxpr(jax.grad(program, argnums=(0, 1, 2, 3, 4)))(
+      x, weights, *_expert_weights()).jaxpr
+  names = [eqn.primitive.name for eqn in _equations(jaxpr, False)]
+  assert names.count("while") == 2
+  assert not [name for name in names
+              if name.startswith(("gather", "scatter"))]
+  # The guard sees a gather where one stands: each loop's body has the
+  # round's own.
+  inside = [eqn.primitive.name for eqn in _equations(jaxpr)]
+  assert inside.count("gather") >= 2 and "scatter-add" in inside
+  sorts = [(len(eqn.invars), eqn.params["num_keys"],
+            eqn.params["is_stable"])
+           for eqn in _equations(jaxpr) if eqn.primitive.name == "sort"]
+  # Forward: keys, positions and weights, ties in the order they came.
+  # Backward: the order (a permutation: no ties) and the cotangent.
+  assert sorted(sorts) == [(2, 1, False), (3, 1, True)]
+
+
+def _old_sort_by_expert(local, flat):
+  """`moe._sort_by_expert` as it stood before ISSUE 44: a stable
+  argsort and a gather by its order, whose gradient JAX scatter-adds."""
+  order = jnp.argsort(local, stable=True)
+  return order, flat[order]
+
+
+def _old_held_counts(local, held):
+  """`moe._held_counts` as it stood before ISSUE 44."""
+  return jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+
+
+def _strained(case):
+  """(experts [N, k], first, held, num_experts) of routings that strain
+  a sort."""
+  key = jax.random.PRNGKey(21)
+  if case == "every_expert_held":
+    return jax.lax.top_k(jax.random.normal(key, (300, 8)), 3)[1], 0, 8, 8
+  if case == "none_of_a_tokens_experts_held":
+    # Every second token chooses among experts 8..15 alone.
+    chosen = jax.lax.top_k(jax.random.normal(key, (300, 8)), 2)[1]
+    return chosen + 8 * (jnp.arange(300) % 2)[:, None], 2, 4, 16
+  if case == "nothing_held":
+    return jax.lax.top_k(jax.random.normal(key, (64, 4)), 2)[1], 8, 4, 16
+  if case == "all_on_one_expert":
+    return jnp.full((257, 3), 5, jnp.int32), 4, 2, 16
+  if case == "long_runs_of_equal_keys":
+    # Runs of 97 assignments an expert, in an order that is not sorted.
+    return ((jnp.arange(2000 * 2) // 97 * 5) % 8).reshape(2000, 2), 2, 4, 8
+  if case == "rows_do_not_divide":
+    return _routing("pushed")[0], ROUNDS["first"], ROUNDS["held"], 16
+  if case == "held_1":
+    return jax.lax.top_k(jax.random.normal(key, (300, 8)), 3)[1], 6, 1, 8
+  raise ValueError(case)
+
+
+@pytest.mark.parametrize("case", [
+    "every_expert_held", "none_of_a_tokens_experts_held", "nothing_held",
+    "all_on_one_expert", "long_runs_of_equal_keys", "rows_do_not_divide",
+    "held_1"])
+def test_the_bookkeeping_equals_its_old_definitions(monkeypatch, case):
+  """The order, the weights in that order, the counts and the weights'
+  gradient: exactly what `jnp.argsort(stable=True)`, the gather by it
+  with JAX's own gradient, and `jnp.bincount` give; and the layer
+  around them gives the same result, counters and gradients to the
+  last bit as with those put back."""
+  experts, first, held, num_experts = _strained(case)
+  experts = experts.astype(jnp.int32)
+  n, k = experts.shape
+  keys = jax.random.split(jax.random.PRNGKey(22), 4)
+  weights = jax.random.uniform(keys[0], (n, k), minval=0.1)
+  probe = jax.random.normal(keys[1], (n * k,))
+  local = experts.reshape(-1) - first
+  local = jnp.where((local >= 0) & (local < held), local, held)
+
+  def riding(sort_by_expert):
+    def weighed(weights):
+      order, sorted_weights = sort_by_expert(local, weights.reshape(-1))
+      return jnp.sum(sorted_weights * probe), (order, sorted_weights)
+    return jax.grad(weighed, has_aux=True)(weights)
+
+  got_grad, (got_order, got_weights) = riding(moe._sort_by_expert)
+  want_grad, (want_order, want_weights) = riding(_old_sort_by_expert)
+  np.testing.assert_array_equal(got_order, want_order)
+  np.testing.assert_array_equal(got_weights, want_weights)
+  np.testing.assert_array_equal(got_grad, want_grad)
+  got_counts = moe._held_counts(local, held)
+  np.testing.assert_array_equal(got_counts, _old_held_counts(local, held))
+  assert got_counts.dtype == jnp.int32 and got_counts.shape == (held,)
+  if case == "nothing_held":
+    assert int(jnp.sum(got_counts)) == 0
+  if case == "long_runs_of_equal_keys":  # ties in the order they came
+    assert bool(jnp.all((jnp.diff(got_order) > 0)
+                        | (jnp.diff(local[got_order]) > 0)))
+
+  # The layer around the bookkeeping.
+  x = jax.random.normal(keys[2], (n, WIDTH))
+  gate, up, down = (jax.random.normal(key, shape) * 0.4 for key, shape in
+                    zip(jax.random.split(keys[3], 3),
+                        ((held, WIDTH, F), (held, WIDTH, F),
+                         (held, F, WIDTH))))
+
+  def layer(x, weights, *matrices):
+    out, counters = moe.held_experts_ffn(
+        x, experts, weights, *matrices, first_expert=first,
+        num_experts=num_experts, dtype=jnp.float32)
+    return jnp.sum(out ** 2), (out, counters)
+
+  def run():
+    return jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+        x, weights, gate, up, down)
+
+  got = run()
+  monkeypatch.setattr(moe, "_sort_by_expert", _old_sort_by_expert)
+  monkeypatch.setattr(moe, "_held_counts", _old_held_counts)
+  want = run()
+  for a, b in zip(jax.tree_util.tree_leaves(got),
+                  jax.tree_util.tree_leaves(want)):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+  if case == "rows_do_not_divide":
+    assert (n * k) % moe.round_rows(n * k, held, num_experts)
+  if case != "nothing_held":
+    assert float(jnp.max(jnp.abs(got[0][1]))) > 0.0  # weights' gradient
 
 
 def test_one_round_running_is_round_compute_called_once():
