@@ -3,9 +3,10 @@
 that may"; ISSUE 34): the cases of `benchmark/tests/test_follow.py`
 (the reference side's donated step, the leaf-by-leaf comparison) and of
 `benchmark/tests/test_trace_reduce.py` (whole programs of a recording)
-and `benchmark/tests/test_lm_gdn_recompute_device_ms.py` and
-`benchmark/tests/test_lm_gdn_fused_forward_share.py` (ISSUE 41's and
-ISSUE 42's readers), run here as they are; `harness/lm_flops.py` held against
+and `benchmark/tests/test_lm_gdn_recompute_device_ms.py`,
+`benchmark/tests/test_lm_gdn_fused_forward_share.py` and
+`benchmark/tests/test_lm_flash_backward_fused_share.py` (ISSUE 41's,
+ISSUE 42's and ISSUE 45's readers), run here as they are; `harness/lm_flops.py` held against
 XLA's own cost analysis of the reference's forward pass; the new
 readers on made-up records; BENCHMARK.json's new entries resolved to
 their files.
@@ -32,6 +33,7 @@ from benchmark.reference import qwen3_next as ref  # noqa: E402
 from benchmark.reference import qwen3_next_weights  # noqa: E402
 from benchmark.tests import test_benchmark_json as bench_json  # noqa: E402
 from benchmark.tests.test_follow import *  # noqa: E402,F401,F403
+from benchmark.tests.test_lm_flash_backward_fused_share import *  # noqa: E402,F401,F403
 from benchmark.tests.test_lm_gdn_fused_forward_share import *  # noqa: E402,F401,F403
 from benchmark.tests.test_lm_gdn_recompute_device_ms import *  # noqa: E402,F401,F403
 from benchmark.tests.test_trace_reduce import *  # noqa: E402,F401,F403

@@ -380,7 +380,10 @@ def _kernel_calls(jaxpr, found=None):
   return found
 
 
-FLASH_BACKWARD = {"_dkdv_kernel": 1, "_dq_kernel": 1}
+# The flash kernel's backward pass is one program (ISSUE 45); the pair
+# `_dkdv_kernel`, `_dq_kernel` is what a sequence takes whose
+# accumulators do not fit in VMEM.
+FLASH_BACKWARD = {"_fused_bwd_kernel": 1}
 
 
 # The delta rule's forward pass is the fused program (ISSUE 42); the

@@ -629,7 +629,7 @@ def test_benchmark_json_has_the_new_entries_and_no_other():
   # Seven of ISSUE 43's nine: a device time of the sliding layers, and
   # the rest that would be reckoned from it, wait for the scope
   # `window_attention` in the reduction's list (PERF.md section 7 (0)).
-  new = bench["per_layer"][-7:]
+  new = [m for m in bench["per_layer"] if m["name"].startswith("lm_swa_")]
   assert [m["name"] for m in new] == [
       "lm_swa_step_mfu", "lm_swa_full_attention_device_ms",
       "lm_swa_moe_device_ms", "lm_swa_window_attention_roofline",
@@ -640,10 +640,15 @@ def test_benchmark_json_has_the_new_entries_and_no_other():
     assert metric["moves"] == "train_steps_per_s"
     assert metric["better"] == (
         "lower" if metric["name"].endswith("_device_ms") else "higher")
-  # No accepted entry lists the new cell: appending it to their lists
-  # is a `benchmark` PR's (PERF.md section 7).
-  for metric in bench["per_layer"][:-7]:
+  # No entry accepted before the cell lists it: appending it to their
+  # lists is a `benchmark` PR's (PERF.md section 7). An entry born
+  # after it may: ISSUE 45's one, of the kernel all three families run.
+  first = bench["per_layer"].index(new[0])
+  assert bench["per_layer"][first:first + 7] == new
+  for metric in bench["per_layer"][:first]:
     assert CELL not in metric["workloads"], metric["name"]
+  assert [m["name"] for m in bench["per_layer"][first + 7:]] == [
+      "lm_flash_backward_fused_share"]
 
 
 # --- the banded programs compiled for the chip -------------------------
@@ -661,18 +666,17 @@ def one_chip():
   return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)])
-def test_kernels_compile_for_a_v5e_at_the_cells_widths(one_chip, heads,
-                                                       window):
-  """A sliding layer's call (64 heads over 8, a band of 512) and a full
-  layer's (48 over 8), one row of 8,192 positions in bfloat16, forward
-  and backward: what the interpreter cannot refuse (tiling, VMEM) is
-  refused here. Such a compile cannot be read back from the persistent
+def _compile_grad_for_the_chip(one_chip, t, heads, kv_heads, dk, dv,
+                                dtype, window):
+  """(compiled gradient of one row's causal attention, what the
+  backward pass counted): the forward program and the backward's, for
+  the v5e. Such a compile cannot be read back from the persistent
   cache: keep it out."""
   from jax.experimental.compilation_cache import compilation_cache
+  from tensor2robot_tpu.telemetry import metrics as tmetrics
 
-  def aval(heads):
-    return jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
+  def aval(heads, width):
+    return jax.ShapeDtypeStruct((1, t, heads, width), dtype,
                                 sharding=one_chip)
 
   def loss(q, k, v):
@@ -682,10 +686,70 @@ def test_kernels_compile_for_a_v5e_at_the_cells_widths(one_chip, heads,
   enabled = jax.config.jax_enable_compilation_cache
   jax.config.update("jax_enable_compilation_cache", False)
   compilation_cache.reset_cache()
+  tmetrics.reset_for_tests()
   try:
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
-        aval(heads), aval(8), aval(8)).compile()
+        aval(heads, dk), aval(kv_heads, dk), aval(kv_heads, dv)).compile()
+    counts = tmetrics.registry().scalars("flash_attention.backward.")
   finally:
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
-  assert compiled.as_text().count("tpu_custom_call") >= 3
+    tmetrics.reset_for_tests()
+  return compiled, counts
+
+
+# query heads, key-value heads, keys' width, values' width, window: a
+# sliding layer's call and a full layer's of this cell, and the JoyAI
+# cell's latent attention (ISSUE 45: the backward pass is one program
+# with a sequence's dQ, and under a group its dK and dV, in VMEM).
+@pytest.mark.parametrize("heads,kv_heads,dk,dv,window", [
+    (64, 8, 128, 128, 512), (48, 8, 128, 128, None),
+    (32, 32, 192, 128, None)])
+def test_kernels_compile_for_a_v5e_at_the_cells_widths(
+    one_chip, heads, kv_heads, dk, dv, window):
+  """A sliding layer's call (64 heads over 8, a band of 512), a full
+  layer's (48 over 8) and a latent-attention layer's (32 heads, keys
+  192 over values 128), one row of 8,192 positions in bfloat16, the
+  forward program and the fused backward program: what the interpreter
+  cannot refuse (tiling, VMEM) is refused here."""
+  compiled, counts = _compile_grad_for_the_chip(
+      one_chip, 8192, heads, kv_heads, dk, dv, jnp.bfloat16, window)
+  assert counts == {"flash_attention.backward.fused_traces": 1.0}
+  assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+# t, query heads, key-value heads, keys' and values' width, dtype: the
+# longest sequences whose byte count is still within the fused
+# program's budget (41 to 44 of its 44 MiB), one of each kind of
+# accumulator: dQ alone at 64 lanes (the SNAIL trunks' and the ring's
+# longest), at one lane tile and at two (key blocks of 2048 and of
+# 1024), the same in float32 (tiles and streamed blocks of twice the
+# bytes), and under a group dK and dV whole too. The least limit under
+# which Mosaic compiles the program was bisected at twelve shapes (PR
+# 45): at most the count and 14 MiB (bfloat16 at the 1024 x 2048 tile).
+@pytest.mark.parametrize("t,heads,kv_heads,width,dtype", [
+    (32768, 2, 2, 64, jnp.bfloat16),
+    (34816, 2, 2, 128, jnp.bfloat16),
+    (17408, 2, 2, 256, jnp.bfloat16),
+    (20480, 2, 2, 128, jnp.float32),
+    (9216, 2, 2, 256, jnp.float32),
+    (13312, 6, 1, 128, jnp.bfloat16),
+    (8192, 8, 1, 128, jnp.float32),
+])
+def test_a_sequence_just_under_the_fused_budget_compiles_for_a_v5e(
+    one_chip, t, heads, kv_heads, width, dtype):
+  """The byte count that picks the fused program leaves Mosaic the room
+  it takes for a tile's arithmetic: a shape the count admits is one the
+  compiler admits within the kernels' VMEM limit."""
+  blocks = flash._fused_blocks(
+      width, flash._auto_block(1024, t), flash._auto_block(2048, t),
+      None)
+  count = flash.fused_backward_bytes(
+      t, width, width, heads // kv_heads, *blocks,
+      jnp.dtype(dtype).itemsize)
+  assert 0.93 * flash._FUSED_BACKWARD_BUDGET < count
+  assert count <= flash._FUSED_BACKWARD_BUDGET
+  compiled, counts = _compile_grad_for_the_chip(
+      one_chip, t, heads, kv_heads, width, width, dtype, None)
+  assert counts == {"flash_attention.backward.fused_traces": 1.0}
+  assert compiled.as_text().count("tpu_custom_call") == 2
