@@ -26,6 +26,6 @@ RUN pip install -r requirements.txt
 
 COPY tensor2robot_tpu/ tensor2robot_tpu/
 COPY tests/ tests/
-COPY bench.py chip_smoke.py __graft_entry__.py ./
+COPY chip_smoke.py __graft_entry__.py ./
 
 CMD ["python", "-m", "pytest", "tests/", "-q"]

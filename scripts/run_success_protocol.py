@@ -490,7 +490,7 @@ def run_seedcheck(tmp: str) -> None:
             batch_size=8, replay_capacity=64, max_train_steps=4,
             log_every_steps=2, save_checkpoints_steps=4,
             # count 1 runs the PR-9 jit program (num_devices=None),
-            # >=2 the pmap'd pod — the envs_bench leg's mapping.
+            # >=2 the pmap'd pod (docs/ENVS.md, "Pod mode").
             num_devices=None if count == 1 else count,
             seed=PROTOCOL_SEED)
       digest = hashlib.sha256()

@@ -3,7 +3,7 @@
 # meta_policies / vrgripper / transformer-BC / online-qtopt /
 # grasp2vec / pose_env / pipelined-BC end-to-end) and the heaviest
 # equivalence/e2e pins (SavedModel export chain, ring-flash vs
-# reference, 2-worker plane throughput, coldstart smoke), marked
+# reference, 2-worker plane throughput), marked
 # @pytest.mark.slow and
 # EXCLUDED from tier-1 so tier-1 fits its 870 s budget on degraded
 # hosts (ROADMAP open item). Same log/DOTS_PASSED shape as tier-1 but

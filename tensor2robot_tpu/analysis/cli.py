@@ -53,7 +53,7 @@ _CONCURRENCY_PATHS = (
 )
 _GIN_PATHS = ("tensor2robot_tpu",)
 # obs (OBS501, ISSUE 15) scans the package's literal metric names
-# against the docs/OBSERVABILITY.md catalog; tests/bench construct
+# against the docs/OBSERVABILITY.md catalog; the tests construct
 # fixture names on purpose and are out of scope.
 _OBS_PATHS = ("tensor2robot_tpu",)
 # fleet (FLT5xx, ISSUE 20) resolves string-literal rpc sends against

@@ -55,7 +55,7 @@ flags.DEFINE_integer(
     "in THIS process before training (0 = ephemeral; the bound port "
     "is printed). Unset, the gin-backed default applies "
     "(`default_port.port` in telemetry.prometheus) — so scraping no "
-    "longer requires bench-side wiring (docs/OBSERVABILITY.md).")
+    "longer requires caller-side wiring (docs/OBSERVABILITY.md).")
 flags.DEFINE_enum(
     "trainer", "train_eval", ["train_eval", "qtopt", "fleet",
                               "anakin"],
@@ -137,7 +137,7 @@ def main(argv):
   # Prometheus scrape endpoint (ISSUE 15): flag wins, else the
   # gin-backed default (telemetry.prometheus.default_port). Started
   # here so EVERY trainer entry — and the fleet orchestrator — serves
-  # /metrics off its live registry with no bench-side wiring.
+  # /metrics off its live registry with no caller-side wiring.
   from tensor2robot_tpu.telemetry import prometheus as prometheus_lib
   prometheus_port = FLAGS.prometheus_port
   if prometheus_port is None:

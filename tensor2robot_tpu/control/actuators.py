@@ -25,7 +25,7 @@ The catalog (docs/CONTROL.md):
                     records) — what every breach did before ISSUE 18
 
 `fleet_actuators(fleet)` builds the standard set over a live
-`fleet.orchestrator.Fleet`; the bench and tests compose their own
+`fleet.orchestrator.Fleet`; the tests compose their own
 `Actuator` instances over whatever they drive (a FrontTier, a fake).
 
 jax-free (IMP401 worker-safe set): the Fleet is duck-typed, never

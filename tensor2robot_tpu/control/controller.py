@@ -63,7 +63,7 @@ OUTCOMES = ("actuated", "would_act", "cooldown", "budget", "error")
 class Controller:
   """Ordered rule evaluation with a global actuation budget.
 
-  One owner thread by design (the orchestrator's poll loop or a bench
+  One owner thread by design (the orchestrator's poll loop or a test's
   driver calls `step()`/`handle_alert()`); like the sentinel, no lock
   is held across actuator calls or file I/O (the CON301 contract this
   package is linted with).

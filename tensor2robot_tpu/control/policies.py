@@ -162,6 +162,6 @@ def degradation_priorities(
     shed_rate_rps: float = 1.0,
 ) -> Tuple[Tuple[str, ...], float]:
   """The gin seam for the shed ladder when rules come from gin but
-  the ladder is built by a driver (bench legs); the orchestrator
+  the ladder is built by a driver (a bare tier); the orchestrator
   reads `FleetConfig.control_shed_priorities` instead."""
   return tuple(priorities), float(shed_rate_rps)

@@ -1,10 +1,10 @@
 """Process-parallel host data plane: N decode workers → shm ring → one
 consumer stream.
 
-WHY: the committed input benches (BENCH_DETAIL.json `input_pipeline*`)
-show the tf.data pipeline capping out around one core's worth of
-decode — and `decode_scaling` shows threads can't fix it (2-process
-aggregate ≈ 1-process in-process: the GIL plus TF intra-op contention).
+WHY: the in-process tf.data pipeline caps out around one core's worth
+of decode, and threads can't fix it (the GIL plus TF intra-op
+contention). Where the feed bounds a chip is `feed_wait_share` and the
+`feed_*` metrics of BENCHMARK.json's `qtopt_472.train` (PERF.md §5).
 The Podracer lesson (arXiv:2104.06272) is that TPU utilization is a
 host-side data-plane problem: decouple a scalable host plane from
 device compute. This module is that plane's local form — the same

@@ -584,8 +584,8 @@ class TimedIterator:
   The `input_wait_fraction` measurement of every trainer with a feed
   (`train_loop.TrainLoop.attach_feed` wraps the prefetcher in one):
   near 0 the feed keeps up (the device is the bottleneck); toward 1
-  the chip starves — the continuously-measured form of the bench's
-  `feeds_chip` verdict. Raise `TFRecordInputGenerator.num_workers` (the
+  the chip starves — the benchmark's `feed_wait_share` reads it over
+  a window. Raise `TFRecordInputGenerator.num_workers` (the
   process-parallel data plane, docs/DATA.md) when it climbs.
   """
 

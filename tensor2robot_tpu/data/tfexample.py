@@ -36,8 +36,8 @@ def _is_raw(spec: ExtendedTensorSpec) -> bool:
 
   `data_format="raw"` trades disk for host CPU — parse is a near-memcpy
   `decode_raw` instead of a jpeg/png codec, which is what lets a
-  few-core host feed a chip at full step rate (BENCH_DETAIL.json
-  `input_pipeline` measures the decode path as the feed bottleneck).
+  few-core host keep up with a chip (the decode path is the feed's
+  bottleneck on such a host; no cell of BENCHMARK.json reads files).
   Byte order is little-endian (every supported platform; decode_raw's
   default).
   """

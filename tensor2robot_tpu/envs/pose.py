@@ -176,8 +176,8 @@ class PoseBanditEnv(FunctionalEnv):
 
 def host_parity_env(bandit) -> PoseBanditEnv:
   """A `PoseBanditEnv` geometry-matched to a host `PoseGraspBandit`
-  (same image size, action width, threshold): the construction both
-  the parity test and the bench parity check use."""
+  (same image size, action width, threshold): the construction the
+  parity tests use (tests/test_envs.py::TestHostDeviceParity)."""
   return PoseBanditEnv(
       image_size=bandit.env.image_size,
       action_dim=bandit.action_dim,

@@ -81,9 +81,9 @@ def rollout(batched: BatchedEnv,
     # One render/step is only reachable by storing the post-reset
     # frame as next_obs for done rows (wire-dishonest: replay would
     # carry the next episode's frame as a terminal observation).
-    # Measured bound on the waste: render+step is ~17% of a
-    # CEM-acting iteration (bench --envs: 36.5k stepping ceiling vs
-    # 6.4k), so the redundant half is <9% — not worth the contract.
+    # The waste is bounded by render+step's share of a CEM-acting
+    # iteration, which the CEM's scoring dominates (not measured on
+    # the chip: ROADMAP W2) — not worth breaking the wire contract.
     obs = batched.observe(states)
     key_act, key_step = jax.random.split(step_key)
     actions = policy_fn(obs, key_act)
@@ -177,8 +177,8 @@ def make_anakin_collect_fn(learner, env: FunctionalEnv,
   current — params. On a TPU host the pmap axis is the local chips; on
   CPU the 8-virtual-device mesh stands in AND sidesteps XLA:CPU's
   intra-op parallelism ceiling (one jitted rollout program leaves
-  ~2/3 of a 24-core host idle — measured on the bench --envs axis —
-  while the pmap'd twin saturates it).
+  most of a many-core host idle while the pmap'd twin uses every
+  core; a CPU observation, no device metric).
 
   Returns ``(init_fn, collect_fn)`` shaped like `make_collect_fn` but
   with a leading device axis on env states and collected batches

@@ -8,7 +8,7 @@ episodes into, ONE replay/serving host process (`CEMPolicyServer` +
 running the unmodified `train_qtopt` loop; fresh checkpoints flow back
 as param publications hot-swapped into the serving engine, stamped
 with the learner step so `param_refresh_lag` is measured next to
-replay staleness. See docs/FLEET.md; `bench.py --fleet` measures it.
+replay staleness. See docs/FLEET.md; no chip measurement (ROADMAP W4).
 
   * `orchestrator` — `FleetConfig` / `Fleet` / `run_fleet`: the
     launch gate, heartbeat + exit-code supervision, actor-crash

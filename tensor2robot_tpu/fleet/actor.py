@@ -44,7 +44,7 @@ from tensor2robot_tpu.telemetry import metrics as tmetrics
 
 log = logging.getLogger(__name__)
 
-# Exit code for injected hard crashes (tests/bench assert on it being
+# Exit code for injected hard crashes (the tests assert on it being
 # distinguishable from a clean 0 and a Python-exception 1).
 CRASH_EXIT_CODE = 13
 
@@ -195,7 +195,7 @@ def build_env(config, actor_index: int):
 
 
 def _inject_crash(mode: str, sink: FleetReplaySession) -> None:
-  """Test/bench fault injection (FleetConfig.actor_crash_*)."""
+  """Test fault injection (FleetConfig.actor_crash_*)."""
   if mode == "mid_episode":
     # Die BETWEEN append and end_episode: rows are staged in the
     # host-side session when the process vanishes. The disconnect

@@ -339,7 +339,7 @@ class FrontTier:
   """A standalone replicated front tier: N `front_main` processes +
   broadcast wiring, WITHOUT the rest of the fleet.
 
-  The bench and the e2e tests drive the replicated tier against
+  The e2e tests drive the replicated tier on its own against
   synthetic load; they need fronts and a router, not actors, shards,
   or a learner. `launch()` spawns every front, awaits the ready
   handshakes, and wires the `broadcast_degree`-ary publish tree over
@@ -406,7 +406,7 @@ class FrontTier:
   def scale_to(self, num_fronts: int,
                timeout_secs: float = 240.0) -> List[int]:
     """Grows/shrinks the live tier to `num_fronts` replicas (ISSUE 18
-    — the standalone `scale_fronts` actuator for bench legs; inside a
+    — the standalone `scale_fronts` actuator of a bare tier; inside a
     full fleet the orchestrator's `scale_fronts_to` owns this).
 
     Growth spawns at fresh indices past the highest ever used; shrink
@@ -503,7 +503,7 @@ class FrontTier:
                     "origin_wall": time.time()})
 
   def kill(self, index: int) -> None:
-    """Hard-kills one front replica (the chaos/bench shed leg)."""
+    """Hard-kills one front replica (the tests' shed leg)."""
     process = self.processes[index]
     process.kill()
     process.join(timeout=10.0)

@@ -327,7 +327,7 @@ class _HostState:
             # restored from a checkpoint. The host is the one witness
             # with continuous state across learner incarnations, so
             # the MEASURED restore point is recorded here — the chaos
-            # bench's loss-bounded-by-cadence gate reads it instead
+            # tests' loss-bounded-by-cadence gate reads it instead
             # of trusting config arithmetic.
             self._resumes.append({"from_step": last, "to_step": step})
           self._learner_window = (t0, s0, now, step)

@@ -193,7 +193,7 @@ class _HeartbeatHook(Hook):
 
 
 class _CrashAfterHook(Hook):
-  """Fault injection: kill the learner mid-run (tests/bench)."""
+  """Fault injection: kill the learner mid-run (tests only)."""
 
   def __init__(self, crash_after_steps: int):
     self._after = int(crash_after_steps)

@@ -278,7 +278,7 @@ class FleetConfig:
   # the ISSUE-17 survivable membership shrink, unchanged.
   front_respawn: bool = True
   max_front_restarts: int = 2
-  # Fault injection (tests / bench failure-path rehearsal). The
+  # Fault injection (the tests' failure-path rehearsal). The
   # legacy single-fault knobs remain; `fault_plan` is the ISSUE-14
   # deterministic schedule (faults.FaultPlan — picklable, shipped to
   # every child, each role injects its own events through the
@@ -406,7 +406,7 @@ class FleetConfig:
 
 @dataclasses.dataclass
 class FleetResult:
-  """What a completed fleet run measured (the bench `fleet` axis)."""
+  """What a completed fleet run measured, on its hosts' clocks."""
 
   env_steps_per_sec: float
   learner_steps_per_sec: float
@@ -1801,7 +1801,7 @@ class Fleet:
   def admission_slo_reports(self) -> Dict[str, Any]:
     """Per-front SLO scorecards (`AdmissionController.slo_report`),
     keyed by front name — the controller's retune rules and the
-    bench's goodput gates read these."""
+    tests' goodput checks read these."""
     reports: Dict[str, Any] = {}
     for entry in [e for e in self._aux_hosts if e["kind"] == "front"]:
       try:
@@ -1910,7 +1910,7 @@ class Fleet:
         except Exception:
           log.warning("final metrics read failed", exc_info=True)
         else:
-          # The chaos bench's RPC-recovery gates read the
+          # The chaos tests' RPC-recovery gates read the
           # actor/learner registry snapshots (retry/recovery
           # counters live in THOSE processes); actors push a final
           # snapshot as they drain, so this read sees them all.

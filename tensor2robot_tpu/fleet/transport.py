@@ -29,8 +29,8 @@ those bytearrays (the one kernel→user copy is the only copy). That is
 the "≤1 copy per side" contract `tests/test_fleet_transport.py` proves
 with `np.shares_memory`, not assumes — versus the loopback's in-band
 pickle, which serializes arrays INTO the stream and back out (two full
-extra payload copies, measured 6–12× slower at ≥1 MiB payloads on the
-`bench.py --fleet` wire microbench).
+extra payload copies; a host-side cost that no cell of BENCHMARK.json
+measures, ROADMAP W4).
 
 Connection hygiene:
 

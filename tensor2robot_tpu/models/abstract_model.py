@@ -158,7 +158,7 @@ class AbstractT2RModel(ModelInterface):
     ``key`` makes the wrap IDEMPOTENT per key: re-wrapping with the
     same key replaces the previous incarnation instead of stacking on
     top of it. Trainers that may be invoked repeatedly on one model
-    (bench device-scaling rows, successive runs in one process) MUST
+    (one learner across device counts, successive runs in a process) MUST
     pass a key — a stacked stale wrapper would otherwise pin the tx
     to a dead mesh's devices. Keyless wraps keep the raw composing
     behavior.

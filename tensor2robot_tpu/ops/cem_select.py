@@ -24,9 +24,9 @@ the caller's operand dtype; all selection/statistics math is f32. The
 `cem_select_lax` reference implements the identical contract in plain
 lax and is the parity oracle for the interpret-mode CPU tests; on
 hardware the compiled kernel is checked against it by `chip_smoke.py`
-and `bench.py --verify` at the flagship shape (first compiled on a
-v5e in PR 21: exact agreement). Its speed against the lax path is not
-measured; `cem_select="lax"` stays the default.
+at the flagship shape (first compiled on a v5e in PR 21: exact
+agreement). Its speed against the lax path is not measured on the
+chip (ROADMAP S6); `cem_select="lax"` stays the default.
 """
 
 from __future__ import annotations

@@ -702,7 +702,7 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal: bool,
   # neither side pays an MXU relayout (rounds 4-5 made two lossy
   # systolic-array passes here — forward identity-transpose, backward
   # 1/8-contraction — which was the dominant term in the hardware
-  # gate's dv error; see bench_verify_numerics).
+  # gate's dv error; chip_smoke.py's flash leg holds it).
   delta = (jnp.sum(do_f.astype(jnp.float32) * o_f.astype(jnp.float32),
                    axis=-1)
            - dlse.astype(jnp.float32))              # [BH, T]

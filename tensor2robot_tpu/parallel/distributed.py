@@ -30,12 +30,12 @@ def ephemeral_coordinator_address(host: str = "127.0.0.1") -> str:
   """Picks a collision-safe coordinator address for same-host launches.
 
   The launch contract for same-host multi-process runs (fleets, the
-  two-process distributed test, bench rehearsals): the COORDINATOR —
+  two-process distributed test, CPU rehearsals): the COORDINATOR —
   the one process that spawns the others — calls this ONCE before
   spawning and hands the result to every child via
   `JAX_COORDINATOR_ADDRESS` (or the explicit flag). The OS assigns a
   port from the ephemeral range (`bind(0)`), so two concurrent fleets
-  (or bench + tests) on one machine never race on a fixed port the
+  (or two test runs) on one machine never race on a fixed port the
   way a hard-coded constant guarantees they eventually would.
 
   The port is released before jax binds it, so a theoretical window

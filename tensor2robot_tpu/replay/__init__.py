@@ -19,9 +19,9 @@ production-shaped:
                  for reproducibility checks.
 
 `research/qtopt/replay_buffer.ReplayBuffer` remains the thin
-API-compatible adapter over a 1-shard store; `bench.py --replay`
-measures the plane (shard scaling, actor-fleet ingestion, staleness).
-See docs/REPLAY.md.
+API-compatible adapter over a 1-shard store; its gather feeds both
+QT-Opt cells of BENCHMARK.json (`feed_*`); the plane under actor load
+has no measurement on the chip (ROADMAP W4). See docs/REPLAY.md.
 """
 
 from tensor2robot_tpu.replay.sampler import (

@@ -155,7 +155,7 @@ class GraspingQNetwork(nn.Module):
     """Scores a CEM population without materializing tiled torso maps.
 
     The naive population path tiles `encoded` to [B*P, h, w, C] — at
-    QT-Opt bench scale a ~0.5 GB materialization per CEM iteration that
+    the 64-wide cell a ~0.5 GB materialization per CEM iteration that
     profiles as the single most expensive op in the Bellman step. The
     first head conv is linear, so conv(encoded + broadcast(a)) splits
     exactly into conv(encoded) — once per STATE — plus the action
@@ -251,8 +251,8 @@ class GraspingQNetwork(nn.Module):
     b = encoded.shape[0]
     a_pm = a.transpose(1, 0, 2).reshape(p * b, c)
     act = (a_pm @ v.reshape(c, -1)).reshape(p * b, h2, w2, oc)
-    # Population-replicating enc0, three measured variants (bench
-    # primary, round 4): jnp.tile = 487 steps/s (lowers as broadcast
+    # Population-replicating enc0, three measured variants (a v5e,
+    # round 4, pre-ledger): jnp.tile = 487 steps/s (lowers as broadcast
     # + layout-changing reshape — two full copies, profiled at ~36%
     # of device time); 5-D broadcast-add then reshape = 414 (layout
     # assignment re-transposes the population tensor before the

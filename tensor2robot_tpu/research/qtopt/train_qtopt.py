@@ -191,8 +191,8 @@ def train_qtopt(
 
   # Hooks begin BEFORE the replay wait: an ActorStateRefreshHook whose
   # actors bootstrap an empty buffer must start collecting now, or
-  # this wait would deadlock. Live MFU attribution: the SAME analytic
-  # denominator bench.py uses (utils.profiling.analytic_flops — the
+  # this wait would deadlock. Live MFU attribution: the one analytic
+  # denominator of the repo (utils.profiling.analytic_flops — the
   # ISSUE-15 shared-path pin), scaled to the mesh (batch_size is
   # PER-PROCESS, so × process_count is the global batch; peak × devices
   # keeps perf.mfu the per-chip fraction).

@@ -89,8 +89,8 @@ class TenantPolicy:
       block_timeout_secs: cap on a "block" wait (None = wait forever,
         which is only safe when the dispatcher is known alive).
       slo_ms: the tenant's latency objective; `slo_report()` scores
-        the dispatch histograms against it and the bench counts a
-        completion under it as GOODPUT.
+        the dispatch histograms against it; a completion under it
+        counts as GOODPUT.
     """
     if overflow not in OVERFLOW_POLICIES:
       raise ValueError(

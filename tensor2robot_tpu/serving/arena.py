@@ -16,8 +16,8 @@ by many workloads, none owns it). The arena is that multiplexer:
     compilation cache configured (`startup/compile_cache.py`) an
     evicted tenant's reload DESERIALIZES every bucket instead of
     recompiling — `cache_misses == 0` on reload is the contract,
-    counted per load via `CompileWatch` and pinned by tests and the
-    bench's eviction leg.
+    counted per load via `CompileWatch` and pinned by
+    tests/test_serving_front.py::TestArena.
 
 Loads use a placeholder-future protocol so the structural lock never
 covers a blocking operation (the CON301 contract): a miss installs a

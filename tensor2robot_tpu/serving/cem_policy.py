@@ -113,8 +113,8 @@ class CEMPolicyServer:
     return np.asarray(self._batcher.predict(struct))
 
   def select_actions_direct(self, observations, rng) -> np.ndarray:
-    """Engine-direct selection (no batcher): latency benches use this
-    to measure the device program without queueing."""
+    """Engine-direct selection (no batcher): the device program
+    without queueing, for a caller that times one request."""
     struct = (observations
               if isinstance(observations, TensorSpecStruct)
               else TensorSpecStruct.from_flat_dict(dict(observations)))

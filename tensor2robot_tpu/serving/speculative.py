@@ -172,7 +172,7 @@ class SpeculativeCEM:
 
   def flush(self, timeout_secs: float = 5.0) -> bool:
     """Waits until every queued AND in-flight refinement has landed
-    or been discarded (tests/bench only)."""
+    or been discarded (tests only)."""
     import time
     deadline = time.monotonic() + timeout_secs
     while True:

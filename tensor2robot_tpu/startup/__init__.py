@@ -8,15 +8,15 @@ spin-up. This package makes restarts cheap and measured:
 
   * `compile_cache` — gin-configurable wiring of jax's persistent XLA
     compilation cache (`jax_compilation_cache_dir` + min-entry knobs),
-    shared by the trainer, predictors, the serving engine, and bench,
+    shared by the trainer, predictors and the serving engine,
     plus `CompileWatch`: a jax.monitoring tap that counts cache
     hits/misses so "the warm path compiled nothing" is provable.
   * `orchestrator` — `run_overlapped`: named startup phases on threads
     (device compile, disk restore, host input prep don't contend),
     with per-phase wall timings and the serial-vs-overlapped saving.
-  * `coldstart` — subprocess probes measuring trainer
-    time-to-first-step and predictor time-to-first-prediction, driven
-    by `bench.py --coldstart` (cold vs. warm cache).
+
+What a start costs is measured inside the real trainer: `setup_s` and
+the seven `startup_*` metrics of `BENCHMARK.json` (PERF.md section 3).
 """
 
 from tensor2robot_tpu.startup.compile_cache import (

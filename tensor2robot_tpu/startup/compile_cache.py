@@ -6,8 +6,8 @@ serialized executable under `jax_compilation_cache_dir`; a process that
 re-traces the same program skips XLA entirely and deserializes the
 cached binary (the pjit/TPUv4 scaling work, arXiv:2204.06514, is what
 makes frequent restarts affordable at pod scale). This module is the
-ONE place the cache is placed — all three train loops, the predictor,
-the serving engine and bench call `configure_compilation_cache()`.
+ONE place the cache is placed — all three train loops, the predictor
+and the serving engine call `configure_compilation_cache()`.
 
 Placement contract (the directory is part of the cache key, so it must
 not move between the processes that are meant to share it):
@@ -18,8 +18,8 @@ not move between the processes that are meant to share it):
     a caller passes.
   * unset — the cache lives at `DEFAULT_CACHE_DIR`, one fixed path
     inside the checkout resolved from the package location. An
-    explicit `cache_dir=` may override only in this case (tests, the
-    cold/warm probes).
+    explicit `cache_dir=` may override only in this case (the tests'
+    cold and warm child processes).
 
 `CompileWatch` taps `jax.monitoring` for the cache's hit/miss events —
 the proof obligation for every warm-start claim in this repo is
@@ -211,7 +211,7 @@ class CompileWatch:
       # is active — this is what closes the CompileWatch gap (ISSUE
       # 11): warm-path recompiles surface in ordinary training logs
       # (`compile_cache.misses` in metrics_<tag>.jsonl), not only
-      # under `bench.py --coldstart`. Names resolve PER EVENT (not
+      # under an explicit watch. Names resolve PER EVENT (not
       # captured handles): a registry reset (test isolation) must not
       # orphan these counters for the rest of the process — compiles
       # are rare, the lookup is nothing.

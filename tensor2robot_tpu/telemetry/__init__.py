@@ -20,7 +20,7 @@ Three legs (ISSUE 11, docs/OBSERVABILITY.md):
 (``{step, wall, role, payload}``) and its one reader.
 
 The always-on performance plane (ISSUE 15) rides the same three legs:
-`perf` (live MFU attribution on bench's analytic denominator +
+`perf` (live MFU attribution on one analytic FLOPs denominator +
 `rsrc.*` resource watermarks from a per-role sampler thread),
 `sentinel` (gin-configurable watch rules over the registry's scalar
 view, alert events/counters/`alerts.jsonl`, page severity → flight

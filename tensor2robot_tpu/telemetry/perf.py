@@ -3,12 +3,12 @@
 PR 11's plane answers "what happened"; this module answers "is the run
 healthy and how fast should it be", always on:
 
-  * `mfu_value` — THE one MFU formula. `bench.py` (via
-    `utils.profiling.mfu`) and the trainers' live gauges both call it,
-    and the FLOPs denominator both sides pass comes from the one
-    `utils.profiling.analytic_flops` model — bench MFU and live
-    ``perf.mfu`` agree by construction (the shared-code-path pin in
-    tests/test_perf_plane.py).
+  * `mfu_value` — THE one MFU formula. `utils.profiling.mfu` and
+    the trainers' live gauges both call it, and the FLOPs
+    denominator the trainers pass comes from the one
+    `utils.profiling.analytic_flops` model, so every ``perf.mfu``
+    of the repo is one formula over one model count (the
+    shared-code-path pin in tests/test_perf_plane.py).
   * `PerfMeter` — per-process live attribution: at log cadence
     publishes the ``perf.mfu`` and ``perf.flops_per_sec`` gauges into
     the registry (so every ``metrics_<tag>.jsonl`` envelope and the
@@ -27,7 +27,7 @@ healthy and how fast should it be", always on:
 
 The whole plane honors one switch: `set_plane_enabled(False)` (or env
 ``T2R_PERF_PLANE=0``) turns publication, sampling, and the sentinel
-off — the A/B arm of the bench overhead gate.
+off — the off arm of an overhead comparison.
 
 jax-free BY CONTRACT like the rest of the package (IMP401 worker-safe
 set): actors run the sampler too; anything device-specific arrives as
@@ -65,7 +65,7 @@ _plane_lock = threading.Lock()
 def plane_enabled() -> bool:
   """Whether the always-on perf plane (live gauges, resource sampler,
   sentinel) is active in this process. Default on; ``T2R_PERF_PLANE=0``
-  or `set_plane_enabled(False)` disables (the bench A/B off-arm)."""
+  or `set_plane_enabled(False)` disables (an A/B's off arm)."""
   global _plane_enabled
   if _plane_enabled is None:
     _plane_enabled = os.environ.get(_PLANE_ENV, "1") not in (
@@ -85,7 +85,7 @@ def mfu_value(steps_per_sec: float,
               devices: int = 1) -> Optional[float]:
   """Model FLOPs utilization: achieved / (per-chip peak × devices).
 
-  THE one MFU formula — `utils.profiling.mfu` (bench.py's path) and
+  THE one MFU formula — `utils.profiling.mfu` (a caller's own rate) and
   `PerfMeter.publish` (the live gauges) both call it, so the two can
   never drift. None when the peak or the denominator is unknowable
   (e.g. XLA:CPU with no `T2R_PEAK_FLOPS_OVERRIDE`).

@@ -15,7 +15,7 @@ in a fleet learner process, ``anakin`` under `--trainer=anakin`), and
 emitted four ad-hoc flat shapes; merged-timeline tooling (and the
 fleet's aggregated view) needs one.
 
-`read_records` is the ONE reader the repo's tests/benches/scripts use:
+`read_records` is the ONE reader the repo's tests and scripts use:
 it normalizes both the envelope and the legacy flat shape
 (``{"step": ..., **scalars}``) to flat dicts, so analysis code indexes
 scalars directly and old run directories stay readable.

@@ -366,9 +366,9 @@ def fleet_watches(
   """The orchestrator's standing rules over the aggregated view.
 
   ``rpc_timeout_severity`` defaults to ``warn`` so routine chaos
-  rehearsal (bench --chaos injects RPC faults on purpose) does not
-  page; the bench --telemetry sentinel leg and deployments that want
-  the flight record set it to ``page``.
+  rehearsal (a seeded `FaultPlan` injects RPC faults on purpose) does
+  not page; tests/test_perf_plane.py's fleet test and deployments that
+  want the flight record set it to ``page``.
   """
   return [
       # `above 0`, not `increase`: the timeouts counter is CREATED

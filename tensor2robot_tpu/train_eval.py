@@ -421,7 +421,7 @@ def train_eval_model(
     # `steps_per_sec` is the PURE train-loop rate (checkpoint saves
     # and interleaved evals excluded); `stall_fraction` is the
     # interval's share lost to them — the restart/save regressions
-    # the cold-start bench axis watches.
+    # that `checkpoint_stall_ms` and `setup_s` watch on the chip.
     scalars["steps_per_sec"] = steps / max(dt - stall_secs, 1e-9)
     scalars["stall_fraction"] = min(
         max(stall_secs / max(dt, 1e-9), 0.0), 1.0)

@@ -1,25 +1,24 @@
-"""jax.profiler trace capture + step timing + FLOPs/MFU estimation.
+"""FLOPs/MFU estimation for the trainers' live gauges.
 
 Reference parity: the reference had no in-repo profiling — TPU traces
 were captured with the external `capture_tpu_profile` tool and viewed
-in TensorBoard (SURVEY.md §6 "Tracing/profiling"). TPU-native upgrade:
-`jax.profiler` traces captured programmatically (viewable in
-TensorBoard / Perfetto), and XLA-cost-analysis-based FLOPs + MFU
-estimation so benchmarks can report fraction-of-peak instead of bare
-steps/sec.
+in TensorBoard (SURVEY.md §6 "Tracing/profiling"). Here the device
+trace is the benchmark's (`benchmark/harness/window.py` records it,
+`harness/trace_reduce.py` reduces it); this module keeps what the
+running trainers need: the device's peak, XLA's FLOP count of a
+compiled program, and a model-FLOPs count from shapes.
 
-This module also owns THE analytic-FLOPs MFU denominator
-(`analytic_flops`, hoisted from bench.py by ISSUE 15): `bench.py`
-imports it back and the trainers' live `perf.mfu` gauges
-(`telemetry/perf.py`) compute against the SAME model-flops count, so
-bench MFU and live MFU can never drift — one denominator by
-construction (docs/PERF.md). The MFU *arithmetic* itself lives in
-jax-free `telemetry.perf.mfu_value`; `mfu()` here delegates to it.
+This module owns the analytic-FLOPs denominator of the live MFU
+gauges (`analytic_flops`): `train_qtopt` and `train_anakin` publish
+`perf.mfu` (`telemetry/perf.py`) against this one model-flops count
+(docs/PERF.md). The benchmark's `step_flops_share` has its own copy
+of the model (`benchmark/harness/flops.py`). The MFU *arithmetic*
+itself lives in jax-free `telemetry.perf.mfu_value`; `mfu()` here
+delegates to it.
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 from typing import Any, Callable, Dict, Optional
@@ -96,8 +95,8 @@ def mfu(steps_per_sec: float, flops_per_step: Optional[float],
   """Model FLOPs utilization: achieved / peak. None when unknowable.
 
   Delegates the arithmetic to `telemetry.perf.mfu_value` — the SAME
-  code path the trainers' live ``perf.mfu`` gauges use, so bench MFU
-  and live MFU agree by construction (the ISSUE-15 shared-path pin).
+  code path the trainers' live ``perf.mfu`` gauges use (the ISSUE-15
+  shared-path pin, `tests/test_perf_plane.py`).
   """
   return perf_lib.mfu_value(steps_per_sec, flops_per_step,
                             device_peak_flops(device))
@@ -120,17 +119,15 @@ def _same_conv_taps(h: int, k: int, s: int):
 
 
 def analytic_flops(kind: str, **kw):
-  """THE shared analytic-FLOPs model for every MFU figure in the repo.
+  """The analytic-FLOPs model behind the trainers' live MFU gauges.
 
   MFU's denominator is MODEL flops from shapes — NOT XLA's count of
   the compiled program — so the figure stays comparable across
   dtype/remat/kernel levers: an int8 tower or a remat recompute does
   not change the model, only the schedule, and must not move the
-  denominator (docs/PERF.md). XLA cost analysis rides along in
-  bench.py's detail sections as a cross-check (`xla_flops_per_step`,
-  ratio asserted near 1 on the unlevered program). Hoisted here from
-  bench.py (ISSUE 15) so the live ``perf.mfu`` gauges the train loops
-  publish use the SAME count bench does; bench imports it back.
+  denominator (docs/PERF.md). XLA's cost analysis of the unlevered
+  program is the cross-check: `tests/test_perf_plane.py` holds the
+  ratio near 1 on a tiny model.
 
   kinds:
     "qtopt_step": one fused Bellman step — kw: learner, batch_size,
@@ -138,13 +135,7 @@ def analytic_flops(kind: str, **kw):
       CEM target (encode once + I scored populations through the
       linearity-split head) + critic fwd/bwd (bwd = 2× fwd) + the
       elementwise optimizer/Polyak tail.
-    "attention": flash attention forward — kw: b, heads, d, t,
-      causal. (The long-context axis's 4·B·H·D·T² [/2 causal].)
   """
-  if kind == "attention":
-    flops = 4 * kw["b"] * kw["heads"] * kw["d"] * kw["t"] * kw["t"]
-    return flops / 2 if kw.get("causal", True) else flops
-
   if kind != "qtopt_step":
     raise ValueError(f"unknown analytic_flops kind {kind!r}")
   learner = kw["learner"]
@@ -266,29 +257,3 @@ def device_memory_source() -> Callable[[], Dict[str, float]]:
     return out
 
   return sample
-
-
-@contextlib.contextmanager
-def trace(logdir: str, host_tracer_level: int = 0):
-  """Captures a jax.profiler trace into `logdir`.
-
-  View with TensorBoard's profile plugin or Perfetto. Wrap the steps of
-  interest. The device alone by default: with the host tracer at level
-  1 or 2 (or the Python tracer) the train loop's host side grew by a
-  quarter of a GB a second until no dispatch finished (PR 23;
-  `benchmark/harness/window.py`), and what the host does is on the
-  program's own spans, whose clock is the recording's (PERF.md §5).
-  `step_annotation` is a host event: it shows from level 1 on.
-  """
-  os.makedirs(logdir, exist_ok=True)
-  options = jax.profiler.ProfileOptions()
-  options.host_tracer_level = host_tracer_level
-  options.python_tracer_level = 0
-  with jax.profiler.trace(logdir, profiler_options=options):
-    yield
-  log.info("Profiler trace written to %s", logdir)
-
-
-def step_annotation(step: int):
-  """Names one training step inside an active trace."""
-  return jax.profiler.StepTraceAnnotation("train", step_num=step)

@@ -2,8 +2,8 @@
 
 The image's tensorboard profile plugin can't parse traces (protobuf /
 pywrap version skew), so this module decodes the XSpace wire format
-directly — enough to aggregate device time by HLO op name, which is
-what `bench.py --profile` and perf debugging need. Schema (stable tsl
+directly — enough to aggregate device time by HLO op name (the tests
+call it; the benchmark reduces by trace_reduce.py). Schema (stable tsl
 profiler protos): XSpace.planes=1; XPlane{name=2, lines=3,
 event_metadata=4 (map<int64, XEventMetadata{name=2}>)};
 XLine{name=2, events=4}; XEvent{metadata_id=1, duration_ps=3}.

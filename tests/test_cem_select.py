@@ -1,7 +1,7 @@
 """Fused CEM select kernel: interpret-mode parity vs the lax oracle.
 
-The kernel's compiled path is exercised on real TPU hardware (bench
---mfu / --verify); here the pallas interpreter verifies the math —
+The kernel's compiled path is exercised on real TPU hardware by
+`chip_smoke.py`; here the pallas interpreter verifies the math —
 running-top-k exactness against `cem_select_lax` (which shares the
 f32 numerics policy), lax.top_k tie semantics, odd shapes where the
 population does not divide the sample block, and block-size

@@ -403,6 +403,48 @@ class TestStandardPolicyTable:
     # must be enough to pre-scale.
     assert rule.aggregate == "max"
 
+  def test_p95_breach_scales_the_front_tier_within_its_bounds(self):
+    """The closed loop's ramp: the shipped table over the shipped
+    actuators on a fleet surface. A sustained p95 breach on the worst
+    replica adds one front; past the table's `max_fronts` the rule
+    still triggers and the actuator holds the bound; nothing pages,
+    since the breach has a remediation."""
+    class _Tier:
+      num_actors, num_fronts = 1, 1
+      def __init__(self): self.calls = []
+      def scale_to(self, n): raise AssertionError("actors untouched")
+      def kick(self, role): raise AssertionError("nobody kicked")
+      def retune_admission(self, tenant, **kw): return {}
+      def scale_fronts_to(self, n):
+        self.calls.append(n)
+        self.num_fronts = n
+    tier, pages = _Tier(), []
+    ctrl = Controller(
+        policies_lib.fleet_rules(tenant="policy", slo_ms=100.0,
+                                 max_fronts=2, cooldown_secs=0.0),
+        fleet_actuators(tier, on_page=pages.append),
+        registry=tmetrics.MetricsRegistry())
+    p95 = "serving.policy.request_ms_p95"
+    breach = {f"front0/{p95}": 40.0, f"front1/{p95}": 250.0}
+    ctrl.step(breach, now=1000.0)   # sustain=2: one reading is noise
+    assert tier.calls == []
+    ctrl.step(breach, now=1001.0)
+    assert tier.calls == [2]
+    scale_up = [d for d in ctrl.decisions
+                if d["rule"] == "front_p95_scale_up"]
+    assert [d["outcome"] for d in scale_up] == ["actuated"]
+    assert scale_up[0]["detail"] == {"fronts_before": 1,
+                                     "fronts_after": 2}
+    # Re-arm under the clear band, breach again: at the bound.
+    ctrl.step({f"front0/{p95}": 10.0}, now=1002.0)
+    ctrl.step({f"front0/{p95}": 10.0}, now=1003.0)
+    ctrl.step(breach, now=1004.0)
+    ctrl.step(breach, now=1005.0)
+    assert tier.calls == [2] and tier.num_fronts == 2
+    assert ctrl.decisions[-1]["detail"] == {"noop": "at_bound",
+                                            "fronts": 2}
+    assert pages == [] and ctrl.stats()["alert_unhandled"] == 0
+
   def test_respawn_role_requires_concrete_role(self):
     acts = fleet_actuators(object())
     with pytest.raises(ActuationError):

@@ -26,7 +26,7 @@ def test_two_process_cluster_runs_sharded_train_step(tmp_path):
   repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
   worker = os.path.join(repo, "tests", "distributed_worker.py")
   # The coordinator-side port pick the fleet orchestrator uses too:
-  # bench + tests on one machine must never race on a fixed port.
+  # two runs on one machine must never race on a fixed port.
   coordinator = ephemeral_coordinator_address()
 
   # Scrub jax/tpu config the parent test session forced (cpu platform,

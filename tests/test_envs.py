@@ -892,10 +892,10 @@ class TestShardMapPodProgram:
   @pytest.mark.slow
   def test_zero_rewrap_across_device_counts_does_not_stack(
       self, tmp_path):
-    """Bench rows reuse ONE learner across device counts: the keyed
+    """A caller may reuse ONE learner across device counts: the keyed
     `wrap_optimizer(key="shard_weight_update")` must REPLACE the
     previous pod-mesh wrap, not stack a constraint pinned to a dead
-    mesh's devices (the full-bench failure this regression-pins)."""
+    mesh's devices (the failure this regression-pins)."""
     learner = _tiny_learner()
     kwargs = {**self.POD_KWARGS, "max_train_steps": 8,
               "log_every_steps": 4, "save_checkpoints_steps": 8}

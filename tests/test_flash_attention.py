@@ -1,8 +1,9 @@
 """Pallas flash attention: interpret-mode numerics on the CPU suite.
 
-The kernel's compiled path is exercised on real TPU hardware (bench /
-driver); here the pallas interpreter verifies the math — exactness
-against the reference oracle, causal masking, block-size independence.
+The kernel's compiled path is exercised on real TPU hardware (the
+benchmark's cells, `chip_smoke.py`); here the pallas interpreter
+verifies the math — exactness against the reference oracle, causal
+masking, block-size independence.
 """
 
 import importlib

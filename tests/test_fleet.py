@@ -20,6 +20,7 @@ The lifecycle contract of docs/FLEET.md, pinned:
 
 from __future__ import annotations
 
+import json
 import multiprocessing as mp
 import os
 import subprocess
@@ -136,7 +137,7 @@ class TestRpc:
     first = ephemeral_coordinator_address()
     second = ephemeral_coordinator_address()
     assert first.startswith("127.0.0.1:")
-    # Two concurrent launches (two fleets, bench + tests) must never
+    # Two concurrent launches (two fleets, two test runs) must never
     # be handed the same port.
     assert first != second
 
@@ -543,7 +544,8 @@ class TestFleetLifecycle:
     # distributed_learner=True also exercises the collision-safe
     # ephemeral-coordinator handoff end to end (a 1-process gloo
     # cluster in the learner child).
-    config = _tiny_config(env="mujoco_pose", distributed_learner=True)
+    config = _tiny_config(env="mujoco_pose", distributed_learner=True,
+                          telemetry_poll_secs=2.0)
     fleet = Fleet(config, str(tmp_path / "fleet"))
     result = fleet.run()
 
@@ -565,6 +567,25 @@ class TestFleetLifecycle:
     # distribution (ages in learner steps).
     staleness = [s for s in result.replay_staleness.values() if s]
     assert staleness and staleness[0]["rows"] > 0
+    # The telemetry plane of a healthy fleet: every role's trace
+    # merges into one timeline WITH spans (a process that configured
+    # tracing and wedged would leave a meta line only), the
+    # orchestrator's aggregated records keep the envelope schema and
+    # carry the samplers' watermarks, and nothing alerted.
+    from tensor2robot_tpu.telemetry import merge, records, sentinel
+    trace_dir = tmp_path / "fleet" / "telemetry"
+    roles = set(merge.roles_with_spans(merge.merge_traces(
+        str(trace_dir))))
+    assert roles >= {"host", "learner", "actor-0", "actor-1"}, roles
+    with open(trace_dir / "fleet_metrics.jsonl") as f:
+      aggregated = [json.loads(line) for line in f if line.strip()]
+    assert aggregated
+    assert [records.validate_record(r) for r in aggregated] == (
+        [[]] * len(aggregated))
+    assert any("rsrc." in key for record in aggregated
+               for key in record["payload"])
+    assert sentinel.read_alerts(
+        str(trace_dir / sentinel.ALERTS_FILENAME)) == []
     # The shutdown barrier: no child processes, no shm segments.
     assert _fleet_children() == []
     del fleet

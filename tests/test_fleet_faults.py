@@ -463,7 +463,7 @@ class TestFleetFaultsE2E:
     # The host WITNESSED the restore (a backward set_learner_step):
     # the measured restore point is the last checkpoint before the
     # crash, so the measured loss is bounded by the publish cadence —
-    # the same record bench --chaos gates on.
+    # the host's own record of the restore.
     (resume,) = result.metrics["learner_resumes"]
     assert resume["to_step"] <= resume["from_step"] <= 10
     assert resume["from_step"] - resume["to_step"] <= (
@@ -474,7 +474,7 @@ class TestFleetFaultsE2E:
 
   @pytest.mark.slow
   def test_multi_class_chaos_plan_recovers_every_class(self, tmp_path):
-    # The bench --chaos shape in miniature: hang + crash + client/
+    # A whole fault schedule in miniature: hang + crash + client/
     # server RPC faults in ONE run, every class recovering through its
     # real path.
     plan = faults.FaultPlan(seed=0, events=(

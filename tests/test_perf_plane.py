@@ -2,10 +2,11 @@
 resource watermarks, the alert sentinel, trace-flow correlation, and
 the run report — pinned contracts:
 
-  * live `perf.mfu` equals bench MFU for the same config/denominator
-    within 1e-6 relative: both ride `utils.profiling.analytic_flops`
-    (bench re-imports it) and `telemetry.perf.mfu_value`, published by
-    all three trainers incl. the pod modes (device-count aware);
+  * live `perf.mfu` equals `telemetry.perf.mfu_value` over
+    `utils.profiling.analytic_flops` for the same config within 1e-6
+    relative, published by all three trainers incl. the pod modes
+    (device-count aware); that model count stands near XLA's cost
+    analysis of the unlevered step;
   * sentinel semantics: EWMA warmup never fires, a sustained breach
     fires exactly once (hysteresis) and re-arms on recovery, a
     page-severity breach in a REAL 2-actor fleet (slow_host stimulus
@@ -58,14 +59,41 @@ def _expected_mfu(record, flops, devices):
 
 
 class TestSharedDenominator:
-  """One MFU code path: bench's and the live gauges' (the ISSUE-15
-  shared-path pin)."""
+  """One MFU code path for every caller and the live gauges (the
+  ISSUE-15 shared-path pin), over a model count that XLA bears out."""
 
-  def test_bench_reexports_profiling_analytic_flops(self):
-    import bench
+  def test_analytic_flops_near_xla_cost_analysis(self):
+    """A dropped or doubled term of the model count would skew every
+    live `perf.mfu`: on the unlevered step (bf16 tower, lax select, no
+    remat) it must stand near XLA's own count. The band is wide
+    because a tiny model is elementwise-heavy (0.81 here)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensor2robot_tpu.research.qtopt import (
+        GraspingQModel,
+        QTOptLearner,
+    )
+    from tensor2robot_tpu.specs import make_random_tensors
     from tensor2robot_tpu.utils import profiling
-    assert bench.analytic_flops is profiling.analytic_flops
-    assert bench._same_conv_taps is profiling._same_conv_taps
+
+    learner = QTOptLearner(
+        GraspingQModel(image_size=16, torso_filters=(8,),
+                       head_filters=(8, 8), dense_sizes=(16,),
+                       action_dim=2),
+        cem_population=8, cem_iterations=1, cem_elites=2,
+        cem_inference="bf16", cem_select="lax")
+    state = learner.create_state(jax.random.PRNGKey(0))
+    transitions = jax.tree_util.tree_map(jnp.asarray, make_random_tensors(
+        learner.transition_specification(), batch_size=8, seed=0))
+    xla = profiling.compiled_flops_per_call(
+        jax.jit(learner.train_step).lower(
+            state, transitions, jax.random.PRNGKey(2)).compile())
+    analytic = profiling.analytic_flops(
+        "qtopt_step", learner=learner, batch_size=8,
+        params=state.train_state.params)
+    assert xla, "XLA:CPU gave no cost analysis"
+    assert 0.7 <= analytic / xla <= 1.3, (analytic, xla)
 
   def test_profiling_mfu_delegates_to_perf_formula(self, monkeypatch):
     from tensor2robot_tpu.utils import profiling
@@ -242,9 +270,9 @@ def _read_perf_record(model_dir):
 
 
 class TestTrainerLiveMfu:
-  """The acceptance pin: live perf.mfu == bench MFU (same config,
-  same denominator) within 1e-6 relative, all three trainers, pod
-  modes device-count aware."""
+  """The acceptance pin: live perf.mfu == `mfu_value` over the one
+  analytic denominator (same config) within 1e-6 relative, all three
+  trainers, pod modes device-count aware."""
 
   def test_train_qtopt_live_mfu_matches_bench_formula(
       self, tmp_path, monkeypatch):
@@ -269,8 +297,8 @@ class TestTrainerLiveMfu:
         max_train_steps=32, batch_size=batch, log_every_steps=16,
         save_checkpoints_steps=32, seed=0)
     record = _read_perf_record(str(tmp_path))
-    # Bench's formula over bench's denominator — the exact same
-    # analytic_flops call bench_config makes, devices = the mesh.
+    # The one formula over the one denominator — the same
+    # analytic_flops call the trainer makes, devices = the mesh.
     flops = profiling.analytic_flops(
         "qtopt_step", learner=learner, batch_size=batch,
         params=state.train_state.params)
@@ -559,7 +587,7 @@ class TestGoodputGauge:
   def test_front_publishes_per_tenant_goodput(self):
     """The serving front's completion loop feeds the goodput window;
     pin the gauge arithmetic through the internal seam (the full
-    open-loop path is bench_serving_front's job)."""
+    open-loop path has no measurement yet: ROADMAP W1)."""
     from tensor2robot_tpu.serving import front as front_lib
 
     entry = front_lib._Tenant("tenA", max_queue=4, seed=0,

@@ -10,7 +10,8 @@ Pins the contracts docs/SERVING.md promises:
     counter AND jax.monitoring compile events);
   * checkpoint hot-swap mid-traffic serves only fully-restored params
     (old or new tree per dispatch, never a mix);
-  * the `bench.py --serving --dry-run` smoke path runs on CPU.
+  * the CEM policy server answers direct requests of one row and of
+    a full bucket after its warmup with no compile.
 
 Numerics note: XLA specializes code per batch shape, so outputs of
 DIFFERENT bucket programs may differ by float-associativity ulps;
@@ -328,7 +329,7 @@ class TestCEMPolicyServer:
     learner = QTOptLearner(model, cem_population=8, cem_iterations=1,
                            cem_elites=2)
     state = learner.create_state(jax.random.PRNGKey(0), batch_size=2)
-    server = CEMPolicyServer(learner, state.train_state, max_batch=4,
+    server = CEMPolicyServer(learner, state.train_state, max_batch=8,
                              max_wait_us=10_000, seed=0)
     yield learner, server
     server.close()
@@ -364,6 +365,30 @@ class TestCEMPolicyServer:
     assert all(results[i].shape == (1, 2) for i in results)
     assert srv.batcher.dispatches - d0 < 4
 
+  @pytest.mark.parametrize("rows", [1, 8])
+  def test_direct_requests_compile_nothing_after_warmup(self, server,
+                                                         rows):
+    """What the control loop pays after a restart: the server's warmup
+    compiled the CEM program of every bucket, so a direct request of
+    one row, or of a full bucket, finds its program."""
+    from tensor2robot_tpu.startup import CompileWatch
+
+    learner, srv = server
+    obs = specs.make_random_tensors(
+        learner.observation_specification(), batch_size=rows, seed=rows)
+    keys = list(jax.random.split(jax.random.PRNGKey(11), 3))
+    assert srv.engine.compiled_buckets == (1, 2, 4, 8)
+    before = engine_lib.compile_count()
+    with CompileWatch() as watch:
+      actions = [srv.select_actions_direct(obs, key) for key in keys]
+    assert engine_lib.compile_count() == before
+    assert watch.backend_compiles == 0 and watch.cache_requests == 0
+    assert all(a.shape == (rows, 2) for a in actions)
+    assert all(np.all(np.abs(a) <= 1.0) for a in actions)
+    # The request's key decides the draw: the server holds no hidden
+    # state between direct requests.
+    assert not np.array_equal(actions[0], actions[1])
+
 
 class TestServingAssets:
   """The export→fleet serving contract: the exporter ships its
@@ -397,23 +422,3 @@ class TestServingAssets:
     predictor = SavedModelPredictor(str(tmp_path / "export"))
     assert predictor.restore(timeout_secs=0)
     assert predictor.serving_metadata is None
-
-
-class TestServingBenchSmoke:
-  """`bench.py --serving --dry-run` must keep working on CPU — it is
-  the tier-1 guard on the serving bench path itself."""
-
-  def test_dry_run_smoke(self):
-    import importlib
-    import sys as _sys
-
-    _sys.path.insert(0, ".")
-    try:
-      bench = importlib.import_module("bench")
-    finally:
-      _sys.path.pop(0)
-    detail = bench.bench_serving(dry_run=True)
-    assert detail["batch_1"]["calls"] >= 3
-    assert detail["batch_1"]["p50_ms"] > 0
-    assert detail["recompiles_during_timed_phases"] == 0
-    assert detail["microbatcher_curve"]

@@ -7,8 +7,8 @@ previous contract required the parent module to be literally named
 ``moe``, which silently replicated experts under any other mount —
 the round-5 advisor finding these tests regression-pin.) Indivisible
 expert dims raise instead of silently falling back.
-`xplane.is_async_window` (the compute-table filter behind the bench's
-per-op attribution) gets direct unit coverage too.
+`xplane.is_async_window` (the compute-table filter behind that
+module's per-op attribution) gets direct unit coverage too.
 """
 
 import numpy as np

@@ -10,8 +10,8 @@ envelope, the merge, and the flight recorder — pinned contracts:
     crash-policy harness of tests/test_fleet.py);
   * the whole telemetry package imports WITHOUT jax (actor/worker
     processes record spans — the IMP401 worker-safe property);
-  * the tracing fast paths stay cheap (the overhead gate's in-process
-    twin: the bench --telemetry axis gates the steps/s A/B at <2%);
+  * the tracing fast paths stay cheap (in-process; what tracing costs
+    a step on the chip: PERF.md §6, PR 38);
   * every `metrics_<tag>.jsonl` record the tier-1 trainers produce is
     the unified `{step, wall, role, payload}` envelope.
 """
@@ -113,8 +113,7 @@ class TestSpanRing:
     assert len(spans) == 3 * tcore.FLUSH_BATCH
 
   def test_span_fast_paths_are_cheap(self):
-    """The in-process overhead pin (the steps/s twin lives in
-    bench --telemetry): disabled spans must be ~free, enabled
+    """The in-process overhead pin: disabled spans must be ~free, enabled
     memory-mode spans micro-scale. Bounds are generous for loaded CI
     hosts — they catch a lock or an I/O call landing on the hot path,
     not microarchitecture."""
