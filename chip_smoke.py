@@ -384,8 +384,11 @@ def _check_cem_select(sizes: Sizes, rng) -> None:
 def _check_delta_rule(sizes: Sizes, rng) -> None:
   """The gated delta rule's three programs against what they replace:
   the walk's kernel pair (`ops/delta_rule_walk.py`) against
-  `scan_walk`, forward and the five cotangents, and the fused forward
-  program (`ops/delta_rule_fused.py`) against the prepared rule."""
+  `scan_walk`, forward and the five cotangents, with `end_decay` a
+  head's scalar (the Qwen3-Next cell's) and a vector over the key
+  channels (the Kimi-Linear cell's: the state's rows scaled), and the
+  fused forward program (`ops/delta_rule_fused.py`) against the
+  prepared rule."""
   import jax
   import jax.numpy as jnp
   from tensor2robot_tpu.layers import gated_delta
@@ -400,25 +403,28 @@ def _check_delta_rule(sizes: Sizes, rng) -> None:
   # 128 chunks of operands that no preparation has matched.
   keys = [(0.1 * gated_delta.l2_normalize(normal(n, b, h, chunk, d))
            ).astype(dtype) for _ in range(3)]
-  operands = (normal(n, b, h, chunk, d), *keys,
-              jnp.asarray(rng.uniform(0.5, 0.95, (n, b, h)), jnp.float32))
   probes = [normal(n, b, h, chunk, d) for _ in range(2)]
   kernels = lambda *x: delta_rule_walk.walk(  # noqa: E731
       *x, interpret=sizes.interpret)
-
-  def both(walk):
-    scalar = lambda *x: sum(  # noqa: E731
-        jnp.sum(out * probe) for out, probe in zip(walk(*x), probes))
-    return jax.jit(walk)(*operands) + jax.jit(jax.grad(
-        scalar, argnums=(0, 1, 2, 3, 4)))(*operands)
-
   names = ("new", "carried", "d_writes", "d_k_decayed", "d_q_decayed",
            "d_k_to_end", "d_end_decay")
-  errs = {name: _max_err(got, want) for name, got, want in zip(
-      names, both(kernels), both(gated_delta.scan_walk))}
-  _emit("kernel", name="delta_rule_walk fwd+bwd", shape=shape, errs=errs)
-  if not max(errs.values()) < KERNEL_BAR:
-    raise RuntimeError(f"delta_rule_walk over the bar: {errs}")
+  for end_decay, channels in (("scalar", ()), ("vector", (d,))):
+    operands = (normal(n, b, h, chunk, d), *keys, jnp.asarray(
+        rng.uniform(0.5, 0.95, (n, b, h) + channels), jnp.float32))
+
+    def both(walk):
+      scalar = lambda *x: sum(  # noqa: E731
+          jnp.sum(out * probe) for out, probe in zip(walk(*x), probes))
+      return jax.jit(walk)(*operands) + jax.jit(jax.grad(
+          scalar, argnums=(0, 1, 2, 3, 4)))(*operands)
+
+    errs = {name: _max_err(got, want) for name, got, want in zip(
+        names, both(kernels), both(gated_delta.scan_walk))}
+    _emit("kernel", name="delta_rule_walk fwd+bwd",
+          shape=dict(shape, end_decay=end_decay), errs=errs)
+    if not max(errs.values()) < KERNEL_BAR:
+      raise RuntimeError(
+          f"delta_rule_walk ({end_decay} end_decay) over the bar: {errs}")
 
   q = gated_delta.l2_normalize(normal(b, t, h, d)) * d ** -0.5
   k = gated_delta.l2_normalize(normal(b, t, h, d))

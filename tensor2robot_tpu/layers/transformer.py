@@ -327,15 +327,21 @@ class LatentAttention(nn.Module):
   `.materialised_traces` count the traced calls that took each). The
   materialised form is the published training form; the absorbed
   (latent-space) form that serving wants is not here.
+
+  `q_lora_rank` None: the query is one projection `q_proj` to
+  [T, H, nope + rope], no latent and no norm. `rope_theta` None:
+  nothing is turned (a model whose other layers place the positions),
+  and the `rope` dims stay what the weights make them: one key head
+  from `kv_a_proj` that all heads share, beside each head's own.
   """
 
   num_heads: int
-  q_lora_rank: int
+  q_lora_rank: Optional[int]
   kv_lora_rank: int
   qk_nope_head_dim: int
   qk_rope_head_dim: int
   v_head_dim: int
-  rope_theta: float = 1e4
+  rope_theta: Optional[float] = 1e4
   rope_interleave: bool = True
   eps: float = 1e-6
   attention_impl: str = "auto"
@@ -357,11 +363,16 @@ class LatentAttention(nn.Module):
                     self.rope_interleave).astype(self.dtype)
 
     with jax.named_scope("mla/q_proj"):
-      c_q = RMSNorm(self.eps, name="q_a_norm")(
-          dense("q_a_proj", self.q_lora_rank)(x))
-      q = dense("q_b_proj", h * (nope + rope))(
-          c_q.astype(self.dtype)).reshape(b, t, h, nope + rope)
-      q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+      if self.q_lora_rank is None:
+        q = dense("q_proj", h * (nope + rope))(x)
+      else:
+        c_q = RMSNorm(self.eps, name="q_a_norm")(
+            dense("q_a_proj", self.q_lora_rank)(x))
+        q = dense("q_b_proj", h * (nope + rope))(c_q.astype(self.dtype))
+      q = q.reshape(b, t, h, nope + rope)
+      if self.rope_theta is not None:
+        q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])],
+                            axis=-1)
     with jax.named_scope("mla/kv_proj"):
       c_kv, k_r = jnp.split(
           dense("kv_a_proj", self.kv_lora_rank + rope)(x),
@@ -371,7 +382,10 @@ class LatentAttention(nn.Module):
           dense("kv_b_proj", h * (nope + self.v_head_dim))(
               c_kv.astype(self.dtype)
           ).reshape(b, t, h, nope + self.v_head_dim), [nope], axis=-1)
-      k_r = jnp.broadcast_to(turn(k_r[:, :, None, :]), (b, t, h, rope))
+      k_r = k_r[:, :, None, :]
+      if self.rope_theta is not None:
+        k_r = turn(k_r)
+      k_r = jnp.broadcast_to(k_r, (b, t, h, rope))
       k = jnp.concatenate([k_n, k_r], axis=-1)
     with jax.named_scope("mla/attend"):
       impl = _resolve_impl(self.attention_impl)

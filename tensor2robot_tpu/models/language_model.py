@@ -3,7 +3,7 @@ cross-entropy of every position's successor out, trained by
 `train_eval_model` like every other family.
 
 The network is an embedding, a `layers/transformer.SequenceTrunk` whose
-blocks are data, a final norm and an untied head. Three families build
+blocks are data, a final norm and an untied head. Four families build
 it, each with the names of its published configuration's keys as
 constructor arguments, so a configuration file and the model read
 alike; what they share (specs, the loss in blocks, the reduction of the
@@ -36,6 +36,17 @@ routing counters, the per-layer checkpointing) is `_LanguageModel`'s:
   whether its feed-forward is a dense gated unit or experts chosen by
   sigmoid score beside an ungated shared expert.
 
+- `ChannelGatedDeltaLanguageModel`, the delta rule with a decay per
+  key channel beside latent attention without positions as
+  Kimi-Linear publishes it (`train_kimi_linear.gin`): the 1-based lists
+  `linear_attn_config["kda_layers"]` and `["full_attn_layers"]` say
+  which layers mix with `layers/gated_delta.KimiDeltaAttention` and
+  which with `LatentAttention` (no query latent; with `mla_use_nope`
+  nothing is turned: the linear layers place the positions); the first
+  `first_k_dense_replace` feed-forwards are dense, the others experts
+  chosen by sigmoid score plus a selection bias beside an ungated
+  shared expert.
+
 A chip's share of an expert-parallel deployment (docs/SEQUENCE.md):
 `num_experts` is the router's width, `experts_held` how many of them
 this model holds from `first_expert` on; `vocab_size` is the slice of
@@ -57,7 +68,10 @@ import numpy as np
 
 from tensor2robot_tpu import config as gin
 from tensor2robot_tpu.data.abstract_input_generator import Mode
-from tensor2robot_tpu.layers.gated_delta import GatedDeltaNet
+from tensor2robot_tpu.layers.gated_delta import (
+    GatedDeltaNet,
+    KimiDeltaAttention,
+)
 from tensor2robot_tpu.layers.transformer import (
     GatedAttention,
     GatedMLP,
@@ -641,6 +655,150 @@ class WindowedAttentionLanguageModel(_LanguageModel):
           routed_scaling_factor=self._moe_routed_scaling_factor,
           expert_width=self._moe_intermediate_size,
           shared_width=self._shared_expert_intermediate_size,
+          shared_gated=False, dtype=dtype)
+    return TransformerBlock(norm="rms", norm_eps=eps, mixer=mixer,
+                            ffn=ffn, dtype=dtype)
+
+
+# Kimi-Linear's published layer lists, 1-based: 27 layers of period 4,
+# three KDA layers then one of latent attention; the last is layer 27.
+_KIMI_LINEAR_ATTN = {
+    "full_attn_layers": [4, 8, 12, 16, 20, 24, 27],
+    "head_dim": 128,
+    "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19,
+                   21, 22, 23, 25, 26],
+    "num_heads": 32,
+    "short_conv_kernel_size": 4}
+
+
+@gin.configurable
+class ChannelGatedDeltaLanguageModel(_LanguageModel):
+  """A language model whose layers mix by the delta rule with a decay
+  per key channel (Kimi Delta Attention) or by latent attention, over a
+  leading dense feed-forward and mixtures of experts (the module's
+  docstring); the defaults are Kimi-Linear-48B-A3B's published
+  configuration, and the layer lists are as long as the published
+  depth: a model of fewer layers reads their head."""
+
+  def __init__(self,
+               vocab_size: int = 163840,
+               sequence_length: int = 8192,
+               hidden_size: int = 2304,
+               num_hidden_layers: int = 27,
+               linear_attn_config: Optional[Dict[str, Any]] = None,
+               num_attention_heads: int = 32,
+               q_lora_rank: Optional[int] = None,
+               kv_lora_rank: int = 512,
+               qk_nope_head_dim: int = 128,
+               qk_rope_head_dim: int = 64,
+               v_head_dim: int = 128,
+               mla_use_nope: bool = True,
+               rope_theta: float = 10000.0,
+               first_k_dense_replace: int = 1,
+               intermediate_size: int = 9216,
+               num_experts: int = 256,
+               experts_held: Optional[int] = None,
+               first_expert: int = 0,
+               num_experts_per_token: int = 8,
+               moe_renormalize: bool = True,
+               moe_router_activation_func: str = "sigmoid",
+               num_expert_group: int = 1,
+               topk_group: int = 1,
+               routed_scaling_factor: float = 2.446,
+               num_shared_experts: int = 1,
+               moe_intermediate_size: int = 1024,
+               rms_norm_eps: float = 1e-5,
+               attention_impl: str = "auto",
+               loss_block: int = 4096,
+               device_dtype=jnp.bfloat16,
+               remat_policy: Optional[str] = "full",
+               **kwargs):
+    """`linear_attn_config` is the published block: `kda_layers` and
+    `full_attn_layers` (1-based, together every layer once), and the
+    linear layers' `num_heads`, `head_dim` and `short_conv_kernel_size`;
+    it defaults to Kimi-Linear's. `experts_held` defaults to all
+    `num_experts`. The router chooses among all experts (one group, as
+    published; more are not here) by score plus a selection bias, a
+    parameter that no gradient reaches, and weighs by the score alone.
+    With `mla_use_nope` the latent attention turns nothing; without
+    it, its `qk_rope_head_dim` dims by `rope_theta`."""
+    super().__init__(
+        vocab_size=vocab_size, sequence_length=sequence_length,
+        hidden_size=hidden_size, num_hidden_layers=num_hidden_layers,
+        rms_norm_eps=rms_norm_eps, attention_impl=attention_impl,
+        loss_block=loss_block, device_dtype=device_dtype,
+        remat_policy=remat_policy, **kwargs)
+    linear = (_KIMI_LINEAR_ATTN if linear_attn_config is None
+              else linear_attn_config)
+    if (num_expert_group, topk_group) != (1, 1):
+      raise ValueError("group-limited routing is not implemented: "
+                       f"num_expert_group={num_expert_group}, "
+                       f"topk_group={topk_group}")
+    kda, full = set(linear["kda_layers"]), set(linear["full_attn_layers"])
+    wanted = set(range(1, num_hidden_layers + 1))
+    if kda & full or not wanted <= kda | full:
+      raise ValueError(
+          f"kda_layers and full_attn_layers must name each of the "
+          f"{num_hidden_layers} layers once (1-based): "
+          f"{sorted(kda & full)} are in both, "
+          f"{sorted(wanted - kda - full)} in neither")
+    self._linear_attn_config = linear
+    self._num_attention_heads = num_attention_heads
+    self._q_lora_rank = q_lora_rank
+    self._kv_lora_rank = kv_lora_rank
+    self._qk_nope_head_dim = qk_nope_head_dim
+    self._qk_rope_head_dim = qk_rope_head_dim
+    self._v_head_dim = v_head_dim
+    self._mla_use_nope = mla_use_nope
+    self._rope_theta = rope_theta
+    self._first_k_dense_replace = first_k_dense_replace
+    self._intermediate_size = intermediate_size
+    self._num_experts = num_experts
+    self._experts_held = (num_experts if experts_held is None
+                          else experts_held)
+    self._first_expert = first_expert
+    self._num_experts_per_token = num_experts_per_token
+    self._moe_renormalize = moe_renormalize
+    self._moe_router_activation_func = moe_router_activation_func
+    self._num_expert_group = num_expert_group
+    self._topk_group = topk_group
+    self._routed_scaling_factor = routed_scaling_factor
+    self._num_shared_experts = num_shared_experts
+    self._moe_intermediate_size = moe_intermediate_size
+
+  def _block(self, layer: int) -> TransformerBlock:
+    dtype, eps = self.device_dtype, self._rms_norm_eps
+    linear = self._linear_attn_config
+    if layer + 1 in linear["kda_layers"]:
+      mixer = KimiDeltaAttention(
+          num_heads=linear["num_heads"], head_dim=linear["head_dim"],
+          conv_kernel=linear["short_conv_kernel_size"], eps=eps,
+          dtype=dtype)
+    else:
+      mixer = LatentAttention(
+          num_heads=self._num_attention_heads,
+          q_lora_rank=self._q_lora_rank,
+          kv_lora_rank=self._kv_lora_rank,
+          qk_nope_head_dim=self._qk_nope_head_dim,
+          qk_rope_head_dim=self._qk_rope_head_dim,
+          v_head_dim=self._v_head_dim,
+          rope_theta=None if self._mla_use_nope else self._rope_theta,
+          eps=eps, attention_impl=self._attention_impl, dtype=dtype)
+    if layer < self._first_k_dense_replace:
+      ffn = GatedMLP(width=self._intermediate_size, dtype=dtype)
+    else:
+      ffn = SparseMoE(
+          num_experts=self._num_experts,
+          experts_held=self._experts_held,
+          first_expert=self._first_expert,
+          k=self._num_experts_per_token,
+          normalise_top_k=self._moe_renormalize,
+          scoring=self._moe_router_activation_func,
+          selection_bias=True,
+          routed_scaling_factor=self._routed_scaling_factor,
+          expert_width=self._moe_intermediate_size,
+          shared_width=(self._num_shared_experts
+                        * self._moe_intermediate_size),
           shared_gated=False, dtype=dtype)
     return TransformerBlock(norm="rms", norm_eps=eps, mixer=mixer,
                             ffn=ffn, dtype=dtype)

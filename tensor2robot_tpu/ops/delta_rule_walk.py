@@ -34,6 +34,17 @@ cotangent leaves, as a row of Dv lanes ([N, G, 1, Dv]): a [1, Dv] tile
 broadcasts against the state's sublanes with no scalar memory, and the
 lane sum of the cotangent is XLA's.
 
+Or it is a vector over the key channels ([N, G, Dk]: the state's ROWS
+are scaled, `layers/gated_delta.py`'s decay per channel). A row of Dk
+lanes scales columns, not rows, so these calls keep the state
+transposed, [Dv, Dk], in scratch and in the saved states: the row
+[1, Dk] then broadcasts against its sublanes as the scalar's does, the
+cotangent is a sublane sum that leaves as [1, Dk], and the six
+products are the same with the state's two axes exchanged in their
+dimension numbers. One kernel body each way; which layout a call has
+is read off `end_decay`'s rank, and the scalar's calls trace the
+operations they always have.
+
 `tiles()` says whether shapes are ones Mosaic takes; which path runs is
 `gated_delta_rule`'s to read off its input. `interpret=True` runs both
 kernels in the Pallas interpreter: how the CPU tests check them.
@@ -86,13 +97,29 @@ _NT = ((1,), (1,))  # x @ y^T
 _TN = ((0,), (0,))  # x^T @ y
 
 
+# The products against the state S [Dk, Dv], which a call whose
+# `end_decay` is a vector holds as S^T (`transposed`).
+def _through(x, state, transposed):  # x S: [C, Dk] -> [C, Dv]
+  return _dot(x, state, _NT if transposed else _NN)
+
+
+def _back_through(x, state, transposed):  # x S^T: [C, Dv] -> [C, Dk]
+  return _dot(x, state, _NN if transposed else _NT)
+
+
+def _outer(x, y, transposed):  # x^T y: [C, Dk], [C, Dv] -> the state's
+  return _dot(y, x, _TN) if transposed else _dot(x, y, _TN)
+
+
 def _forward_kernel(writes_ref, k_decayed_ref, q_decayed_ref,
                     k_to_end_ref, end_decay_ref, new_ref, carried_ref,
-                    *states_ref_and_scratch, heads: int):
+                    *states_ref_and_scratch, heads: int,
+                    transposed: bool):
   """One chunk of `heads` heads. Refs [1, heads, C, D] (end_decay
   [1, heads, 1, Dv], states [1, heads, Dk, Dv], there only where a
   backward pass will read them); `state_scr` [heads, Dk, Dv] float32
-  lives across the chunk axis."""
+  lives across the chunk axis. `transposed`: end_decay [1, heads, 1,
+  Dk], the states and the scratch [.., Dv, Dk]."""
   *states_ref, state_scr = states_ref_and_scratch
 
   @pl.when(pl.program_id(1) == 0)
@@ -105,18 +132,21 @@ def _forward_kernel(writes_ref, k_decayed_ref, q_decayed_ref,
     for ref in states_ref:
       ref[0, h] = state
     operand = state.astype(dtype)
-    new = writes_ref[0, h] - _dot(k_decayed_ref[0, h], operand, _NN)
+    new = writes_ref[0, h] - _through(k_decayed_ref[0, h], operand,
+                                      transposed)
     new_ref[0, h] = new
-    carried_ref[0, h] = _dot(q_decayed_ref[0, h], operand, _NN)
+    carried_ref[0, h] = _through(q_decayed_ref[0, h], operand,
+                                 transposed)
     state_scr[h] = (state * end_decay_ref[0, h]
-                    + _dot(k_to_end_ref[0, h], new.astype(dtype), _TN))
+                    + _outer(k_to_end_ref[0, h], new.astype(dtype),
+                             transposed))
 
 
 def _backward_kernel(k_decayed_ref, q_decayed_ref, k_to_end_ref,
                      end_decay_ref, new_ref, states_ref, d_new_ref,
                      d_carried_ref, d_writes_ref, d_k_decayed_ref,
                      d_q_decayed_ref, d_k_to_end_ref, d_end_decay_ref,
-                     d_state_scr, *, heads: int):
+                     d_state_scr, *, heads: int, transposed: bool):
   """The chunk's transpose; the grid's chunk axis runs from the last
   chunk to the first (the index maps reverse it). `d_state_scr` holds
   the cotangent of the state at the chunk's END on entry and of the
@@ -132,28 +162,42 @@ def _backward_kernel(k_decayed_ref, q_decayed_ref, k_to_end_ref,
     d_end_operand = d_end.astype(dtype)
     state = states_ref[0, h]
     operand = state.astype(dtype)
-    d_new = d_new_ref[0, h] + _dot(k_to_end_ref[0, h], d_end_operand,
-                                   _NN)
+    d_new = d_new_ref[0, h] + _through(k_to_end_ref[0, h],
+                                       d_end_operand, transposed)
     d_writes_ref[0, h] = d_new
     d_new = d_new.astype(dtype)
     d_carried = d_carried_ref[0, h].astype(dtype)
-    d_k_decayed_ref[0, h] = (-_dot(d_new, operand, _NT)).astype(dtype)
-    d_q_decayed_ref[0, h] = _dot(d_carried, operand, _NT).astype(dtype)
-    d_k_to_end_ref[0, h] = _dot(new_ref[0, h].astype(dtype),
-                                d_end_operand, _NT).astype(dtype)
+    d_k_decayed_ref[0, h] = (-_back_through(d_new, operand, transposed)
+                             ).astype(dtype)
+    d_q_decayed_ref[0, h] = _back_through(d_carried, operand, transposed
+                                          ).astype(dtype)
+    d_k_to_end_ref[0, h] = _back_through(
+        new_ref[0, h].astype(dtype), d_end_operand, transposed
+    ).astype(dtype)
+    # Over the state's sublanes: all of it for a scalar (XLA sums the
+    # lanes), the value axis for a vector, whose state is transposed.
     d_end_decay_ref[0, h] = jnp.sum(state * d_end, axis=0,
                                     keepdims=True)
     d_state_scr[h] = (d_end * end_decay_ref[0, h]
-                      + _dot(q_decayed_ref[0, h], d_carried, _TN)
-                      - _dot(k_decayed_ref[0, h], d_new, _TN))
+                      + _outer(q_decayed_ref[0, h], d_carried, transposed)
+                      - _outer(k_decayed_ref[0, h], d_new, transposed))
 
 
 def _fold(x):  # [N, B, H, ...] -> [N, B * H, ...]
   return x.reshape((x.shape[0], x.shape[1] * x.shape[2]) + x.shape[3:])
 
 
-def _lanes(x, dv):  # [N, G] -> [N, G, 1, Dv]
+def _lanes(x, dv):  # [N, G] -> [N, G, 1, Dv]; [N, G, Dk] -> [N, G, 1, Dk]
+  if x.ndim == 3:
+    return x[:, :, None, :]
   return jnp.broadcast_to(x[..., None, None], x.shape + (1, dv))
+
+
+def _state_layout(end_decay, dk, dv):
+  """(whether the call holds the state transposed, the state's shape):
+  read off `end_decay`'s rank, [N, G] or [N, G, Dk]."""
+  transposed = end_decay.ndim == 3
+  return transposed, (dv, dk) if transposed else (dk, dv)
 
 
 def _forward(writes, k_decayed, q_decayed, k_to_end, end_decay, block,
@@ -162,18 +206,20 @@ def _forward(writes, k_decayed, q_decayed, k_to_end, end_decay, block,
   `save_states`."""
   n, g, chunk, dv = writes.shape
   dk = k_decayed.shape[-1]
+  transposed, state = _state_layout(end_decay, dk, dv)
   spec = lambda *tile: pl.BlockSpec(  # noqa: E731
       (1, block) + tile, lambda j, i: (i, j, 0, 0))
   return pl.pallas_call(
-      functools.partial(_forward_kernel, heads=block),
+      functools.partial(_forward_kernel, heads=block,
+                        transposed=transposed),
       grid=(pl.cdiv(g, block), n),
       in_specs=[spec(chunk, dv), spec(chunk, dk), spec(chunk, dk),
-                spec(chunk, dk), spec(1, dv)],
+                spec(chunk, dk), spec(1, state[1])],
       out_specs=[spec(chunk, dv), spec(chunk, dv)]
-      + [spec(dk, dv)] * save_states,
+      + [spec(*state)] * save_states,
       out_shape=[jax.ShapeDtypeStruct((n, g, chunk, dv), jnp.float32)] * 2
-      + [jax.ShapeDtypeStruct((n, g, dk, dv), jnp.float32)] * save_states,
-      scratch_shapes=[pltpu.VMEM((block, dk, dv), jnp.float32)],
+      + [jax.ShapeDtypeStruct((n, g) + state, jnp.float32)] * save_states,
+      scratch_shapes=[pltpu.VMEM((block,) + state, jnp.float32)],
       # `new` over `writes`: a step reads its tile before it writes it.
       input_output_aliases={0: 0},
       compiler_params=_COMPILER_PARAMS,
@@ -186,30 +232,34 @@ def _backward(k_decayed, q_decayed, k_to_end, end_decay, new, states,
   n, g, chunk, dv = new.shape
   dk = k_decayed.shape[-1]
   dtype = k_decayed.dtype
+  transposed, state = _state_layout(end_decay, dk, dv)
   spec = lambda *tile: pl.BlockSpec(  # noqa: E731
       (1, block) + tile, lambda j, i: (n - 1 - i, j, 0, 0))
   *cotangents, d_end_decay = pl.pallas_call(
-      functools.partial(_backward_kernel, heads=block),
+      functools.partial(_backward_kernel, heads=block,
+                        transposed=transposed),
       grid=(pl.cdiv(g, block), n),
       in_specs=[spec(chunk, dk), spec(chunk, dk), spec(chunk, dk),
-                spec(1, dv), spec(chunk, dv), spec(dk, dv),
+                spec(1, state[1]), spec(chunk, dv), spec(*state),
                 spec(chunk, dv), spec(chunk, dv)],
       out_specs=[spec(chunk, dv), spec(chunk, dk), spec(chunk, dk),
-                 spec(chunk, dk), spec(1, dv)],
+                 spec(chunk, dk), spec(1, state[1])],
       out_shape=[
           jax.ShapeDtypeStruct((n, g, chunk, dv), jnp.float32),
           jax.ShapeDtypeStruct((n, g, chunk, dk), dtype),
           jax.ShapeDtypeStruct((n, g, chunk, dk), dtype),
           jax.ShapeDtypeStruct((n, g, chunk, dk), dtype),
-          jax.ShapeDtypeStruct((n, g, 1, dv), jnp.float32),
+          jax.ShapeDtypeStruct((n, g, 1, state[1]), jnp.float32),
       ],
-      scratch_shapes=[pltpu.VMEM((block, dk, dv), jnp.float32)],
+      scratch_shapes=[pltpu.VMEM((block,) + state, jnp.float32)],
       # d `writes` over d `new`, likewise.
       input_output_aliases={6: 0},
       compiler_params=_COMPILER_PARAMS,
       interpret=interpret,
   )(k_decayed, q_decayed, k_to_end, _lanes(end_decay, dv), new, states,
     d_new, d_carried)
+  if transposed:
+    return (*cotangents, d_end_decay[:, :, 0, :])
   return (*cotangents, jnp.sum(d_end_decay, axis=(-2, -1)))
 
 
@@ -246,7 +296,8 @@ def walk(writes: jax.Array, k_decayed: jax.Array, q_decayed: jax.Array,
   """The walk over chunks, with the scan's own signature: `writes`
   [N, B, H, C, Dv] float32; `k_decayed`, `q_decayed`, `k_to_end`
   [N, B, H, C, Dk] in the products' dtype; `end_decay` [N, B, H]
-  float32. Returns (`new`, `carried`), [N, B, H, C, Dv] float32.
+  float32, or [N, B, H, Dk] (a decay for every row of the state).
+  Returns (`new`, `carried`), [N, B, H, C, Dv] float32.
   Differentiable in all five. `block` heads a grid step (of the B * H
   the call has; it need not divide them); left out, `head_block`'s."""
   shape = writes.shape

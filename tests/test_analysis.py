@@ -1265,7 +1265,7 @@ class TestGinValidation:
     )
     package = os.path.join(REPO_ROOT, "tensor2robot_tpu")
     configs = discover_configs([package])
-    assert len(configs) == 23, configs  # re-pin when shipping new ones
+    assert len(configs) == 24, configs  # re-pin when shipping new ones
     found = run_gin_rules([package], REPO_ROOT)
     assert found == [], [f.render() for f in found]
 

@@ -508,13 +508,20 @@ def test_lm_mla_step_mfu_on_made_up_records():
 def test_benchmark_json_has_the_new_entries_and_no_other():
   with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     bench = json.load(f)
-  # ISSUE 43 appended a third family's configuration, its cell and
-  # its seven metrics (tests/test_windowed_language_model.py).
-  assert bench["configs"][-2]["name"] == "joyai_llm_flash_ep16"
-  assert bench["workloads"][-2] == {
+  # ISSUE 47 appended a fourth family's configuration, its cell and
+  # its nine metrics (tests/test_channel_gated_language_model.py),
+  # ISSUE 43 a third's with its seven
+  # (tests/test_windowed_language_model.py).
+  assert bench["configs"][-3]["name"] == "joyai_llm_flash_ep16"
+  assert bench["workloads"][-3] == {
       "name": CELL, "config": "joyai_llm_flash_ep16",
       "traffic": "train_eval", "chips": 1,
-      "why": bench["workloads"][-2]["why"]}
+      "why": bench["workloads"][-3]["why"]}
+  newest = [m for m in bench["per_layer"]
+            if m["name"].startswith("lm_kda_")]
+  assert len(newest) == 9
+  assert bench["per_layer"][-9:] == newest
+  bench["per_layer"] = bench["per_layer"][:-9]
   # ISSUE 45 appended one entry of the flash kernel's backward pass,
   # which lists this cell from its birth
   # (benchmark/tests/test_lm_flash_backward_fused_share.py).

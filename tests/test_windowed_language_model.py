@@ -618,14 +618,16 @@ def test_rehearsed_cell_with_a_part_taken_out_is_not_correct(
 def test_benchmark_json_has_the_new_entries_and_no_other():
   with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     bench = json.load(f)
-  assert bench["configs"][-1]["name"] == "laguna_xs2_ep16"
-  assert bench["configs"][-1]["reduced"] == [
+  # ISSUE 47 appended a fourth family's configuration and cell
+  # (tests/test_channel_gated_language_model.py).
+  assert bench["configs"][-2]["name"] == "laguna_xs2_ep16"
+  assert bench["configs"][-2]["reduced"] == [
       "num_hidden_layers", "experts_held", "vocab_size"]
-  assert bench["workloads"][-1] == {
+  assert bench["workloads"][-2] == {
       "name": CELL, "config": "laguna_xs2_ep16",
       "traffic": "train_eval", "chips": 1,
-      "why": bench["workloads"][-1]["why"]}
-  assert len(bench["workloads"]) == 5
+      "why": bench["workloads"][-2]["why"]}
+  assert len(bench["workloads"]) == 6
   # Seven of ISSUE 43's nine: a device time of the sliding layers, and
   # the rest that would be reckoned from it, wait for the scope
   # `window_attention` in the reduction's list (PERF.md section 7 (0)).
@@ -647,8 +649,11 @@ def test_benchmark_json_has_the_new_entries_and_no_other():
   assert bench["per_layer"][first:first + 7] == new
   for metric in bench["per_layer"][:first]:
     assert CELL not in metric["workloads"], metric["name"]
-  assert [m["name"] for m in bench["per_layer"][first + 7:]] == [
-      "lm_flash_backward_fused_share"]
+  later = [m["name"] for m in bench["per_layer"][first + 7:]]
+  assert later[0] == "lm_flash_backward_fused_share"
+  # ISSUE 47's nine, of the fourth family's cell alone.
+  assert len(later) == 10
+  assert all(name.startswith("lm_kda_") for name in later[1:])
 
 
 # --- the banded programs compiled for the chip -------------------------
